@@ -152,20 +152,14 @@ def bench_rpc_tree(n_peers: int = 4, sizes=(2**16, 2**20, 2**23)):
 
 
 def bench_ici_psum(sizes=(2**20, 2**23, 2**25)):
-    from moolib_tpu.utils.benchmark import install_watchdog
-
-    watchdog = install_watchdog("ici_psum_gbps")
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
     from moolib_tpu.parallel.mesh import make_mesh
-    from moolib_tpu.utils.jaxenv import shard_map
 
     n = len(jax.devices())
-    if watchdog is not None:
-        watchdog.cancel()
     # A psum over a virtual CPU mesh measures XLA:CPU thread scheduling,
     # not ICI — label it so it cannot be read as an interconnect number
     # (VERDICT r3 weak #2).
@@ -189,7 +183,7 @@ def bench_ici_psum(sizes=(2**20, 2**23, 2**25)):
             def inner(x):
                 return jax.lax.psum(x, "dp")
 
-            return shard_map(
+            return jax.shard_map(
                 inner, mesh=mesh, in_specs=P("dp", None),
                 out_specs=P("dp", None),
             )(x)
@@ -213,8 +207,5 @@ def bench_ici_psum(sizes=(2**20, 2**23, 2**25)):
 
 
 if __name__ == "__main__":
-    from moolib_tpu.utils import ensure_platforms
-
-    ensure_platforms()  # honor JAX_PLATFORMS=cpu for the ICI leg
     bench_rpc_tree()
     bench_ici_psum()

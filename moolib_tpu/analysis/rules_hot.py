@@ -1,8 +1,8 @@
 """Hot-path device/host discipline rules (the hotlint family).
 
 The eight prior families police *inside-jit* mistakes; these police the
-host side of the step loop — the discipline PERF_ANALYSIS.md round 5
-established by hand: device->host reads are staged asynchronously
+host side of the step loop — the discipline round 5 established by
+hand: device->host reads are staged asynchronously
 (``copy_to_host_async`` via ``utils.stage_host_async``) and drained at
 log boundaries, state threads through donating jits, nothing blocks
 between an async dispatch and the device work that could overlap it.

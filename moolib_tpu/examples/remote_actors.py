@@ -104,9 +104,6 @@ def run_learner(cfg: RemoteConfig, listen: str = "127.0.0.1:0",
     """Serve inference + consume unrolls + train. ``ready_fn(addr)`` (if
     given) fires once every service is registered — use it to hand the
     bound address to actors race-free."""
-    from moolib_tpu.utils import ensure_platforms
-
-    ensure_platforms()
     import jax
     import jax.numpy as jnp
     import optax
@@ -264,10 +261,6 @@ def run_learner(cfg: RemoteConfig, listen: str = "127.0.0.1:0",
 def run_actor(cfg: RemoteConfig, learner_addr: str,
               max_seconds: Optional[float] = None) -> int:
     """Thin actor: local envs, remote policy. Returns env frames stepped."""
-    from moolib_tpu.utils import ensure_platforms
-
-    ensure_platforms()
-
     rpc = moolib_tpu.Rpc(f"actor-{moolib_tpu.create_uid()[:8]}")
     rpc.connect(learner_addr)
 

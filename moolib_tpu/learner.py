@@ -33,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .ops import vtrace
 from .parallel.mesh import batch_specs, dp_average_grads
-from .utils.jaxenv import shard_map
 
 __all__ = [
     "ImpalaConfig",
@@ -255,7 +254,7 @@ def make_impala_train_step(
             )
             return sgd(state, grads, metrics)
 
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(replicated, batch_specs(batch, batch_axes, axis_name)),
@@ -332,7 +331,7 @@ def make_grad_step(
             )
             return finish(grads, metrics)
 
-        return shard_map(
+        return jax.shard_map(
             inner,
             mesh=mesh,
             in_specs=(replicated, batch_specs(batch, batch_axes, axis_name)),

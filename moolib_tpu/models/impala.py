@@ -36,8 +36,8 @@ def space_to_depth(x: jax.Array, s: int) -> jax.Array:
 
     Trades spatial resolution for channel depth: the first conv's implicit-
     matmul contraction becomes K = kh*kw*C*s*s, multiplying MXU tile
-    occupancy by s^2 (PERF_ANALYSIS.md names narrow channels as the
-    measured-MFU ceiling). Pure data movement — XLA lowers it to a reshape/
+    occupancy by s^2 (tools/roofline.py: narrow channels cap the MXU tile
+    efficiency). Pure data movement — XLA lowers it to a reshape/
     transpose pair that fuses into the consuming conv's input layout.
     """
     if s == 1:

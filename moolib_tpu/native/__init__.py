@@ -3,8 +3,11 @@
 The reference ships its native layer as a pybind11 module compiled at
 install time (reference: CMakeLists.txt + src/moolib.cc). Here the extension
 is a single C++ translation unit compiled with the system toolchain on
-first use and cached next to the source; everything it accelerates has a
-pure-Python fallback, so the framework works (slower) without a compiler.
+first use and cached next to the source under a name that carries a hash of
+that source — a binary left over from another version of ``_native.cpp``
+(or copied in with scrambled mtimes) is never loaded. Everything it
+accelerates has a pure-Python fallback, so the framework works (slower)
+without a compiler; falling back is logged as a warning, never silently.
 
 Set ``MOOLIB_TPU_NO_NATIVE=1`` to force the pure-Python paths.
 """
@@ -13,6 +16,8 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import glob
+import hashlib
 import importlib.util
 import os
 import subprocess
@@ -36,18 +41,17 @@ _module = None
 
 
 def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     tag = sysconfig.get_config_var("SOABI") or "unknown"
-    return os.path.join(_DIR, f"_native.{tag}.so")
+    return os.path.join(_DIR, f"_native.{digest}.{tag}.so")
 
 
 def build_native(force: bool = False) -> Optional[str]:
-    """Compile the extension if needed; returns the .so path or None."""
+    """Compile the extension from ``_native.cpp`` as it is on disk unless a
+    build of exactly that source is cached; returns the .so path or None."""
     out = _so_path()
-    if (
-        not force
-        and os.path.exists(out)
-        and os.path.getmtime(out) >= os.path.getmtime(_SRC)
-    ):
+    if not force and os.path.exists(out):
         return out
     cxx = os.environ.get("CXX", "g++")
     include = sysconfig.get_paths()["include"]
@@ -64,10 +68,12 @@ def build_native(force: bool = False) -> Optional[str]:
             cmd, capture_output=True, text=True, timeout=120
         )
     except (OSError, subprocess.TimeoutExpired) as e:
-        log.info("native build unavailable (%s); using pure-Python paths", e)
+        log.warning(
+            "native build unavailable (%s); using pure-Python paths", e
+        )
         return None
     if proc.returncode != 0:
-        log.info(
+        log.warning(
             "native build failed; using pure-Python paths:\n%s",
             proc.stderr[-2000:],
         )
@@ -77,6 +83,12 @@ def build_native(force: bool = False) -> Optional[str]:
             pass
         return None
     os.replace(tmp, out)
+    for stale in glob.glob(os.path.join(_DIR, "_native.*.so")):
+        if stale != out:
+            try:
+                os.unlink(stale)  # builds of other source versions
+            except OSError:
+                pass
     return out
 
 
@@ -105,7 +117,7 @@ def get_native():
                     concurrent.futures.CancelledError):
                 raise  # never swallow task cancellation
             except Exception as e:  # corrupt cache, ABI mismatch, ...
-                log.info("native load failed (%s); rebuilding once", e)
+                log.warning("native load failed (%s); rebuilding once", e)
                 so = build_native(force=True)
                 if so is not None:
                     try:
@@ -118,7 +130,11 @@ def get_native():
                     except (asyncio.CancelledError,
                             concurrent.futures.CancelledError):
                         raise
-                    except Exception:
+                    except Exception as e2:
+                        log.warning(
+                            "native load failed after rebuild (%s); using "
+                            "pure-Python paths", e2,
+                        )
                         _module = None
         _cached = True
         if _module is not None:

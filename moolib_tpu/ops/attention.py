@@ -35,11 +35,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "dense_attention",
     "blockwise_attention",
     "flash_attention",
+    "resolve_backend",
     "attention",
 ]
 
@@ -202,25 +205,56 @@ def blockwise_attention(
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash-attention forward kernel
+# Pallas flash-attention kernels (forward, dQ, dK/dV)
 # ---------------------------------------------------------------------------
+#
+# Mosaic layout rules the kernels are written to (every value is 2-D):
+#
+# - a per-row statistic of a [rows, cols] score tile (running max, sum, lse,
+#   delta, the row axis' segment ids) lives LANE-REPLICATED as [rows, 128]
+#   — in VMEM scratch, in the kernel and in HBM — and is widened or narrowed
+#   to a tile's column count with :func:`_lanes`; a 1-D [rows] vector cannot
+#   be turned back into a column on the chip (lane -> sublane relayout);
+# - a per-column quantity is a [1, cols] row, broadcast along sublanes;
+# - q·kᵀ is an NT ``dot_general`` (contract both minor dims), never an
+#   in-kernel transpose. The dK/dV kernel works on the TRANSPOSED tile
+#   sᵀ = k·qᵀ [block_k, block_q] so that its products pᵀ·dO and dsᵀ·q are
+#   plain NN matmuls; per-query statistics are rows there and the key
+#   segment ids are the lane-replicated columns.
+
+_LANES = 128
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
 
 
-def _tile_bias(s_like, causal, qi, ki, block_q, block_k, seg_q, seg_k):
-    """Additive mask for one (q-block, k-block) tile — the ONE definition
-    shared by the forward and both backward kernels, so the masks can
-    never diverge."""
-    bias = jnp.zeros_like(s_like)
+def _lanes(x, n: int):
+    """Lane-replicated [rows, 128] -> [rows, n]."""
+    reps, rem = divmod(n, _LANES)
+    if rem == 0:
+        return jnp.tile(x, (1, reps))
+    if reps == 0:
+        return x[:, :n]
+    raise ValueError(
+        f"flash attention tile width {n} must be below or a multiple of "
+        f"{_LANES}"
+    )
+
+
+def _tile_mask(causal, q_axis, q_start, k_start, seg_rows, seg_cols):
+    """Visibility of one score tile — the ONE definition shared by the
+    forward and both backward kernels, so the masks can never diverge.
+    ``seg_rows`` [rows, cols] / ``seg_cols`` [1, cols] are the segment ids
+    of the tile's row and column axes; ``q_axis`` says which axis carries
+    the queries (0 for s, 1 for the dK/dV kernel's sᵀ)."""
+    mask = seg_rows == seg_cols
     if causal:
-        qpos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s_like.shape, 0
+        qpos = q_start + jax.lax.broadcasted_iota(
+            jnp.int32, mask.shape, q_axis
         )
-        kpos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s_like.shape, 1
+        kpos = k_start + jax.lax.broadcasted_iota(
+            jnp.int32, mask.shape, 1 - q_axis
         )
-        bias = jnp.where(qpos >= kpos, bias, _NEG_INF)
-    same = seg_q[:, None] == seg_k[None, :]
-    return jnp.where(same, bias, _NEG_INF)
+        mask = jnp.logical_and(mask, qpos >= kpos)
+    return mask
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
@@ -231,12 +265,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
     scratch. q/k/v blocks arrive pre-staged by BlockSpec. Also emits the
     per-row log-sum-exp (lse) the backward kernels rebuild P from."""
     ki = pl.program_id(2)
+    head_dim = q_ref.shape[-1]
 
     @pl.when(ki == 0)
     def _init():
-        m_sc[:] = jnp.full_like(m_sc, -jnp.inf)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
+        m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
 
     qi = pl.program_id(1)
     # Causal block skipping: a k-block strictly above the diagonal is fully
@@ -247,62 +282,59 @@ def _flash_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
 
     @pl.when(visible)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) / np.sqrt(q_ref.shape[-1])
+        q = q_ref[0].astype(jnp.float32) * (1.0 / np.sqrt(head_dim))
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = s + _tile_bias(
-            s, causal, qi, ki, block_q, block_k, seg_q_ref[0, 0],
-            seg_k_ref[0, 0],
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+        mask = _tile_mask(
+            causal, 0, qi * block_q, ki * block_k,
+            _lanes(seg_q_ref[0], block_k), seg_k_ref[0],
         )
+        s = jnp.where(mask, s, _NEG_INF)
 
-        m_prev = m_sc[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_sc[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         shift = jnp.where(m_new > _NEG_INF / 2, m_new, 0.0)
-        p = jnp.exp(s - shift[:, None])
+        p = jnp.exp(s - _lanes(shift, block_k))
         scale_old = jnp.where(
             m_prev > _NEG_INF / 2, jnp.exp(m_prev - shift), 0.0
         )
-        m_sc[:] = m_new
-        l_sc[:] = l_sc[:] * scale_old + jnp.sum(p, axis=-1)
-        acc_sc[:] = acc_sc[:] * scale_old[:, None] + jnp.dot(
+        m_sc[...] = m_new
+        l_sc[...] = l_sc[...] * scale_old + jnp.sum(p, axis=-1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * _lanes(scale_old, head_dim) + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
 
     @pl.when(ki == n_k - 1)
     def _done():
-        l = l_sc[:]
+        l = l_sc[...]
         safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_sc[:] / safe_l[:, None]).astype(o_ref.dtype)
-        # lse = m + log(l); +inf for fully-masked rows so exp(s - lse) = 0
-        # in the backward regardless of s.
-        m = m_sc[:]
-        shift = jnp.where(m > _NEG_INF / 2, m, 0.0)
-        lse_ref[0, 0] = jnp.where(
-            l > 0, shift + jnp.log(safe_l), jnp.inf
+        o_ref[0] = (acc_sc[...] / _lanes(safe_l, head_dim)).astype(
+            o_ref.dtype
         )
+        # lse = m + log(l). Fully-masked rows get the finite sentinel
+        # +1e30 so the backward's exp(s - lse) underflows to exactly 0
+        # (s is at most -1e30 there) without an isfinite select.
+        m = m_sc[...]
+        shift = jnp.where(m > _NEG_INF / 2, m, 0.0)
+        lse_ref[0] = jnp.where(l > 0, shift + jnp.log(safe_l), -_NEG_INF)
 
 
-try:  # pallas is TPU/interpret-only; import lazily-ish at module load
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # jax 0.4.x spells it TPUCompilerParams; same kwargs. Keep the alias
-    # module-local — mutating the shared pltpu module would leak to other
-    # libraries' feature detection.
-    _CompilerParams = getattr(
-        pltpu, "CompilerParams", None
-    ) or pltpu.TPUCompilerParams
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+def _row_form(x):
+    """[B, T] per-position values -> [B, 1, T]: a (1, 1, block) BlockSpec
+    then delivers a [1, block] row (the middle singleton satisfies the
+    sublane rule as a full dimension)."""
+    return x[:, None, :]
 
 
-def _flash_forward(q, k, v, seg_q, seg_k, causal, block_q, block_k,
-                   interpret):
-    B, H, Tq, D = q.shape
-    Tk = k.shape[-2]
+def _col_form(x):
+    """[B, T] per-position values -> lane-replicated [B, T, 128]: a
+    (1, block, 128) BlockSpec delivers the column :func:`_lanes` widens."""
+    return jnp.broadcast_to(x[:, :, None], x.shape + (_LANES,))
+
+
+def _check_blocks(Tq, Tk, block_q, block_k):
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     if Tq % block_q or Tk % block_k:
@@ -310,19 +342,18 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, block_q, block_k,
             f"sequence lengths ({Tq}, {Tk}) must be multiples of the block "
             f"sizes ({block_q}, {block_k})"
         )
+    return block_q, block_k
+
+
+def _flash_forward(q, k, v, seg_q, seg_k, causal, block_q, block_k,
+                   interpret):
+    B, H, Tq, D = q.shape
+    Tk = k.shape[-2]
+    block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
     n_k = Tk // block_k
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * H, Tk, D)
     vr = v.reshape(B * H, Tk, D)
-    # [B*H, 1, T] layout: pallas requires the last two block dims to be
-    # (multiple of 8 | full dim, multiple of 128 | full dim); a middle
-    # singleton satisfies the sublane rule exactly.
-    segq = jnp.broadcast_to(seg_q[:, None, :], (B, H, Tq)).reshape(
-        B * H, 1, Tq
-    )
-    segk = jnp.broadcast_to(seg_k[:, None, :], (B, H, Tk)).reshape(
-        B * H, 1, Tk
-    )
 
     kernel = functools.partial(
         _flash_kernel, causal=causal, block_q=block_q, block_k=block_k,
@@ -335,28 +366,32 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, block_q, block_k,
             pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0)),
             pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
-            pl.BlockSpec((1, 1, block_k), lambda b, qi, ki: (b, 0, ki)),
+            # Segment ids are per batch row, shared by its H heads.
+            pl.BlockSpec(
+                (1, block_q, _LANES), lambda b, qi, ki: (b // H, qi, 0)
+            ),
+            pl.BlockSpec((1, 1, block_k), lambda b, qi, ki: (b // H, 0, ki)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, qi, ki: (b, 0, qi)),
+            pl.BlockSpec((1, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tq, D), v.dtype),
-            jax.ShapeDtypeStruct((B * H, 1, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, Tq, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(qr, kr, vr, segq, segk)
-    return out.reshape(B, H, Tq, D), lse
+    )(qr, kr, vr, _col_form(seg_q), _row_form(seg_k))
+    # The residual keeps one lane: O(T) memory, not O(128 T).
+    return out.reshape(B, H, Tq, D), lse[:, :, 0]
 
 
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
@@ -369,7 +404,7 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
 
     @pl.when(ki == 0)
     def _init():
-        dq_sc[:] = jnp.zeros_like(dq_sc)
+        dq_sc[...] = jnp.zeros_like(dq_sc)
 
     visible = (
         ki * block_k <= qi * block_q + block_q - 1 if causal else ki >= 0
@@ -382,40 +417,37 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = s + _tile_bias(
-            s, causal, qi, ki, block_q, block_k, seg_q_ref[0, 0],
-            seg_k_ref[0, 0],
+        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+        mask = _tile_mask(
+            causal, 0, qi * block_q, ki * block_k,
+            _lanes(seg_q_ref[0], block_k), seg_k_ref[0],
         )
-        # exp(-inf - +inf) is nan, not 0: clamp fully-masked rows' lse.
-        safe_lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
-        p = jnp.where(
-            jnp.isfinite(lse)[:, None], jnp.exp(s - safe_lse[:, None]), 0.0
+        s = jnp.where(mask, s, _NEG_INF)
+        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
+        dp = jax.lax.dot_general(
+            do, v, _NT, preferred_element_type=jnp.float32
         )
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dq_sc[:] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
+        ds = p * (dp - _lanes(delta_ref[0], block_k))
+        dq_sc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
 
     @pl.when(ki == n_k - 1)
     def _done():
-        dq_ref[0] = dq_sc[:].astype(dq_ref.dtype)
+        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
                            lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
                            dk_sc, dv_sc, *, causal: bool, block_q: int,
                            block_k: int, n_q: int):
-    """dK/dV pass. Grid (B*H, n_k, n_q); q-axis sequential, dk/dv
-    accumulate in VMEM scratch."""
+    """dK/dV pass on the transposed tile sᵀ [block_k, block_q]. Grid
+    (B*H, n_k, n_q); q-axis sequential, dk/dv accumulate in VMEM scratch."""
     kj, qi = pl.program_id(1), pl.program_id(2)
 
     @pl.when(qi == 0)
     def _init():
-        dk_sc[:] = jnp.zeros_like(dk_sc)
-        dv_sc[:] = jnp.zeros_like(dv_sc)
+        dk_sc[...] = jnp.zeros_like(dk_sc)
+        dv_sc[...] = jnp.zeros_like(dv_sc)
 
     # A q-block strictly above this k-block sees none of it.
     visible = (
@@ -429,54 +461,52 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
 
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = s + _tile_bias(
-            s, causal, qi, kj, block_q, block_k, seg_q_ref[0, 0],
-            seg_k_ref[0, 0],
+        st = jax.lax.dot_general(
+            k, q, _NT, preferred_element_type=jnp.float32
         )
-        safe_lse = jnp.where(jnp.isfinite(lse), lse, 0.0)
-        p = jnp.where(
-            jnp.isfinite(lse)[:, None], jnp.exp(s - safe_lse[:, None]), 0.0
+        mask = _tile_mask(
+            causal, 1, qi * block_q, kj * block_k,
+            _lanes(seg_k_ref[0], block_q), seg_q_ref[0],
         )
-        dv_sc[:] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dk_sc[:] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+        st = jnp.where(mask, st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0])
+        dv_sc[...] += jnp.dot(pt, do, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, do, _NT, preferred_element_type=jnp.float32
+        )
+        dst = pt * (dpt - delta_ref[0])
+        # q already carries the softmax scale: dK = dSᵀ · (scale · Q).
+        dk_sc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
 
     @pl.when(qi == n_q - 1)
     def _done():
-        dk_ref[0] = dk_sc[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_sc[:].astype(dv_ref.dtype)
+        dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, block_q,
                     block_k, interpret):
     B, H, Tq, D = q.shape
     Tk = k.shape[-2]
-    block_q = min(block_q, Tq)
-    block_k = min(block_k, Tk)
+    block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
     n_q, n_k = Tq // block_q, Tk // block_k
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * H, Tk, D)
     vr = v.reshape(B * H, Tk, D)
     gr = g.reshape(B * H, Tq, D)
-    segq = jnp.broadcast_to(seg_q[:, None, :], (B, H, Tq)).reshape(
-        B * H, 1, Tq
-    )
-    segk = jnp.broadcast_to(seg_k[:, None, :], (B, H, Tk)).reshape(
-        B * H, 1, Tk
-    )
     # delta_i = rowsum(dO * O): the softmax-jacobian correction term.
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    ).reshape(B * H, 1, Tq)
+    ).reshape(B * H, Tq)
+    semantics = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+    )
 
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, x, y: (b, x, 0))
-    row_q = pl.BlockSpec((1, 1, block_q), lambda b, x, y: (b, 0, x))
-
+    # dQ: score tile [block_q, block_k]; per-query stats are columns.
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0))
+    k_spec = pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0))
+    q_col = pl.BlockSpec((1, block_q, _LANES), lambda b, qi, ki: (b, qi, 0))
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, causal=causal, block_q=block_q,
@@ -485,24 +515,28 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, block_q,
         grid=(B * H, n_q, n_k),
         in_specs=[
             q_spec,
-            pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0)),
-            row_q,
-            pl.BlockSpec((1, 1, block_k), lambda b, qi, ki: (b, 0, ki)),
-            row_q,
-            row_q,
+            k_spec,
+            k_spec,
+            pl.BlockSpec(
+                (1, block_q, _LANES), lambda b, qi, ki: (b // H, qi, 0)
+            ),
+            pl.BlockSpec((1, 1, block_k), lambda b, qi, ki: (b // H, 0, ki)),
+            q_col,
+            q_col,
             q_spec,
         ],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=semantics,
         interpret=interpret,
-    )(qr, kr, vr, segq, segk, lse, delta, gr)
+    )(qr, kr, vr, _col_form(seg_q), _row_form(seg_k), _col_form(lse),
+      _col_form(delta), gr)
 
+    # dK/dV: transposed tile [block_k, block_q]; per-query stats are rows.
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, kj, qi: (b, qi, 0))
     k_spec = pl.BlockSpec((1, block_k, D), lambda b, kj, qi: (b, kj, 0))
+    q_row = pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b, 0, qi))
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
@@ -510,14 +544,16 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, block_q,
         ),
         grid=(B * H, n_k, n_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, kj, qi: (b, qi, 0)),
+            q_spec,
             k_spec,
             k_spec,
-            pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b, 0, qi)),
-            pl.BlockSpec((1, 1, block_k), lambda b, kj, qi: (b, 0, kj)),
-            pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b, 0, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b, 0, qi)),
-            pl.BlockSpec((1, block_q, D), lambda b, kj, qi: (b, qi, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b // H, 0, qi)),
+            pl.BlockSpec(
+                (1, block_k, _LANES), lambda b, kj, qi: (b // H, kj, 0)
+            ),
+            q_row,
+            q_row,
+            q_spec,
         ],
         out_specs=[k_spec, k_spec],
         out_shape=[
@@ -528,11 +564,10 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, block_q,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=semantics,
         interpret=interpret,
-    )(qr, kr, vr, segq, segk, lse, delta, gr)
+    )(qr, kr, vr, _row_form(seg_q), _col_form(seg_k), _row_form(lse),
+      _row_form(delta), gr)
 
     return (
         dq.reshape(B, H, Tq, D),
@@ -580,18 +615,16 @@ def flash_attention(
     kv_segment_ids: Optional[jax.Array] = None,
     block_q: int = 256,
     block_k: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
-    """Pallas flash-attention forward (custom VJP backward). On non-TPU
-    backends ``interpret`` defaults to True so tests exercise the same
-    kernel logic."""
-    if not _HAVE_PALLAS:
-        return blockwise_attention(
-            q, k, v, causal=causal, segment_ids=segment_ids,
-            kv_segment_ids=kv_segment_ids,
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    """Pallas flash attention (custom VJP backward), compiled by Mosaic.
+
+    ``interpret=True`` runs the same kernel logic in the Pallas interpreter
+    for CPU tests; it is an error on a TPU, where nothing may quietly
+    replace the compiled kernel. Without it a non-TPU backend, or a shape
+    Mosaic rejects, fails in the caller's compile."""
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError("flash_attention(interpret=True) on a TPU backend")
     B, _, Tq, _ = q.shape
     Tk = k.shape[-2]
     seg_q = (
@@ -613,79 +646,41 @@ def flash_attention(
     )
 
 
-_flash_probe_cache: dict = {}
+def resolve_backend(Tq: int, Tk: int, block_q: int = 256,
+                    block_k: int = 256) -> str:
+    """What ``attention(backend="auto")`` runs, decided from what can be
+    observed at trace time — the platform and the shape — and nothing else:
 
-
-def _probe_flash(block_q: int, block_k: int) -> bool:
-    """Check (once per block shape) that the pallas kernel compiles on this
-    TPU with the blocks 'auto' is about to dispatch.
-
-    'auto' must never hard-fail on first hardware contact: Mosaic can reject
-    a kernel shape (e.g. the (block_q,)-VMEM scratch) at compile time on a
-    backend generation the kernel was never tried on — and the failure class
-    is block-shape-dependent, so the probe must use the caller's effective
-    block sizes, memoized per (block_q, block_k). Probing at Python level
-    (outside any surrounding jit trace) lets 'auto' degrade to blockwise
-    instead of poisoning the caller's compile.
+    - ``flash`` on a TPU when the effective blocks tile the sequences and
+      are multiples of 128, the lane width the kernels' row statistics and
+      segment-id blocks are laid out for (``chip_smoke.py`` compiles exactly
+      these shapes; a Mosaic rejection of one is a bug and surfaces);
+    - ``dense`` when the score matrix is small (≤ 1M entries: an IMPALA
+      unroll of T+1 = 21 frames lands here on every platform);
+    - ``blockwise`` otherwise.
     """
-    key = (block_q, block_k)
-    ok = _flash_probe_cache.get(key)
-    if ok is None:
-        try:
-            # Multi-block grid in both q and k; both causal branches.
-            q = jnp.zeros((1, 1, 2 * block_q, 64), jnp.float32)
-            kv = jnp.zeros((1, 1, 2 * block_k, 64), jnp.float32)
-            jax.block_until_ready(
-                flash_attention(q, kv, kv, block_q=block_q, block_k=block_k)
-            )
-            jax.block_until_ready(
-                flash_attention(
-                    q, kv, kv, causal=True, block_q=block_q, block_k=block_k
-                )
-            )
-            # The backward kernels are separate Mosaic programs: probe them
-            # too, or 'auto' could poison the caller's grad compile.
-            jax.block_until_ready(
-                jax.grad(
-                    lambda q: jnp.sum(
-                        flash_attention(
-                            q, kv, kv, causal=True,
-                            block_q=block_q, block_k=block_k,
-                        )
-                    )
-                )(q)
-            )
-            ok = True
-        except Exception as e:  # Mosaic lowering/compile rejection
-            import logging
-
-            logging.getLogger("moolib_tpu.attention").warning(
-                "pallas flash attention unavailable for blocks %s on this "
-                "backend (%s); 'auto' will use blockwise", key, e
-            )
-            ok = False
-        _flash_probe_cache[key] = ok
-    return ok
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    if (
+        jax.default_backend() == "tpu"
+        and Tq % bq == 0
+        and Tk % bk == 0
+        and bq % _LANES == 0
+        and bk % _LANES == 0
+    ):
+        return "flash"
+    if Tq * Tk <= 1024 * 1024:
+        return "dense"
+    return "blockwise"
 
 
 def attention(q, k, v, backend: str = "auto", **kw):
-    """Dispatcher: 'dense' | 'blockwise' | 'flash' | 'auto' (flash on TPU,
-    dense for short sequences, blockwise otherwise)."""
+    """Dispatcher: 'dense' | 'blockwise' | 'flash' | 'auto'
+    (:func:`resolve_backend`)."""
     if backend == "auto":
-        Tq, Tk = q.shape[-2], k.shape[-2]
-        bq = min(kw.get("block_q", 256), Tq)
-        bk = min(kw.get("block_k", 256), Tk)
-        if (
-            jax.default_backend() == "tpu"
-            and Tq % bq == 0
-            and Tk % bk == 0
-            and _probe_flash(bq, bk)
-        ):
-            backend = "flash"
-        elif Tq * Tk <= 1024 * 1024:
-            backend = "dense"
-        else:
-            backend = "blockwise"
+        backend = resolve_backend(
+            q.shape[-2], k.shape[-2], kw.get("block_q", 256),
+            kw.get("block_k", 256),
+        )
         if backend != "flash":
             kw.pop("block_q", None)  # flash-only knob
             if backend == "dense":

@@ -32,7 +32,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .attention import _finalize, _online_block, _scale
 from ..parallel.mesh import pvary_if_needed
-from ..utils.jaxenv import axis_size, shard_map
 
 __all__ = [
     "ring_attention",
@@ -62,7 +61,7 @@ def ring_attention(
 
     Returns [B, H, T_local, D] — this device's rows of the global result.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, H, T, D = q.shape
     qf = _scale(q.astype(jnp.float32))
@@ -162,7 +161,7 @@ def sequence_sharded_attention(
             return ring_attention(q, k, v, axis_name=axis_name, causal=causal)
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 f,
                 mesh=mesh,
                 in_specs=(seq_spec, seq_spec, seq_spec),
@@ -177,7 +176,7 @@ def sequence_sharded_attention(
         )
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             f,
             mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec, seg_spec),
@@ -239,7 +238,7 @@ def zigzag_ring_attention(
     :func:`zigzag_order`; :func:`zigzag_sharded_attention` does it for you).
     Causality is implicit in the layout — there is no ``causal=False``.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, H, T2, D = q.shape
     if T2 % 2 != 0:
@@ -336,7 +335,7 @@ def _zigzag_jitted(mesh: Mesh, axis_name: str, use_seg: bool):
             return zigzag_ring_attention(q, k, v, axis_name=axis_name)
 
         return jax.jit(
-            shard_map(
+            jax.shard_map(
                 f, mesh=mesh,
                 in_specs=(seq_spec, seq_spec, seq_spec),
                 out_specs=seq_spec,
@@ -350,7 +349,7 @@ def _zigzag_jitted(mesh: Mesh, axis_name: str, use_seg: bool):
         )
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             f, mesh=mesh,
             in_specs=(seq_spec, seq_spec, seq_spec, seg_spec),
             out_specs=seq_spec,

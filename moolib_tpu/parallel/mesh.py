@@ -39,17 +39,9 @@ def pvary_if_needed(x, axis_name: str):
     """Mark a value device-varying over ``axis_name`` for shard_map's vma
     typing (no-op if already varying). Needed when a fresh constant enters
     a scan whose body makes it varying — the initial carry must match."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        # jax 0.4.x (experimental shard_map): no varying-manual-axes
-        # typing exists, so there is nothing to mark.
+    if axis_name in jax.typeof(x).vma:
         return x
-    vma = getattr(typeof(x), "vma", frozenset())
-    if axis_name in vma:
-        return x
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis_name,), to="varying")
-    return jax.lax.pvary(x, (axis_name,))
+    return jax.lax.pcast(x, (axis_name,), to="varying")
 
 
 def make_mesh(
@@ -199,13 +191,6 @@ def dp_average_grads(grads, axis_name: str = "dp"):
     ``jax.grad`` w.r.t. replicated params inside shard_map yields
     sum_d grad(mean_loss_d) = n * grad(global_mean_loss); dividing by the
     axis size recovers the global-mean gradient exactly.
-
-    On jax 0.4.x the code runs under ``jax.experimental.shard_map`` with
-    ``check_rep=False`` (see :func:`moolib_tpu.utils.jaxenv.shard_map`):
-    there is NO automatic cotangent psum, grads stay per-device local
-    values, and the global mean is an explicit pmean instead.
     """
-    if getattr(jax, "shard_map", None) is None:
-        return pmean_gradients(grads, axis_name)
-    n = jax.lax.psum(1, axis_name)
+    n = jax.lax.axis_size(axis_name)
     return jax.tree_util.tree_map(lambda g: g / n, grads)

@@ -38,7 +38,6 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-from ..utils.jaxenv import axis_size
 
 __all__ = ["moe_params", "moe_ffn", "moe_ffn_sharded"]
 
@@ -201,7 +200,7 @@ def moe_ffn_sharded(
     Returns ``([T_local, d_model], aux)``; aux losses are psum-averaged
     over the axis (identical on every device).
     """
-    groups = axis_size(axis_name)
+    groups = jax.lax.axis_size(axis_name)
     T_local, d_model = x_local.shape
     E_local = params["w_up"].shape[0]
     E = E_local * groups

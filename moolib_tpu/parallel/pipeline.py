@@ -42,7 +42,7 @@ Usage::
     mesh = make_mesh(pp=4, ...)
     stacked = stack_stage_params(stages)           # shard P('pp', ...)
     x_sh = shard_microbatches(x, pp)               # [k, pp, mb, F]
-    y_sh = jax.jit(shard_map(
+    y_sh = jax.jit(jax.shard_map(
         lambda p, x: pipeline_apply(stage_fn, p, x, axis_name="pp"),
         mesh=mesh, in_specs=(P("pp"), MICRO_SPEC), out_specs=MICRO_SPEC,
     ))(stacked, x_sh)
@@ -58,7 +58,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .mesh import pvary_if_needed
-from ..utils.jaxenv import axis_size, shard_map
 
 __all__ = [
     "pipeline_apply",
@@ -134,7 +133,7 @@ def pipeline_apply(
     (``out_specs=MICRO_SPEC``; :func:`unshard_microbatches` restores
     ``[n_micro, ...]``).
     """
-    n_stages = axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     # Local shard arrives [k, 1, mb, ...] (the pp axis is sharded away).
     squeeze = microbatches.shape[1] == 1
@@ -248,7 +247,7 @@ def pipeline_train_1f1b(
     the weight-grad accumulation for THIS device's stage with leading dim
     1 (``out_specs=P('pp', ...)`` re-stacks the pipeline).
     """
-    n_stages = axis_size(axis_name)
+    n_stages = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     S = n_stages
     M = microbatches.shape[0]

@@ -213,13 +213,12 @@ class Hotwatch:
     # -- patching -------------------------------------------------------------
 
     def _activate(self) -> None:
-        import jax  # noqa: F401  (guards live on the jax config)
+        import jax
         import numpy as np
-        from jaxlib import xla_extension as xe
+        from jax._src.array import ArrayImpl as array_cls
 
         watch = self
 
-        array_cls = xe.ArrayImpl
         orig_value = array_cls._value
         orig_stage = array_cls.copy_to_host_async
         orig_asarray = np.asarray
@@ -275,11 +274,11 @@ class Hotwatch:
 
     def _deactivate(self) -> None:
         import numpy as np
-        from jaxlib import xla_extension as xe
+        from jax._src.array import ArrayImpl
 
         if self._orig:
-            xe.ArrayImpl._value = self._orig["value"]
-            xe.ArrayImpl.copy_to_host_async = self._orig["stage"]
+            ArrayImpl._value = self._orig["value"]
+            ArrayImpl.copy_to_host_async = self._orig["stage"]
             np.asarray = self._orig["asarray"]
             np.array = self._orig["array"]
             self._orig = {}

@@ -6,7 +6,6 @@ import importlib
 
 from .checkpoint import (CheckpointError, Checkpointer, load_checkpoint,
                          save_checkpoint)
-from .jaxenv import ensure_platforms
 from .logging import get_logger, set_log_level, set_logging
 from .stats import StatMax, StatMean, StatSum, Stats
 from .timer import Ewma, Timer
@@ -45,10 +44,7 @@ def stage_host_async(tree):
     def stage(x):
         start = getattr(x, "copy_to_host_async", None)
         if start is not None:
-            try:
-                start()
-            except Exception:  # non-jax array-likes with the attr
-                pass
+            start()
         return x
 
     return nest.map_structure(stage, tree)

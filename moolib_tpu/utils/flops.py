@@ -17,7 +17,7 @@ per layer).
 from __future__ import annotations
 
 import math
-from typing import Optional, Sequence
+from typing import Sequence
 
 __all__ = [
     "conv2d_flops",
@@ -139,27 +139,28 @@ def impala_train_flops(frames: int, **kw) -> int:
     return TRAIN_FLOPS_MULTIPLIER * frames * impala_forward_flops(**kw)
 
 
-# Peak dense matmul throughput per chip, bf16, FLOP/s. Public numbers from
-# cloud.google.com/tpu/docs (per-chip; a jax device is one chip on v4+, one
-# core on v2/v3).
-_PEAK_BF16 = (
-    ("v5 lite", 197e12),  # v5e
-    ("v5litepod", 197e12),
-    ("v5e", 197e12),
-    ("v6 lite", 918e12),  # v6e / Trillium
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5", 459e12),  # bare "TPU v5" = v5p
-    ("v4", 275e12),
-    ("v3", 61.4e12),  # per core
-    ("v2", 22.8e12),
-)
+# Peak dense matmul throughput per chip, bf16, FLOP/s, keyed by the exact
+# ``jax.devices()[0].device_kind`` string. Public numbers from
+# cloud.google.com/tpu/docs (per-chip; a jax device is one chip on v4+).
+_PEAK_BF16 = {
+    "TPU v4": 275e12,
+    "TPU v5 lite": 197e12,  # v5e
+    "TPU v5e": 197e12,
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,  # v6e / Trillium
+    "TPU v6e": 918e12,
+}
 
 
-def device_peak_flops(device_kind: str) -> Optional[float]:
-    """Peak bf16 FLOP/s for a jax ``device_kind`` string, or None if unknown."""
-    kind = device_kind.lower()
-    for key, peak in _PEAK_BF16:
-        if key in kind:
-            return peak
-    return None
+def device_peak_flops(device_kind: str) -> float:
+    """Peak bf16 FLOP/s for a jax ``device_kind``. A kind that is not in
+    the table is an error, never a default: an MFU over a guessed peak is
+    worse than none."""
+    try:
+        return _PEAK_BF16[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no bf16 peak recorded for device_kind {device_kind!r}; known: "
+            f"{sorted(_PEAK_BF16)} (add it to moolib_tpu/utils/flops.py with "
+            "its source)"
+        ) from None

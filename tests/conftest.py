@@ -80,21 +80,6 @@ def pytest_unconfigure(config):
         _faulthandler_fd = None
 
 
-def has_multiprocess_cpu_collectives() -> bool:
-    """Capability probe: can THIS jax/jaxlib run multi-process computations
-    on the CPU backend?
-
-    XLA:CPU rejects cross-process programs outright
-    ("Multiprocess computations aren't implemented on the CPU backend")
-    until jax grew CPU collectives (gloo/mpi) together with the
-    ``jax_cpu_collectives_implementation`` config — so the presence of that
-    config IS the capability. Tests that spawn multi-controller CPU
-    workers (``test_distributed``) skip with a clear reason instead of
-    failing, so tier-1 reflects real regressions only.
-    """
-    return hasattr(jax.config, "jax_cpu_collectives_implementation")
-
-
 @pytest.fixture
 def rng():
     import numpy as np

@@ -24,7 +24,6 @@ from moolib_tpu.ops.ring_attention import (
     sequence_sharded_attention,
 )
 from moolib_tpu.parallel.mesh import make_mesh
-from moolib_tpu.utils.jaxenv import shard_map
 
 
 def _qkv(rng, B=2, H=3, T=64, D=16, dtype=np.float32):
@@ -59,7 +58,8 @@ def test_flash_matches_dense(rng, causal, with_segs):
     seg = _segs(rng) if with_segs else None
     o1 = dense_attention(q, k, v, causal=causal, segment_ids=seg)
     o3 = flash_attention(
-        q, k, v, causal=causal, segment_ids=seg, block_q=16, block_k=16
+        q, k, v, causal=causal, segment_ids=seg, block_q=16, block_k=16,
+        interpret=True,
     )
     np.testing.assert_allclose(o1, o3, atol=2e-5)
 
@@ -85,7 +85,8 @@ def test_gradients_match(rng):
         (q, k, v)
     )
     g_flash = jax.grad(
-        lambda i: loss(flash_attention, i, block_q=16, block_k=16)
+        lambda i: loss(flash_attention, i, block_q=16, block_k=16,
+                       interpret=True)
     )((q, k, v))
     for a, b in zip(g_dense, g_block):
         np.testing.assert_allclose(a, b, atol=1e-4)
@@ -112,7 +113,7 @@ def test_ring_gradients(rng):
     spec = P(None, None, "sp", None)
 
     def ring_loss(q):
-        f = shard_map(
+        f = jax.shard_map(
             lambda q, k, v: ring_attention(q, k, v, causal=True),
             mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
         )
@@ -163,6 +164,8 @@ def test_transformer_backends_agree():
     net_d, params, obs, done = _net_and_params(
         jax.random.PRNGKey(0), backend="dense"
     )
+    from jax.experimental.pallas import tpu as pltpu
+
     from moolib_tpu.models import TransformerNet
 
     for backend in ("blockwise", "flash"):
@@ -171,7 +174,10 @@ def test_transformer_backends_agree():
             attention_backend=backend,
         )
         (l1, b1), _ = net_d.apply(params, obs, done, ())
-        (l2, b2), _ = net_b.apply(params, obs, done, ())
+        # The model has no interpret switch (a chip must never interpret):
+        # on CPU the flash backend runs under jax's own interpret context.
+        with pltpu.force_tpu_interpret_mode():
+            (l2, b2), _ = net_b.apply(params, obs, done, ())
         np.testing.assert_allclose(l1, l2, atol=2e-4)
         np.testing.assert_allclose(b1, b2, atol=2e-4)
 
@@ -345,7 +351,7 @@ def test_transformer_zigzag_backend_matches_dense():
         return l, b
 
     l_z, b_z = jax.jit(
-        shard_map(
+        jax.shard_map(
             f, mesh=mesh,
             in_specs=(P(), P("sp"), P("sp"), P(None, "sp"), P("sp")),
             out_specs=(P("sp"), P("sp")),
@@ -415,7 +421,7 @@ def test_transformer_zigzag_training_keeps_sharded_layout():
         return jax.lax.psum(s, "sp") / (T * B * A)
 
     def zig_loss(params):
-        return shard_map(
+        return jax.shard_map(
             shard_loss, mesh=mesh,
             in_specs=(P(), P("sp"), P("sp"), P(None, "sp"), P("sp")),
             out_specs=P(),
@@ -468,7 +474,7 @@ def test_flash_backward_kernel_with_segments(rng, causal):
         return jnp.sum(
             flash_attention(q, k, v, causal=causal, segment_ids=seg_q,
                             kv_segment_ids=seg, block_q=16,
-                            block_k=16) ** 2
+                            block_k=16, interpret=True) ** 2
         )
 
     g_ref = jax.grad(ref_loss, argnums=(0, 1, 2))(q, k, v)

@@ -10,15 +10,6 @@ import textwrap
 
 import pytest
 
-from conftest import has_multiprocess_cpu_collectives
-
-pytestmark = pytest.mark.skipif(
-    not has_multiprocess_cpu_collectives(),
-    reason="this jaxlib cannot run multiprocess computations on the CPU "
-           "backend (no cpu-collectives support / "
-           "jax_cpu_collectives_implementation config; needs jax >= 0.5)",
-)
-
 _WORKER = textwrap.dedent(
     """
     import os, sys

@@ -24,7 +24,8 @@ def test_flops_primitives():
 def test_device_peak_lookup():
     assert device_peak_flops("TPU v5 lite") == pytest.approx(197e12)
     assert device_peak_flops("TPU v4") == pytest.approx(275e12)
-    assert device_peak_flops("Tesla V100") is None
+    with pytest.raises(ValueError, match="no such chip"):
+        device_peak_flops("no such chip")  # unknown is an error, not None
 
 
 def test_impala_forward_flops_matches_xla():

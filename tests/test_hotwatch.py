@@ -83,18 +83,18 @@ def test_disabled_window_patches_nothing(step):
     """enabled=False (and the MOOLIB_TPU_HOTWATCH=0 escape hatch) is a
     true no-op: the array class keeps its original descriptors and syncs
     inside the window are free."""
-    from jaxlib import xla_extension as xe
+    from jax._src.array import ArrayImpl
 
-    before_value = xe.ArrayImpl._value
-    before_stage = xe.ArrayImpl.copy_to_host_async
+    before_value = ArrayImpl._value
+    before_stage = ArrayImpl.copy_to_host_async
     s = step(jnp.zeros((8,)))
     with Hotwatch(enabled=False, jits=[step]) as hw:
-        assert xe.ArrayImpl._value is before_value
-        assert xe.ArrayImpl.copy_to_host_async is before_stage
+        assert ArrayImpl._value is before_value
+        assert ArrayImpl.copy_to_host_async is before_stage
         s = step(s)
         s.sum().item()
     assert hw.d2h == 0
-    assert xe.ArrayImpl._value is before_value
+    assert ArrayImpl._value is before_value
 
 
 def test_env_gate(monkeypatch):
@@ -110,14 +110,14 @@ def test_env_gate(monkeypatch):
 def test_patches_restored_after_window(step):
     """Exit (clean or raising) restores every descriptor: reads outside
     any window are untouched."""
-    from jaxlib import xla_extension as xe
+    from jax._src.array import ArrayImpl
 
-    before = xe.ArrayImpl._value
+    before = ArrayImpl._value
     s = step(jnp.zeros((8,)))
     with pytest.raises(HotwatchViolation):
         with Hotwatch(jits=[step]):
             s.sum().item()
-    assert xe.ArrayImpl._value is before
+    assert ArrayImpl._value is before
     assert float(step(s)[0]) == pytest.approx(2.0)
 
 

@@ -238,25 +238,64 @@ def test_apply_step_donated_path_matches_and_survives_get_state():
     assert int(s_don.step) == 20
 
 
-def test_examples_thread_state_through_donating_apply():
-    """The a2c and vtrace learners must keep the donating apply_step AND
-    the state_lock that makes it safe (get_state runs on RPC threads);
-    remote_actors must stay non-donating — its infer() reads params
-    outside the lock, concurrently with the train step."""
-    import re
+def test_examples_thread_state_through_donating_apply(monkeypatch):
+    """The a2c and vtrace learners must apply every update through a
+    DONATING apply step and only while holding the state_lock that makes
+    donation safe (get_state runs on RPC threads) — observed on real, tiny
+    runs: a spy on make_apply_step sees the factory's arguments and, at
+    each call, whether the caller holds its lock. remote_actors must stay
+    non-donating — its infer() reads params outside the lock, concurrently
+    with the train step."""
+    import sys
     from pathlib import Path
 
-    root = Path(__file__).resolve().parent.parent / "moolib_tpu"
-    for rel in ("examples/a2c.py", "examples/vtrace/experiment.py"):
-        src = (root / rel).read_text()
-        assert "make_apply_step(optimizer, donate=True)" in src, rel
-        assert "state_lock = threading.Lock()" in src, rel
-        # The apply+rebind is inside the lock: `with state_lock:` with
-        # `state = apply_step(` on the following lines.
-        assert re.search(
-            r"with state_lock:\s*\n\s*state = apply_step\(", src
-        ), f"{rel}: apply+rebind must hold state_lock"
-    remote = (root / "examples/remote_actors.py").read_text()
+    from moolib_tpu import learner
+    from moolib_tpu.examples.a2c import A2CConfig, train as a2c_train
+    from moolib_tpu.examples.vtrace.experiment import (
+        VtraceConfig,
+        train as vtrace_train,
+    )
+
+    real = learner.make_apply_step
+    seen = []
+
+    def spy(optimizer, **kwargs):
+        apply = real(optimizer, **kwargs)
+        record = {"donate": kwargs.get("donate"), "locked": []}
+        seen.append(record)
+
+        def watched(state, grads):
+            lock = sys._getframe(1).f_locals["state_lock"]
+            record["locked"].append(lock.locked())
+            return apply(state, grads)
+
+        return watched
+
+    monkeypatch.setattr(learner, "make_apply_step", spy)
+    quiet = lambda *a, **k: None  # noqa: E731
+    a2c_train(
+        A2CConfig(total_steps=1_500, batch_size=8, num_processes=1,
+                  log_interval_steps=500),
+        log_fn=quiet,
+    )
+    vtrace_train(
+        VtraceConfig(env="cartpole", total_steps=1_500, actor_batch_size=8,
+                     learn_batch_size=8, virtual_batch_size=8,
+                     num_actor_processes=1, unroll_length=10,
+                     log_interval_steps=500, stats_interval=0.2),
+        log_fn=quiet,
+    )
+    assert len(seen) == 2, seen
+    for record in seen:
+        assert record["donate"] is True, record
+        assert record["locked"], "no update was applied"
+        assert all(record["locked"]), (
+            "apply+rebind must hold state_lock on every update"
+        )
+    remote = (
+        Path(__file__).resolve().parent.parent
+        / "moolib_tpu/examples/remote_actors.py"
+    ).read_text()
     assert "donate=False" in remote, (
         "remote_actors must NOT donate: infer() reads params outside "
         "the lock concurrently with the train step"

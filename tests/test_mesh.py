@@ -14,7 +14,6 @@ from moolib_tpu.parallel import (
     shard_batch,
 )
 from jax.sharding import NamedSharding, PartitionSpec as P
-from moolib_tpu.utils.jaxenv import shard_map
 
 
 def test_make_mesh_shapes():
@@ -47,7 +46,7 @@ def test_psum_gradients_in_shard_map():
         return psum_gradients(grads)
 
     f = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device,
             mesh=mesh,
             in_specs=P("dp"),
@@ -86,7 +85,7 @@ def test_data_parallel_train_step_grads_match_single_device():
         return dp_average_grads(g)
 
     sharded_step = jax.jit(
-        shard_map(
+        jax.shard_map(
             step,
             mesh=mesh,
             in_specs=(P(), P(None, "dp"), P(None, "dp")),

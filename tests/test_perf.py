@@ -154,7 +154,7 @@ def test_detector_needs_history_and_skips_null_rows():
     rows = _trend_rows([100.0, 101.0, 99.0, 100.0])
     rows.append(BenchResult(metric="proxy_gbps", value=None, unit="GB/s",
                             suite="cpu-proxy", smoke=True,
-                            error="tunnel dead"))
+                            error="no device"))
     # The null artifact stays on record but is not a regression verdict.
     assert detect_regressions(rows) == []
 
@@ -426,7 +426,7 @@ def test_perf_cli_check_trends_flags_trailing_nulls(tmp_path):
     append_trend(str(trends), BenchResult(
         metric="impala_train_env_steps_per_sec_per_chip", value=None,
         unit="", suite="device", cmd="python bench.py",
-        error="device tunnel unreachable for 1000s"))
+        error="no TPU found"))
     proc = _run_perf(["--check-trends-only", "--trends", str(trends)])
     assert proc.returncode == 1, proc.stdout + proc.stderr
     assert "NULL impala_train_env_steps_per_sec_per_chip" in proc.stdout

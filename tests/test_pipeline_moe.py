@@ -16,7 +16,6 @@ from moolib_tpu.parallel.pipeline import (
     stack_stage_params,
     unshard_microbatches,
 )
-from moolib_tpu.utils.jaxenv import shard_map
 
 
 def _stage_fn(params, x):
@@ -38,7 +37,7 @@ def _pipe_loss(mesh, n_stages, remat=False):
     (one construction for every TestPipeline case)."""
 
     def loss(stacked, x):
-        y_sh = shard_map(
+        y_sh = jax.shard_map(
             lambda p, x: pipeline_apply(
                 _stage_fn, p, x, axis_name="pp", remat=remat
             ),
@@ -68,7 +67,7 @@ class TestPipeline:
         stacked = stack_stage_params(stages)
 
         out_sh = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda p, x: pipeline_apply(_stage_fn, p, x, axis_name="pp"),
                 mesh=mesh,
                 in_specs=(P("pp"), MICRO_SPEC),
@@ -190,7 +189,7 @@ class TestPipeline:
         loss_ref, g_ref = jax.value_and_grad(ref_loss)(stacked, x)
 
         loss_1f1b, g_1f1b = jax.jit(
-            shard_map(
+            jax.shard_map(
                 lambda p, x: pipeline_train_1f1b(
                     _stage_fn, mb_loss, p, x, axis_name="pp"
                 ),
@@ -237,7 +236,7 @@ class TestPipeline:
         )
         mem_1f1b = (
             jax.jit(
-                shard_map(
+                jax.shard_map(
                     lambda p, x: pipeline_train_1f1b(
                         _stage_fn, mb_loss, p, x, axis_name="pp"
                     ),
@@ -272,7 +271,7 @@ class TestPipeline:
         stacked = stack_stage_params(stages)
         compiled = (
             jax.jit(
-                shard_map(
+                jax.shard_map(
                     lambda p, x: pipeline_apply(
                         _stage_fn, p, x, axis_name="pp"
                     ),
@@ -490,7 +489,7 @@ class TestMoE:
             return y, aux["drop_fraction"]
 
         fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 fwd,
                 mesh=mesh,
                 in_specs=(

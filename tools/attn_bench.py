@@ -1,19 +1,17 @@
 """Attention backend benchmark: dense vs blockwise vs flash (pallas) vs
 zigzag-ring, as steps/s + attention MFU at long context.
 
-VERDICT r3 #2: the pallas flash kernels and the long-context subsystem had
-zero measured perf and had never met real Mosaic. This tool:
+This tool:
 
-1. validates flash fwd+bwd NON-INTERPRETED on the current backend (on TPU
-   that is the Mosaic compiler) against the dense oracle — numerics
-   asserted, probe result recorded;
+1. validates flash fwd+bwd as compiled by Mosaic against the dense oracle
+   — numerics asserted, result recorded (``chip_smoke.py`` is the gate for
+   this; here it labels the timing rows);
 2. times a training-shaped step (attention + sum-of-squares loss backward)
    per backend at T in {2048, 8192}, recording steps/s and achieved
    attention TFLOP/s vs the chip peak.
 
-Runs anywhere (CPU uses interpret mode for pallas and marks the artifact
-accordingly); the judge-facing artifact comes from a TPU run via
-tools/chip_session.py.
+The flash rows need a TPU: the kernels are never interpreted outside the
+tests, so on another backend they are recorded as errors.
 
 Usage: python tools/attn_bench.py [--json ATTN_r04.json] [--quick]
 
@@ -74,7 +72,6 @@ def bench_backend(backend, B, H, T, D, dtype, iters, mesh=None):
     if backend == "zigzag":
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from moolib_tpu.utils.jaxenv import shard_map
         from moolib_tpu.ops.ring_attention import (
             zigzag_order, zigzag_ring_attention,
         )
@@ -89,7 +86,7 @@ def bench_backend(backend, B, H, T, D, dtype, iters, mesh=None):
 
         def grad_fn(q, k, v):
             def loss(q, k, v):
-                o = shard_map(
+                o = jax.shard_map(
                     lambda q, k, v: zigzag_ring_attention(
                         q, k, v, axis_name="sp"
                     ),
@@ -127,9 +124,8 @@ def bench_backend(backend, B, H, T, D, dtype, iters, mesh=None):
 
 
 def validate_flash_nonintepreted(dtype):
-    """Flash fwd+bwd with interpret=False vs the dense oracle. On TPU this
-    is the Mosaic acceptance test; returns (ok, max_err_fwd, max_err_bwd,
-    error_string)."""
+    """Flash fwd+bwd, compiled, vs the dense oracle; returns (ok,
+    max_err_fwd, max_err_bwd, error_string)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -145,8 +141,7 @@ def validate_flash_nonintepreted(dtype):
     try:
         def f_loss(q, k, v):
             o = attn_mod.flash_attention(
-                q, k, v, causal=True, interpret=False,
-                block_q=256, block_k=256,
+                q, k, v, causal=True, block_q=256, block_k=256,
             )
             return jnp.sum(o.astype(jnp.float32) ** 2), o
 
@@ -188,9 +183,7 @@ def main():
     args = ap.parse_args()
 
     from moolib_tpu.bench.harness import append_device_trend
-    from moolib_tpu.utils import ensure_platforms
 
-    ensure_platforms()
     import jax
     import jax.numpy as jnp
 
@@ -212,12 +205,7 @@ def main():
         "dtype": str(jnp.dtype(dtype)),
         "flash_noninterpret_validation": {
             "ok": ok, "max_err_fwd": ef, "max_err_bwd": eb, "error": err,
-            "note": (
-                "Mosaic acceptance + numerics vs dense oracle"
-                if platform == "tpu"
-                else "non-TPU backend: interpret=False still exercises the "
-                "pallas lowering on this platform"
-            ),
+            "note": "Mosaic acceptance + numerics vs dense oracle",
         },
         "rows": [],
     }

@@ -42,9 +42,8 @@ echo "== lint enforcement tests (slow-marked) =="
 # against the baseline and the CLI exit-zero pin — are ~150s of pure
 # moolint wall, the same sweep the three stages above just ran. They
 # are slow-marked out of the tier-1 pytest window (ISSUE 19 headroom)
-# and run here as their own named stage, mirroring the chip_session
-# rehearsal precedent: coverage is unchanged, only the budget it
-# bills against moved.
+# and run here as their own named stage: coverage is unchanged, only
+# the budget it bills against moved.
 timeout -k 10 400 env JAX_PLATFORMS=cpu python -m pytest \
   tests/test_lint.py -q -m slow -p no:cacheprovider
 
@@ -198,16 +197,6 @@ echo "== incident smoke =="
 # scenario failure writes a bundle into incidents/ and prints its path
 # next to the seed-replay command (upload incidents/ as a CI artifact).
 timeout -k 10 120 env JAX_PLATFORMS=cpu python tools/incident_report.py --smoke
-
-echo "== chip_session rehearsal =="
-# The full probe -> stage-run -> artifact-write rehearsal (400-500s of
-# subprocess compiles on this class of container) no longer fits inside
-# tier-1's 870s window, so it is `slow`-marked out of the pytest sweep
-# below and runs here as its own named stage — coverage is unchanged,
-# only the budget it bills against moved. MOOLIB_SKIP_REHEARSAL=1 still
-# opts out for quick local iterations.
-timeout -k 10 800 env JAX_PLATFORMS=cpu python -m pytest \
-  tests/test_bench_tools.py -q -m slow -p no:cacheprovider
 
 echo "== tier-1 tests =="
 rm -f /tmp/_t1.log

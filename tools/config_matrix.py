@@ -187,10 +187,6 @@ def main():
     ap.add_argument("--only", type=int, default=None)
     args = ap.parse_args()
 
-    from moolib_tpu.utils import ensure_platforms
-
-    ensure_platforms()
-
     installed = {}
     for m in ("ale_py", "procgen", "nle"):
         try:

@@ -33,9 +33,6 @@ def main():
     ap.add_argument("--env", default="cartpole")
     args = ap.parse_args()
 
-    from moolib_tpu.utils import ensure_platforms
-
-    ensure_platforms()
     from moolib_tpu.examples.a2c import A2CConfig, train
 
     cfg = A2CConfig(env=args.env, total_steps=args.steps)

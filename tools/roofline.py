@@ -33,7 +33,9 @@ MXU = 128  # systolic array is MXU x MXU lanes
 BF16 = 2  # bytes
 PEAK = 197e12  # v5e bf16 FLOP/s
 HBM = 819e9  # v5e bytes/s
-MEASURED_MS_B256 = 67.0  # PERF_r03.json: 76,377 env-steps/s at T=20, B=256
+# 76,377 env-steps/s at T=20, B=256: taken on a harness that has since been
+# removed (CHANGES.md, PR 21), older than most of the code; not re-measured.
+MEASURED_MS_B256 = 67.0
 
 
 def tile_eff(k: int, n: int) -> float:
@@ -78,7 +80,7 @@ def main():
           "weight traffic omitted)")
     if (B, T) == (256, 20):
         print(f"\nreading: measured {MEASURED_MS_B256:.0f} ms/step "
-              "(PERF_r03.json, B=256) sits between the naive-mapping MXU "
+              "(removed round-3 harness, B=256) sits between the naive-mapping MXU "
               "bound and the HBM floor -> XLA's conv packing already beats "
               "naive im2col on these narrow channels; the remaining gap is "
               "lane padding, which is architectural.")
