@@ -308,7 +308,8 @@ def train(cfg: A2CConfig, log_fn=print) -> List[dict]:
     futures = [pool.step(i, actions[i]) for i in range(cfg.num_batches)]
     # Phase attribution for the learner loop (docs/observability.md,
     # "Step-phase attribution"): one ledger per while-iteration, phases
-    # env_wait / host_sync / fwd_bwd / grad_allreduce / optimizer.
+    # env_wait / host_sync / grad_dispatch / grad_allreduce /
+    # apply_dispatch.
     scope = StepScope("a2c_learner")
 
     try:
@@ -361,7 +362,7 @@ def train(cfg: A2CConfig, log_fn=print) -> List[dict]:
                             k: jnp.asarray(v) if not isinstance(v, tuple) else v
                             for k, v in unroll.items()
                         }
-                        with scope.phase("fwd_bwd"):
+                        with scope.phase("grad_dispatch"):
                             grads, metrics = grad_step(state.params, batch)
                             # Defer the host readback (same as the vtrace
                             # loop): a float() here would block on device
@@ -397,7 +398,7 @@ def train(cfg: A2CConfig, log_fn=print) -> List[dict]:
                         accumulator.skip_gradients()
                         stats["skips"] += 1
                 if accumulator.has_gradients():
-                    with scope.phase("optimizer"):
+                    with scope.phase("apply_dispatch"):
                         mean_grads, _count = accumulator.result_gradients()
                         # Atomic with the rebind: a get_state on an RPC
                         # thread between the donating dispatch and the

@@ -78,13 +78,16 @@ def make_train_state(params, optimizer: optax.GradientTransformation) -> TrainSt
 
 
 def _scoped(fn: Callable, stepscope, phase: str) -> Callable:
-    """Wrap a jitted step so each invocation is attributed to a stepscope
-    phase (moolib_tpu.telemetry.stepscope). The phase CM no-ops outside
-    an active ``scope.step()``, so a scoped step factory is safe to call
-    from anywhere; with dispatch being async, the attributed time is
-    trace/compile on the first call and dispatch overhead after — the
-    blocking readback shows up in the caller's ``host_sync`` phase, where
-    it actually serializes."""
+    """Wrap a jitted step so each call is a stepscope phase, and with it
+    a ``moolib.<loop>.<phase>`` span (moolib_tpu.telemetry.stepscope).
+    The phase CM no-ops outside an active ``scope.step()``, so a scoped
+    step factory is safe to call from anywhere. Dispatch is
+    asynchronous: what is timed is the call (trace and compile the first
+    time, then argument handling and the enqueue), hence the names
+    ``act_dispatch`` / ``grad_dispatch`` / ``apply_dispatch``. The
+    device's time for the step is in the profiler's device plane, and
+    the host's wait for its result in the caller's ``host_sync`` (or
+    wherever it first reads one), where it actually serializes."""
     if stepscope is None:
         return fn
     cm = stepscope.phase(phase)
@@ -235,7 +238,7 @@ def make_impala_train_step(
 
         return _scoped(
             jax.jit(step, donate_argnums=(0,) if donate else ()),
-            stepscope, "fwd_bwd",
+            stepscope, "grad_dispatch",
         )
 
     replicated = P()
@@ -263,7 +266,7 @@ def make_impala_train_step(
 
     return _scoped(
         jax.jit(sharded_step, donate_argnums=(0,) if donate else ()),
-        stepscope, "fwd_bwd",
+        stepscope, "grad_dispatch",
     )
 
 
@@ -316,7 +319,7 @@ def make_grad_step(
             )(params, batch)
             return finish(grads, metrics)
 
-        return _scoped(jax.jit(step), stepscope, "fwd_bwd")
+        return _scoped(jax.jit(step), stepscope, "grad_dispatch")
 
     replicated = P()
 
@@ -338,7 +341,7 @@ def make_grad_step(
             out_specs=(replicated, replicated),
         )(params, batch)
 
-    return _scoped(jax.jit(sharded_step), stepscope, "fwd_bwd")
+    return _scoped(jax.jit(sharded_step), stepscope, "grad_dispatch")
 
 
 def make_apply_step(
@@ -357,7 +360,7 @@ def make_apply_step(
 
     return _scoped(
         jax.jit(apply, donate_argnums=(0,) if donate else ()),
-        stepscope, "optimizer",
+        stepscope, "apply_dispatch",
     )
 
 
@@ -389,7 +392,7 @@ def make_act_step(apply_fn: Callable, temperature: float = 1.0,
         a = jax.random.categorical(rng, logits, axis=-1)
         return a, logits, core_state
 
-    return _scoped(act, stepscope, "act")
+    return _scoped(act, stepscope, "act_dispatch")
 
 
 def replicate_state(state: TrainState, mesh: Mesh) -> TrainState:

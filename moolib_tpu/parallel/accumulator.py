@@ -65,6 +65,7 @@ from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import contextlib
 import threading
 import time
 import weakref
@@ -93,13 +94,22 @@ def _to_numpy_tree(tree):
 
 
 
-def _materialize_parts(parts):
+_NO_SPAN = contextlib.nullcontext()
+
+
+def _materialize_parts(parts, to_host=_NO_SPAN, local_reduce=_NO_SPAN):
     """Convert staged contribution trees to numpy and sum them (None for
     an empty list). Runs OFF the training thread, after the async D2H
-    staged in :func:`_stage_host_async` has had a round-trip to finish."""
+    staged in :func:`_stage_host_async` has had a round-trip to finish.
+    A committed count round hands in the Accumulator's two spans, so a
+    profiler capture shows the conversion (which waits for the gradient
+    step and its copy) and the sum beside the loop thread's phases."""
     out = None
     for p in parts:
-        out = _tree_add(out, _to_numpy_tree(p))
+        with to_host:
+            p = _to_numpy_tree(p)
+        with local_reduce:
+            out = _tree_add(out, p)
     return out
 
 
@@ -429,6 +439,16 @@ class Accumulator:
         # parts list it times.
         self._scope = StepScope("acc_grad_round", telemetry=rpc.telemetry)
         self._scope_local_s = 0.0
+        # The two blocks of a committed count round that run on an RPC
+        # completion thread while the training thread goes on. One count
+        # round is in flight at a time, so each span has one thread at a
+        # time.
+        self._span_to_host = rpc.telemetry.span(
+            "moolib.acc.grad_to_host", cat="acc"
+        )
+        self._span_local_reduce = rpc.telemetry.span(
+            "moolib.acc.local_reduce", cat="acc"
+        )
         # The registry outlives this Accumulator; a strong `self` in the
         # gauge closures would pin model-sized buffers (_zeros_bundle,
         # _committed_bundle, _results) after close(). A dead ref scrapes
@@ -1046,7 +1066,10 @@ class Accumulator:
             cancelled = None
             if snap_parts:
                 try:
-                    snap_parts = [_materialize_parts(snap_parts)]
+                    snap_parts = [_materialize_parts(
+                        snap_parts, self._span_to_host,
+                        self._span_local_reduce,
+                    )]
                 except (asyncio.CancelledError,
                         concurrent.futures.CancelledError) as e:
                     # Never swallow cancellation — but the cluster already

@@ -20,6 +20,11 @@ Two independent switches, both cheap to consult:
   RPC wire metadata — caller and handler spans of one call share a trace
   id across peers.
 
+The program's own spans (``Telemetry.span``: a loop's step and phases
+through ``StepScope``, the Accumulator's off-thread blocks) are under
+``on``; they also land, as ``jax.profiler.TraceAnnotation``, on any live
+profiler session, which needs no switch of its own.
+
 Ownership: each ``Rpc`` owns a private ``Telemetry`` (so two peers in one
 process scrape as two distinct processes); components without a peer
 identity (local ``Batcher``/``EnvPool`` instances, chaosnet plans, the
@@ -46,7 +51,7 @@ from .registry import (
     parse_prometheus,
     quantile_from_export,
 )
-from .trace import Span, TraceBuffer, now_us, spans_to_chrome
+from .trace import ProgramSpan, Span, TraceBuffer, now_us, spans_to_chrome
 # Imported AFTER .registry/.trace: the flightrec package imports
 # moolib_tpu.telemetry.trace, which is satisfied mid-cycle only because
 # those submodules are already in sys.modules by this line.
@@ -70,6 +75,7 @@ __all__ = [
     "RollingQuantile",
     "TraceBuffer",
     "Span",
+    "ProgramSpan",
     "DEFAULT_TIME_EDGES",
     "EXPORT_QUANTILES",
     "FRACTION_EDGES",
@@ -123,6 +129,15 @@ class Telemetry:
 
     def set_tracing(self, on: bool = True) -> None:
         self.tracing = bool(on)
+
+    def span(self, name: str, cat: str = "program",
+             args: Optional[Dict[str, Any]] = None) -> ProgramSpan:
+        """A reusable span of the program under this telemetry's gates:
+        a ``TraceAnnotation`` on any live profiler session and, while
+        ``tracing`` is on, a ``TraceBuffer`` span at its real start and
+        duration. The caller keeps the object and enters it each time
+        (see :class:`ProgramSpan`)."""
+        return ProgramSpan(self, name, cat, args)
 
     # -- exports --------------------------------------------------------------
 

@@ -32,7 +32,7 @@ Usage, single-owner-thread loop (the common case)::
         with scope.step():
             with scope.phase("env_wait"):
                 batch = futures.pop().result()
-            with scope.phase("fwd_bwd"):
+            with scope.phase("grad_dispatch"):
                 grads = grad_step(state, batch)
 
 ``phase`` context managers nest: a child's time is attributed to the
@@ -41,7 +41,17 @@ sub-region inside it never double-counts. Producers whose steps overlap
 in time (envpool's double-buffered batches) or complete on another
 thread (accumulator rounds) use the thread-safe low-level API instead::
 
-    scope.observe_step(wall_s, {"env_wait": w, "staging": s}, ts_us=t0)
+    scope.observe_step(wall_s, {"env_wait": w, "staging": s})
+
+One ``with``, two readings of it: ``step()`` and ``phase()`` also open
+the telemetry layer's span (:class:`~moolib_tpu.telemetry.trace
+.ProgramSpan`) named ``moolib.<loop>.step`` / ``moolib.<loop>.<phase>``
+from the same two clock readings as the ledger entry. The counter is
+what a long window reads with no profiler on; the span is what a
+profiler capture (or the ``TraceBuffer``, while tracing is on) shows in
+place, beside the device's operations. ``observe_step`` producers keep
+their counters and have no span: their steps overlap or end on another
+thread, so there is no one line to draw them on.
 
 Cost discipline: the context managers are gated on a single attribute
 snapshot taken at ``step()`` entry (so a mid-step ``Telemetry.on`` flip
@@ -63,9 +73,6 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
-from .registry import FRACTION_EDGES
-from .trace import now_us
-
 __all__ = [
     "StepScope",
     "PHASE_CLASS",
@@ -81,10 +88,10 @@ __all__ = [
 #: The reserved residual phase: wall time no explicit phase claimed.
 OTHER_PHASE = "other"
 
-#: phase name -> critical-path class. Phases outside this table (fwd_bwd,
-#: act, optimizer, infer, other, ...) are compute/residual and contribute
-#: to no derived fraction. The catalogue in docs/observability.md mirrors
-#: this mapping.
+#: phase name -> critical-path class. Phases outside this table (the
+#: ``*_dispatch`` phases, infer, other, ...) are compute/residual and
+#: contribute to no derived fraction. The catalogue in
+#: docs/observability.md mirrors this mapping.
 PHASE_CLASS: Dict[str, str] = {
     # Host blocked on collective results — the overlap target.
     "grad_allreduce": "comms",
@@ -92,6 +99,14 @@ PHASE_CLASS: Dict[str, str] = {
     # Host/device serialization.
     "host_sync": "host",
     "staging": "host",
+    # The vtrace loop's own names for where its thread copies on the
+    # host, or blocks on the device or on a copy.
+    "unroll_cat": "host",
+    "obs_stage": "host",
+    "learn_batch_get": "host",
+    "learn_stage": "host",
+    "grad_stage": "host",
+    "metrics_drain": "host",
     "local_reduce": "host",
     "checkpoint": "host",
     # Input starvation (env tier and serving queue alike).
@@ -121,10 +136,11 @@ class _StepCM:
     """Reusable ``with scope.step():`` context manager (no per-step
     allocation beyond the ledger dict itself)."""
 
-    __slots__ = ("_s",)
+    __slots__ = ("_s", "_span")
 
     def __init__(self, scope: "StepScope"):
         self._s = scope
+        self._span = scope._span("step")
 
     def __enter__(self) -> "_StepCM":
         s = self._s
@@ -135,8 +151,8 @@ class _StepCM:
             return self
         s._ledger = {}
         s._stack.clear()
-        s._step_ts_us = now_us() if s._tel.tracing else 0
         s._step_t0 = time.monotonic()
+        self._span.begin()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
@@ -145,7 +161,8 @@ class _StepCM:
             return False
         s._active = False
         wall = time.monotonic() - s._step_t0
-        s._finish_step(wall, s._ledger, s._step_ts_us)
+        self._span.end(wall)
+        s._finish_step(wall, s._ledger)
         return False
 
 
@@ -154,11 +171,12 @@ class _PhaseCM:
     self-time: a child's duration is subtracted from its parent's
     attribution, so the ledger never double-counts nested regions."""
 
-    __slots__ = ("_s", "name")
+    __slots__ = ("_s", "name", "_span")
 
     def __init__(self, scope: "StepScope", name: str):
         self._s = scope
         self.name = name
+        self._span = scope._span(name)
 
     def __enter__(self) -> "_PhaseCM":
         s = self._s
@@ -166,6 +184,7 @@ class _PhaseCM:
             return self
         # [name, t0, child_seconds]
         s._stack.append([self.name, time.monotonic(), 0.0])
+        self._span.begin()
         return self
 
     def __exit__(self, *exc: Any) -> bool:
@@ -174,6 +193,7 @@ class _PhaseCM:
             return False
         frame = s._stack.pop()
         dt = time.monotonic() - frame[1]
+        self._span.end(dt)
         self_dt = dt - frame[2]
         if self_dt > 0.0:
             led = s._ledger
@@ -189,11 +209,11 @@ class StepScope:
     critical-path fractions as windowed registry gauges.
 
     Threading contract (racelint-shaped): ``_active`` / ``_stack`` /
-    ``_ledger`` / ``_step_t0`` / ``_step_ts_us`` belong to the loop's
-    owner thread and are NEVER touched under ``_lock``; the cumulative
-    and windowed aggregates live only under ``_lock``. Registry metric
-    objects are internally thread-safe and are recorded outside the
-    scope lock.
+    ``_ledger`` / ``_step_t0`` and the step and phase context managers
+    (each with its span) belong to the loop's owner thread and are NEVER
+    touched under ``_lock``; the cumulative and windowed aggregates live
+    only under ``_lock``. Registry metric objects are internally
+    thread-safe and are recorded outside the scope lock.
     """
 
     def __init__(self, loop: str, telemetry=None, window: int = 32,
@@ -205,7 +225,6 @@ class StepScope:
         self._tel = telemetry
         self._window = max(1, int(window))
         self._flight_every = max(1, int(flight_every))
-        self._pid = telemetry.name or "stepscope"
         self._closed = False
 
         # Owner-thread step state (see class docstring).
@@ -213,7 +232,6 @@ class StepScope:
         self._stack: List[List[Any]] = []
         self._ledger: Dict[str, float] = {}
         self._step_t0 = 0.0
-        self._step_ts_us = 0
 
         # Shared aggregates — guarded by _lock.
         self._lock = threading.Lock()
@@ -224,9 +242,9 @@ class StepScope:
         self._win: Deque[Tuple[float, ...]] = deque()
         self._win_sums = [0.0] * 6
 
-        # Metrics. Phase-labeled counter/histogram pairs are cached
-        # per phase name; creation races are benign (the registry's
-        # get-or-create is idempotent and returns the same object).
+        # Metrics. Phase-labeled counters are cached per phase name;
+        # creation races are benign (the registry's get-or-create is
+        # idempotent and returns the same object).
         reg = telemetry.registry
         self._m_steps = reg.counter("stepscope_steps_total", loop=self.loop)
         self._m_wall = reg.counter(
@@ -245,15 +263,26 @@ class StepScope:
         self._g_overrun = reg.gauge(
             "stepscope_ledger_overrun_fraction", loop=self.loop
         )
-        self._phase_m: Dict[str, Tuple[Any, Any]] = {}
+        self._phase_m: Dict[str, Any] = {}
         self._phase_cm: Dict[str, _PhaseCM] = {}
-        self._step_cm = _StepCM(self)
+        # Made on first use: only a loop that runs step() on its own
+        # thread has spans (and with them the profiler's import).
+        self._step_cm: Optional[_StepCM] = None
 
     # -- owner-thread API ----------------------------------------------------
 
+    def _span(self, part: str):
+        return self._tel.span(
+            f"moolib.{self.loop}.{part}", cat="stepscope",
+            args={"loop": self.loop},
+        )
+
     def step(self) -> _StepCM:
         """Context manager spanning one loop iteration."""
-        return self._step_cm
+        cm = self._step_cm
+        if cm is None:
+            cm = self._step_cm = _StepCM(self)
+        return cm
 
     def phase(self, name: str) -> _PhaseCM:
         """Context manager attributing a region of the current step to
@@ -275,8 +304,8 @@ class StepScope:
 
     # -- thread-safe low-level API -------------------------------------------
 
-    def observe_step(self, wall_s: float, phases: Dict[str, float],
-                     ts_us: Optional[int] = None) -> None:
+    def observe_step(self, wall_s: float,
+                     phases: Dict[str, float]) -> None:
         """Record one completed step with an externally measured ledger.
 
         For producers whose steps overlap in wall time (double-buffered
@@ -289,30 +318,19 @@ class StepScope:
         self._finish_step(
             max(float(wall_s), 0.0),
             {k: float(v) for k, v in phases.items() if v > 0.0},
-            int(ts_us) if ts_us else 0,
         )
 
     # -- ingestion -----------------------------------------------------------
 
-    def _phase_metrics(self, name: str) -> Tuple[Any, Any]:
+    def _phase_seconds(self, name: str):
         m = self._phase_m.get(name)
         if m is None:
-            reg = self._tel.registry
-            m = (
-                reg.counter(
-                    "stepscope_phase_seconds_total",
-                    loop=self.loop, phase=name,
-                ),
-                reg.histogram(
-                    "stepscope_phase_fraction", edges=FRACTION_EDGES,
-                    loop=self.loop, phase=name,
-                ),
+            m = self._phase_m[name] = self._tel.registry.counter(
+                "stepscope_phase_seconds_total", loop=self.loop, phase=name,
             )
-            self._phase_m[name] = m
         return m
 
-    def _finish_step(self, wall: float, ledger: Dict[str, float],
-                     ts_us: int) -> None:
+    def _finish_step(self, wall: float, ledger: Dict[str, float]) -> None:
         wall = max(wall, 1e-9)
         explicit = sum(ledger.values())
         residual = wall - explicit
@@ -322,29 +340,12 @@ class StepScope:
         overrun = -residual if residual < 0.0 else 0.0
         attributed = min(explicit / wall, 1.0)
 
-        tel = self._tel
-        if tel.tracing and ts_us:
-            # Attribution track: phases drawn back-to-back from step
-            # start in ledger (completion) order. It shows composition,
-            # not exact in-step placement — the ordinary span tracks
-            # carry placement.
-            t = ts_us
-            for name, secs in ledger.items():
-                dur = int(secs * 1e6)
-                tel.traces.add_span(
-                    f"phase {name}", "stepscope", pid=self._pid,
-                    ts_us=t, dur_us=dur, args={"loop": self.loop},
-                )
-                t += dur
-
         self._m_steps.inc()
         self._m_wall.inc(wall)
         self._m_step_s.observe(wall)
         by_class = dict.fromkeys(_CLASSES, 0.0)
         for name, secs in ledger.items():
-            ctr, hist = self._phase_metrics(name)
-            ctr.inc(secs)
-            hist.observe(min(secs / wall, 1.0))
+            self._phase_seconds(name).inc(secs)
             cls = PHASE_CLASS.get(name)
             if cls is not None:
                 by_class[cls] += secs
@@ -386,8 +387,8 @@ class StepScope:
                     "host_blocked": fractions["host"],
                     "env_wait": fractions["env"],
                 }
-        if flight_fields is not None and tel.flight.on:
-            tel.flight.record("step_phases", **flight_fields)
+        if flight_fields is not None and self._tel.flight.on:
+            self._tel.flight.record("step_phases", **flight_fields)
 
     # -- exports -------------------------------------------------------------
 
@@ -544,7 +545,8 @@ def phase_trace(peer_summaries: Dict[str, Dict[str, Dict[str, Any]]],
     one track (pid) per peer, one row (tid) per loop, phases drawn
     back-to-back with widths proportional to cumulative seconds. Shows
     where step time went, not when — the span timeline
-    (``TraceBuffer.chrome_trace``) carries placement. ``pid_base``
+    (``TraceBuffer.chrome_trace``: each loop's ``moolib.<loop>.*`` spans
+    where they happened) carries placement. ``pid_base``
     offsets track ids when appending onto an existing merged trace."""
     events: List[Dict[str, Any]] = []
     for i, peer in enumerate(sorted(peer_summaries), start=1):

@@ -15,6 +15,12 @@ Span timestamps are wall-clock microseconds (``time.time()``), the one
 clock different hosts share well enough to merge; durations are measured
 with the monotonic clock, so a span's extent is immune to wall-clock
 steps even though its placement is not.
+
+The program's own spans (a loop's step and its phases, the Accumulator's
+off-thread blocks) go through one seam, :class:`ProgramSpan`, which has
+two sinks: a ``jax.profiler.TraceAnnotation`` on the host plane of any
+live profiler session, on the device trace's own clock, and this buffer
+on the wall clock (``docs/observability.md``, "Trace spans").
 """
 
 from __future__ import annotations
@@ -24,7 +30,7 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "TraceBuffer", "now_us"]
+__all__ = ["Span", "TraceBuffer", "ProgramSpan", "now_us"]
 
 
 def now_us() -> int:
@@ -69,6 +75,82 @@ class Span:
         else:
             ev["s"] = "p"  # instant scope: process
         return ev
+
+
+class ProgramSpan:
+    """One named region of the program, written to two sinks.
+
+    On entry it opens a ``jax.profiler.TraceAnnotation(name)`` if a
+    profiler session is live (the benchmark's ``--trace 1``, an
+    operator's ``profile_dir``): the span lands on this thread's line of
+    the xplane's host plane, on the clock the device's operations are
+    on. With no session that is one static check. When the owner's
+    ``Telemetry.tracing`` is on, the exit also records the span into the
+    ``TraceBuffer`` at its real start (wall clock) and duration.
+
+    Created by :meth:`Telemetry.span` and reused: an entry allocates
+    nothing but the annotation itself. The open span's state is on the
+    object, so an object serves one thread at a time and does not nest
+    in itself. As a context manager it is gated on ``Telemetry.on`` and
+    times itself; an owner with a gate and a clock of its own
+    (``StepScope``) calls :meth:`begin` and :meth:`end` with the
+    duration it measured, so its counter and its span come from the same
+    two readings.
+    """
+
+    __slots__ = ("name", "_tel", "_cat", "_args", "_annotate", "_ann",
+                 "_ts_us", "_t0")
+
+    def __init__(self, telemetry, name: str, cat: str,
+                 args: Optional[Dict[str, Any]]):
+        # The one place the program meets the profiler. Imported here
+        # and not at module level: env workers import this package and
+        # must not pay for (or touch) jax.
+        from jax.profiler import TraceAnnotation
+
+        self.name = name
+        self._tel = telemetry
+        self._cat = cat
+        self._args = args
+        self._annotate = TraceAnnotation
+        self._ann = None
+        self._ts_us = 0
+        self._t0 = -1.0
+
+    def begin(self) -> None:
+        if self._annotate.is_enabled():
+            # The annotation starts at construction; its __enter__ is a
+            # no-op.
+            self._ann = self._annotate(self.name)
+        if self._tel.tracing:
+            self._ts_us = now_us()
+
+    def end(self, seconds: float) -> None:
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+        ts_us = self._ts_us
+        if ts_us:
+            self._ts_us = 0
+            tel = self._tel
+            tel.traces.add_span(
+                self.name, self._cat, pid=tel.name or "program",
+                ts_us=ts_us, dur_us=int(seconds * 1e6), args=self._args,
+            )
+
+    def __enter__(self) -> "ProgramSpan":
+        if self._tel.on:
+            self._t0 = time.monotonic()
+            self.begin()
+        else:
+            self._t0 = -1.0
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        if self._t0 >= 0.0:
+            self.end(time.monotonic() - self._t0)
+        return False
 
 
 class TraceBuffer:

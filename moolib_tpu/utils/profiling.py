@@ -6,7 +6,10 @@ actionable trace is XLA's: ``jax.profiler`` captures device timelines
 (MXU occupancy, HBM traffic, collective overlap) viewable in TensorBoard
 or Perfetto. This wraps it with a zero-dependency context manager and a
 step-window helper so experiments can capture exactly N steps without
-instrumenting their loops twice.
+instrumenting their loops twice. A capture also holds the program's own
+spans (``moolib.<loop>.<phase>``, ``moolib.acc.*``) on the host plane,
+on the device planes' clock: every ``StepScope`` phase opens one while a
+session is live (``telemetry/trace.py:ProgramSpan``).
 
 Timeline merge: every capture window is also recorded as a span on the
 :mod:`moolib_tpu.telemetry` trace buffer (category ``profiler``, args

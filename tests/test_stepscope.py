@@ -181,20 +181,160 @@ def test_close_unregisters_gauges_keeps_cumulative_series(clock):
         in snap
 
 
-def test_flight_events_and_trace_spans(clock):
+def test_flight_events_and_trace_spans(clock, monkeypatch):
+    # The TraceBuffer is on the wall clock: tie it to the fake one so
+    # placement is exact.
+    monkeypatch.setattr(time, "time", lambda: 1_000.0 + clock.t)
     tel = Telemetry("t", tracing=True)
     scope = _scope(telemetry=tel, flight_every=2)
     for i in range(4):
-        scope.observe_step(1.0, {"grad_allreduce": 0.25}, ts_us=1000 * i)
+        scope.observe_step(1.0, {"grad_allreduce": 0.25})
     events = [e for e in tel.flight.events() if e["kind"] == "step_phases"]
     assert [e["fields"]["steps"] for e in events] == [2, 4]
     assert events[-1]["fields"]["loop"] == "loop"
     assert events[-1]["fields"]["exposed_comms"] == pytest.approx(0.25)
     assert events[-1]["fields"]["wall_s"] == pytest.approx(4.0)
-    trace = tel.chrome_trace()
-    names = {e["name"] for e in trace["traceEvents"]
+    # observe_step producers keep their counters and draw no span: their
+    # steps overlap or end on another thread.
+    assert not [s for s in tel.traces.spans() if s.cat == "stepscope"]
+
+    # A step on the loop's own thread: every phase's span is where the
+    # phase was, not drawn back to back from the step's start.
+    mine = _scope(telemetry=tel, loop="mine")
+    with mine.step():
+        clock.advance(0.5)  # `other`: no placement, so no span
+        with mine.phase("outer"):
+            clock.advance(0.25)
+            with mine.phase("inner"):
+                clock.advance(0.125)
+            clock.advance(0.125)
+        clock.advance(1.0)
+        with mine.phase("inner"):
+            clock.advance(0.25)
+    spans = [s for s in tel.traces.spans() if s.cat == "stepscope"]
+    assert sorted(s.name for s in spans) == [
+        "moolib.mine.inner", "moolib.mine.inner", "moolib.mine.outer",
+        "moolib.mine.step",
+    ]
+    assert all(s.args == {"loop": "mine"} and s.pid == "t" for s in spans)
+    step = next(s for s in spans if s.name == "moolib.mine.step")
+    outer = next(s for s in spans if s.name == "moolib.mine.outer")
+    inner, again = sorted(
+        (s for s in spans if s.name == "moolib.mine.inner"),
+        key=lambda s: s.ts,
+    )
+    assert step.dur == 2_250_000
+    assert (outer.ts - step.ts, outer.dur) == (500_000, 500_000)
+    # Nested phases nest: the child lies inside its parent.
+    assert (inner.ts - step.ts, inner.dur) == (750_000, 125_000)
+    assert outer.ts <= inner.ts and inner.ts + inner.dur <= outer.ts + outer.dur
+    assert (again.ts - step.ts, again.dur) == (2_000_000, 250_000)
+    # ... and last what the ledger says: a leaf phase's spans add up to
+    # its seconds, a parent's span less its children's to its self time.
+    phases = mine.summary()["phases"]
+    assert (inner.dur + again.dur) / 1e6 == pytest.approx(phases["inner"])
+    assert (outer.dur - inner.dur) / 1e6 == pytest.approx(phases["outer"])
+    assert phases["other"] == pytest.approx(1.5)
+    names = {e["name"] for e in tel.chrome_trace()["traceEvents"]
              if e.get("cat") == "stepscope"}
-    assert names == {"phase grad_allreduce", "phase other"}
+    assert names == {"moolib.mine.step", "moolib.mine.outer",
+                     "moolib.mine.inner"}
+
+
+def test_profiler_capture_holds_the_step_and_its_phases(tmp_path):
+    """The other sink: during a live jax profiler session the same
+    ``with`` lands on the xplane's host plane, on the profiler's clock,
+    with the phases inside the step on one line."""
+    import jax
+    from jax.profiler import ProfileData
+
+    scope = _scope(loop="prof")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with scope.step():
+            with scope.phase("first"):
+                time.sleep(0.002)
+            with scope.phase("second"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    lines = [
+        {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+         for e in line.events if e.name.startswith("moolib.prof.")}
+        for plane in ProfileData.from_file(str(path)).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+    ]
+    (line,) = [found for found in lines if found]
+    assert set(line) == {"moolib.prof.step", "moolib.prof.first",
+                         "moolib.prof.second"}
+    step, first, second = (line["moolib.prof." + n]
+                           for n in ("step", "first", "second"))
+    assert step[0] <= first[0] < first[1] <= second[0] < second[1] <= step[1]
+    assert first[1] - first[0] >= 2e6 and second[1] - second[0] >= 2e6
+
+
+def test_gate_off_enters_no_annotation_and_records_no_span(monkeypatch):
+    entered = []
+
+    class Annotation:
+        @staticmethod
+        def is_enabled():
+            return True  # as if a profiler session were live
+
+        def __init__(self, name):
+            entered.append(name)
+
+        def __exit__(self, *exc):
+            pass
+
+    def run(tel):
+        scope = _scope(telemetry=tel)
+        spans = [scope.step()._span, scope.phase("a")._span,
+                 tel.span("moolib.acc.block")]
+        for span in spans:
+            monkeypatch.setattr(span, "_annotate", Annotation)
+        with scope.step():
+            with scope.phase("a"):
+                pass
+        with spans[-1]:
+            pass
+
+    off = Telemetry("off", enabled=False, tracing=True)
+    run(off)
+    assert entered == [] and len(off.traces) == 0
+    on = Telemetry("on", tracing=True)
+    run(on)
+    assert entered == ["moolib.loop.step", "moolib.loop.a", "moolib.acc.block"]
+    assert [s.name for s in on.traces.spans()] == [
+        "moolib.loop.a", "moolib.loop.step", "moolib.acc.block",
+    ]
+
+
+def test_phase_cost_ceiling_with_no_session_live():
+    """A phase with telemetry on and no profiler session: the ledger
+    entry, one static check for a session, nothing else. The ceiling is
+    generous (a phase measures about a microsecond) and the statistic a
+    median, so a loaded runner cannot flap it."""
+    import statistics
+
+    from jax.profiler import TraceAnnotation
+
+    assert not TraceAnnotation.is_enabled()
+    scope = _scope()
+    cm = scope.phase("p")
+    samples = []
+    with scope.step():
+        for _ in range(10_000):
+            t0 = time.perf_counter()
+            with cm:
+                pass
+            samples.append(time.perf_counter() - t0)
+    assert statistics.median(samples) < 50e-6
+    assert scope.summary()["phases"]["p"] > 0.0
 
 
 # -- snapshot analysis --------------------------------------------------------
@@ -417,3 +557,55 @@ def test_acceptance_serialized_comms_strictly_higher_than_overlap():
         f"serialized exposed_comms {serial_frac:.4f} not above "
         f"overlapped baseline {overlap_frac:.4f}"
     )
+
+
+#: Every phase of one turn of the vtrace loop (PERF.md section 3 says
+#: which metric reads which).
+VTRACE_TURN_PHASES = (
+    "env_wait", "unroll_cat", "obs_stage", "act_dispatch", "host_sync",
+    "env_submit", "acc_update", "learn_batch_get", "learn_stage",
+    "grad_dispatch", "grad_allreduce", "grad_result", "grad_stage",
+    "apply_dispatch", "metrics_drain", "log", "checkpoint",
+)
+
+
+@pytest.mark.integration
+def test_vtrace_train_names_every_part_of_its_turn(tmp_path):
+    from moolib_tpu.examples.vtrace.experiment import VtraceConfig, train
+    from moolib_tpu.telemetry import global_telemetry
+
+    def reading():
+        return summarize_stepscope(global_telemetry().snapshot()).get(
+            "vtrace_learner", {"wall_s": 0.0, "phases": {}}
+        )
+
+    before = reading()
+    logs = train(
+        VtraceConfig(
+            env="synthetic", num_actions=4, episode_length=40,
+            total_steps=1_280, actor_batch_size=8, learn_batch_size=8,
+            virtual_batch_size=8, num_actor_processes=2,
+            num_actor_batches=2, unroll_length=4, log_interval_steps=320,
+            stats_interval=1e9, savedir=str(tmp_path),
+            checkpoint_interval=0.0, checkpoint_history_interval=None,
+            seed=0,
+        ),
+        log_fn=lambda *a, **k: None,
+    )
+    assert logs and logs[-1]["updates"] >= 1
+    after = reading()
+    spent = {
+        name: secs - before["phases"].get(name, 0.0)
+        for name, secs in after["phases"].items()
+    }
+    missing = [p for p in VTRACE_TURN_PHASES if spent.get(p, 0.0) <= 0.0]
+    assert not missing, missing
+    assert not {"act", "fwd_bwd", "optimizer"} & set(spent)
+    wall = after["wall_s"] - before["wall_s"]
+    assert spent["other"] <= 0.10 * wall, (spent, wall)
+    # The host-side names count toward the host-blocked fraction.
+    for name in ("unroll_cat", "obs_stage", "learn_batch_get",
+                 "learn_stage", "grad_stage", "metrics_drain"):
+        assert PHASE_CLASS[name] == "host"
+    for name in ("act_dispatch", "grad_dispatch", "apply_dispatch"):
+        assert name not in PHASE_CLASS

@@ -205,7 +205,7 @@ def smoke() -> int:
     from moolib_tpu.telemetry import StepScope
     scope = StepScope("smoke_loop", telemetry=a.telemetry)
     for _ in range(8):
-        scope.observe_step(0.01, {"fwd_bwd": 0.007, "wire_wait": 0.002})
+        scope.observe_step(0.01, {"grad_dispatch": 0.007, "wire_wait": 0.002})
     # Both peers listen: only peers with a dialable address are
     # advertised to the crawler (connect-only lurkers are unreachable).
     a.listen("127.0.0.1:0")
