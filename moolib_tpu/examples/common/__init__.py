@@ -10,7 +10,7 @@ cluster-wide stats accumulator now lives in the library proper,
 time-major learn-unrolls of the layout the learner expects
 (:func:`moolib_tpu.learner.impala_loss` batch contract): frames overlap by
 one step so frame T of one unroll is frame 0 of the next, giving every
-unroll its bootstrap frame for free.
+unroll its bootstrap frame for one more copy of it.
 """
 
 from __future__ import annotations
@@ -22,6 +22,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from moolib_tpu.ops.batcher import LearnSlabs
 from moolib_tpu.utils import StatMax, StatMean, StatSum, Stats
 from moolib_tpu.utils import nest  # noqa: F401  (re-export)
 
@@ -96,34 +97,59 @@ class InProcessBroker:
 
 
 class EnvBatchState:
-    """Per-EnvPool-batch rollout state: RNN core state, frame/action buffers,
-    episode-return tracking.
+    """Per-EnvPool-batch rollout state: RNN core state, the unroll being
+    written, episode-return tracking.
+
+    Frames and actions are written in place, once each, into buffers shaped
+    as the learner wants them (:class:`moolib_tpu.ops.batcher.LearnSlabs`);
+    nothing is stacked when an unroll completes.
 
     Protocol, once per pool step (one `i` of the double buffer)::
 
         out = pool.step(i, actions).result()       # frame t arrives
         unroll = state.observe(out)                # may complete an unroll
-        if unroll is not None: learn_batcher.cat(unroll)
+        if unroll is not None: ...                 # [T+1, B, ...] / [T, B, ...]
         a, logits, core = act(params, rng, out["obs"], out["done"], state.core_state)
         state.record_action(a, logits, core)
         actions = a
+
+    Every unroll handed out this way has buffers of its own and is never
+    written again. With ``slabs`` (one ``LearnSlabs`` shared by all actor
+    batches of a loop) an unroll is a window of columns of the learn batch
+    itself: ``observe`` then returns True when the columns are complete,
+    and the caller answers, before it acts, with ``start_unroll(keep)``::
+
+        if state.observe(out):
+            state.start_unroll(keep=learner_wants_it)  # False: columns written again
+        ...
+        if not slabs.empty(): slab = slabs.get()   # the learn batch, in place
     """
 
-    def __init__(self, unroll_length: int, initial_core_state: Any):
+    def __init__(self, unroll_length: int, initial_core_state: Any,
+                 slabs: Optional[LearnSlabs] = None):
         self.T = unroll_length
         self.core_state = initial_core_state  # state at the newest frame
-        self._unroll_start_state = initial_core_state  # state at buffered frame 0
-        self._frames: List[Dict[str, np.ndarray]] = []
-        self._actions: List[np.ndarray] = []
-        self._logits: List[np.ndarray] = []
+        self._unroll_start_state = initial_core_state  # state at row 0
+        self._shared = slabs is not None
+        self._slabs = slabs  # made at the first frame when not shared
+        self._window: Optional[list] = None  # where the unroll is written
+        self._t = -1  # row of the newest frame
+        self._n_actions = 0
+        # The newest frame as the pool handed it out: views over shared
+        # memory, good until this batch is stepped again. Held from the
+        # frame that completes an unroll to start_unroll, which copies it
+        # once more, as row 0 of the next.
+        self._frame: Optional[tuple] = None
         # Episode stats harvested from done transitions, drained by
         # recent_returns()/recent_lengths().
         self._completed_returns: List[float] = []
         self._completed_lengths: List[float] = []
 
-    def observe(self, env_out: Dict[str, np.ndarray]) -> Optional[Dict]:
-        """Feed one EnvPool output dict (frame t); returns a completed
-        time-major unroll every ``unroll_length`` frames, else None."""
+    def observe(self, env_out: Dict[str, np.ndarray]):
+        """Feed one EnvPool output dict (frame t). Every ``unroll_length``
+        frames an unroll completes: returns it (time-major, buffers of its
+        own), or True where the unroll is columns of shared slabs; else
+        None."""
         done = np.asarray(env_out["done"])
         if done.any():
             rets = np.asarray(env_out["episode_return"])[done]
@@ -136,42 +162,52 @@ class EnvBatchState:
                 del self._completed_returns[:-1_000]
             if len(self._completed_lengths) > 10_000:
                 del self._completed_lengths[:-1_000]
-        obs = obs_from_env_out(env_out)
-        # Copy: EnvPool returns zero-copy views over shared memory that the
-        # next step into this buffer will overwrite.
-        frame = {
-            "obs": nest.map_structure(np.array, obs),
-            "done": np.array(done),
-            "rewards": np.asarray(env_out["reward"], np.float32).copy(),
-        }
-        self._frames.append(frame)
-        if len(self._frames) < self.T + 1:
+        if self._window is None:
+            if self._slabs is None:
+                # A slab as wide as this batch, never recycled: each
+                # unroll is a fresh one, the caller's to keep.
+                self._slabs = LearnSlabs(self.T, len(done), name="unroll")
+            self._window = self._slabs.window(len(done))
+        # The copy EnvPool's zero-copy views need (the next step into this
+        # buffer overwrites them), made straight into the frame's row.
+        frame = (obs_from_env_out(env_out), done, env_out["reward"])
+        self._t += 1
+        self._slabs.write_frame(self._window, self._t, *frame)
+        if self._t < self.T:
             return None
-        assert len(self._actions) == self.T, (
-            f"{len(self._actions)} actions for {len(self._frames)} frames"
+        assert self._n_actions == self.T, (
+            f"{self._n_actions} actions for {self._t + 1} frames"
         )
-        unroll = {
-            "obs": nest.map_structure(
-                lambda *xs: np.stack(xs), *[f["obs"] for f in self._frames]
-            ),
-            "done": np.stack([f["done"] for f in self._frames]),
-            "rewards": np.stack([f["rewards"] for f in self._frames]),
-            "actions": np.stack(self._actions).astype(np.int32),
-            "behavior_logits": np.stack(self._logits),
-            "core_state": self._unroll_start_state,
-        }
-        # Frame T becomes frame 0 of the next unroll (bootstrap overlap).
-        self._frames = [self._frames[-1]]
-        self._actions = []
-        self._logits = []
+        self._frame = frame
+        if self._shared:
+            return True
+        self.start_unroll()
+        return self._slabs.get().batch
+
+    def start_unroll(self, keep: bool = True) -> None:
+        """After the frame that completed an unroll, and before the next
+        ``record_action``: commit the unroll's columns and take the next
+        window (``keep``), or drop the unroll and write the same columns
+        again. Frame T becomes frame 0 of the next unroll either way
+        (bootstrap overlap)."""
+        if keep:
+            self._slabs.commit(self._window, self._unroll_start_state)
+            self._window = self._slabs.window(len(self._frame[1]))
+        else:
+            self._slabs.rewind()
         self._unroll_start_state = self.core_state
-        return unroll
+        self._t = 0
+        self._n_actions = 0
+        self._slabs.write_frame(self._window, 0, *self._frame)
+        self._frame = None
 
     def record_action(self, action, behavior_logits, new_core_state=None):
         """Record the action taken at the newest frame (and the core state
         that acting produced, which belongs to the *next* frame)."""
-        self._actions.append(np.asarray(action))
-        self._logits.append(np.asarray(behavior_logits, np.float32))
+        self._slabs.write_action(
+            self._window, self._t, action, behavior_logits
+        )
+        self._n_actions += 1
         if new_core_state is not None:
             self.core_state = new_core_state
 

@@ -14,8 +14,10 @@ overrides; main loop at :364-529), redesigned TPU-first:
 - the elastic cross-peer path (virtual batch, joiners/leavers, leader model
   push) is the :class:`moolib_tpu.Accumulator` over the broker group — DCN
   control plane only;
-- rollout→HBM staging is one ``jax.device_put`` per learn batch via the
-  :class:`moolib_tpu.Batcher`'s device staging + ``shard_batch``.
+- the two batching stages are one: every env frame is copied once, into
+  its row and columns of a reusable learn slab
+  (:class:`moolib_tpu.ops.batcher.LearnSlabs`), and rollout→HBM staging is
+  one ``jax.device_put`` per learn batch + ``shard_batch``.
 
 Run (one peer, starts its own broker):
     python -m moolib_tpu.examples.vtrace.experiment total_steps=200000
@@ -188,7 +190,7 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         make_train_state,
         replicate_state,
     )
-    from moolib_tpu.ops import Batcher
+    from moolib_tpu.ops.batcher import LearnSlabs
     from moolib_tpu.parallel import GlobalStatsAccumulator, make_mesh
     from moolib_tpu.parallel.mesh import shard_batch
     from moolib_tpu.utils import Checkpointer
@@ -402,9 +404,17 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         num_batches=cfg.num_actor_batches,
         action_dtype=np.int64,
     )
+    # The learn batch is assembled where it is staged from: each actor
+    # batch writes its unroll straight into a window of columns of a
+    # reusable [T+1, learn_batch_size, ...] slab, so a frame is copied once
+    # and nothing is stacked or concatenated when the columns fill
+    # (reference: examples/common/__init__.py:154-207 + Batcher, which copy
+    # it three times). core_state's [B, ...] leaves are joined on axis 0.
+    learn_slabs = LearnSlabs(cfg.unroll_length, cfg.learn_batch_size)
     batch_states = [
         EnvBatchState(
-            cfg.unroll_length, net.initial_state(cfg.actor_batch_size)
+            cfg.unroll_length, net.initial_state(cfg.actor_batch_size),
+            slabs=learn_slabs,
         )
         for _ in range(cfg.num_actor_batches)
     ]
@@ -412,13 +422,6 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         np.zeros(cfg.actor_batch_size, np.int64)
         for _ in range(cfg.num_actor_batches)
     ]
-    # Two-stage batching: EnvBatchState time-batches unrolls; this cats them
-    # along the batch axis into learn batches (reference:
-    # examples/common/__init__.py:154-207 + Batcher). Unroll leaves are
-    # [T, B, ...] except core_state's [B, ...] — hence the per-key axis.
-    learn_batcher = Batcher(
-        batch_size=cfg.learn_batch_size, dim=1, dims={"core_state": 0}
-    )
     max_ready_batches = 4  # backpressure: drop rollouts past this backlog
 
     env_steps = 0
@@ -470,18 +473,18 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         )
                 bs = batch_states[i]
                 with scope.phase("unroll_cat"):
-                    unroll = bs.observe(out)
-                    if unroll is not None:
+                    if bs.observe(out):
                         # Backpressure: while disconnected/electing/syncing
                         # the learner consumes nothing — drop rollouts
                         # rather than queue stale off-policy data without
-                        # bound.
-                        if (
+                        # bound. A dropped unroll's columns are written
+                        # again.
+                        keep = (
                             accumulator.connected()
-                            and learn_batcher.ready() < max_ready_batches
-                        ):
-                            learn_batcher.cat(unroll)
-                        else:
+                            and learn_slabs.ready() < max_ready_batches
+                        )
+                        bs.start_unroll(keep)
+                        if not keep:
                             stats["dropped_unrolls"] += 1
                 # The key's split is a dispatch of its own, and stays where
                 # it was, ahead of the staging.
@@ -514,17 +517,16 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                 accumulator.update()
             if accumulator.connected():
                 if accumulator.wants_gradients():
-                    if not learn_batcher.empty():
+                    if not learn_slabs.empty():
                         with scope.phase("learn_batch_get"):
-                            batch = learn_batcher.get()
+                            slab = learn_slabs.get()
                         with scope.phase("learn_stage"):
-                            # Per-leaf staging: obs may be a dict
-                            # (NLE-style) and core_state a tuple of
-                            # [B, ...] leaves.
-                            batch = {
-                                k: jax.tree_util.tree_map(jnp.asarray, v)
-                                for k, v in batch.items()
-                            }
+                            # device_put, not jnp.asarray: the slab is
+                            # written again, and on the CPU backend asarray
+                            # aliases a host array. The slab goes back into
+                            # use once these arrays are ready.
+                            batch = jax.device_put(slab.batch)
+                            learn_slabs.recycle(slab, batch)
                             if mesh is not None:
                                 batch = shard_batch(mesh, batch)
                         # No host sync between grad_step dispatch and
@@ -622,7 +624,6 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         scope.close()
         profiler.close()
         pool.close()
-        learn_batcher.close()
         accumulator.close()
         rpc.close()
         if broker is not None:

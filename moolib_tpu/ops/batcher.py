@@ -11,6 +11,11 @@ TPU twist: when a ``device`` is given, completed batches are assembled on the
 host in one contiguous buffer per leaf and moved in a single
 ``jax.device_put`` per structure — one H2D transfer instead of per-item
 copies, which is what keeps actor→HBM staging off the critical path.
+
+:class:`LearnSlabs` is the in-place assembler of learn batches: where a
+``Batcher`` copies what it is handed into a new batch, a slab *is* the batch,
+and each env frame is copied once, from the EnvPool's view into its row and
+columns.
 """
 
 from __future__ import annotations
@@ -26,7 +31,7 @@ import numpy as np
 from ..telemetry import global_telemetry
 from ..utils import nest
 
-__all__ = ["Batcher", "stage_batch"]
+__all__ = ["Batcher", "LearnSlabs", "stage_batch"]
 
 
 def stage_batch(batch: Any, device: Optional[Any]) -> Any:
@@ -388,3 +393,192 @@ class Batcher:
         the async transfer overlaps accumulation of the next batch and get()
         returns an already-staged jax.Array."""
         return stage_batch(batch, self.device)
+
+
+class _Slab:
+    """One learn batch on the host, and what its pool keeps with it."""
+
+    __slots__ = ("arrays", "core", "cols_done", "batch", "staged")
+
+    def __init__(self):
+        # key -> tree of host arrays [rows, B, ...], made at the key's first
+        # write and kept for every later fill.
+        self.arrays: dict = {}
+        self.core: list = []  # (first column, core state) per committed piece
+        self.cols_done = 0
+        self.batch: Any = None  # the learn batch, once every column is in
+        self.staged: Any = None  # device arrays staged from ``arrays``
+
+
+class LearnSlabs:
+    """Learn batches assembled in place, in reusable host slabs.
+
+    A slab holds one learn batch as the learner wants it: ``obs``, ``done``
+    and ``rewards`` as ``[T+1, B, ...]``, ``actions`` and
+    ``behavior_logits`` as ``[T, B, ...]``. A producer (one actor batch,
+    :class:`moolib_tpu.examples.common.EnvBatchState`) takes a *window* of
+    columns, writes frame and action ``t`` of its unroll straight into row
+    ``t`` of them, and once row ``T`` is in either commits the columns or
+    writes them again (a dropped unroll). A slab whose columns are all
+    committed is a learn batch: nothing is stacked or concatenated, every
+    frame was copied once. ``batch_size`` need not be a multiple of a
+    window's width: a window may end one slab and begin the next, and a
+    write is then two slice assignments per leaf.
+
+    ``core_state`` (``[B_actor, ...]`` device arrays, ``()`` without an
+    RNN) is not written anywhere: each committed unroll's start state is
+    kept, and the pieces are joined along axis 0 when the slab completes.
+
+    The hazard of a reusable buffer: ``jax.device_put`` returns while the
+    transfer still reads the host array. So :meth:`recycle` takes the
+    device arrays staged from the slab, and the slab is written again only
+    once they are ready. Stage with ``jax.device_put``, not
+    ``jnp.asarray``: the CPU backend lets the latter alias the host array.
+    A slab that is never recycled is never reused, and its batch then owns
+    its memory.
+
+    Not thread-safe: one thread writes, commits and takes.
+
+    Telemetry (process-global, label ``slabs=``):
+    ``learn_slab_batches_total`` (learn batches completed in place),
+    ``learn_slab_reuse_waits_total`` / ``learn_slab_reuse_wait_seconds_total``
+    (slabs taken back into use, and the seconds that waited for their
+    transfer), ``learn_slab_rewinds_total`` (unrolls whose columns were
+    written again).
+    """
+
+    def __init__(self, unroll_length: int, batch_size: int,
+                 name: str = "learn_slabs"):
+        if unroll_length < 1 or batch_size < 1:
+            raise ValueError("unroll_length and batch_size must be >= 1")
+        self.T = unroll_length
+        self.batch_size = batch_size
+        self._tail: Optional[_Slab] = None  # slab with columns still free
+        self._col = 0  # its first free column
+        self._ready: deque = deque()  # completed slabs, oldest first
+        self._free: deque = deque()  # recycled slabs, oldest staging first
+        self._tel = global_telemetry()
+        reg = self._tel.registry
+        self._m_batches = reg.counter("learn_slab_batches_total", slabs=name)
+        self._m_reuses = reg.counter("learn_slab_reuse_waits_total",
+                                     slabs=name)
+        self._m_reuse_wait = reg.counter(
+            "learn_slab_reuse_wait_seconds_total", slabs=name)
+        self._m_rewinds = reg.counter("learn_slab_rewinds_total", slabs=name)
+
+    # -- producer side ------------------------------------------------------
+
+    def window(self, n_cols: int) -> list:
+        """The next ``n_cols`` free columns, as pieces ``(slab, lo, hi,
+        src)``: columns ``lo:hi`` of ``slab`` take rows ``src:src+hi-lo``
+        of what the producer writes."""
+        pieces = []
+        src = 0
+        while src < n_cols:
+            if self._tail is None or self._col == self.batch_size:
+                self._tail, self._col = self._take(), 0
+            n = min(n_cols - src, self.batch_size - self._col)
+            pieces.append((self._tail, self._col, self._col + n, src))
+            self._col += n
+            src += n
+        return pieces
+
+    def write_frame(self, window: list, t: int, obs: Any, done: Any,
+                    rewards: Any) -> None:
+        """Copy frame ``t`` of the window's unroll into row ``t``."""
+        self._write(window, "obs", t, obs, self.T + 1)
+        self._write(window, "done", t, done, self.T + 1)
+        self._write(window, "rewards", t, rewards, self.T + 1, np.float32)
+
+    def write_action(self, window: list, t: int, actions: Any,
+                     behavior_logits: Any) -> None:
+        """Copy the action taken at frame ``t``, and the logits it was
+        drawn from, into row ``t``."""
+        self._write(window, "actions", t, actions, self.T, np.int32)
+        self._write(window, "behavior_logits", t, behavior_logits, self.T,
+                    np.float32)
+
+    def commit(self, window: list, core_state: Any = ()) -> None:
+        """Hand the window's columns, rows ``0..T`` written, to their
+        slabs; ``core_state`` is the unroll's start state."""
+        n_cols = sum(hi - lo for _, lo, hi, _ in window)
+        for slab, lo, hi, src in window:
+            n = hi - lo
+            slab.core.append((
+                lo,
+                core_state if n == n_cols
+                else nest.slice_fields(core_state, src, src + n, 0),
+            ))
+            slab.cols_done += n
+            if slab.cols_done == self.batch_size:
+                pieces = [c for _, c in sorted(slab.core, key=lambda p: p[0])]
+                slab.batch = dict(
+                    slab.arrays,
+                    core_state=pieces[0] if len(pieces) == 1
+                    else nest.cat_fields(pieces, axis=0),
+                )
+                self._ready.append(slab)
+                if self._tel.on:
+                    self._m_batches.inc()
+
+    def rewind(self) -> None:
+        """Count an unroll that is dropped: its producer keeps the window
+        and writes the columns again."""
+        if self._tel.on:
+            self._m_rewinds.inc()
+
+    # -- consumer side ------------------------------------------------------
+
+    def empty(self) -> bool:
+        return not self._ready
+
+    def ready(self) -> int:
+        """Completed learn batches waiting: what a producer holds against
+        its backlog bound before it commits or drops."""
+        return len(self._ready)
+
+    def get(self) -> _Slab:
+        """The oldest completed slab; its ``batch`` is the learn batch
+        (host arrays, ``core_state`` as committed). Give the slab back
+        with :meth:`recycle`, or keep the batch for good."""
+        if not self._ready:
+            raise RuntimeError("no completed learn batch")
+        return self._ready.popleft()
+
+    def recycle(self, slab: _Slab, staged: Any) -> None:
+        """Give ``slab`` back for another fill. ``staged`` is what was
+        made from its batch and may still be reading it: the slab is not
+        written before ``jax.block_until_ready(staged)`` returns."""
+        slab.batch = None
+        slab.core = []
+        slab.cols_done = 0
+        slab.staged = staged
+        self._free.append(slab)
+
+    # -- internals ----------------------------------------------------------
+
+    def _take(self) -> _Slab:
+        if not self._free:
+            return _Slab()
+        slab = self._free.popleft()
+        t0 = time.monotonic()
+        jax.block_until_ready(slab.staged)  # hotlint: sync -- reuse guard: the transfer dispatched a whole fill ago must be done reading the slab before its first row is written again
+        if self._tel.on:
+            self._m_reuses.inc()
+            self._m_reuse_wait.inc(time.monotonic() - t0)
+        slab.staged = None
+        return slab
+
+    def _write(self, window, key, t, tree, rows, dtype=None):
+        for slab, lo, hi, src in window:
+            dst = slab.arrays.get(key)
+            if dst is None:
+                dst = slab.arrays[key] = nest.map_structure(
+                    lambda x: np.empty(
+                        (rows, self.batch_size) + np.shape(x)[1:],
+                        dtype or np.asarray(x).dtype,
+                    ),
+                    tree,
+                )
+            for d, x in zip(nest.flatten(dst), nest.flatten(tree)):
+                d[t, lo:hi] = np.asarray(x)[src:src + hi - lo]
