@@ -77,7 +77,11 @@ class VtraceConfig:
     entropy_cost: float = 0.0006
     reward_clip: float = 1.0
     use_lstm: bool = False
-    model: str = "auto"  # auto | mlp | resnet | transformer
+    model: str = "auto"  # auto | mlp | resnet | transformer | decoder_lm
+    # decoder_lm: a JSON file of moolib_tpu.models.lm.decoder_lm's
+    # arguments (a benchmark configuration's model.kwargs); the env's
+    # observation is then a token id and its action the next token.
+    lm_config: Optional[str] = None
     transformer_mlp: str = "dense"  # dense | moe (Switch blocks + aux loss)
     num_experts: int = 8
     total_steps: int = 500_000
@@ -161,6 +165,17 @@ def _make_model(cfg: VtraceConfig):
             num_actions=num_actions, compute_dtype=dtype,
             mlp=cfg.transformer_mlp, num_experts=cfg.num_experts,
         )
+    if model == "decoder_lm":
+        import json
+
+        from moolib_tpu.models.lm import decoder_lm
+
+        if cfg.lm_config is None:
+            raise ValueError("model='decoder_lm' needs lm_config=<json file>")
+        with open(cfg.lm_config) as f:
+            kwargs = json.load(f)
+        kwargs = kwargs.get("model", {}).get("kwargs", kwargs)
+        return decoder_lm(**dict(kwargs, compute_dtype=dtype))
     if model == "nethack":
         return NetHackNet(
             num_actions=num_actions, use_lstm=cfg.use_lstm,
@@ -270,7 +285,13 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
     scope = StepScope("vtrace_learner")
     act = make_act_step(net.apply)
     learn_apply = net.apply
-    if getattr(net, "mlp", "dense") == "moe":
+    if cfg.model == "decoder_lm":
+        # The expert layers' counters ride the three-element convention
+        # into the training metrics (an overflow must never be silent).
+        from moolib_tpu.models.lm import learn_apply as lm_learn_apply
+
+        learn_apply = lm_learn_apply(net)
+    elif getattr(net, "mlp", "dense") == "moe":
         # MoE models sow per-layer aux (lb/z losses, drop fraction) into
         # intermediates; the 3-tuple apply convention folds them into the
         # loss and the training metrics (drops must never be silent).

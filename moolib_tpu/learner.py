@@ -128,12 +128,15 @@ def impala_loss(
     The model is unrolled over all T+1 frames; frame T provides the
     bootstrap value.
 
-    ``apply_fn`` may return an optional THIRD element, a dict of model aux
-    losses (the MoE convention: ``load_balance_loss``, ``router_z_loss``,
-    ``drop_fraction`` from
-    :func:`moolib_tpu.models.transformer.moe_aux_losses`); they are folded
-    into the total with ``config.moe_lb_cost`` / ``config.moe_z_cost`` and
-    surfaced in the metrics so capacity drops are visible in training logs.
+    ``apply_fn`` may return an optional THIRD element, a dict of model aux.
+    Where it carries ``load_balance_loss`` / ``router_z_loss`` (the
+    capacity-factor MoE's, from
+    :func:`moolib_tpu.models.transformer.moe_aux_losses`) they are folded
+    into the total with ``config.moe_lb_cost`` / ``config.moe_z_cost``;
+    ``drop_fraction`` is surfaced so capacity drops are visible in training
+    logs; every other entry (the dropless layer's ``moe_*`` counters, from
+    :func:`moolib_tpu.models.lm.learn_apply`) passes through to the
+    metrics as a counter.
     """
     out = apply_fn(
         params, batch["obs"], batch["done"], batch["core_state"]
@@ -146,33 +149,38 @@ def impala_loss(
     logits, bootstrap_value = logits[:-1], baseline[-1]
     baseline = baseline[:-1]
 
-    rewards = batch["rewards"][1:]
-    if config.reward_clip > 0:
-        rewards = jnp.clip(rewards, -config.reward_clip, config.reward_clip)
-    discounts = (~batch["done"][1:]).astype(jnp.float32) * config.discounting
+    with jax.named_scope("moolib.loss"):
+        rewards = batch["rewards"][1:]
+        if config.reward_clip > 0:
+            rewards = jnp.clip(
+                rewards, -config.reward_clip, config.reward_clip
+            )
+        discounts = (
+            ~batch["done"][1:]
+        ).astype(jnp.float32) * config.discounting
 
-    vt = vtrace.from_logits(
-        behavior_policy_logits=batch["behavior_logits"],
-        target_policy_logits=logits,
-        actions=batch["actions"],
-        discounts=discounts,
-        rewards=rewards,
-        values=baseline,
-        bootstrap_value=bootstrap_value,
-        clip_rho_threshold=config.clip_rho_threshold,
-        clip_pg_rho_threshold=config.clip_pg_rho_threshold,
-        lambda_=config.lambda_,
-    )
+        vt = vtrace.from_logits(
+            behavior_policy_logits=batch["behavior_logits"],
+            target_policy_logits=logits,
+            actions=batch["actions"],
+            discounts=discounts,
+            rewards=rewards,
+            values=baseline,
+            bootstrap_value=bootstrap_value,
+            clip_rho_threshold=config.clip_rho_threshold,
+            clip_pg_rho_threshold=config.clip_pg_rho_threshold,
+            lambda_=config.lambda_,
+        )
 
-    pg_loss = -jnp.mean(vt.target_action_log_probs * vt.pg_advantages)
-    baseline_loss = 0.5 * jnp.mean((vt.vs - baseline) ** 2)
-    entropy = _entropy(logits)
+        pg_loss = -jnp.mean(vt.target_action_log_probs * vt.pg_advantages)
+        baseline_loss = 0.5 * jnp.mean((vt.vs - baseline) ** 2)
+        entropy = _entropy(logits)
 
-    total = (
-        pg_loss
-        + config.baseline_cost * baseline_loss
-        - config.entropy_cost * entropy
-    )
+        total = (
+            pg_loss
+            + config.baseline_cost * baseline_loss
+            - config.entropy_cost * entropy
+        )
     metrics = {
         "total_loss": total,
         "pg_loss": pg_loss,
@@ -181,15 +189,20 @@ def impala_loss(
         "mean_baseline": jnp.mean(baseline),
     }
     if model_aux is not None:
-        total = (
-            total
-            + config.moe_lb_cost * model_aux["load_balance_loss"]
-            + config.moe_z_cost * model_aux["router_z_loss"]
-        )
+        # Loss terms where the model's aux carries them; every other entry
+        # is a counter of the model's own and goes to the metrics as it is.
+        aux = dict(model_aux)
+        for key, cost, name in (
+            ("load_balance_loss", config.moe_lb_cost, "moe_lb_loss"),
+            ("router_z_loss", config.moe_z_cost, "moe_z_loss"),
+        ):
+            if key in aux:
+                metrics[name] = aux.pop(key)
+                total = total + cost * metrics[name]
+        if "drop_fraction" in aux:
+            metrics["moe_drop_fraction"] = aux.pop("drop_fraction")
         metrics["total_loss"] = total
-        metrics["moe_lb_loss"] = model_aux["load_balance_loss"]
-        metrics["moe_z_loss"] = model_aux["router_z_loss"]
-        metrics["moe_drop_fraction"] = model_aux["drop_fraction"]
+        metrics.update(aux)
     return total, metrics
 
 
@@ -220,12 +233,13 @@ def make_impala_train_step(
         return loss_fn(params, apply_fn, batch, config)
 
     def sgd(state: TrainState, grads, metrics):
-        updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params
-        )
-        params = optax.apply_updates(state.params, updates)
-        metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("moolib.optimizer"):
+            updates, opt_state = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            params = optax.apply_updates(state.params, updates)
+            metrics = dict(metrics)
+            metrics["grad_norm"] = optax.global_norm(grads)
         return TrainState(params, opt_state, state.step + 1), metrics
 
     if mesh is None:

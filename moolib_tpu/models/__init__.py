@@ -7,6 +7,7 @@ from .impala import (
     space_to_depth,
     widen_impala_params,
 )
+from .lm import DecoderLM, decoder_lm
 from .nethack import NetHackNet
 from .transformer import TransformerNet
 
@@ -14,10 +15,12 @@ __all__ = [
     "A2CNet",
     "LSTMCore",
     "ConvSequence",
+    "DecoderLM",
     "ImpalaNet",
     "NetHackNet",
     "ResidualBlock",
     "TransformerNet",
+    "decoder_lm",
     "space_to_depth",
     "widen_impala_params",
 ]

@@ -40,7 +40,7 @@ from ..ops import attention as attn_ops
 from ..ops import ring_attention as ring_ops
 from ..parallel.moe import moe_ffn
 
-__all__ = ["TransformerNet", "moe_aux_losses"]
+__all__ = ["TransformerNet", "attend", "moe_aux_losses", "residual_block"]
 
 
 def segment_ids_from_done(done) -> jax.Array:
@@ -48,6 +48,45 @@ def segment_ids_from_done(done) -> jax.Array:
     of a new episode, matching the EnvPool convention where a done frame
     already holds the next episode's reset observation)."""
     return jnp.cumsum(done.astype(jnp.int32), axis=0).T
+
+
+def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
+           window: Optional[int] = None, **blocks):
+    """The one attention call site of the models: causal, cut at segment
+    boundaries, ``[B, H, T, D]`` in and out (``k``/``v`` may carry fewer
+    heads). ``ring`` / ``zigzag`` run across the ``ring_axis`` mesh axis
+    inside shard_map; everything else is :func:`attn_ops.attention` and
+    what its ``backend`` resolves to. ``blocks``: ``block_q``/``block_k``
+    where the caller sets them."""
+    if backend in ("ring", "zigzag"):
+        if window is not None or k.shape[1] != q.shape[1]:
+            raise ValueError(
+                f"the {backend} backend has neither a window nor grouped "
+                "heads"
+            )
+        if backend == "ring":
+            return ring_ops.ring_attention(
+                q, k, v, axis_name=ring_axis, causal=True,
+                segment_ids=seg_bt, kv_segment_ids=seg_bt,
+            )
+        # Caller feeds zigzag-laid-out shards (zigzag_order applied to the
+        # T axis of obs/done/segment_ids/positions before shard_map) —
+        # causal work then balances across the sp axis.
+        return ring_ops.zigzag_ring_attention(
+            q, k, v, axis_name=ring_axis, segment_ids=seg_bt,
+            kv_segment_ids=seg_bt,
+        )
+    return attn_ops.attention(
+        q, k, v, backend=backend, causal=True, segment_ids=seg_bt,
+        window=window, **blocks,
+    )
+
+
+def residual_block(x, norm1, mixer, norm2, mlp):
+    """The pre-norm residual skeleton every block shares:
+    ``h = x + mixer(norm1(x)); out = h + mlp(norm2(h))``."""
+    x = x + mixer(norm1(x))
+    return x + mlp(norm2(x))
 
 
 class _SelfAttention(nn.Module):
@@ -67,25 +106,10 @@ class _SelfAttention(nn.Module):
         def heads(t):  # [T, B, E] -> [B, H, T, D]
             return t.reshape(T, B, self.num_heads, D).transpose(1, 2, 0, 3)
 
-        q, k, v = heads(q), heads(k), heads(v)
-        if self.backend == "ring":
-            o = ring_ops.ring_attention(
-                q, k, v, axis_name=self.ring_axis, causal=True,
-                segment_ids=seg_bt, kv_segment_ids=seg_bt,
-            )
-        elif self.backend == "zigzag":
-            # Caller feeds zigzag-laid-out shards (zigzag_order applied to
-            # the T axis of obs/done/segment_ids/positions before
-            # shard_map) — causal work then balances across the sp axis.
-            o = ring_ops.zigzag_ring_attention(
-                q, k, v, axis_name=self.ring_axis,
-                segment_ids=seg_bt, kv_segment_ids=seg_bt,
-            )
-        else:
-            o = attn_ops.attention(
-                q, k, v, backend=self.backend, causal=True,
-                segment_ids=seg_bt,
-            )
+        o = attend(
+            heads(q), heads(k), heads(v), seg_bt, backend=self.backend,
+            ring_axis=self.ring_axis,
+        )
         o = o.transpose(2, 0, 1, 3).reshape(T, B, E)
         return nn.Dense(E, use_bias=False, name="out")(o)
 
@@ -135,16 +159,14 @@ class _MoEMlp(nn.Module):
         return y.reshape(T, B, E)
 
 
-def moe_aux_losses(intermediates) -> dict:
-    """Aggregate every MoE layer's sown aux from a flax ``intermediates``
-    collection: summed load-balance and router-z losses (add them to the
-    training loss, typically with weights ~1e-2 / ~1e-3) and the mean drop
-    fraction (log it — silent drops are a capacity bug)."""
+def sown_dicts(intermediates, marker: str) -> list:
+    """Every dict with the key ``marker`` that a module sowed into a flax
+    ``intermediates`` collection, in traversal order."""
     found = []
 
     def walk(node):
         if isinstance(node, dict):
-            if "load_balance_loss" in node:
+            if marker in node:
                 found.append(node)
             else:
                 for v in node.values():
@@ -154,6 +176,15 @@ def moe_aux_losses(intermediates) -> dict:
                 walk(v)
 
     walk(intermediates)
+    return found
+
+
+def moe_aux_losses(intermediates) -> dict:
+    """Aggregate every MoE layer's sown aux from a flax ``intermediates``
+    collection: summed load-balance and router-z losses (add them to the
+    training loss, typically with weights ~1e-2 / ~1e-3) and the mean drop
+    fraction (log it — silent drops are a capacity bug)."""
+    found = sown_dicts(intermediates, "load_balance_loss")
     if not found:
         raise ValueError("no MoE aux entries in intermediates — was the "
                          "model built with mlp='moe' and applied with "
@@ -183,21 +214,25 @@ class _Block(nn.Module):
             raise ValueError(
                 f"unknown mlp type {self.mlp!r}; expected 'dense' or 'moe'"
             )
-        h = nn.LayerNorm()(x)
-        x = x + _SelfAttention(
+        attention = _SelfAttention(
             self.num_heads, self.backend, self.ring_axis, name="attn"
-        )(h, seg_bt, positions)
-        h = nn.LayerNorm()(x)
+        )
         if self.mlp == "moe":
-            x = x + _MoEMlp(
+            mlp = _MoEMlp(
                 self.num_experts, self.mlp_ratio, self.moe_top_k,
                 self.moe_capacity_factor, name="moe",
-            )(h)
-            return x
-        h = nn.Dense(self.mlp_ratio * x.shape[-1])(h)
-        h = nn.gelu(h)
-        x = x + nn.Dense(x.shape[-1])(h)
-        return x
+            )
+        else:
+            width = x.shape[-1]
+
+            def mlp(h):
+                h = nn.gelu(nn.Dense(self.mlp_ratio * width)(h))
+                return nn.Dense(width)(h)
+
+        return residual_block(
+            x, nn.LayerNorm(), lambda h: attention(h, seg_bt, positions),
+            nn.LayerNorm(), mlp,
+        )
 
 
 class TransformerNet(nn.Module):

@@ -24,19 +24,26 @@ Three implementations, one contract ``[B, H, T, D] -> [B, H, T, D]``:
 
 All three support causal masking and ``segment_ids`` (attention is blocked
 across segment boundaries — used by the transformer agent to stop attention
-across episode resets inside an unroll).
+across episode resets inside an unroll), a causal ``window`` (query i sees
+key j only while ``i - j < window``) and grouped heads: ``k``/``v`` may
+carry fewer heads than ``q`` (``H % Hkv == 0``; query head h reads key/value
+head ``h // (H // Hkv)``), and no backend materialises the repeats. The
+flash kernels visit only the key blocks a window can reach, and skip the
+tiles that lie wholly above the diagonal or wholly in another segment.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ..telemetry import global_telemetry
 
 __all__ = [
     "dense_attention",
@@ -53,7 +60,28 @@ def _scale(q):
     return q / np.sqrt(q.shape[-1])
 
 
-def _mask_bias(Tq: int, Tk: int, causal: bool, seg_q, seg_k, q_offset=0):
+def _check_window(window, causal: bool):
+    if window is not None and (not causal or window < 1):
+        raise ValueError(
+            f"window={window!r} needs causal=True and at least one key"
+        )
+
+
+def _group_heads(q, k):
+    """q [B, H, Tq, D] as [B, Hkv, G, Tq, D] beside k [B, Hkv, Tk, D]:
+    grouped heads are a reshape of the queries, never a repeat of the
+    keys."""
+    B, H, Tq, D = q.shape
+    Hkv = k.shape[1]
+    if H % Hkv:
+        raise ValueError(
+            f"{H} query heads do not split over {Hkv} key/value heads"
+        )
+    return q.reshape(B, Hkv, H // Hkv, Tq, D)
+
+
+def _mask_bias(Tq: int, Tk: int, causal: bool, seg_q, seg_k, q_offset=0,
+               window=None):
     """[.., Tq, Tk] additive bias: 0 where allowed, -inf where masked.
 
     ``q_offset`` is the absolute position of q row 0 relative to k row 0
@@ -63,7 +91,10 @@ def _mask_bias(Tq: int, Tk: int, causal: bool, seg_q, seg_k, q_offset=0):
     if causal:
         qpos = jnp.arange(Tq)[:, None] + q_offset
         kpos = jnp.arange(Tk)[None, :]
-        bias = jnp.where(qpos >= kpos, 0.0, _NEG_INF)
+        seen = qpos >= kpos
+        if window is not None:
+            seen = jnp.logical_and(seen, qpos - kpos < window)
+        bias = jnp.where(seen, 0.0, _NEG_INF)
     if seg_q is not None:
         same = seg_q[..., :, None] == seg_k[..., None, :]
         seg_bias = jnp.where(same, 0.0, _NEG_INF)
@@ -78,24 +109,29 @@ def dense_attention(
     causal: bool = False,
     segment_ids: Optional[jax.Array] = None,
     kv_segment_ids: Optional[jax.Array] = None,
+    window: Optional[int] = None,
 ):
-    """Oracle attention. q [B, H, Tq, D], k/v [B, H, Tk, D],
+    """Oracle attention. q [B, H, Tq, D], k/v [B, Hkv, Tk, D],
     segment_ids [B, Tq] / kv_segment_ids [B, Tk] (defaults to segment_ids)."""
-    q = _scale(q.astype(jnp.float32))
+    _check_window(window, causal)
+    shape = q.shape
+    q = _group_heads(_scale(q.astype(jnp.float32)), k)
     k = k.astype(jnp.float32)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k)
+    scores = jnp.einsum("bhgqd,bhkd->bhgqk", q, k)
     seg_q = seg_k = None
     if segment_ids is not None:
         kv_seg = segment_ids if kv_segment_ids is None else kv_segment_ids
-        seg_q = segment_ids[:, None, :]  # [B, 1, Tq]
-        seg_k = kv_seg[:, None, :]
-    bias = _mask_bias(q.shape[-2], k.shape[-2], causal, seg_q, seg_k)
+        seg_q = segment_ids[:, None, None, :]  # [B, 1, 1, Tq]
+        seg_k = kv_seg[:, None, None, :]
+    bias = _mask_bias(
+        q.shape[-2], k.shape[-2], causal, seg_q, seg_k, window=window
+    )
     if bias is not None:
         scores = scores + bias
     w = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", w, v.astype(jnp.float32)).astype(
-        v.dtype
-    )
+    return jnp.einsum(
+        "bhgqk,bhkd->bhgqd", w, v.astype(jnp.float32)
+    ).reshape(shape).astype(v.dtype)
 
 
 def _online_block(q, k, v, bias, m, l, acc):
@@ -140,17 +176,22 @@ def blockwise_attention(
     kv_segment_ids: Optional[jax.Array] = None,
     block_k: int = 512,
     kv_position_offset: int = 0,
+    window: Optional[int] = None,
 ):
     """Memory-efficient attention: lax.scan over key blocks.
 
     ``kv_position_offset``: absolute position of k row 0 relative to q row 0
     (negative when keys precede queries — the ring-attention case).
     """
+    _check_window(window, causal)
     orig_dtype = v.dtype
-    qf = _scale(q.astype(jnp.float32))
+    # Grouped heads: the G query heads of one key/value head are G more
+    # rows of queries against the same keys.
+    qf = _group_heads(_scale(q.astype(jnp.float32)), k)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
-    B, H, Tq, D = q.shape
+    B, _, Tq, D = q.shape
+    H, G = qf.shape[1:3]
     Tk = k.shape[-2]
     block_k = min(block_k, Tk)
     n_blocks = -(-Tk // block_k)
@@ -185,23 +226,29 @@ def blockwise_attention(
         bias = None
         if causal:
             kpos = ki * block_k + jnp.arange(block_k)[None, :]
-            bias = jnp.where(qpos >= kpos, 0.0, _NEG_INF)  # [Tq, block_k]
+            seen = qpos >= kpos
+            if window is not None:
+                seen = jnp.logical_and(seen, qpos - kpos < window)
+            bias = jnp.where(seen, 0.0, _NEG_INF)  # [Tq, block_k]
         if segment_ids is not None:
             same = (
-                segment_ids[:, None, :, None] == segk[:, None, None, :]
-            )  # [B, 1, Tq, block_k]
+                segment_ids[:, None, None, :, None]
+                == segk[:, None, None, None, :]
+            )  # [B, 1, 1, Tq, block_k]
             seg_bias = jnp.where(same, 0.0, _NEG_INF)
             bias = seg_bias if bias is None else bias + seg_bias
-        m, l, acc = _online_block(qf, kblk, vblk, bias, m, l, acc)
+        m, l, acc = _online_block(
+            qf, kblk[:, :, None], vblk[:, :, None], bias, m, l, acc
+        )
         return (m, l, acc), None
 
-    m0 = jnp.full((B, H, Tq), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, H, Tq), jnp.float32)
-    a0 = jnp.zeros((B, H, Tq, D), jnp.float32)
+    m0 = jnp.full((B, H, G, Tq), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros((B, H, G, Tq), jnp.float32)
+    a0 = jnp.zeros((B, H, G, Tq, D), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0), (jnp.arange(n_blocks), kb, vb, sb)
     )
-    return _finalize(m, l, acc, orig_dtype)
+    return _finalize(m, l, acc, orig_dtype).reshape(q.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -239,7 +286,8 @@ def _lanes(x, n: int):
     )
 
 
-def _tile_mask(causal, q_axis, q_start, k_start, seg_rows, seg_cols):
+def _tile_mask(causal, q_axis, q_start, k_start, seg_rows, seg_cols,
+               window=None):
     """Visibility of one score tile — the ONE definition shared by the
     forward and both backward kernels, so the masks can never diverge.
     ``seg_rows`` [rows, cols] / ``seg_cols`` [1, cols] are the segment ids
@@ -254,33 +302,122 @@ def _tile_mask(causal, q_axis, q_start, k_start, seg_rows, seg_cols):
             jnp.int32, mask.shape, 1 - q_axis
         )
         mask = jnp.logical_and(mask, qpos >= kpos)
+        if window is not None:
+            mask = jnp.logical_and(mask, qpos - kpos < window)
     return mask
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
-                  lse_ref, m_sc, l_sc, acc_sc, *, causal: bool,
-                  block_q: int, block_k: int, n_k: int):
-    """Grid: (B*H, Tq//block_q, Tk//block_k); k-axis is the sequential
-    ('arbitrary') dimension carrying the online-softmax state in VMEM
-    scratch. q/k/v blocks arrive pre-staged by BlockSpec. Also emits the
-    per-row log-sum-exp (lse) the backward kernels rebuild P from."""
-    ki = pl.program_id(2)
-    head_dim = q_ref.shape[-1]
+class _Tiles(NamedTuple):
+    """The static geometry of one flash call, and with it which tiles a
+    kernel visits at all. Without a window every kernel walks the whole
+    other axis (and skips what the diagonal hides); with one, only the
+    ``n_kw`` key blocks a query block can reach (``n_qw`` query blocks
+    that can reach a key block), counted here from the block sizes."""
 
-    @pl.when(ki == 0)
+    causal: bool
+    window: Optional[int]
+    block_q: int
+    block_k: int
+    n_q: int
+    n_k: int
+    H: int  # query heads
+    Hkv: int  # key/value heads
+
+    @property
+    def G(self) -> int:
+        return self.H // self.Hkv
+
+    def first_k(self, qi):
+        """First key block the window lets query block ``qi`` see (python
+        ints or traced scalars alike)."""
+        if self.window is None:
+            return 0 * qi
+        lo = qi * self.block_q - (self.window - 1)
+        lo = max(lo, 0) if isinstance(lo, int) else jnp.maximum(lo, 0)
+        return lo // self.block_k
+
+    def first_q(self, kj):
+        """First query block that can see key block ``kj``."""
+        if self.window is None:
+            return 0 * kj
+        return (kj * self.block_k) // self.block_q
+
+    def k_block(self, qi, j):
+        """Key block at step ``j`` of query block ``qi``'s walk, held
+        inside the sequence (a step past it is skipped by ``visible``)."""
+        return jnp.minimum(self.first_k(qi) + j, self.n_k - 1)
+
+    def q_block(self, kj, step):
+        """Query block at ``step`` of key block ``kj``'s walk over its
+        ``G`` query heads, ``n_qw`` blocks a head."""
+        return jnp.minimum(self.first_q(kj) + step % self.n_qw, self.n_q - 1)
+
+    @property
+    def n_kw(self) -> int:
+        if self.window is None:
+            return self.n_k
+        return max(
+            min(self.n_k - 1, (qi * self.block_q + self.block_q - 1)
+                // self.block_k) - self.first_k(qi) + 1
+            for qi in range(self.n_q)
+        )
+
+    @property
+    def n_qw(self) -> int:
+        if self.window is None:
+            return self.n_q
+        reach = self.block_k - 1 + self.window - 1
+        return max(
+            min(self.n_q - 1, (kj * self.block_k + reach) // self.block_q)
+            - self.first_q(kj) + 1
+            for kj in range(self.n_k)
+        )
+
+    def visible(self, qi, ki, q_rng, k_rng, b):
+        """Whether tile (query block ``qi``, key block ``ki``) of batch row
+        ``b`` holds anything: not wholly above the diagonal, not wholly
+        out of the window, not wholly in another segment (``q_rng`` /
+        ``k_rng``: the least and largest segment id of every block, in
+        SMEM), and inside the sequence (a window's walk may step past the
+        last block)."""
+        lo_q = q_rng[(b * self.n_q + qi) * 2]
+        hi_q = q_rng[(b * self.n_q + qi) * 2 + 1]
+        lo_k = k_rng[(b * self.n_k + ki) * 2]
+        hi_k = k_rng[(b * self.n_k + ki) * 2 + 1]
+        seen = jnp.logical_and(lo_k <= hi_q, hi_k >= lo_q)
+        if self.causal:
+            q_last = qi * self.block_q + self.block_q - 1
+            seen = jnp.logical_and(seen, ki * self.block_k <= q_last)
+        if self.window is not None:
+            k_last = ki * self.block_k + self.block_k - 1
+            seen = jnp.logical_and(
+                seen, k_last >= qi * self.block_q - (self.window - 1)
+            )
+        return seen
+
+
+def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
+                  o_ref, lse_ref, m_sc, l_sc, acc_sc, *, t: _Tiles):
+    """Grid: (B*H, n_q, n_kw); the k-axis is the sequential ('arbitrary')
+    dimension carrying the online-softmax state in VMEM scratch. q/k/v
+    blocks arrive pre-staged by BlockSpec. Also emits the per-row
+    log-sum-exp (lse) the backward kernels rebuild P from."""
+    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    ki = t.first_k(qi) + j
+    head_dim = q_ref.shape[-1]
+    block_k = t.block_k
+
+    @pl.when(j == 0)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, -jnp.inf)
         l_sc[...] = jnp.zeros_like(l_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    qi = pl.program_id(1)
-    # Causal block skipping: a k-block strictly above the diagonal is fully
-    # masked — skip its MXU work entirely (roughly halves causal FLOPs).
-    visible = (
-        ki * block_k <= qi * block_q + block_q - 1 if causal else ki >= 0
-    )
-
-    @pl.when(visible)
+    # Block skipping: a tile the diagonal, the window or the segments hide
+    # wholly costs no MXU work (causal alone roughly halves the FLOPs).
+    @pl.when(jnp.logical_and(
+        ki < t.n_k, t.visible(qi, ki, q_rng, k_rng, b // t.H)
+    ))
     def _compute():
         q = q_ref[0].astype(jnp.float32) * (1.0 / np.sqrt(head_dim))
         k = k_ref[0].astype(jnp.float32)
@@ -288,8 +425,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
 
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
         mask = _tile_mask(
-            causal, 0, qi * block_q, ki * block_k,
-            _lanes(seg_q_ref[0], block_k), seg_k_ref[0],
+            t.causal, 0, qi * t.block_q, ki * block_k,
+            _lanes(seg_q_ref[0], block_k), seg_k_ref[0], t.window,
         )
         s = jnp.where(mask, s, _NEG_INF)
 
@@ -306,7 +443,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref, o_ref,
             p, v, preferred_element_type=jnp.float32
         )
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(j == t.n_kw - 1)
     def _done():
         l = l_sc[...]
         safe_l = jnp.where(l > 0, l, 1.0)
@@ -345,72 +482,112 @@ def _check_blocks(Tq, Tk, block_q, block_k):
     return block_q, block_k
 
 
-def _flash_forward(q, k, v, seg_q, seg_k, causal, block_q, block_k,
+def _tiles(q, k, causal, window, block_q, block_k) -> _Tiles:
+    _, H, Tq, _ = q.shape
+    Hkv, Tk = k.shape[1], k.shape[2]
+    if H % Hkv:
+        raise ValueError(
+            f"{H} query heads do not split over {Hkv} key/value heads"
+        )
+    block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
+    return _Tiles(causal, window, block_q, block_k, Tq // block_q,
+                  Tk // block_k, H, Hkv)
+
+
+def _block_ranges(seg, n_blocks: int):
+    """[B, T] segment ids -> flat int32 [B * n_blocks * 2]: every block's
+    least and largest id, for the kernels' scalar memory."""
+    B = seg.shape[0]
+    blocks = seg.astype(jnp.int32).reshape(B, n_blocks, -1)
+    return jnp.stack(
+        [blocks.min(axis=-1), blocks.max(axis=-1)], axis=-1
+    ).reshape(-1)
+
+
+def _kv_row(t: _Tiles, b):
+    """Row of the [B*Hkv, T, D] keys that query row ``b`` of [B*H, T, D]
+    reads: grouped heads are an index map, not a repeat."""
+    return (b // t.H) * t.Hkv + (b % t.H) // t.G
+
+
+_SEMANTICS = ("parallel", "parallel", "arbitrary")
+
+
+def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
                    interpret):
     B, H, Tq, D = q.shape
+    t = _tiles(q, k, causal, window, block_q, block_k)
+    block_q, block_k = t.block_q, t.block_k
     Tk = k.shape[-2]
-    block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
-    n_k = Tk // block_k
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    kr = k.reshape(B * t.Hkv, Tk, D)
+    vr = v.reshape(B * t.Hkv, Tk, D)
 
-    kernel = functools.partial(
-        _flash_kernel, causal=causal, block_q=block_q, block_k=block_k,
-        n_k=n_k,
+    k_spec = pl.BlockSpec(
+        (1, block_k, D),
+        lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
     )
     out, lse = pl.pallas_call(
-        kernel,
-        grid=(B * H, Tq // block_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0)),
-            pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0)),
-            # Segment ids are per batch row, shared by its H heads.
-            pl.BlockSpec(
-                (1, block_q, _LANES), lambda b, qi, ki: (b // H, qi, 0)
-            ),
-            pl.BlockSpec((1, 1, block_k), lambda b, qi, ki: (b // H, 0, ki)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0)),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, qi, ki: (b, qi, 0)),
-        ],
+        functools.partial(_flash_kernel, t=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * H, t.n_q, t.n_kw),
+            in_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, qi, j, *_: (b, qi, 0)),
+                k_spec,
+                k_spec,
+                # Segment ids are per batch row, shared by its H heads.
+                pl.BlockSpec(
+                    (1, block_q, _LANES),
+                    lambda b, qi, j, *_: (b // H, qi, 0),
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k),
+                    lambda b, qi, j, *_: (b // H, 0, t.k_block(qi, j)),
+                ),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, D), lambda b, qi, j, *_: (b, qi, 0)),
+                pl.BlockSpec(
+                    (1, block_q, _LANES), lambda b, qi, j, *_: (b, qi, 0)
+                ),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, D), jnp.float32),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Tq, D), v.dtype),
             jax.ShapeDtypeStruct((B * H, Tq, _LANES), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, D), jnp.float32),
-        ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=_SEMANTICS
         ),
         interpret=interpret,
-    )(qr, kr, vr, _col_form(seg_q), _row_form(seg_k))
+    )(_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k),
+      qr, kr, vr, _col_form(seg_q), _row_form(seg_k))
     # The residual keeps one lane: O(T) memory, not O(128 T).
     return out.reshape(B, H, Tq, D), lse[:, :, 0]
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
-                         lse_ref, delta_ref, do_ref, dq_ref, dq_sc, *,
-                         causal: bool, block_q: int, block_k: int,
-                         n_k: int):
-    """dQ pass. Grid (B*H, n_q, n_k); k-axis sequential, dq accumulates in
+def _flash_bwd_dq_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
+                         seg_k_ref, lse_ref, delta_ref, do_ref, dq_ref,
+                         dq_sc, *, t: _Tiles):
+    """dQ pass. Grid (B*H, n_q, n_kw); k-axis sequential, dq accumulates in
     VMEM scratch. P is rebuilt from the saved lse (no second softmax)."""
-    qi, ki = pl.program_id(1), pl.program_id(2)
+    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    ki = t.first_k(qi) + j
+    block_k = t.block_k
 
-    @pl.when(ki == 0)
+    @pl.when(j == 0)
     def _init():
         dq_sc[...] = jnp.zeros_like(dq_sc)
 
-    visible = (
-        ki * block_k <= qi * block_q + block_q - 1 if causal else ki >= 0
-    )
-
-    @pl.when(visible)
+    @pl.when(jnp.logical_and(
+        ki < t.n_k, t.visible(qi, ki, q_rng, k_rng, b // t.H)
+    ))
     def _compute():
         scale = 1.0 / np.sqrt(q_ref.shape[-1])
         q = q_ref[0].astype(jnp.float32) * scale
@@ -420,8 +597,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
 
         s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
         mask = _tile_mask(
-            causal, 0, qi * block_q, ki * block_k,
-            _lanes(seg_q_ref[0], block_k), seg_k_ref[0],
+            t.causal, 0, qi * t.block_q, ki * block_k,
+            _lanes(seg_q_ref[0], block_k), seg_k_ref[0], t.window,
         )
         s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - _lanes(lse_ref[0], block_k))
@@ -431,30 +608,31 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         ds = p * (dp - _lanes(delta_ref[0], block_k))
         dq_sc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(j == t.n_kw - 1)
     def _done():
         dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
-                           lse_ref, delta_ref, do_ref, dk_ref, dv_ref,
-                           dk_sc, dv_sc, *, causal: bool, block_q: int,
-                           block_k: int, n_q: int):
+def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
+                           seg_k_ref, lse_ref, delta_ref, do_ref, dk_ref,
+                           dv_ref, dk_sc, dv_sc, *, t: _Tiles):
     """dK/dV pass on the transposed tile sᵀ [block_k, block_q]. Grid
-    (B*H, n_k, n_q); q-axis sequential, dk/dv accumulate in VMEM scratch."""
-    kj, qi = pl.program_id(1), pl.program_id(2)
+    (B*Hkv, n_k, G*n_qw); the last axis is sequential and walks the G
+    query heads of this key/value head, each over the query blocks that
+    can see key block kj: dk/dv accumulate over all of them in VMEM
+    scratch."""
+    b, kj, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    qi = t.first_q(kj) + step % t.n_qw
+    block_q = t.block_q
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
 
-    # A q-block strictly above this k-block sees none of it.
-    visible = (
-        qi * block_q + block_q - 1 >= kj * block_k if causal else qi >= 0
-    )
-
-    @pl.when(visible)
+    @pl.when(jnp.logical_and(
+        qi < t.n_q, t.visible(qi, kj, q_rng, k_rng, b // t.Hkv)
+    ))
     def _compute():
         scale = 1.0 / np.sqrt(q_ref.shape[-1])
         q = q_ref[0].astype(jnp.float32) * scale
@@ -466,8 +644,8 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
             k, q, _NT, preferred_element_type=jnp.float32
         )
         mask = _tile_mask(
-            causal, 1, qi * block_q, kj * block_k,
-            _lanes(seg_k_ref[0], block_q), seg_q_ref[0],
+            t.causal, 1, qi * block_q, kj * t.block_k,
+            _lanes(seg_k_ref[0], block_q), seg_q_ref[0], t.window,
         )
         st = jnp.where(mask, st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[0])
@@ -479,126 +657,150 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         # q already carries the softmax scale: dK = dSᵀ · (scale · Q).
         dk_sc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(step == t.G * t.n_qw - 1)
     def _done():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
 
 
-def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, block_q,
-                    block_k, interpret):
+def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
+                    block_q, block_k, interpret):
     B, H, Tq, D = q.shape
     Tk = k.shape[-2]
-    block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
-    n_q, n_k = Tq // block_q, Tk // block_k
+    t = _tiles(q, k, causal, window, block_q, block_k)
+    block_q, block_k, Hkv = t.block_q, t.block_k, t.Hkv
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    kr = k.reshape(B * Hkv, Tk, D)
+    vr = v.reshape(B * Hkv, Tk, D)
     gr = g.reshape(B * H, Tq, D)
     # delta_i = rowsum(dO * O): the softmax-jacobian correction term.
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).reshape(B * H, Tq)
-    semantics = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-    )
+    semantics = pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
+    ranges = (_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k))
 
     # dQ: score tile [block_q, block_k]; per-query stats are columns.
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, qi, ki: (b, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, qi, ki: (b, ki, 0))
-    q_col = pl.BlockSpec((1, block_q, _LANES), lambda b, qi, ki: (b, qi, 0))
+    q_spec = pl.BlockSpec((1, block_q, D), lambda b, qi, j, *_: (b, qi, 0))
+    k_spec = pl.BlockSpec(
+        (1, block_k, D),
+        lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
+    )
+    q_col = pl.BlockSpec(
+        (1, block_q, _LANES), lambda b, qi, j, *_: (b, qi, 0)
+    )
     dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, causal=causal, block_q=block_q,
-            block_k=block_k, n_k=n_k,
+        functools.partial(_flash_bwd_dq_kernel, t=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * H, t.n_q, t.n_kw),
+            in_specs=[
+                q_spec,
+                k_spec,
+                k_spec,
+                pl.BlockSpec(
+                    (1, block_q, _LANES),
+                    lambda b, qi, j, *_: (b // H, qi, 0),
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k),
+                    lambda b, qi, j, *_: (b // H, 0, t.k_block(qi, j)),
+                ),
+                q_col,
+                q_col,
+                q_spec,
+            ],
+            out_specs=q_spec,
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         ),
-        grid=(B * H, n_q, n_k),
-        in_specs=[
-            q_spec,
-            k_spec,
-            k_spec,
-            pl.BlockSpec(
-                (1, block_q, _LANES), lambda b, qi, ki: (b // H, qi, 0)
-            ),
-            pl.BlockSpec((1, 1, block_k), lambda b, qi, ki: (b // H, 0, ki)),
-            q_col,
-            q_col,
-            q_spec,
-        ],
-        out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         compiler_params=semantics,
         interpret=interpret,
-    )(qr, kr, vr, _col_form(seg_q), _row_form(seg_k), _col_form(lse),
-      _col_form(delta), gr)
+    )(*ranges, qr, kr, vr, _col_form(seg_q), _row_form(seg_k),
+      _col_form(lse), _col_form(delta), gr)
 
     # dK/dV: transposed tile [block_k, block_q]; per-query stats are rows.
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, kj, qi: (b, qi, 0))
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, kj, qi: (b, kj, 0))
-    q_row = pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b, 0, qi))
+    def q_row_of(b, step):  # the query head this step walks
+        return (b // Hkv) * H + (b % Hkv) * t.G + step // t.n_qw
+
+    q_spec = pl.BlockSpec(
+        (1, block_q, D),
+        lambda b, kj, step, *_: (q_row_of(b, step), t.q_block(kj, step), 0),
+    )
+    k_spec = pl.BlockSpec((1, block_k, D), lambda b, kj, step, *_: (b, kj, 0))
+    q_row = pl.BlockSpec(
+        (1, 1, block_q),
+        lambda b, kj, step, *_: (q_row_of(b, step), 0, t.q_block(kj, step)),
+    )
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkdv_kernel, causal=causal, block_q=block_q,
-            block_k=block_k, n_q=n_q,
+        functools.partial(_flash_bwd_dkdv_kernel, t=t),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B * Hkv, t.n_k, t.G * t.n_qw),
+            in_specs=[
+                q_spec,
+                k_spec,
+                k_spec,
+                pl.BlockSpec(
+                    (1, 1, block_q),
+                    lambda b, kj, step, *_: (
+                        b // Hkv, 0, t.q_block(kj, step)
+                    ),
+                ),
+                pl.BlockSpec(
+                    (1, block_k, _LANES),
+                    lambda b, kj, step, *_: (b // Hkv, kj, 0),
+                ),
+                q_row,
+                q_row,
+                q_spec,
+            ],
+            out_specs=[k_spec, k_spec],
+            scratch_shapes=[
+                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, D), jnp.float32),
+            ],
         ),
-        grid=(B * H, n_k, n_q),
-        in_specs=[
-            q_spec,
-            k_spec,
-            k_spec,
-            pl.BlockSpec((1, 1, block_q), lambda b, kj, qi: (b // H, 0, qi)),
-            pl.BlockSpec(
-                (1, block_k, _LANES), lambda b, kj, qi: (b // H, kj, 0)
-            ),
-            q_row,
-            q_row,
-            q_spec,
-        ],
-        out_specs=[k_spec, k_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, D), jnp.float32),
-            pltpu.VMEM((block_k, D), jnp.float32),
+            jax.ShapeDtypeStruct((B * Hkv, Tk, D), k.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, Tk, D), v.dtype),
         ],
         compiler_params=semantics,
         interpret=interpret,
-    )(qr, kr, vr, _row_form(seg_q), _col_form(seg_k), _row_form(lse),
-      _row_form(delta), gr)
+    )(*ranges, qr, kr, vr, _row_form(seg_q), _col_form(seg_k),
+      _row_form(lse), _row_form(delta), gr)
 
     return (
         dq.reshape(B, H, Tq, D),
-        dk.reshape(B, H, Tk, D),
-        dv.reshape(B, H, Tk, D),
+        dk.reshape(B, Hkv, Tk, D),
+        dv.reshape(B, Hkv, Tk, D),
     )
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
 )
-def _flash_attention(q, k, v, seg_q, seg_k, causal, block_q, block_k,
-                     interpret):
+def _flash_attention(q, k, v, seg_q, seg_k, causal, window, block_q,
+                     block_k, interpret):
     out, _lse = _flash_forward(
-        q, k, v, seg_q, seg_k, causal, block_q, block_k, interpret
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
     )
     return out
 
 
-def _flash_fwd(q, k, v, seg_q, seg_k, causal, block_q, block_k, interpret):
+def _flash_fwd(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
+               interpret):
     out, lse = _flash_forward(
-        q, k, v, seg_q, seg_k, causal, block_q, block_k, interpret
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
     )
     return out, (q, k, v, seg_q, seg_k, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, window, block_q, block_k, interpret, res, g):
     q, k, v, seg_q, seg_k, out, lse = res
     dq, dk, dv = _flash_backward(
-        q, k, v, seg_q, seg_k, out, lse, g, causal, block_q, block_k,
-        interpret,
+        q, k, v, seg_q, seg_k, out, lse, g, causal, window, block_q,
+        block_k, interpret,
     )
     return dq, dk, dv, None, None
 
@@ -616,6 +818,7 @@ def flash_attention(
     block_q: int = 256,
     block_k: int = 256,
     interpret: bool = False,
+    window: Optional[int] = None,
 ):
     """Pallas flash attention (custom VJP backward), compiled by Mosaic.
 
@@ -625,6 +828,7 @@ def flash_attention(
     Mosaic rejects, fails in the caller's compile."""
     if interpret and jax.default_backend() == "tpu":
         raise ValueError("flash_attention(interpret=True) on a TPU backend")
+    _check_window(window, causal)
     B, _, Tq, _ = q.shape
     Tk = k.shape[-2]
     seg_q = (
@@ -642,7 +846,7 @@ def flash_attention(
         )
     )
     return _flash_attention(
-        q, k, v, seg_q, seg_k, causal, block_q, block_k, interpret
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
     )
 
 
@@ -675,16 +879,22 @@ def resolve_backend(Tq: int, Tk: int, block_q: int = 256,
 
 def attention(q, k, v, backend: str = "auto", **kw):
     """Dispatcher: 'dense' | 'blockwise' | 'flash' | 'auto'
-    (:func:`resolve_backend`)."""
+    (:func:`resolve_backend`). The backend a call runs is on record: the
+    process-global counter ``attention_calls_traced_total{backend=}`` counts
+    calls where they are traced (once a compile under jit), so whoever
+    holds a program to a backend reads what its own trace picked."""
     if backend == "auto":
         backend = resolve_backend(
             q.shape[-2], k.shape[-2], kw.get("block_q", 256),
             kw.get("block_k", 256),
         )
-        if backend != "flash":
-            kw.pop("block_q", None)  # flash-only knob
-            if backend == "dense":
-                kw.pop("block_k", None)
+    global_telemetry().registry.counter(
+        "attention_calls_traced_total", backend=backend
+    ).inc()
+    if backend != "flash":
+        kw.pop("block_q", None)  # flash-only knob
+        if backend == "dense":
+            kw.pop("block_k", None)
     fn = {
         "dense": dense_attention,
         "blockwise": blockwise_attention,

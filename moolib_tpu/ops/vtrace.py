@@ -118,23 +118,24 @@ def from_logits(
     lambda_: float = 1.0,
 ) -> VTraceFromLogitsReturns:
     """V-trace for softmax policies: [T, B, A] logits, [T, B] actions."""
-    behavior_log_probs = action_log_probs(behavior_policy_logits, actions)
-    target_log_probs = action_log_probs(target_policy_logits, actions)
-    log_rhos = target_log_probs - behavior_log_probs
-    vt = from_importance_weights(
-        log_rhos=log_rhos,
-        discounts=discounts,
-        rewards=rewards,
-        values=values,
-        bootstrap_value=bootstrap_value,
-        clip_rho_threshold=clip_rho_threshold,
-        clip_pg_rho_threshold=clip_pg_rho_threshold,
-        lambda_=lambda_,
-    )
-    return VTraceFromLogitsReturns(
-        vs=vt.vs,
-        pg_advantages=vt.pg_advantages,
-        log_rhos=log_rhos,
-        behavior_action_log_probs=behavior_log_probs,
-        target_action_log_probs=target_log_probs,
-    )
+    with jax.named_scope("moolib.vtrace"):
+        behavior_log_probs = action_log_probs(behavior_policy_logits, actions)
+        target_log_probs = action_log_probs(target_policy_logits, actions)
+        log_rhos = target_log_probs - behavior_log_probs
+        vt = from_importance_weights(
+            log_rhos=log_rhos,
+            discounts=discounts,
+            rewards=rewards,
+            values=values,
+            bootstrap_value=bootstrap_value,
+            clip_rho_threshold=clip_rho_threshold,
+            clip_pg_rho_threshold=clip_pg_rho_threshold,
+            lambda_=lambda_,
+        )
+        return VTraceFromLogitsReturns(
+            vs=vt.vs,
+            pg_advantages=vt.pg_advantages,
+            log_rhos=log_rhos,
+            behavior_action_log_probs=behavior_log_probs,
+            target_action_log_probs=target_log_probs,
+        )

@@ -1,0 +1,240 @@
+"""The dropless expert layer (``parallel.moe.moe_dropless``) against a loop
+over experts under ``jit``, forward and gradients; against the
+capacity-factor ``moe_ffn`` where that one drops nothing; at an imbalance
+that sends every token to one expert; with a buffer smaller than the
+routing asks; and with the Pallas grouped matmul (in the interpreter) in
+the place of ``ragged_dot``."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from moolib_tpu.learner import ImpalaConfig, impala_loss
+from moolib_tpu.parallel import moe
+from moolib_tpu.parallel.moe import (moe_dropless, moe_ffn, moe_params,
+                                     resolve_grouped)
+
+T, D, F, E = 96, 16, 12, 8
+
+
+def gated_params(seed, count=E):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    return {
+        "router": jax.random.normal(ks[0], (D, E)) / 4,
+        "w_gate": jax.random.normal(ks[1], (count, D, F)) / 4,
+        "w_up": jax.random.normal(ks[2], (count, D, F)) / 4,
+        "w_down": jax.random.normal(ks[3], (count, F, D)) / 3,
+    }, jax.random.normal(ks[4], (T, D))
+
+
+def loop_over_experts(params, x, top_k, first, count):
+    probs = jax.nn.softmax(x @ params["router"], axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, top_k)
+    gates = top_p / top_p.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(count):
+        g = jnp.sum(jnp.where(top_i == first + e, gates, 0.0), axis=-1)
+        h = jax.nn.silu(x @ params["w_gate"][e]) * (x @ params["w_up"][e])
+        y = y + g[:, None] * (h @ params["w_down"][e])
+    return y
+
+
+@pytest.mark.parametrize("top_k,held", [(2, None), (3, (2, 4)), (1, (6, 2))])
+def test_equal_to_a_loop_over_experts_under_jit(top_k, held):
+    first, count = held or (0, E)
+    params, x = gated_params(0, count)
+    ours = jax.jit(
+        lambda p, x: moe_dropless(p, x, top_k=top_k, held=held)[0]
+    )
+    ref = lambda p, x: loop_over_experts(p, x, top_k, first, count)  # noqa
+    np.testing.assert_allclose(ours(params, x), ref(params, x),
+                               rtol=1e-5, atol=1e-5)
+    w = jax.random.normal(jax.random.PRNGKey(9), (T, D))
+    g_ours = jax.jit(jax.grad(
+        lambda p, x: jnp.sum(w * ours(p, x)), argnums=(0, 1)
+    ))(params, x)
+    g_ref = jax.grad(
+        lambda p, x: jnp.sum(w * ref(p, x)), argnums=(0, 1)
+    )(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(g_ours),
+                    jax.tree_util.tree_leaves(g_ref)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("top_k", [2, 3])
+def test_equal_to_moe_ffn_where_that_one_drops_nothing(top_k):
+    """``moe_ffn``'s own parameters (plain GELU experts) through both
+    layers, its capacity set to hold every token."""
+    params = moe_params(jax.random.PRNGKey(1), D, F, E)
+    x = jax.random.normal(jax.random.PRNGKey(2), (T, D))
+    y_cap, aux = moe_ffn(params, x, capacity=T, top_k=top_k)
+    assert float(aux["drop_fraction"]) == 0.0
+    y, counters = moe_dropless(params, x, top_k=top_k)
+    np.testing.assert_allclose(y, y_cap, rtol=2e-5, atol=2e-5)
+    assert float(counters["moe_assignments_held"]) == T * top_k
+    # and where moe_ffn's default capacity drops, this one does not
+    y_drop, aux = moe_ffn(params, x, capacity=4, top_k=top_k)
+    assert float(aux["drop_fraction"]) > 0
+    assert float(jnp.max(jnp.abs(y_drop - y))) > 1e-3
+
+
+def test_every_token_to_one_expert_and_nothing_dropped():
+    params, x = gated_params(3)
+    x = x.at[:, 0].set(5.0)
+    router = jnp.zeros((D, E)).at[0, 5].set(9.0).at[0, 1].set(4.0)
+    params = dict(params, router=router)
+    y, counters = jax.jit(
+        lambda p, x: moe_dropless(p, x, top_k=2)
+    )(params, x)
+    np.testing.assert_allclose(
+        y, loop_over_experts(params, x, 2, 0, E), rtol=1e-5, atol=1e-5
+    )
+    assert float(counters["moe_load_max"]) == T  # one expert, every token
+    assert float(counters["moe_overflow"]) == 0.0
+    assert float(counters["moe_tokens_unserved"]) == 0.0
+
+
+@pytest.mark.parametrize("rows,spills", [
+    (None, 0.0), ("held", 0.0), ("held - 1", 1.0), (8, 1.0), (10**6, 0.0),
+])
+def test_a_buffer_too_small_spills_to_the_worst_case(rows, spills):
+    """``buffer_rows`` is the size that usually does: routing that fits it
+    runs over it, routing that does not over the worst case, and the result
+    and its gradients are the unbounded layer's whichever runs."""
+    params, x = gated_params(5, count=3)
+    kw = dict(top_k=2, held=(1, 3))
+    full, counters = moe_dropless(params, x, **kw)
+    held = int(counters["moe_assignments_held"])
+    rows = eval(rows, {"held": held}) if isinstance(rows, str) else rows
+    w = jax.random.normal(jax.random.PRNGKey(8), (T, D))
+
+    def loss(rows):
+        return lambda p, x: jnp.sum(w * moe_dropless(
+            p, x, buffer_rows=rows, **kw)[0])
+
+    g_full = jax.grad(loss(None), argnums=(0, 1))(params, x)
+    y, c = jax.jit(lambda p, x: moe_dropless(
+        p, x, buffer_rows=rows, **kw))(params, x)
+    np.testing.assert_allclose(y, full, rtol=1e-5, atol=1e-6)
+    assert float(c["moe_spills"]) == spills
+    assert float(c["moe_overflow"]) == 0.0
+    g = jax.jit(jax.grad(loss(rows), argnums=(0, 1)))(params, x)
+    for a, b in zip(jax.tree_util.tree_leaves(g),
+                    jax.tree_util.tree_leaves(g_full)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_a_stated_buffer_keeps_none_of_its_rows_for_the_backward_pass():
+    """Two buffers behind a cond rebuild their rows in the backward pass:
+    the products run forward, again, and twice transposed, in each
+    branch. One buffer keeps its rows and runs them once forward."""
+    params, x = gated_params(6, count=3)
+
+    def products(rows):
+        text = str(jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(moe_dropless(
+            p, x, top_k=2, held=(1, 3), buffer_rows=rows)[0]),
+            argnums=(0, 1)))(params, x))
+        return text.count("= ragged_dot_general["), text.count("= cond[")
+
+    kept, conds = products(None)
+    assert conds == 0 and products(10**6) == (kept, 0)
+    rebuilt, conds = products(16)
+    assert conds >= 2 and rebuilt == 2 * (kept + 3)
+
+
+@pytest.mark.parametrize("held,buffer_rows", [(None, None), ((1, 3), 128)])
+def test_the_pallas_grouped_matmul_equals_ragged_dot(held, buffer_rows,
+                                                     monkeypatch):
+    """Widths and rows that tile (lanes of 128), the kernels in the Pallas
+    interpreter: forward and gradients equal ``ragged_dot``'s, in both
+    branches of a stated buffer."""
+    d, f, e, top_k = 128, 256, 4, 2
+    count = e if held is None else held[1]
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    params = {
+        "router": jax.random.normal(ks[0], (d, e)) / 8,
+        "w_gate": jax.random.normal(ks[1], (count, d, f)) / 11,
+        "w_up": jax.random.normal(ks[2], (count, d, f)) / 11,
+        "w_down": jax.random.normal(ks[3], (count, f, d)) / 16,
+    }
+    x = jax.random.normal(ks[4], (128, d))  # 128 tokens: 256 rows at worst
+    w = jax.random.normal(jax.random.PRNGKey(12), x.shape)
+
+    def both(how):
+        monkeypatch.setattr(moe, "resolve_grouped", lambda *a: how)
+
+        def loss(p, x):
+            y, aux = moe_dropless(p, x, top_k=top_k, held=held,
+                                  buffer_rows=buffer_rows)
+            return jnp.sum(w * y), (y, aux)
+
+        (_, (y, aux)), grads = jax.jit(
+            jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+        )(params, x)
+        return y, aux, grads
+
+    y_r, aux_r, g_r = both("ragged_dot")
+    y_g, aux_g, g_g = both("gmm_interpret")
+    assert float(aux_g["moe_overflow"]) == 0
+    assert float(aux_g["moe_spills"]) == float(aux_r["moe_spills"])
+    np.testing.assert_allclose(y_g, y_r, rtol=1e-5, atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(g_g),
+                    jax.tree_util.tree_leaves(g_r)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+def test_auto_is_ragged_dot_off_the_chip_and_the_router_load_is_whole():
+    assert resolve_grouped(16384, 2304, 896, jnp.bfloat16) == "ragged_dot"
+    params, x = gated_params(8, count=3)
+    _, aux = moe_dropless(params, x, top_k=3, held=(2, 3))
+    load = np.asarray(aux["moe_router_load"])
+    assert load.shape == (E,) and load.sum() == T * 3
+    assert load[2:5].sum() == float(aux["moe_assignments_held"])
+
+
+def test_held_has_to_match_the_expert_rows():
+    params, x = gated_params(4, count=3)
+    with pytest.raises(ValueError, match="held"):
+        moe_dropless(params, x, top_k=2, held=(6, 3))
+    with pytest.raises(ValueError, match="held"):
+        moe_dropless(params, x, top_k=2, held=(1, 4))
+
+
+def test_impala_loss_passes_counters_through_and_folds_only_loss_terms():
+    """A third return value without ``load_balance_loss`` /
+    ``router_z_loss`` used to be a KeyError."""
+    Tn, B, A = 4, 3, 5
+    batch = {
+        "obs": jnp.zeros((Tn + 1, B, 2)), "done": jnp.zeros((Tn + 1, B), bool),
+        "rewards": jnp.ones((Tn + 1, B)),
+        "actions": jnp.zeros((Tn, B), jnp.int32),
+        "behavior_logits": jnp.zeros((Tn, B, A)), "core_state": (),
+    }
+
+    def apply_with(aux):
+        def apply(params, obs, done, state):
+            logits = jnp.zeros((Tn + 1, B, A)) + params
+            return (logits, jnp.zeros((Tn + 1, B))), state, aux
+        return apply
+
+    cfg = ImpalaConfig(moe_lb_cost=0.5, moe_z_cost=0.25)
+    plain, m0 = impala_loss(jnp.float32(0.1), apply_with({}), batch, cfg)
+    total, m1 = impala_loss(
+        jnp.float32(0.1), apply_with({"moe_overflow": jnp.float32(3.0)}),
+        batch, cfg,
+    )
+    assert float(total) == float(plain) and float(m1["moe_overflow"]) == 3.0
+    total, m2 = impala_loss(
+        jnp.float32(0.1),
+        apply_with({"load_balance_loss": jnp.float32(2.0),
+                    "router_z_loss": jnp.float32(4.0),
+                    "drop_fraction": jnp.float32(0.5),
+                    "moe_tokens_unserved": jnp.float32(7.0)}),
+        batch, cfg,
+    )
+    assert float(total) == pytest.approx(float(plain) + 0.5 * 2 + 0.25 * 4)
+    assert float(m2["total_loss"]) == float(total)
+    assert float(m2["moe_lb_loss"]) == 2.0 and float(m2["moe_z_loss"]) == 4.0
+    assert float(m2["moe_drop_fraction"]) == 0.5
+    assert float(m2["moe_tokens_unserved"]) == 7.0

@@ -126,6 +126,8 @@ MODULES = [
     ("moolib_tpu.models.transformer", "transformer with sequence-parallel "
      "attention"),
     ("moolib_tpu.models.nethack", "NetHack dict-obs model"),
+    ("moolib_tpu.models.lm", "decoder language model as a token-level "
+     "agent: layer list, windowed grouped-head attention, dropless experts"),
     ("moolib_tpu.learner", "jitted IMPALA train step + train state"),
     ("moolib_tpu.utils.checkpoint", "atomic checkpoint/resume"),
     ("moolib_tpu.utils.diskio", "crash-atomic disk writes + the "
