@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from ..ops.embed import embed_lookup
 from .core import LSTMCore
 
 __all__ = ["NetHackNet"]
@@ -47,9 +48,15 @@ class NetHackNet(nn.Module):
         T, B = glyphs.shape[:2]
         HH, WW = glyphs.shape[2:]
 
-        g = nn.Embed(self.num_glyphs, self.glyph_embed, name="glyph_embed")(
-            glyphs.astype(jnp.int32).reshape(T * B, HH, WW)
-        ).astype(self.compute_dtype)
+        # nn.Embed for the parameter (glyph_embed/embedding, its shape and
+        # initialiser); the lookup is ours for its gradient (ops/embed.py)
+        table = nn.Embed(
+            self.num_glyphs, self.glyph_embed, name="glyph_embed"
+        ).embedding
+        g = embed_lookup(
+            table, glyphs.astype(jnp.int32).reshape(T * B, HH, WW),
+            self.compute_dtype,
+        )
         for ch in (32, 64, 64):
             g = nn.relu(
                 nn.Conv(ch, (3, 3), strides=(2, 2), dtype=self.compute_dtype)(g)

@@ -1,4 +1,4 @@
-from . import attention, ring_attention, vtrace
+from . import attention, embed, ring_attention, vtrace
 from .batcher import Batcher
 
-__all__ = ["vtrace", "attention", "ring_attention", "Batcher"]
+__all__ = ["vtrace", "attention", "ring_attention", "embed", "Batcher"]

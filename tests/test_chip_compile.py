@@ -1,6 +1,7 @@
-"""The kernels of the language-model cell compiled for the chip at the
-cell's real widths, with no chip attached: the TPU's compiler is installed
-here and compiles for a described v5e. What interpret mode cannot show
+"""The kernels of the language-model cell, and the glyph embedding's backward
+of the NetHack cell, compiled for the chip at the cells' real sizes, with no
+chip attached: the TPU's compiler is installed here and compiles for a
+described v5e. What interpret mode cannot show
 (tiling, fast memory, a kernel Mosaic refuses) fails here at no chip time.
 Nothing runs, so nothing here is a result or a time.
 
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from moolib_tpu.ops.attention import flash_attention
+from moolib_tpu.ops.embed import embed_lookup
 from moolib_tpu.parallel import moe
 from moolib_tpu.parallel.moe import moe_dropless
 
@@ -115,3 +117,30 @@ def test_grouped_products_compile_at_the_cells_shape(one_chip,
     else:
         assert "ragged-dot" in text
     assert "conditional" in text  # the worst case waits behind a cond
+
+
+def test_glyph_embedding_backward_at_the_cells_size(one_chip, no_compile_cache):
+    """nethack_learner: 81 x 128 frames of 21 x 79 glyphs, 17,200,512 lookups
+    of a row of 16 out of 5,976. What the scatter-add's program had and the
+    product's must not: the cotangent as float32 rows of 16 (8.8 GB under an
+    (8,128) tile), channel by channel as ``[1,16,lookups]``, and a scatter."""
+    frames, rows, width = 81 * 128, 5976, 16
+    lookups = frames * 21 * 79
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def backward(table, ids, g):
+        return jax.vjp(lambda t: embed_lookup(t, ids, g.dtype), table)[1](g)
+
+    compiled = jax.jit(backward).lower(
+        s((rows, width), jnp.float32), s((frames, 21, 79), jnp.int32),
+        s((frames, 21, 79, width), jnp.bfloat16),
+    ).compile()
+    text = compiled.as_text()
+    assert "moolib.embed.grad.contract" in text
+    assert f"f32[{lookups},{width}]" not in text
+    assert f"f32[1,{width},{lookups}]" not in text
+    assert " scatter(" not in text and "scatter-add" not in text
+    assert "convolution(" in text  # the product, on the MXU
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
