@@ -10,10 +10,9 @@ cross-host trace alignment) are legitimate and stay unflagged: the rule
 fires only when a ``time.time()`` value flows into a subtraction — the
 duration idiom.
 
-Scope: benchmark-bearing trees only (``tools/``, ``moolib_tpu/bench/``,
-root-level ``bench*.py`` scripts, and the shared timing module
-``moolib_tpu/utils/benchmark.py``). Elsewhere ``time.time()`` has
-legitimate duration-free uses the rule should not police.
+Scope: benchmark-bearing trees only (``tools/`` and
+``moolib_tpu/bench/``). Elsewhere ``time.time()`` has legitimate
+duration-free uses the rule should not police.
 """
 
 from __future__ import annotations
@@ -28,16 +27,10 @@ __all__ = ["RULES", "is_bench_path"]
 
 def is_bench_path(relpath: str) -> bool:
     """Is this file part of the measurement surface the rule polices?
-    ``tools/``, ``moolib_tpu/bench/``, the shared timing module, and
-    ROOT-level ``bench*.py`` scripts only — a bench-named file deeper in
-    the package (an example, a test helper) is not automatically a
+    ``tools/`` and ``moolib_tpu/bench/`` only — a bench-named file
+    elsewhere (an example, a test helper) is not automatically a
     benchmark and stays out of scope."""
-    if relpath.startswith(("tools/", "moolib_tpu/bench/")):
-        return True
-    if relpath == "moolib_tpu/utils/benchmark.py":
-        return True
-    return ("/" not in relpath and relpath.startswith("bench")
-            and relpath.endswith(".py"))
+    return relpath.startswith(("tools/", "moolib_tpu/bench/"))
 
 
 def _is_time_time(node: ast.AST) -> bool:

@@ -528,7 +528,7 @@ class InflightGateUnguarded(Rule):
                     for n in ast.walk(stmt):
                         in_try.add(id(n))
         increments: List[Tuple[str, ast.stmt]] = []
-        for node in self._scoped(fn):
+        for node in self._in_source_order(fn):
             if isinstance(node, (ast.Assign, ast.AugAssign)):
                 for attr, op, n in _counter_ops(node):
                     if op == "up" and attr in gates:
@@ -559,7 +559,7 @@ class InflightGateUnguarded(Rule):
                 continue
             if id(inc) in in_try:
                 continue  # the increment itself sits under a try
-            for node in self._scoped(fn):
+            for node in self._in_source_order(fn):
                 if getattr(node, "lineno", 0) <= inc.lineno:
                     continue
                 if isinstance(node, ast.Call):
@@ -584,7 +584,7 @@ class InflightGateUnguarded(Rule):
                     break
 
     @staticmethod
-    def _scoped(fn: ast.AST) -> Iterable[ast.AST]:
+    def _in_source_order(fn: ast.AST) -> Iterable[ast.AST]:
         return sorted(
             iter_scoped_body(fn.body),
             key=lambda n: (getattr(n, "lineno", 0),

@@ -2,11 +2,8 @@
 derived budgets, and the append-only trend store + regression detector.
 
 One CLI fronts all of it: ``python tools/perf.py`` (see docs/perf.md).
-The legacy entry points (``bench.py``, ``bench_allreduce.py``,
-``bench_e2e.py``, ``tools/perf_sweep.py``, ``tools/envpool_bench.py``,
-``tools/attn_bench.py``) stay as thin wrappers that keep their one-line
-JSON contracts while feeding the same trend schema through
-:func:`~moolib_tpu.bench.harness.maybe_append_trend`.
+It times the host plane only (RPC, serialization, the tree all-reduce,
+batcher, envpool); device speed is ``benchmark/run.py``'s to measure.
 """
 
 from .harness import (
@@ -14,7 +11,6 @@ from .harness import (
     BenchResult,
     clock,
     env_fingerprint,
-    maybe_append_trend,
     measure,
     parse_result,
     trimmed_stats,
@@ -37,7 +33,6 @@ __all__ = [
     "env_fingerprint",
     "evaluate_budgets",
     "load_trends",
-    "maybe_append_trend",
     "measure",
     "parse_result",
     "run_suite",

@@ -1,8 +1,9 @@
 """perfwatch harness: one timing protocol, one result schema.
 
-Every number this repo quotes — device headline steps/s, CPU-proxy echo
-latency, loopback allreduce GB/s — goes through this module's protocol
-and leaves as one machine-readable row:
+Every host-plane number perfwatch gates — echo latency, serializer GB/s,
+loopback allreduce GB/s — goes through this module's protocol and leaves
+as one machine-readable row (device speed is measured by
+``benchmark/run.py`` and nowhere else):
 
 - **protocol**: ``warmup`` untimed reps, then ``repeats`` timed reps on
   ``time.perf_counter`` (the monotonic high-resolution clock; the
@@ -12,16 +13,7 @@ and leaves as one machine-readable row:
 - **schema**: :class:`BenchResult` — metric/value/unit/direction plus the
   per-rep stats, an :func:`env_fingerprint`, the reproduce command, and
   an optional telemetry-registry snapshot, so every benchmark row doubles
-  as a scrape fixture (docs/perf.md documents the schema);
-- **trend plumbing**: :func:`maybe_append_trend` appends rows to the
-  append-only JSONL store (``bench/trends.jsonl`` by convention) when
-  ``MOOLIB_TRENDS`` (or an explicit path) names one, which is how the
-  legacy ``bench*.py`` wrappers feed the same trend schema the CPU-proxy
-  CI suite uses.
-
-The *device-side* timing primitives (chained in-jit steps + D2H
-fingerprint readback) stay in ``moolib_tpu/utils/benchmark.py`` — they
-are re-exported here so harness users need one import.
+  as a scrape fixture (docs/perf.md documents the schema).
 """
 
 from __future__ import annotations
@@ -35,29 +27,29 @@ import statistics
 import time
 from typing import Any, Callable, Dict, List, Optional
 
-# Device-side protocol (chained in-jit steps) — one import surface for
-# benchmark authors.
-from ..utils.benchmark import time_chained, time_train_step  # noqa: F401
-
 __all__ = [
     "SCHEMA_VERSION",
+    "STEPSCOPE_TREND_TOLERANCE",
     "BenchResult",
-    "append_device_trend",
     "clock",
     "env_fingerprint",
-    "maybe_append_trend",
     "measure",
     "parse_result",
-    "time_chained",
-    "time_train_step",
+    "stepscope_trend_rows",
     "trimmed_stats",
 ]
 
 SCHEMA_VERSION = 1
 
-#: THE harness timer. Benchmarks measure durations with this (or the
-#: device-side helpers above), never ``time.time()`` — wall clock steps
-#: (NTP slew, manual set) corrupt short intervals silently.
+#: Default trend tolerance for the stepscope fraction rows. Fractions are
+#: noisy at smoke scale (tens of steps on a shared CPU runner), so the
+#: band is wide — the detector's MAD floor tightens it automatically once
+#: the trend store accumulates stable history.
+STEPSCOPE_TREND_TOLERANCE = 0.5
+
+#: THE harness timer. Benchmarks measure durations with this, never
+#: ``time.time()`` — wall clock steps (NTP slew, manual set) corrupt
+#: short intervals silently.
 clock: Callable[[], float] = time.perf_counter
 
 
@@ -201,36 +193,35 @@ def parse_result(row: Any) -> BenchResult:
     return BenchResult(**row)
 
 
-def maybe_append_trend(
-    results, path: Optional[str] = None, env_var: str = "MOOLIB_TRENDS"
-) -> Optional[str]:
-    """Append result rows to the JSONL trend store named by ``path`` or
-    ``$MOOLIB_TRENDS``; silently a no-op when neither is set (so the
-    legacy one-line-JSON scripts cost nothing outside a perfwatch run).
-    Returns the path written, if any."""
-    path = path or os.environ.get(env_var)
-    if not path:
-        return None
-    from .trends import append_trend
-
-    for r in results:
-        append_trend(path, r)
-    return path
-
-
-def append_device_trend(
-    metric: str, value: float, unit: str, cmd: str, *,
-    direction: str = "higher",
-    stats: Optional[Dict[str, Any]] = None,
-    extra: Optional[Dict[str, Any]] = None,
-    tol: Optional[float] = None,
-) -> Optional[str]:
-    """One-call trend append for the legacy device-suite wrappers
-    (``bench*.py``, ``tools/*_bench*``): builds the harness row and hands
-    it to :func:`maybe_append_trend` — still a no-op unless
-    ``$MOOLIB_TRENDS`` names a store."""
-    return maybe_append_trend([BenchResult(
-        metric=metric, value=value, unit=unit, direction=direction,
-        suite="device", cmd=cmd, stats=stats or {}, extra=extra or {},
-        tol=tol,
-    )])
+def stepscope_trend_rows(summary: Dict[str, Any], *, smoke: bool, cmd: str,
+                         suite: str = "stepscope",
+                         tol: float = STEPSCOPE_TREND_TOLERANCE,
+                         extra: Optional[Dict[str, Any]] = None
+                         ) -> List[BenchResult]:
+    """Build schema-valid :class:`BenchResult` rows from one stepscope
+    loop summary (:func:`moolib_tpu.telemetry.summarize_stepscope`) — one
+    per derived fraction, unit ``fraction``, direction ``lower`` (a
+    growing exposed-comms or host-blocked share is a step-composition
+    regression even when headline throughput holds). The loop name is
+    part of the metric (``stepscope_<loop>_<class>_fraction``): the
+    detector baselines each metric as one series, and an envpool's
+    env-wait share must never share a baseline with a learner's. Append
+    to the CI trends artifact via
+    :func:`~moolib_tpu.bench.trends.append_trend`."""
+    base_extra = {"loop": summary["loop"], "steps": summary["steps"]}
+    if extra:
+        base_extra.update(extra)
+    return [
+        BenchResult(
+            metric=f"stepscope_{summary['loop']}_{key}_fraction",
+            value=float(value),
+            unit="fraction",
+            direction="lower",
+            suite=suite,
+            smoke=bool(smoke),
+            cmd=cmd,
+            tol=tol,
+            extra=dict(base_extra),
+        )
+        for key, value in summary["fractions"].items()
+    ]

@@ -1,9 +1,9 @@
 """The CPU-proxy perf suite: host-plane hot-path benchmarks that run on
 every PR and need no accelerator.
 
-These are not device numbers: they measure the host-side hot paths the
-device loop sits on top of, and the counts (transfers, compiles) a CPU
-run can establish:
+These are not device numbers (``benchmark/run.py`` measures those, on the
+chip): they measure the host-side hot paths the device loop sits on top
+of:
 
 ==============================  ============================================
 benchmark                       hot path it guards
@@ -48,11 +48,6 @@ benchmark                       hot path it guards
                                 closed-loop load — floored by the fixed
                                 settle window, so the row watches the
                                 machinery around it
-``e2e_learner_step_s``          steady-state fused IMPALA train step under
-                                a hotwatch window — ``extra`` proves zero
-                                synchronous D2H and flat compile counts
-                                (the hotlint acceptance row); a stray sync
-                                turns the row into an error row
 ==============================  ============================================
 
 Every benchmark follows the harness protocol (warmup + repeats +
@@ -122,12 +117,6 @@ TREND_TOLERANCE = {
     # threaded load) rides the same shared-container scheduling noise as
     # the serving rows.
     "fleet_rollout_s": 0.65,
-    # XLA-compiled step on the shared CPU: compile cache is warm but the
-    # matmul-heavy step competes with every neighbour for the one core.
-    "e2e_learner_step_s": 0.5,
-    # Two learner steps + a full-pytree bitwise compare: same XLA noise
-    # as the e2e row, plus host-side flatten/tobytes per check.
-    "parity_check_s": 0.5,
 }
 
 
@@ -872,157 +861,6 @@ def bench_fleet_rollout(smoke: bool) -> BenchResult:
         harness.close()
 
 
-# -- learner e2e steady state -------------------------------------------------
-
-
-def bench_e2e_learner_step(smoke: bool) -> BenchResult:
-    """Steady-state fused IMPALA train-step time on the CPU proxy,
-    measured INSIDE a hotwatch window: the row's ``extra`` records the
-    window's transfer/compile accounting, and any unbudgeted synchronous
-    D2H (one stray ``.item()`` in the step path) turns the whole row
-    into an error row — the dynamic half of the hotlint acceptance
-    criteria, on the perf record every PR."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from ..learner import (ImpalaConfig, make_impala_train_step,
-                           make_train_state)
-    from ..models import A2CNet
-    from ..testing.hotwatch import Hotwatch
-
-    from ..telemetry import StepScope, Telemetry
-
-    t_dim, b_dim, f_dim, a_dim = (4, 4, 5, 3) if smoke else (8, 16, 5, 3)
-    steps = 10 if smoke else 50
-    net = A2CNet(num_actions=a_dim, hidden_sizes=(32,))
-    params = net.init(jax.random.PRNGKey(0), jnp.zeros((1, 1, f_dim)),
-                      jnp.zeros((1, 1), bool), ())
-    state = make_train_state(params, optax.sgd(1e-3))
-    # Private telemetry: the bench's phase ledger must not accumulate
-    # into the process-global registry other rows snapshot.
-    scope = StepScope("bench_learner_step",
-                      telemetry=Telemetry("perfwatch-stepscope"))
-    step = make_impala_train_step(
-        net.apply, optax.sgd(1e-3), ImpalaConfig(), donate=True,
-        stepscope=scope,
-    )
-    key = jax.random.PRNGKey(1)
-    ks = jax.random.split(key, 4)
-    # The batch lives on device before the window opens: the steady state
-    # under test is the learner path (grad + apply + metrics staging),
-    # not the host->device feed the actor plane owns.
-    batch = {
-        "obs": jax.random.normal(ks[0], (t_dim + 1, b_dim, f_dim),
-                                 jnp.float32),
-        "done": jax.random.bernoulli(ks[1], 0.1, (t_dim + 1, b_dim)),
-        "rewards": jax.random.normal(ks[2], (t_dim + 1, b_dim),
-                                     jnp.float32),
-        "actions": jax.random.randint(ks[3], (t_dim, b_dim), 0, a_dim),
-        "behavior_logits": jnp.zeros((t_dim, b_dim, a_dim), jnp.float32),
-        "core_state": (),
-    }
-    for _ in range(3):  # warmup: compile + first-touch allocs
-        state, metrics = step(state, batch)
-    jax.block_until_ready(state)
-
-    hw = Hotwatch(jits=[step], d2h=0, h2d=0, max_compiles=0,
-                  label="e2e_learner_step", enabled=True)
-
-    def run_window():
-        nonlocal state
-        with hw:
-            for _ in range(steps):
-                with scope.step():
-                    state, metrics = step(state, batch)
-        jax.block_until_ready(state)
-
-    samples = [s / steps for s in measure(
-        run_window, warmup=1, repeats=3 if smoke else 5
-    )]
-    stats = trimmed_stats(samples)
-    stepscope = _compact_summary(scope.summary())
-    scope.close()
-    return _result(
-        "e2e_learner_step_s", stats["median"], "s", "lower", smoke,
-        stats=stats,
-        extra={
-            "stepscope": stepscope,
-            # The acceptance numbers: zero steady-state synchronous D2H,
-            # compile counts flat across the window. A violation raises
-            # out of run_window, so reaching here proves them — recorded
-            # anyway so the perf ledger shows the contract being checked.
-            "steady_d2h": hw.d2h,
-            "staged_async": hw.staged,
-            "compile_delta": hw.compile_delta,
-            "steps_per_window": steps,
-            "batch": [t_dim, b_dim, f_dim, a_dim],
-        },
-    )
-
-
-# -- paritywatch gate cost ----------------------------------------------------
-
-
-def bench_parity_check(smoke: bool) -> BenchResult:
-    """Wall cost of one ParityWatch bitwise-replay check of the seeded
-    A2C update (docs/analysis.md, "numlint"): two donate=False step
-    executions plus the full-pytree flatten + tobytes compare. This is
-    what the CI parity gate pays per check, on the perf record so the
-    gate's budget is sized from data, not guessed — and the check
-    itself must PASS inside the timer, so the row doubles as a daily
-    bitwise-replay probe of the learner path."""
-    import jax
-    import jax.numpy as jnp
-    import optax
-
-    from ..learner import (ImpalaConfig, make_impala_train_step,
-                           make_train_state)
-    from ..models import A2CNet
-    from ..testing.paritywatch import ParityWatch
-
-    t_dim, b_dim, f_dim, a_dim = (4, 4, 5, 3) if smoke else (8, 16, 5, 3)
-    net = A2CNet(num_actions=a_dim, hidden_sizes=(32,))
-    params = net.init(jax.random.PRNGKey(0), jnp.zeros((1, 1, f_dim)),
-                      jnp.zeros((1, 1), bool), ())
-    state = make_train_state(params, optax.sgd(1e-3))
-    # donate=False: both replay runs must read the same input buffers.
-    step = make_impala_train_step(
-        net.apply, optax.sgd(1e-3), ImpalaConfig(), donate=False
-    )
-    ks = jax.random.split(jax.random.PRNGKey(1), 4)
-    batch = {
-        "obs": jax.random.normal(ks[0], (t_dim + 1, b_dim, f_dim),
-                                 jnp.float32),
-        "done": jax.random.bernoulli(ks[1], 0.1, (t_dim + 1, b_dim)),
-        "rewards": jax.random.normal(ks[2], (t_dim + 1, b_dim),
-                                     jnp.float32),
-        "actions": jax.random.randint(ks[3], (t_dim, b_dim), 0, a_dim),
-        "behavior_logits": jnp.zeros((t_dim, b_dim, a_dim), jnp.float32),
-        "core_state": (),
-    }
-    step(state, batch)  # warmup: compile outside the timed check
-    jax.block_until_ready(state)
-
-    watch = ParityWatch(label="bench_parity_check", enabled=True)
-
-    def run_check():
-        watch.check(lambda: jax.tree_util.tree_map(
-            np.asarray, step(state, batch)
-        ))
-
-    samples = measure(run_check, warmup=1, repeats=3 if smoke else 5)
-    stats = trimmed_stats(samples)
-    return _result(
-        "parity_check_s", stats["median"], "s", "lower", smoke,
-        stats=stats,
-        extra={
-            "runs_per_check": watch.runs,
-            "batch": [t_dim, b_dim, f_dim, a_dim],
-        },
-    )
-
-
 # -- registry -----------------------------------------------------------------
 
 CPU_PROXY_SUITE: Dict[str, Callable[[bool], BenchResult]] = {
@@ -1039,8 +877,6 @@ CPU_PROXY_SUITE: Dict[str, Callable[[bool], BenchResult]] = {
     "serving_qps": bench_serving_qps,
     "serving_p99_latency_s": bench_serving_p99,
     "fleet_rollout_s": bench_fleet_rollout,
-    "e2e_learner_step_s": bench_e2e_learner_step,
-    "parity_check_s": bench_parity_check,
 }
 
 

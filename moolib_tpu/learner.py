@@ -23,7 +23,6 @@ below it.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Callable, NamedTuple, Optional, Tuple
 
 import jax
@@ -75,29 +74,6 @@ def make_train_state(params, optimizer: optax.GradientTransformation) -> TrainSt
         opt_state=optimizer.init(params),
         step=jnp.zeros((), jnp.int32),
     )
-
-
-def _scoped(fn: Callable, stepscope, phase: str) -> Callable:
-    """Wrap a jitted step so each call is a stepscope phase, and with it
-    a ``moolib.<loop>.<phase>`` span (moolib_tpu.telemetry.stepscope).
-    The phase CM no-ops outside an active ``scope.step()``, so a scoped
-    step factory is safe to call from anywhere. Dispatch is
-    asynchronous: what is timed is the call (trace and compile the first
-    time, then argument handling and the enqueue), hence the names
-    ``act_dispatch`` / ``grad_dispatch`` / ``apply_dispatch``. The
-    device's time for the step is in the profiler's device plane, and
-    the host's wait for its result in the caller's ``host_sync`` (or
-    wherever it first reads one), where it actually serializes."""
-    if stepscope is None:
-        return fn
-    cm = stepscope.phase(phase)
-
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        with cm:
-            return fn(*args, **kwargs)
-
-    return wrapped
 
 
 def _entropy(logits):
@@ -215,7 +191,6 @@ def make_impala_train_step(
     donate: bool = True,
     loss_fn: Callable = impala_loss,
     batch_axes: Optional[dict] = None,
-    stepscope=None,
 ) -> Callable[[TrainState, dict], Tuple[TrainState, dict]]:
     """Build the jitted train step ``(state, batch) -> (state, metrics)``.
 
@@ -250,10 +225,7 @@ def make_impala_train_step(
             )(state.params, batch)
             return sgd(state, grads, metrics)
 
-        return _scoped(
-            jax.jit(step, donate_argnums=(0,) if donate else ()),
-            stepscope, "grad_dispatch",
-        )
+        return jax.jit(step, donate_argnums=(0,) if donate else ())
 
     replicated = P()
 
@@ -278,10 +250,7 @@ def make_impala_train_step(
             out_specs=(replicated, replicated),
         )(state, batch)
 
-    return _scoped(
-        jax.jit(sharded_step, donate_argnums=(0,) if donate else ()),
-        stepscope, "grad_dispatch",
-    )
+    return jax.jit(sharded_step, donate_argnums=(0,) if donate else ())
 
 
 def make_grad_step(
@@ -292,7 +261,6 @@ def make_grad_step(
     loss_fn: Callable = impala_loss,
     batch_axes: Optional[dict] = None,
     grad_scale: Optional[float] = None,
-    stepscope=None,
 ) -> Callable[[Any, dict], Tuple[Any, dict]]:
     """Build the jitted gradient step ``(params, batch) -> (grads, metrics)``.
 
@@ -333,7 +301,7 @@ def make_grad_step(
             )(params, batch)
             return finish(grads, metrics)
 
-        return _scoped(jax.jit(step), stepscope, "grad_dispatch")
+        return jax.jit(step)
 
     replicated = P()
 
@@ -355,12 +323,11 @@ def make_grad_step(
             out_specs=(replicated, replicated),
         )(params, batch)
 
-    return _scoped(jax.jit(sharded_step), stepscope, "grad_dispatch")
+    return jax.jit(sharded_step)
 
 
 def make_apply_step(
     optimizer: optax.GradientTransformation, donate: bool = True,
-    stepscope=None,
 ) -> Callable[[TrainState, Any], TrainState]:
     """Build the jitted optimizer-apply step ``(state, grads) -> state`` for
     externally-reduced gradients (the other half of :func:`make_grad_step`)."""
@@ -372,14 +339,10 @@ def make_apply_step(
         params = optax.apply_updates(state.params, updates)
         return TrainState(params, opt_state, state.step + 1)
 
-    return _scoped(
-        jax.jit(apply, donate_argnums=(0,) if donate else ()),
-        stepscope, "apply_dispatch",
-    )
+    return jax.jit(apply, donate_argnums=(0,) if donate else ())
 
 
-def make_act_step(apply_fn: Callable, temperature: float = 1.0,
-                  stepscope=None):
+def make_act_step(apply_fn: Callable, temperature: float = 1.0):
     """Jitted acting step for the actor loop / EnvPool double-buffering.
 
     ``(params, rng, obs_B, done_B, core_state) ->
@@ -406,7 +369,7 @@ def make_act_step(apply_fn: Callable, temperature: float = 1.0,
         a = jax.random.categorical(rng, logits, axis=-1)
         return a, logits, core_state
 
-    return _scoped(act, stepscope, "act_dispatch")
+    return act
 
 
 def replicate_state(state: TrainState, mesh: Mesh) -> TrainState:

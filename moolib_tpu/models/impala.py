@@ -36,9 +36,9 @@ def space_to_depth(x: jax.Array, s: int) -> jax.Array:
 
     Trades spatial resolution for channel depth: the first conv's implicit-
     matmul contraction becomes K = kh*kw*C*s*s, multiplying MXU tile
-    occupancy by s^2 (tools/roofline.py: narrow channels cap the MXU tile
-    efficiency). Pure data movement — XLA lowers it to a reshape/
-    transpose pair that fuses into the consuming conv's input layout.
+    occupancy by s^2 (narrow channels cap the MXU tile efficiency). Pure
+    data movement — XLA lowers it to a reshape/transpose pair that fuses
+    into the consuming conv's input layout.
     """
     if s == 1:
         return x
@@ -95,8 +95,8 @@ class ImpalaNet(nn.Module):
     parity, reference: examples/atari/models.py:16-143) stop wasting MXU
     lanes. Channel padding is function-preserving: zero-extended weights
     compute exactly the baseline network (see :func:`widen_impala_params`
-    and tests/test_models.py). Both flags default off; the headline bench
-    never silently uses them.
+    and tests/test_models.py). Both flags default off; no benchmark
+    cell sets them.
     """
 
     num_actions: int

@@ -1,7 +1,7 @@
 """NetHack agent: glyph-embedding CNN + blstats MLP + LSTM core.
 
-Driver benchmark config 5 (BASELINE.md: "R2D2-style LSTM policy on NetHack
-(NLE) — recurrent rollout batching"). The reference repo itself ships no
+Driver benchmark config 5 ("R2D2-style LSTM policy on NetHack (NLE) —
+recurrent rollout batching"). The reference repo itself ships no
 NetHack model — its moolib-era NetHack work lived in a sibling project — so
 this follows the standard NLE-baseline architecture shape: embed the glyph
 grid, convolve it down, encode blstats with a small MLP, fuse, and run a

@@ -10,11 +10,10 @@ then latency grows linearly and throughput plateaus. ``find_batch_size``
 locates that knee empirically for any jitted step.
 
 Timing protocol: each measurement ends in a device-to-host readback of a
-scalar derived from the last output (the same protocol as bench.py) — on
-remote-device runtimes even ``block_until_ready`` can return before device
-execution finishes, but a D2H value transfer cannot be faked, and the
-runtime executes dispatches in order, so reading the last output bounds
-all ``iters`` calls.
+scalar derived from the last output — on remote-device runtimes even
+``block_until_ready`` can return before device execution finishes, but a
+D2H value transfer cannot be faked, and the runtime executes dispatches in
+order, so reading the last output bounds all ``iters`` calls.
 """
 
 from __future__ import annotations

@@ -7,8 +7,9 @@ equivalent for the asyncio port: a pair of single-producer/
 single-consumer byte rings in ONE shared-memory segment per peer pair,
 named-pipe doorbell wakeups, and large-frame spill slots so a multi-MB
 tensor body is written once by the sender and mapped (not copied) by the
-receiver. ``ALLREDUCE_r05.json`` measured the tax this removes: 2.45 GB/s
-cross-process loopback socket vs 9.33 GB/s raw memcpy on the same host.
+receiver. The tax this removes, as a round-5 CPU-host reading had it:
+2.45 GB/s cross-process loopback socket vs 9.33 GB/s raw memcpy on the
+same host.
 
 Segment layout (one sparse file under ``/dev/shm``, created by the
 greeting winner — see ``rpc.py``'s rendezvous — and unlinked by it the

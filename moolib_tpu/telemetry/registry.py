@@ -12,7 +12,7 @@ dependency-free and tuned for this codebase's hot paths:
 - **near-zero when disabled**: instrument sites guard on
   ``Telemetry.on`` (one attribute load + branch) and skip metric lookups,
   timestamps, and recording entirely, so disabled-mode overhead on the
-  RPC echo micro-benchmark stays within the <5% budget asserted by
+  RPC echo round trip stays within the <5% budget asserted by
   ``tools/telemetry_smoke.py``.
 - **deterministic snapshots**: :meth:`Registry.snapshot` orders series by
   their canonical id, so two registries holding the same state produce
@@ -73,9 +73,8 @@ DEFAULT_TIME_EDGES: Tuple[float, ...] = tuple(
 FRACTION_EDGES: Tuple[float, ...] = tuple(i / 8.0 for i in range(1, 9))
 
 #: Quantiles stamped into every histogram export: JSON ``p50``/``p95``/
-#: ``p99`` keys and Prometheus ``{quantile="..."}`` samples. The perf
-#: budget layer (``moolib_tpu/bench/budgets.py``) reads these straight
-#: off scraped snapshots.
+#: ``p99`` keys and Prometheus ``{quantile="..."}`` samples. Perf
+#: budgets are evaluated straight off scraped snapshots of these.
 EXPORT_QUANTILES: Tuple[float, ...] = (0.5, 0.95, 0.99)
 
 

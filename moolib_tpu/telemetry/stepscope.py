@@ -78,11 +78,9 @@ __all__ = [
     "PHASE_CLASS",
     "OTHER_PHASE",
     "FRACTION_GAUGES",
-    "STEPSCOPE_TREND_TOLERANCE",
     "summarize_metrics",
     "merge_summaries",
     "phase_trace",
-    "trend_rows",
 ]
 
 #: The reserved residual phase: wall time no explicit phase claimed.
@@ -124,13 +122,6 @@ FRACTION_GAUGES: Dict[str, str] = {
     "host": "stepscope_host_blocked_fraction",
     "env": "stepscope_env_wait_fraction",
 }
-
-#: Default trend tolerance for the fraction rows. Fractions are noisy at
-#: smoke scale (tens of steps on a shared CPU runner), so the band is
-#: wide — the detector's MAD floor tightens it automatically once the
-#: trend store accumulates stable history.
-STEPSCOPE_TREND_TOLERANCE = 0.5
-
 
 class _StepCM:
     """Reusable ``with scope.step():`` context manager (no per-step
@@ -571,38 +562,3 @@ def phase_trace(peer_summaries: Dict[str, Dict[str, Dict[str, Any]]],
     return {"traceEvents": events, "displayTimeUnit": "ms",
             "otherData": {"view": "stepscope composition"}}
 
-
-def trend_rows(summary: Dict[str, Any], *, smoke: bool, cmd: str,
-               suite: str = "stepscope",
-               tol: float = STEPSCOPE_TREND_TOLERANCE,
-               extra: Optional[Dict[str, Any]] = None) -> List[Any]:
-    """Build schema-valid :class:`~moolib_tpu.bench.harness.BenchResult`
-    rows from one loop summary — one per derived fraction, unit
-    ``fraction``, direction ``lower`` (a growing exposed-comms or
-    host-blocked share is a step-composition regression even when
-    headline throughput holds). The loop name is part of the metric
-    (``stepscope_<loop>_<class>_fraction``): the detector baselines each
-    metric as one series, and an envpool's env-wait share must never
-    share a baseline with a learner's. Append to the CI trends artifact
-    via :func:`~moolib_tpu.bench.trends.append_trend`."""
-    from ..bench.harness import BenchResult
-
-    base_extra = {"loop": summary["loop"], "steps": summary["steps"]}
-    if extra:
-        base_extra.update(extra)
-    rows: List[Any] = []
-    for key, value in summary["fractions"].items():
-        rows.append(
-            BenchResult(
-                metric=f"stepscope_{summary['loop']}_{key}_fraction",
-                value=float(value),
-                unit="fraction",
-                direction="lower",
-                suite=suite,
-                smoke=bool(smoke),
-                cmd=cmd,
-                tol=tol,
-                extra=dict(base_extra),
-            )
-        )
-    return rows

@@ -1736,6 +1736,15 @@ class EnvFleet:
     cohort for the chaos scenarios (the served-step path is what actors
     and, through them, the learner ride on)."""
 
+    #: Seconds a scenario gives a dead worker's replacement to come up:
+    #: a spawned interpreter importing the env module takes 2.6 s on an
+    #: idle 8-core host and 7.9-8.8 s on the same host oversubscribed 4x.
+    #: Both the respawn counter's wait and the stepper's retries run to
+    #: this one deadline; with the stepper's own default (8 attempts,
+    #: 4.55 s of backoff) a step future gave up on a loaded host while
+    #: the pool was still inside its restart budget.
+    RESPAWN_BUDGET_S = 20.0
+
     def __init__(self, create_env, *, procs: int, batch_size: int,
                  pool_name: str, watchdog_timeout: float = 5.0,
                  restart_backoff: float = 0.05,
@@ -1754,7 +1763,12 @@ class EnvFleet:
         self.server = EnvPoolServer(self.server_rpc, self.pool)
         self.client_rpc = Rpc("actor0")
         self.client_rpc.connect(self.server_rpc.debug_info()["listen"][0])
-        self.stepper = RemoteEnvStepper(self.client_rpc, "env-server")
+        # Backoff doubles from 0.05 s to its 1 s cap over five attempts;
+        # each one after that waits a second.
+        self.stepper = RemoteEnvStepper(
+            self.client_rpc, "env-server",
+            max_retries=5 + int(self.RESPAWN_BUDGET_S),
+        )
 
     def close(self):
         self.stepper.close()
@@ -1840,7 +1854,7 @@ def scenario_envpool_worker_kill(seed: int, *, procs: int = 3,
         # The pool recovered within the restart budget...
         _await(lambda: _reg_delta(
             reg, "envpool_respawns_total", base_respawns, pool=pname
-        ) >= 1, 20, "worker never respawned")
+        ) >= 1, fleet.RESPAWN_BUDGET_S, "worker never respawned")
         assert _reg_delta(reg, "envpool_worker_deaths_total", base_deaths,
                           pool=pname, kind="exit") == 1
         # ... and serves at >= 80% of the pre-kill rate.

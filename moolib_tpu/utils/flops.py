@@ -71,13 +71,11 @@ def impala_layer_walk(
     """Yield per-layer records for ImpalaNet (models/impala.py):
     ``(name, flops_per_frame, contraction_k, output_lanes_n, out_elems)``.
 
-    The single source of truth for the architecture walk — both
-    :func:`impala_forward_flops` (the benchmark's MFU denominator) and
-    ``tools/roofline.py`` (the MXU tile-efficiency table) consume it, so the
-    two cannot drift. Mirrors the model exactly: per ConvSequence one 3x3
-    conv at the incoming resolution, a stride-2 SAME max-pool, then two
-    residual blocks (four 3x3 convs) at the pooled resolution; 84x84 input
-    pools 84→42→21→11; then the FC trunk, optional LSTM, and both heads.
+    The architecture walk :func:`impala_forward_flops` sums. Mirrors the
+    model exactly: per ConvSequence one 3x3 conv at the incoming
+    resolution, a stride-2 SAME max-pool, then two residual blocks (four
+    3x3 convs) at the pooled resolution; 84x84 input pools 84→42→21→11;
+    then the FC trunk, optional LSTM, and both heads.
 
     ``contraction_k`` / ``output_lanes_n`` are the implicit-matmul dims the
     MXU sees (convs: K = kh*kw*c_in, N = c_out).

@@ -1,6 +1,5 @@
-"""Entry-point tooling: the chip smoke's refusal to run without a chip, the
-compile-cache placement every ``main`` shares, and the chained-in-jit timing
-protocol.
+"""Entry-point tooling: the chip smoke's refusal to run without a chip, and
+the compile-cache placement every ``main`` shares.
 
 What ``chip_smoke.py`` does ON a chip is checked by running it there
 (README, "Running on the chip"); what can be pinned on the CPU is that it
@@ -13,7 +12,6 @@ import subprocess
 import sys
 
 import jax
-import jax.numpy as jnp
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -91,21 +89,3 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout():
     want = os.path.join(REPO, ".jax_cache")
     assert seen == [[want, want], [want, want]], seen
 
-
-def test_time_chained_protocol():
-    from moolib_tpu.utils.benchmark import time_chained
-
-    calls = []
-
-    def step(c):
-        calls.append(1)  # traced once: chained INSIDE one jit
-        return jax.tree_util.tree_map(lambda x: x * 1.000001 + 1e-7, c)
-
-    carry = (jnp.ones((8, 8)), jnp.zeros((4,)))
-    out, dt, compile_s = time_chained(step, carry, iters=5)
-    assert dt > 0 and compile_s > 0
-    # Tracing happened a bounded number of times (jit), not per-iteration
-    # per-call: 5 timed + 5 warmup iterations would be 10 calls if the
-    # loop dispatched eagerly.
-    assert len(calls) <= 2
-    assert float(jnp.sum(out[0])) > 64.0  # iterations actually applied

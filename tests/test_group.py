@@ -458,7 +458,7 @@ def test_chunk_pipelining_wins_under_injected_link_latency():
     """VERDICT r4 #5: the depth-bounded chunk pipeline must BEAT the
     monolithic message once per-link transfer latency dominates — the
     cross-host overlap the loopback decomposition cannot show (there,
-    chunking measurably loses; ALLREDUCE_r04.json). Per-peer asyncio
+    chunking measurably loses). Per-peer asyncio
     write delays emulate independent NIC links."""
     import os
     import sys

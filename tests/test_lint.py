@@ -85,15 +85,11 @@ def test_cli_clean_tree_exits_zero():
 
 def test_tools_and_tests_trees_clean():
     """The non-package trees are enforced against their own (empty unless
-    debt accrues) baseline — the second ci_check.sh lint stage. The root
-    bench scripts ride along (ISSUE 7) so the bench-wallclock rule covers
-    every file that quotes a duration."""
+    debt accrues) baseline — the second ci_check.sh lint stage."""
     if not BASELINE_TOOLS.exists():
         pytest.skip("no tools/tests lint baseline checked in")
     findings = lint_paths(
-        [REPO_ROOT / "tools", REPO_ROOT / "tests",
-         REPO_ROOT / "bench.py", REPO_ROOT / "bench_allreduce.py",
-         REPO_ROOT / "bench_e2e.py"], root=REPO_ROOT
+        [REPO_ROOT / "tools", REPO_ROOT / "tests"], root=REPO_ROOT
     )
     new, _fixed = diff_against_baseline(
         findings, load_baseline(BASELINE_TOOLS)
@@ -1763,13 +1759,12 @@ def test_bench_wallclock_scoped_to_bench_and_tools_trees():
     # (checkpoint cadences, trace placement) — out of this rule's scope.
     assert _lint_bench(src, relpath="moolib_tpu/rpc/rpc.py") == []
     assert _lint_bench(src, relpath="tests/test_x.py") == []
-    # bench-NAMED files deeper in the package are not automatically
-    # benchmarks; only root-level bench*.py scripts match by name.
+    # bench-NAMED files are not automatically benchmarks: no file
+    # matches by name, at the root or deeper in the package.
     assert _lint_bench(src, relpath="moolib_tpu/examples/bench_x.py") == []
+    assert _lint_bench(src, relpath="bench.py") == []
     # Bench-bearing trees all in scope.
-    for rel in ("bench.py", "tools/envpool_bench.py",
-                "moolib_tpu/bench/suite.py",
-                "moolib_tpu/utils/benchmark.py"):
+    for rel in ("tools/perf.py", "moolib_tpu/bench/suite.py"):
         assert _lint_bench(src, relpath=rel), rel
 
 

@@ -90,18 +90,16 @@ def test_load_trends_raises_on_corrupt_line(tmp_path):
 def test_suite_catalogue_covers_the_cpu_proxies():
     # The ISSUE 7 catalogue plus ISSUE 8's serving rows, ISSUE 12's
     # env-tier recovery row, ISSUE 14's shm transport-lane row, ISSUE
-    # 15's durable-state replication row, ISSUE 17's hotwatch-gated
-    # learner e2e row, ISSUE 18's paritywatch gate-cost row, and ISSUE
-    # 19's fleet rollout row: every named proxy present, every entry
-    # carrying a reproduce-command-compatible name.
+    # 15's durable-state replication row and ISSUE 19's fleet rollout
+    # row: every named host-plane proxy present, every entry carrying a
+    # reproduce-command-compatible name.
     assert set(CPU_PROXY_SUITE) == {
         "rpc_echo_latency_s", "rpc_payload_gbps", "rpc_shm_payload_gbps",
         "allreduce_tree_gbps",
         "batcher_fill_s", "envpool_steps_per_s", "envpool_recovery_s",
         "serial_encode_gbps", "serial_decode_gbps",
         "statestore_replicate_gbps", "serving_qps",
-        "serving_p99_latency_s", "fleet_rollout_s", "e2e_learner_step_s",
-        "parity_check_s",
+        "serving_p99_latency_s", "fleet_rollout_s",
     }
 
 
@@ -206,21 +204,22 @@ def _fraction_summary(exposed, loop="a2c_learner"):
 
 
 def _fraction_rows(exposed_values, loop="a2c_learner"):
-    from moolib_tpu.telemetry.stepscope import trend_rows
+    from moolib_tpu.bench.harness import stepscope_trend_rows
 
     rows = []
     for v in exposed_values:
-        rows.extend(trend_rows(_fraction_summary(v, loop), smoke=True,
-                               cmd=STEPSCOPE_SMOKE_CMD))
+        rows.extend(stepscope_trend_rows(_fraction_summary(v, loop),
+                                         smoke=True,
+                                         cmd=STEPSCOPE_SMOKE_CMD))
     return rows
 
 
 def test_stepscope_trend_rows_are_schema_valid_fraction_rows(tmp_path):
-    from moolib_tpu.telemetry.stepscope import (STEPSCOPE_TREND_TOLERANCE,
-                                                trend_rows)
+    from moolib_tpu.bench.harness import (STEPSCOPE_TREND_TOLERANCE,
+                                          stepscope_trend_rows)
 
-    rows = trend_rows(_fraction_summary(0.2), smoke=True,
-                      cmd=STEPSCOPE_SMOKE_CMD)
+    rows = stepscope_trend_rows(_fraction_summary(0.2), smoke=True,
+                                cmd=STEPSCOPE_SMOKE_CMD)
     assert [r.metric for r in rows] == [
         "stepscope_a2c_learner_exposed_comms_fraction",
         "stepscope_a2c_learner_host_blocked_fraction",

@@ -18,6 +18,7 @@ import time
 
 import pytest
 
+from moolib_tpu.bench.harness import stepscope_trend_rows
 from moolib_tpu.telemetry import (
     StepScope,
     Telemetry,
@@ -28,7 +29,6 @@ from moolib_tpu.telemetry.stepscope import (
     PHASE_CLASS,
     merge_summaries,
     phase_trace,
-    trend_rows,
 )
 
 
@@ -524,8 +524,8 @@ def test_acceptance_a2c_cohort_fractions_everywhere():
         pytest.approx(learner["fractions"]["exposed_comms"])
 
     # Trend rows: schema-valid through the strict parser, loop-qualified.
-    rows = trend_rows(learner, smoke=True,
-                      cmd="python tools/stepscope_report.py --smoke")
+    rows = stepscope_trend_rows(
+        learner, smoke=True, cmd="python tools/stepscope_report.py --smoke")
     for row in rows:
         assert parse_result(dataclasses.asdict(row)) == row
     assert {r.metric for r in rows} == {

@@ -1,10 +1,10 @@
 """Injected-latency A/B: depth-bounded chunk pipelining vs one monolithic
 message through the DCN tree allreduce (VERDICT r4 weak #2 / next #5).
 
-The loopback decomposition (tools/allreduce_decomp.py, ALLREDUCE_r04.json)
-showed chunking LOSES on a one-core loopback — there is no cross-host
-concurrency to exploit, so extra messages are pure overhead. The design
-justification for chunking is different hardware: on a real DCN, hop i's
+A loopback decomposition (round 4, CPU host) showed chunking LOSES on a
+one-core loopback — there is no cross-host concurrency to exploit, so
+extra messages are pure overhead. The design justification for chunking
+is different hardware: on a real DCN, hop i's
 link transfer overlaps hop i+1's merge on ANOTHER host. This harness
 demonstrates that win without a second host by injecting per-link transfer
 latency: every peer's async write path sleeps ``bytes / link_bw`` before
@@ -176,21 +176,6 @@ def main():
     if args.json:
         with open(args.json, "w") as f:
             json.dump(row, f, indent=1)
-
-    # Harness-schema trend row (no-op unless MOOLIB_TRENDS is set): the
-    # chunked-pipeline speedup at this injected link speed is the number
-    # that must not regress.
-    from moolib_tpu.bench.harness import append_device_trend
-
-    append_device_trend(
-        f"allreduce_chunked_speedup_{args.link_mbps:g}mbps",
-        row["chunked_speedup"], "x",
-        f"python tools/allreduce_latency_ab.py --mb {args.mb:g} "
-        f"--link-mbps {args.link_mbps:g} --peers {args.peers}",
-        extra={k: row[k] for k in
-               ("peers", "mb", "link_mbps", "unchunked_s",
-                "chunked_depth4_s")},
-    )
 
 
 if __name__ == "__main__":

@@ -240,14 +240,10 @@ def _index() -> str:
         "",
         "Other entry points:",
         "",
-        "- `tools/perf.py` — perfwatch CLI: CPU-proxy perf suite + "
-        "budgets + trend gate (CI stage), device-suite front end.",
-        "- `bench.py` — headline learner benchmark (one JSON line; "
-        "perfwatch wrapper).",
-        "- `bench_e2e.py` — end-to-end acting+training benchmark.",
-        "- `bench_allreduce.py` — DCN tree / ICI psum collective benchmark.",
-        "- `tools/roofline.py`, `tools/perf_sweep.py`, "
-        "`tools/allreduce_decomp.py` — perf analysis tooling.",
+        "- `benchmark/run.py` — the benchmark: device speed of every "
+        "cell of `BENCHMARK.json`, on the chip (see `PERF.md`).",
+        "- `tools/perf.py` — perfwatch CLI: host-plane CPU-proxy perf "
+        "suite + budgets + trend gate (CI stage).",
         "- `tools/moolint.py` — static-analysis CLI; `tools/ci_check.sh` — "
         "lint + tier-1 tests, one entrypoint.",
         "- `tools/chaos_soak.py` — chaosnet scenario runner "

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
-"""perf.py: the one perfwatch CLI — every benchmark in the repo behind one
-front end (docs/perf.md).
+"""perf.py: the one perfwatch CLI — every host-plane benchmark behind one
+front end (docs/perf.md). Device speed is ``benchmark/run.py``'s.
 
 Suites:
     cpu-proxy   host-side hot-path proxies (RPC echo/payload, loopback tree
@@ -165,7 +165,7 @@ def main(argv=None) -> int:
             append_trend(args.trends, r)
         _rows, regressions = gate_trends(args)
         # Post-run gate: only THIS run's metrics can fail it. The shared
-        # store also holds other series (device rows, un-run benchmarks)
+        # store also holds other series (stepscope rows, un-run benchmarks)
         # whose stale latest row must not red every unrelated PR —
         # whole-store semantics live in --check-trends-only.
         ran = {res.metric for res in results}

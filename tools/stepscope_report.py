@@ -218,7 +218,7 @@ def smoke(args) -> int:
                                          load_trends)
     from moolib_tpu.examples.a2c import A2CConfig, train
     from moolib_tpu.telemetry import global_telemetry
-    from moolib_tpu.telemetry.stepscope import trend_rows
+    from moolib_tpu.bench.harness import stepscope_trend_rows
 
     cfg = A2CConfig(total_steps=1500, log_interval_steps=500,
                     num_processes=2, batch_size=2, num_batches=2)
@@ -246,7 +246,8 @@ def smoke(args) -> int:
 
     rows = []
     for loop in ("a2c_learner", "envpool"):
-        rows.extend(trend_rows(summaries[loop], smoke=True, cmd=SMOKE_CMD))
+        rows.extend(stepscope_trend_rows(summaries[loop], smoke=True,
+                                         cmd=SMOKE_CMD))
     for row in rows:
         append_trend(args.trends, row)
     ran = {r.metric for r in rows}
