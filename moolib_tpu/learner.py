@@ -60,6 +60,9 @@ class ImpalaConfig:
     # TransformerNet(mlp='moe') path); Switch/ST-MoE defaults.
     moe_lb_cost: float = 0.01
     moe_z_cost: float = 0.001
+    # Weight of a model's own multi-token-prediction cross-entropy, where
+    # its aux carries one (DecoderLM with ``mtp``).
+    mtp_cost: float = 0.1
 
 
 class TrainState(NamedTuple):
@@ -109,8 +112,9 @@ def impala_loss(
     capacity-factor MoE's, from
     :func:`moolib_tpu.models.transformer.moe_aux_losses`) they are folded
     into the total with ``config.moe_lb_cost`` / ``config.moe_z_cost``;
-    ``drop_fraction`` is surfaced so capacity drops are visible in training
-    logs; every other entry (the dropless layer's ``moe_*`` counters, from
+    a multi-token-prediction module's ``mtp_loss`` with
+    ``config.mtp_cost``; ``drop_fraction`` is surfaced so capacity drops
+    are visible in training logs; every other entry (the dropless layer's ``moe_*`` counters, from
     :func:`moolib_tpu.models.lm.learn_apply`) passes through to the
     metrics as a counter.
     """
@@ -171,6 +175,7 @@ def impala_loss(
         for key, cost, name in (
             ("load_balance_loss", config.moe_lb_cost, "moe_lb_loss"),
             ("router_z_loss", config.moe_z_cost, "moe_z_loss"),
+            ("mtp_loss", config.mtp_cost, "mtp_loss"),
         ):
             if key in aux:
                 metrics[name] = aux.pop(key)

@@ -13,14 +13,43 @@ unroll is the context, causal over T and cut at episode boundaries
 0..T-1 over the unroll and are not reset at a boundary.
 
 The stack is **described by data**: ``layers`` is a list, one entry a
-block, each naming its attention kind and its MLP kind, and
-``attention_kinds`` says what a kind is (its window, its rotary
-parameters). A model with three windowed layers to one full layer is a
-list, not a constructor flag. Blocks are pre-norm (RMS), rotary positions
-(plain, or YaRN-scaled) over the whole head, grouped key/value heads,
-sparse MLPs through :func:`moolib_tpu.parallel.moe.moe_dropless`. They use
-the residual skeleton and the one attention call site of
-:mod:`moolib_tpu.models.transformer`.
+block (or, with ``repeat``, that many identical blocks run as one scan
+over their stacked parameters: one block's code, one block's compile),
+each naming its attention kind and its MLP kind, and ``attention_kinds``
+says what a kind is (its window, its rotary parameters, and for latent
+attention its ranks and the split of its head). A model with three
+windowed layers to one full layer is a list, not a constructor flag.
+Blocks are pre-norm (RMS) on the residual skeleton and the one attention
+call site of :mod:`moolib_tpu.models.transformer`.
+
+Attention kinds. Without ``latent``: rotary positions (plain, or
+YaRN-scaled) over the whole head, grouped key/value heads, three dense
+projections. With ``latent`` (multi-head latent attention, DeepSeek-V2):
+
+    c_q = rms(x W_qa);  q = c_q W_qb        -> heads of [nope | rope]
+    [c_kv | k_r] = x W_kva;  c_kv = rms(c_kv)
+    [k_nope | v] a head = c_kv W_kvb;  k = [k_nope | rotary(k_r)]
+
+one rotary key a position shared by every head, rotary over the ``rope``
+part only, scores scaled by ``(nope + rope)^-1/2``. This is the
+decompressed form a training forward computes; the absorbed form with one
+key head belongs to a cache, which the repo has none of.
+
+MLP kinds. ``sparse``: gated experts through
+:func:`moolib_tpu.parallel.moe.moe_dropless`, scored and chosen as
+``router`` says (softmax top-k; or sigmoid scores, a selection bias that
+takes no gradient, scaled gates), with a shared expert beside them where
+``shared_expert_size`` is set. ``dense``: one gated MLP of
+``intermediate_size``.
+
+``mtp`` adds one multi-token-prediction module (DeepSeek-V3's form) after
+the stack: from the last block's output ``h_t`` and the next token's
+embedding, ``u_t = [rms(e_{t+1}) ; rms(h_t)] W_eh``, one more block of the
+kinds it names, a norm of its own, the shared embedding and head: logits
+for token t+2. Its cross-entropy against ``obs[t+2]``, over the positions
+whose two next tokens lie in their episode, is sown with the count of
+those positions; :func:`learn_apply` hands both to the loss
+(``mtp_loss``, ``mtp_positions``).
 
 **A share of a layer.** ``num_heads`` / ``num_kv_heads``, ``experts_held``
 and ``vocab_size`` are what *this chip* holds of a layer that several chips
@@ -56,7 +85,9 @@ from .transformer import (attend, residual_block, segment_ids_from_done,
 __all__ = [
     "AttentionKind",
     "DecoderLM",
+    "Latent",
     "Rope",
+    "Router",
     "decoder_lm",
     "learn_apply",
     "rope_inv_freq",
@@ -80,9 +111,33 @@ class Rope:
 
 
 @dataclasses.dataclass(frozen=True)
+class Latent:
+    """The sizes of latent attention (the keys of a DeepSeek-style
+    config): both low ranks and the split of a head."""
+
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionKind:
     window: Optional[int]  # None: full causal attention
     rope: Rope
+    latent: Optional[Latent] = None  # None: three dense projections
+
+
+@dataclasses.dataclass(frozen=True)
+class Router:
+    """How a sparse MLP scores and chooses (``moe_dropless``'s
+    arguments): ``selection_bias`` adds a parameter ``[num_experts]`` to
+    the scores for the choice alone."""
+
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    gate_scale: float = 1.0
 
 
 def rope_inv_freq(rope: Rope, head_dim: int) -> np.ndarray:
@@ -124,6 +179,22 @@ def _rotary(x, cos, sin):
     return out.astype(x.dtype)
 
 
+def _rotary_tables(rope: Rope, positions, dim: int):
+    """cos and sin [T, dim], float32, of the angles the ``dim`` rotated
+    dimensions turn by at ``positions`` (each frequency twice: the
+    half-split form), times the kind's ``attention_factor``."""
+    angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
+        rope_inv_freq(rope, dim), jnp.float32
+    )
+    angle = jnp.concatenate([angle, angle], axis=-1)
+    return (jnp.cos(angle) * rope.attention_factor,
+            jnp.sin(angle) * rope.attention_factor)
+
+
+def _dense(name: str, width: int, dtype):
+    return nn.Dense(width, use_bias=False, dtype=dtype, name=name)
+
+
 class RMSNorm(nn.Module):
     eps: float
     dtype: jnp.dtype
@@ -153,18 +224,12 @@ class _Attention(nn.Module):
         H, Hkv, D = self.num_heads, self.num_kv_heads, self.head_dim
 
         def proj(name, heads):
-            return nn.Dense(
-                heads * D, use_bias=False, dtype=self.dtype, name=name
-            )(x).reshape(T, B, heads, D)
+            return _dense(name, heads * D, self.dtype)(x).reshape(
+                T, B, heads, D
+            )
 
         with jax.named_scope("moolib.lm.attn_proj"):
-            rope = self.kind.rope
-            angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
-                rope_inv_freq(rope, D), jnp.float32
-            )
-            angle = jnp.concatenate([angle, angle], axis=-1)  # [T, D]
-            cos = jnp.cos(angle) * rope.attention_factor
-            sin = jnp.sin(angle) * rope.attention_factor
+            cos, sin = _rotary_tables(self.kind.rope, positions, D)
             q = _rotary(proj("q", H), cos, sin)
             k = _rotary(proj("k", Hkv), cos, sin)
             v = proj("v", Hkv)
@@ -178,20 +243,103 @@ class _Attention(nn.Module):
             )
         with jax.named_scope("moolib.lm.attn_proj"):
             o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
-            return nn.Dense(
-                x.shape[-1], use_bias=False, dtype=self.dtype, name="o"
-            )(o)
+            return _dense("o", x.shape[-1], self.dtype)(o)
+
+
+class _LatentAttention(nn.Module):
+    """Latent attention in its decompressed form; see the module
+    docstring. The flash kernels have one head size, so the query/key head
+    (``nope + rope``) and the value head have to agree."""
+
+    kind: AttentionKind
+    num_heads: int
+    backend: str
+    block: int
+    eps: float
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x, seg_bt, positions):
+        T, B, _ = x.shape
+        H, lat = self.num_heads, self.kind.latent
+        nope, rot, dv = (
+            lat.qk_nope_head_dim, lat.qk_rope_head_dim, lat.v_head_dim
+        )
+        if nope + rot != dv:
+            raise ValueError(
+                f"latent attention with a query/key head of {nope + rot} "
+                f"and a value head of {dv}: the kernels have one head size"
+            )
+
+        def dense(name, width):
+            return _dense(name, width, self.dtype)
+
+        def norm(name):
+            return RMSNorm(self.eps, self.dtype, name=name)
+
+        with jax.named_scope("moolib.lm.mla_proj"):
+            c_q = norm("q_a_norm")(dense("q_a", lat.q_lora_rank)(x))
+            q = dense("q_b", H * (nope + rot))(c_q).reshape(
+                T, B, H, nope + rot
+            )
+            c_kv, k_r = jnp.split(
+                dense("kv_a", lat.kv_lora_rank + rot)(x),
+                [lat.kv_lora_rank], axis=-1,
+            )
+            kv = dense("kv_b", H * (nope + dv))(
+                norm("kv_a_norm")(c_kv)
+            ).reshape(T, B, H, nope + dv)
+            cos, sin = _rotary_tables(self.kind.rope, positions, rot)
+            q = jnp.concatenate(
+                [q[..., :nope], _rotary(q[..., nope:], cos, sin)], axis=-1
+            )
+            # one rotary key a position, the same for every head
+            k_r = _rotary(k_r[:, :, None, :], cos, sin)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_r, (T, B, H, rot))],
+                axis=-1,
+            )
+            v = kv[..., nope:]
+            q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
+        with jax.named_scope("moolib.lm.attn_core"):
+            o = attend(
+                q, k, v, seg_bt, backend=self.backend,
+                window=self.kind.window, block_q=self.block,
+                block_k=self.block,
+            )
+        with jax.named_scope("moolib.lm.mla_proj"):
+            o = o.transpose(2, 0, 1, 3).reshape(T, B, H * dv)
+            return dense("o", x.shape[-1])(o)
+
+
+class _GatedMlp(nn.Module):
+    """``(silu(x W_gate) * x W_up) W_down``, no bias."""
+
+    d_ff: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x):
+        h = jax.nn.silu(_dense("gate", self.d_ff, self.dtype)(x)) * _dense(
+            "up", self.d_ff, self.dtype
+        )(x)
+        return _dense("down", x.shape[-1], self.dtype)(h)
 
 
 class _SparseMlp(nn.Module):
     """Gated experts behind a router over ``num_experts``; ``held`` of
-    them live here (see :func:`moe_dropless`)."""
+    them live here (see :func:`moe_dropless`). The shared expert, where
+    there is one, is whole on every chip and outside the expert layer's
+    own scopes."""
 
     num_experts: int
     held: Tuple[int, int]
     top_k: int
     d_ff: int
     buffer_rows: Optional[int]
+    router: Router = Router()
+    shared_d_ff: Optional[int] = None
+    dtype: jnp.dtype = jnp.float32
 
     @nn.compact
     def __call__(self, x):  # [T, B, d] -> [T, B, d]
@@ -206,13 +354,24 @@ class _SparseMlp(nn.Module):
             "w_up": self.param("w_up", expert_init, (count, d, self.d_ff)),
             "w_down": self.param("w_down", expert_init, (count, self.d_ff, d)),
         }
+        select_bias = None
+        if self.router.selection_bias:
+            select_bias = self.param(
+                "e_score_correction_bias", nn.initializers.zeros,
+                (self.num_experts,),
+            )
         y, aux = moe_dropless(
             params, x.reshape(T * B, d), top_k=self.top_k, held=self.held,
-            buffer_rows=self.buffer_rows,
+            buffer_rows=self.buffer_rows, scoring=self.router.scoring,
+            select_bias=select_bias, gate_scale=self.router.gate_scale,
         )
         self.sow("intermediates", "moe_router_load", aux.pop("moe_router_load"))
         self.sow("intermediates", "moe_counters", aux)
-        return y.reshape(T, B, d)
+        y = y.reshape(T, B, d)
+        if self.shared_d_ff is not None:
+            with jax.named_scope("moolib.moe.shared"):
+                y = y + _GatedMlp(self.shared_d_ff, self.dtype, name="shared")(x)
+        return y
 
 
 @dataclasses.dataclass(frozen=True)
@@ -231,35 +390,134 @@ class _Sizes:
     compute_dtype: jnp.dtype
     attention_backend: str
     attention_block: int
+    router: Router
+    shared_expert_size: Optional[int]
+    intermediate_size: Optional[int]
 
 
 class _Block(nn.Module):
     kind: AttentionKind
     mlp: str
     net: _Sizes
+    scanned: bool = False  # the body of a scan: returns (carry, None)
 
     @nn.compact
     def __call__(self, x, seg_bt, positions):
         net = self.net
-        if self.mlp != "sparse":
-            raise ValueError(f"unknown mlp kind {self.mlp!r}; have 'sparse'")
 
         def norm(name):
             return RMSNorm(net.rms_norm_eps, net.compute_dtype, name=name)
 
-        attention = _Attention(
-            self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
-            net.attention_backend, net.attention_block, net.compute_dtype,
-            name="attn",
-        )
-        mlp = _SparseMlp(
-            net.num_experts, net.held, net.top_k, net.moe_intermediate_size,
-            net.moe_buffer_rows, name="moe",
-        )
-        return residual_block(
+        if self.kind.latent is None:
+            attention = _Attention(
+                self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
+                net.attention_backend, net.attention_block,
+                net.compute_dtype, name="attn",
+            )
+        else:
+            attention = _LatentAttention(
+                self.kind, net.num_heads, net.attention_backend,
+                net.attention_block, net.rms_norm_eps, net.compute_dtype,
+                name="attn",
+            )
+        if self.mlp == "sparse":
+            mlp = _SparseMlp(
+                net.num_experts, net.held, net.top_k,
+                net.moe_intermediate_size, net.moe_buffer_rows, net.router,
+                net.shared_expert_size, net.compute_dtype, name="moe",
+            )
+        elif self.mlp == "dense":
+            dense = _GatedMlp(
+                net.intermediate_size, net.compute_dtype, name="mlp"
+            )
+
+            def mlp(h):
+                with jax.named_scope("moolib.lm.mlp_dense"):
+                    return dense(h)
+        else:
+            raise ValueError(
+                f"unknown mlp kind {self.mlp!r}; have 'sparse', 'dense'"
+            )
+        out = residual_block(
             x, norm("norm1"), lambda h: attention(h, seg_bt, positions),
             norm("norm2"), mlp,
         )
+        return (out, None) if self.scanned else out
+
+
+def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
+            remat: bool, name: str):
+    """One block, or ``repeat`` of them as one scan over parameters
+    stacked on a leading axis; with ``remat`` each block is rebuilt in the
+    backward pass and only its input kept. ``(x, seg_bt, positions) ->
+    x``."""
+    cls = nn.remat(_Block, prevent_cse=False) if remat else _Block
+    if repeat == 1:
+        return cls(kind, mlp, sizes, name=name)
+    scan = nn.scan(
+        cls, variable_axes={"params": 0, "intermediates": 0},
+        split_rngs={"params": True}, in_axes=nn.broadcast, length=repeat,
+    )(kind, mlp, sizes, True, name=name)
+    return lambda x, seg_bt, positions: scan(x, seg_bt, positions)[0]
+
+
+class _Mtp(nn.Module):
+    """One multi-token-prediction module; see the module docstring.
+    Returns its masked mean cross-entropy and the positions that entered
+    it."""
+
+    kind: AttentionKind
+    mlp: str
+    net: _Sizes
+    remat: bool
+    loss_rows: int
+
+    @nn.compact
+    def __call__(self, hidden, embedded, obs, seg_bt, positions, head_kernel):
+        net = self.net
+        T, B, d = hidden.shape
+
+        def norm(name):
+            return RMSNorm(net.rms_norm_eps, net.compute_dtype, name=name)
+
+        # Position t reads token t+1's embedding and is asked for token
+        # t+2; the last two positions have no such token and are masked.
+        u = _dense("eh_proj", d, net.compute_dtype)(jnp.concatenate(
+            [norm("enorm")(jnp.roll(embedded, -1, axis=0)),
+             norm("hnorm")(hidden)], axis=-1,
+        ))
+        u = _blocks(self.kind, self.mlp, net, 1, self.remat, "block")(
+            u, seg_bt, positions
+        )
+        u = norm("final_norm")(u)
+        seg = seg_bt.T  # [T, B]
+        valid = jnp.logical_and(
+            jnp.roll(seg, -2, axis=0) == seg,  # then t+1 is t's too
+            (jnp.arange(T) < T - 2)[:, None],
+        )
+        target = jnp.roll(obs, -2, axis=0)
+        rows = min(self.loss_rows, T * B)
+        if (T * B) % rows:
+            raise ValueError(
+                f"{T * B} positions do not split into blocks of {rows}"
+            )
+        kernel = head_kernel.astype(net.compute_dtype)
+
+        @jax.checkpoint
+        def block_nll(u, target, valid):
+            logp = jax.nn.log_softmax(
+                (u @ kernel).astype(jnp.float32), axis=-1
+            )
+            nll = -jnp.take_along_axis(logp, target[:, None], axis=-1)[:, 0]
+            return jnp.sum(jnp.where(valid, nll, 0.0))
+
+        total = jnp.sum(jax.lax.map(
+            lambda xs: block_nll(*xs),
+            (u.reshape(-1, rows, d), target.reshape(-1, rows),
+             valid.reshape(-1, rows)),
+        ))
+        count = jnp.sum(valid).astype(jnp.float32)
+        return total / jnp.maximum(count, 1.0), count
 
 
 class DecoderLM(nn.Module):
@@ -269,7 +527,8 @@ class DecoderLM(nn.Module):
 
     vocab_size: int  # rows of the embedding and of the head held here
     hidden_size: int
-    layers: Tuple[Tuple[str, str], ...]  # (attention kind, mlp kind) a block
+    # (attention kind, mlp kind) a block; a third element repeats it
+    layers: Tuple[Tuple, ...]
     attention_kinds: Tuple[Tuple[str, AttentionKind], ...]
     num_heads: int  # query heads held here
     num_kv_heads: int
@@ -286,6 +545,18 @@ class DecoderLM(nn.Module):
     compute_dtype: jnp.dtype = jnp.float32
     attention_backend: str = "auto"
     attention_block: int = 256
+    router: Router = Router()
+    shared_expert_size: Optional[int] = None  # None: no shared expert
+    intermediate_size: Optional[int] = None  # a ``dense`` MLP's width
+    # The multi-token-prediction module's block, (attention kind, mlp
+    # kind); None: no module. Its logits and cross-entropy are computed
+    # ``mtp_loss_rows`` positions at a time and rebuilt in the backward
+    # pass, so no second logits array is ever held.
+    mtp: Optional[Tuple[str, str]] = None
+    mtp_loss_rows: int = 1024
+    # Every block rebuilt in the backward pass from its input, the one
+    # array a block keeps.
+    remat_blocks: bool = False
 
     def _sizes(self) -> _Sizes:
         return _Sizes(
@@ -293,54 +564,84 @@ class DecoderLM(nn.Module):
             self.num_experts, self.experts_held or (0, self.num_experts),
             self.top_k, self.moe_intermediate_size, self.moe_buffer_rows,
             self.rms_norm_eps, jnp.dtype(self.compute_dtype),
-            self.attention_backend, self.attention_block,
+            self.attention_backend, self.attention_block, self.router,
+            self.shared_expert_size, self.intermediate_size,
         )
 
     @nn.compact
     def __call__(self, obs, done, core_state):
         T = obs.shape[0]
-        x = nn.Embed(
+        obs = obs.astype(jnp.int32)
+        embed = nn.Embed(
             self.vocab_size, self.hidden_size, dtype=self.compute_dtype,
             name="embed",
-        )(obs.astype(jnp.int32))
+        )
+        x = embedded = embed(obs)
         seg_bt = segment_ids_from_done(done)
         positions = jnp.arange(T)
         kinds, sizes = dict(self.attention_kinds), self._sizes()
-        for i, (attention, mlp) in enumerate(self.layers):
-            x = _Block(kinds[attention], mlp, sizes, name=f"block_{i}")(
-                x, seg_bt, positions
-            )
+        for i, (attention, mlp, *repeat) in enumerate(self.layers):
+            x = _blocks(
+                kinds[attention], mlp, sizes, *(repeat or [1]),
+                self.remat_blocks, f"block_{i}",
+            )(x, seg_bt, positions)
+        head = _dense("head", self.vocab_size, self.compute_dtype)
+        hidden = x
         with jax.named_scope("moolib.lm.head"):
             x = RMSNorm(
                 self.rms_norm_eps, self.compute_dtype, name="final_norm"
             )(x)
-            logits = nn.Dense(
-                self.vocab_size, use_bias=False, dtype=self.compute_dtype,
-                name="head",
-            )(x).astype(jnp.float32)
+            logits = head(x).astype(jnp.float32)
             baseline = nn.Dense(1, name="baseline")(
                 x.astype(jnp.float32)
             ).squeeze(-1)
+        if self.mtp is not None:
+            with jax.named_scope("moolib.lm.mtp"):
+                loss, count = _Mtp(
+                    kinds[self.mtp[0]], self.mtp[1], sizes,
+                    self.remat_blocks, self.mtp_loss_rows, name="mtp",
+                )(
+                    hidden, embedded, obs, seg_bt, positions,
+                    head.variables["params"]["kernel"],
+                )
+            self.sow("intermediates", "mtp_terms", {
+                "mtp_loss": loss, "mtp_positions": count,
+            })
         return (logits, baseline), core_state
 
     def initial_state(self, batch_size: int) -> Tuple:
         return ()
 
 
-def decoder_lm(*, layers, attention_kinds, experts_held=None,
-               **kwargs) -> DecoderLM:
+def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
+               mtp=None, **kwargs) -> DecoderLM:
     """A :class:`DecoderLM` from JSON-shaped arguments: ``layers`` a list
-    of ``{"attention": kind, "mlp": "sparse"}``, ``attention_kinds`` a
-    mapping ``kind -> {"window": int or null, "rope": {...}}`` whose
-    ``rope`` holds the fields of :class:`Rope`."""
+    of ``{"attention": kind, "mlp": "sparse" | "dense"}``, an entry with
+    ``"repeat": n`` standing for ``n`` identical blocks run as a scan;
+    ``attention_kinds`` a mapping ``kind -> {"window": int or null,
+    "rope": {...}, "latent": {...} or absent}`` whose ``rope`` holds the
+    fields of :class:`Rope` and whose ``latent`` those of
+    :class:`Latent`; ``router`` the fields of :class:`Router`; ``mtp`` the
+    multi-token-prediction module's block, an entry like one of
+    ``layers``."""
     kinds = tuple(
-        (name, AttentionKind(spec.get("window"), Rope(**spec["rope"])))
+        (name, AttentionKind(
+            spec.get("window"), Rope(**spec["rope"]),
+            Latent(**spec["latent"]) if spec.get("latent") else None,
+        ))
         for name, spec in sorted(attention_kinds.items())
     )
+
+    def entry(l):
+        pair = (l["attention"], l["mlp"])
+        return pair + (l["repeat"],) if l.get("repeat", 1) > 1 else pair
+
     return DecoderLM(
-        layers=tuple((l["attention"], l["mlp"]) for l in layers),
+        layers=tuple(entry(l) for l in layers),
         attention_kinds=kinds,
         experts_held=None if experts_held is None else tuple(experts_held),
+        router=Router(**(router or {})),
+        mtp=None if mtp is None else (mtp["attention"], mtp["mlp"]),
         **kwargs,
     )
 
@@ -348,14 +649,20 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None,
 def _sum_counters(intermediates) -> dict:
     """Every expert layer's sown counters, summed over layers; the two
     loads (the fullest held expert's and the mean) averaged over them."""
-    layers = sown_dicts(intermediates, "moe_assignments_total")
     total: dict = {}
-    for layer in layers:
-        for name, value in layer.items():
+    layers = 0
+    for sown in sown_dicts(intermediates, "moe_assignments_total"):
+        # a scan's blocks sow one array, an element a block
+        layers += next(iter(sown.values())).size
+        for name, value in sown.items():
+            if value.ndim:
+                value = jnp.sum(value)
             total[name] = total.get(name, 0.0) + value
     for name in ("moe_load_max", "moe_load_mean"):
         if name in total:
-            total[name] = total[name] / len(layers)
+            total[name] = total[name] / layers
+    for sown in sown_dicts(intermediates, "mtp_loss"):
+        total.update(sown)
     return total
 
 
@@ -363,8 +670,9 @@ def learn_apply(net: DecoderLM) -> Callable:
     """The learner's ``apply_fn`` for ``net``, in the three-element
     convention of :func:`moolib_tpu.learner.impala_loss`: the third is the
     expert layers' counters summed over layers, which the loss passes
-    through to the step's metrics (no loss term: the model's configuration
-    declares no routing loss)."""
+    through to the step's metrics, and, where the model has a
+    multi-token-prediction module, its ``mtp_loss`` (a term of the loss,
+    weighed by ``ImpalaConfig.mtp_cost``) and ``mtp_positions``."""
 
     def apply(params, obs, done, core_state):
         (out, state), inter = net.apply(
@@ -378,9 +686,11 @@ def learn_apply(net: DecoderLM) -> Callable:
 def router_loads(net: DecoderLM) -> Callable:
     """``(params, obs, done) -> [layers, num_experts] int32``: the
     assignments every expert layer's router sends to each of its experts,
-    held here or not, in the order of ``net.layers``. A forward pass and
-    nothing else: for whoever has to know the routing of given weights on
-    given tokens (the benchmark's seeding reads it)."""
+    held here or not, in the order the layers run (a repeated entry's
+    blocks one after the other, the multi-token-prediction module's last;
+    a dense block has no row). A forward pass and nothing else: for whoever
+    has to know the routing of given weights on given tokens (the
+    benchmark's seeding reads it)."""
 
     def loads(params, obs, done):
         _, inter = net.apply(
@@ -388,9 +698,15 @@ def router_loads(net: DecoderLM) -> Callable:
             mutable=["intermediates"],
         )
         blocks = inter["intermediates"]
-        return jnp.stack([
-            blocks[f"block_{i}"]["moe"]["moe_router_load"][0]
-            for i in range(len(net.layers))
+        sparse = [
+            blocks[f"block_{i}"] for i, layer in enumerate(net.layers)
+            if layer[1] == "sparse"
+        ]
+        if net.mtp is not None and net.mtp[1] == "sparse":
+            sparse.append(blocks["mtp"]["block"])
+        return jnp.concatenate([
+            b["moe"]["moe_router_load"][0].reshape(-1, net.num_experts)
+            for b in sparse
         ])
 
     return loads

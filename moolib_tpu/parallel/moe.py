@@ -346,6 +346,12 @@ def _grouped_product(a, w, group_sizes, how: str):
     )
 
 
+_SCORING = {
+    "softmax": lambda logits: jax.nn.softmax(logits, axis=-1),
+    "sigmoid": jax.nn.sigmoid,
+}
+
+
 def moe_dropless(
     params: Dict[str, Any],
     x: jax.Array,
@@ -353,6 +359,9 @@ def moe_dropless(
     top_k: int,
     held: Optional[Tuple[int, int]] = None,
     buffer_rows: Optional[int] = None,
+    scoring: str = "softmax",
+    select_bias: Optional[jax.Array] = None,
+    gate_scale: float = 1.0,
 ):
     """Dropless top-``top_k`` MoE with gated experts. ``x``: [T, d_model].
 
@@ -369,6 +378,15 @@ def moe_dropless(
             g_e * (silu(x @ w_gate_e) * (x @ w_up_e)) @ w_down_e
 
     A token none of whose choices is held gets ``y = 0``.
+
+    The router's rule is the call's: ``scoring`` is ``softmax`` (above) or
+    ``sigmoid``, each expert scored by itself, ``p = sigmoid(x @ router)``.
+    ``select_bias`` [E] moves the *choice* and nothing else: ``S =
+    top_k(p + select_bias)``, the gates still ``p_e / sum_S p``. It enters
+    under ``stop_gradient``, so its gradient is exactly zero and whoever
+    balances the experts with it does so outside the loss (the
+    auxiliary-loss-free rule of Wang et al. 2024; no such rule is built
+    here). ``gate_scale`` multiplies the renormalised gates.
 
     Shapes are static. The gathered buffer has ``T * top_k`` rows, every
     assignment there is: the worst any routing can ask of the experts
@@ -411,14 +429,26 @@ def moe_dropless(
             f"held={held!r} against {params['w_up'].shape[0]} expert "
             f"rows and a router over {E}"
         )
+    if scoring not in _SCORING:
+        raise ValueError(f"scoring={scoring!r}; have {sorted(_SCORING)}")
     worst = T * top_k
     bound = worst if buffer_rows is None else min(buffer_rows, worst)
     experts = {k: v for k, v in params.items() if k != "router"}
 
     with jax.named_scope("moolib.moe.route"):
         logits = x.astype(jnp.float32) @ params["router"].astype(jnp.float32)
-        top_p, top_i = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        scores = _SCORING[scoring](logits)
+        if select_bias is None:
+            top_p, top_i = jax.lax.top_k(scores, top_k)
+        else:
+            _, top_i = jax.lax.top_k(
+                scores + jax.lax.stop_gradient(select_bias.astype(jnp.float32)),
+                top_k,
+            )
+            top_p = jnp.take_along_axis(scores, top_i, axis=-1)
         gates = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)).reshape(-1)
+        if gate_scale != 1.0:
+            gates = gates * gate_scale
         # One key an assignment: its expert's row here, or `count` when the
         # expert lives elsewhere; a stable sort puts the held ones first,
         # in expert order, each expert's tokens in token order.

@@ -25,7 +25,7 @@ from moolib_tpu.ops.embed import embed_lookup
 from moolib_tpu.parallel import moe
 from moolib_tpu.parallel.moe import moe_dropless
 
-T, D, H, HKV, BLOCK = 8192, 128, 4, 1, 512  # mellum2_share8's share
+T, BLOCK = 8192, 512  # both decoder cells: 8,192 tokens, tiles of 512
 
 
 @pytest.fixture(scope="module")
@@ -60,9 +60,14 @@ def no_compile_cache():
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("window", [1024, None])
+# mellum2_share8's windowed and full layers (4 query heads on 1 key/value
+# head of 128), and glm47_flash_share8's decompressed latent attention (20
+# heads of 192 + 64 = 256, as many key and value heads)
+@pytest.mark.parametrize("window,H,HKV,D", [
+    (1024, 4, 1, 128), (None, 4, 1, 128), (None, 20, 20, 256),
+])
 def test_flash_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache,
-                                                  window):
+                                                  window, H, HKV, D):
     def shape(heads, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct((1, heads, T, D), dtype,
                                     sharding=one_chip)
@@ -85,13 +90,22 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache,
     assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 3
 
 
-@pytest.mark.parametrize("grouped", ["gmm", "ragged_dot"])
+# mellum2_share8's expert layer (softmax top-8) by both products, and
+# glm47_flash_share8's (sigmoid top-4 with a selection bias, scaled gates)
+@pytest.mark.parametrize("grouped,d,f,rows,top_k,router", [
+    ("gmm", 2304, 896, 20480, 8, {}),
+    ("ragged_dot", 2304, 896, 20480, 8, {}),
+    ("gmm", 2048, 1536, 10240, 4,
+     {"scoring": "sigmoid", "gate_scale": 1.8}),
+])
 def test_grouped_products_compile_at_the_cells_shape(one_chip,
                                                      no_compile_cache,
-                                                     grouped, monkeypatch):
+                                                     grouped, d, f, rows,
+                                                     top_k, router,
+                                                     monkeypatch):
     # jax.default_backend() is the CPU here: say what the chip would run
     monkeypatch.setattr(moe, "resolve_grouped", lambda *a: grouped)
-    d, f, E, held, rows = 2304, 896, 64, (0, 8), 20480
+    E, held = 64, (0, 8)
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -103,16 +117,20 @@ def test_grouped_products_compile_at_the_cells_shape(one_chip,
         "w_down": s((held[1], f, d), jnp.float32),
     }
 
-    def step(params, x):
+    bias = s((E,), jnp.float32)
+
+    def step(params, x, bias):
         def loss(params, x):
-            y, _ = moe_dropless(params, x, top_k=8, held=held,
-                                buffer_rows=rows)
+            y, _ = moe_dropless(
+                params, x, top_k=top_k, held=held, buffer_rows=rows,
+                select_bias=bias if router else None, **router,
+            )
             return y.astype(jnp.float32).sum()
 
         return jax.grad(loss, argnums=(0, 1))(params, x)
 
     compiled = jax.jit(step).lower(
-        params, s((T, d), jnp.bfloat16)
+        params, s((T, d), jnp.bfloat16), bias
     ).compile()
     text = compiled.as_text()
     kernels = text.count('custom_call_target="tpu_custom_call"')
