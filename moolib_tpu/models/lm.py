@@ -69,6 +69,7 @@ layers into the step's metrics.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import math
 from typing import Callable, Optional, Tuple
@@ -78,6 +79,7 @@ import jax.numpy as jnp
 import numpy as np
 from flax import linen as nn
 
+from ..ops import attention as attn_ops
 from ..parallel.moe import moe_dropless
 from .transformer import (attend, residual_block, segment_ids_from_done,
                           sown_dicts)
@@ -449,16 +451,30 @@ def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
             remat: bool, name: str):
     """One block, or ``repeat`` of them as one scan over parameters
     stacked on a leading axis; with ``remat`` each block is rebuilt in the
-    backward pass and only its input kept. ``(x, seg_bt, positions) ->
-    x``."""
-    cls = nn.remat(_Block, prevent_cse=False) if remat else _Block
+    backward pass from its input and, where its attention ran the flash
+    kernels, its attention core's output and row statistics (what the
+    forward kernel alone can make, so the rebuild runs no such kernel):
+    nothing else is kept. ``(x, seg_bt, positions) -> x``."""
+    cls, traced = _Block, contextlib.nullcontext
+    if remat:
+        cls = nn.remat(_Block, prevent_cse=False, policy=attn_ops.KEEP_CORES)
+        traced = attn_ops.keeping_cores
     if repeat == 1:
-        return cls(kind, mlp, sizes, name=name)
-    scan = nn.scan(
-        cls, variable_axes={"params": 0, "intermediates": 0},
-        split_rngs={"params": True}, in_axes=nn.broadcast, length=repeat,
-    )(kind, mlp, sizes, True, name=name)
-    return lambda x, seg_bt, positions: scan(x, seg_bt, positions)[0]
+        block = cls(kind, mlp, sizes, name=name)
+    else:
+        scan = nn.scan(
+            cls, variable_axes={"params": 0, "intermediates": 0},
+            split_rngs={"params": True}, in_axes=nn.broadcast, length=repeat,
+        )(kind, mlp, sizes, True, name=name)
+
+        def block(x, seg_bt, positions):
+            return scan(x, seg_bt, positions)[0]
+
+    def run(x, seg_bt, positions):
+        with traced():  # the body is traced inside this call
+            return block(x, seg_bt, positions)
+
+    return run
 
 
 class _Mtp(nn.Module):
@@ -554,8 +570,9 @@ class DecoderLM(nn.Module):
     # pass, so no second logits array is ever held.
     mtp: Optional[Tuple[str, str]] = None
     mtp_loss_rows: int = 1024
-    # Every block rebuilt in the backward pass from its input, the one
-    # array a block keeps.
+    # Every block rebuilt in the backward pass from what it keeps: its
+    # input and, where the flash kernels ran it, its attention core's
+    # output and row statistics.
     remat_blocks: bool = False
 
     def _sizes(self) -> _Sizes:

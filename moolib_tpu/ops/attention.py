@@ -34,12 +34,15 @@ tiles that lie wholly above the diagonal or wholly in another segment.
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -51,6 +54,8 @@ __all__ = [
     "flash_attention",
     "resolve_backend",
     "attention",
+    "KEEP_CORES",
+    "keeping_cores",
 ]
 
 _NEG_INF = -1e30
@@ -788,11 +793,26 @@ def _flash_attention(q, k, v, seg_q, seg_k, causal, window, block_q,
     return out
 
 
+# The two residuals of the backward pass that the forward kernel alone can
+# make, by name, and the ``jax.checkpoint`` policy that keeps them and
+# nothing else: what it wraps rebuilds ``q``, ``k``, ``v`` and everything
+# after the core, and not the core, because the rebuilt forward kernel's
+# outputs are then unused and it is dropped. Anywhere else a name is the
+# identity. The dense and blockwise backends have no such residuals and no
+# names.
+_CORE_OUT, _CORE_LSE = "moolib.attn_core.out", "moolib.attn_core.lse"
+KEEP_CORES = jax.checkpoint_policies.save_only_these_names(
+    _CORE_OUT, _CORE_LSE
+)
+
+
 def _flash_fwd(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
                interpret):
     out, lse = _flash_forward(
         q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
     )
+    out = checkpoint_name(out, _CORE_OUT)
+    lse = checkpoint_name(lse, _CORE_LSE)
     return out, (q, k, v, seg_q, seg_k, out, lse)
 
 
@@ -877,20 +897,42 @@ def resolve_backend(Tq: int, Tk: int, block_q: int = 256,
     return "blockwise"
 
 
+_rebuilt = threading.local()  # .keeping: inside keeping_cores(), by thread
+
+
+@contextlib.contextmanager
+def keeping_cores():
+    """Entered, while it is traced, by code that a ``jax.checkpoint`` with
+    the policy :data:`KEEP_CORES` rebuilds in the backward pass: an
+    :func:`attention` call traced inside that runs the flash kernels keeps
+    its core's output and row statistics, and counts under
+    ``attention_cores_kept_total``."""
+    before = getattr(_rebuilt, "keeping", False)
+    _rebuilt.keeping = True
+    try:
+        yield
+    finally:
+        _rebuilt.keeping = before
+
+
 def attention(q, k, v, backend: str = "auto", **kw):
     """Dispatcher: 'dense' | 'blockwise' | 'flash' | 'auto'
     (:func:`resolve_backend`). The backend a call runs is on record: the
     process-global counter ``attention_calls_traced_total{backend=}`` counts
     calls where they are traced (once a compile under jit), so whoever
-    holds a program to a backend reads what its own trace picked."""
+    holds a program to a backend reads what its own trace picked; beside it
+    ``attention_cores_kept_total`` counts the flash calls traced inside
+    :func:`keeping_cores`, whose forward kernel the backward pass does not
+    run again."""
     if backend == "auto":
         backend = resolve_backend(
             q.shape[-2], k.shape[-2], kw.get("block_q", 256),
             kw.get("block_k", 256),
         )
-    global_telemetry().registry.counter(
-        "attention_calls_traced_total", backend=backend
-    ).inc()
+    registry = global_telemetry().registry
+    registry.counter("attention_calls_traced_total", backend=backend).inc()
+    if backend == "flash" and getattr(_rebuilt, "keeping", False):
+        registry.counter("attention_cores_kept_total").inc()
     if backend != "flash":
         kw.pop("block_q", None)  # flash-only knob
         if backend == "dense":

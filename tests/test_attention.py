@@ -14,10 +14,12 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from moolib_tpu.ops.attention import (
+    KEEP_CORES,
     attention,
     blockwise_attention,
     dense_attention,
     flash_attention,
+    keeping_cores,
 )
 from moolib_tpu.ops.ring_attention import (
     ring_attention,
@@ -486,3 +488,79 @@ def test_flash_backward_kernel_with_segments(rng, causal):
         )
     # The fully-masked rows' q gradients are exactly zero.
     np.testing.assert_array_equal(np.asarray(g_fl[0])[0, :, :4, :], 0.0)
+
+
+# -- what a rebuilt caller keeps of the flash kernels -----------------------
+
+
+def _pallas_calls(fn, *args):
+    """``pallas_call`` equations left in ``fn``'s jaxpr once what nothing
+    reads is gone (the compiler drops the same): nested jaxprs are printed
+    inside their equation, so the text holds every one."""
+    from jax.interpreters import partial_eval as pe
+
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    jaxpr, _ = pe.dce_jaxpr(jaxpr, [True] * len(jaxpr.outvars))
+    return str(jaxpr).count("pallas_call")
+
+
+@pytest.mark.parametrize("backend,kernels", [
+    # forward, the rebuilt forward, dQ, dK/dV; the policy drops the second
+    ("flash", {"plain": 3, "rebuilt": 4, "kept": 3}),
+    # no kernel and no named residual: the policy keeps nothing
+    ("dense", {"plain": 0, "rebuilt": 0, "kept": 0}),
+])
+def test_a_rebuilt_caller_keeps_the_core_and_not_the_forward_kernel(
+        rng, backend, kernels):
+    """The gradient of a ``jax.checkpoint``-ed function around the flash
+    call, with and without the policy that saves the core's named output
+    and row statistics: one forward kernel fewer, the same bits."""
+    from moolib_tpu.telemetry import global_telemetry
+
+    q, k, v = _qkv(rng, T=32)
+    seg = _segs(rng, T=32)
+    w = jnp.asarray(rng.standard_normal((16, 16)) / 4, jnp.float32)
+    kw = dict(block_q=16, block_k=16, interpret=True)
+
+    def f(w, q, k, v):
+        o = attention(
+            q @ w, k @ w, v, backend=backend, causal=True, segment_ids=seg,
+            **(kw if backend == "flash" else {})
+        )
+        return jnp.sum(jnp.tanh(o @ w) ** 2)
+
+    forms = {
+        "plain": f,
+        "rebuilt": jax.checkpoint(f),
+        "kept": jax.checkpoint(f, policy=KEEP_CORES),
+    }
+    results = {}
+    for name, fn in forms.items():
+        grad = jax.value_and_grad(fn, argnums=(0, 1, 2, 3))
+        assert _pallas_calls(grad, w, q, k, v) == kernels[name], name
+        results[name] = grad(w, q, k, v)  # op by op: no fusion differs
+    for name in ("rebuilt", "kept"):
+        for a, b in zip(jax.tree_util.tree_leaves(results["plain"]),
+                        jax.tree_util.tree_leaves(results[name])):
+            np.testing.assert_array_equal(a, b, err_msg=name)
+    # the counter of calls whose core a rebuilt caller keeps: flash calls
+    # traced inside keeping_cores(), and no others
+    def kept():
+        return global_telemetry().registry.value(
+            "attention_cores_kept_total") or 0
+
+    before = kept()
+    f(w, q, k, v)
+    assert kept() == before
+    with keeping_cores():
+        f(w, q, k, v)
+    assert kept() - before == (backend == "flash")
+    if backend == "dense":
+        # and the two rebuilt programs are one program
+        text = [
+            [line for line in str(
+                jax.make_jaxpr(jax.grad(forms[n]))(w, q, k, v)
+            ).splitlines() if "policy=" not in line]
+            for n in ("rebuilt", "kept")
+        ]
+        assert text[0] == text[1]

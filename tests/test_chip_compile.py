@@ -216,3 +216,68 @@ def test_glyph_embedding_forward_at_the_cells_size(topo, no_compile_cache,
     )
     assert "convolution(" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+@pytest.mark.parametrize("repeat,policy,kernels", [
+    (2, True, 3),   # forward; dQ and dK/dV in the backward scan
+    (2, False, 4),  # the rebuild as it was: the forward kernel again
+    # a block outside a scan never ran it twice: ``prevent_cse=False``
+    # lets XLA merge the rebuilt kernel with the first
+    (1, False, 3),
+])
+def test_a_rebuilt_latent_block_runs_the_forward_core_once(
+        one_chip, no_compile_cache, monkeypatch, repeat, policy, kernels):
+    """glm47_learner_8k's latent attention (20 heads of 256 over 8,192
+    tokens in tiles of 512) in blocks rebuilt in the backward pass as
+    ``remat_blocks`` rebuilds them, here with the dense MLP of 10,240: the
+    gradient of a scanned stack holds the forward kernel once, because
+    the rebuild reads the kept output and row statistics; without the
+    policy it holds it a second time."""
+    import json
+
+    from moolib_tpu.models import lm
+
+    if not policy:
+        remat = nn.remat
+        monkeypatch.setattr(nn, "remat", lambda cls, prevent_cse, policy:
+                            remat(cls, prevent_cse=prevent_cse))
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "glm47_flash_share8.json")) as f:
+        kwargs = json.load(f)["model"]["kwargs"]
+    # jax.default_backend() is the CPU here: say what the chip would run
+    net = lm.decoder_lm(**dict(
+        kwargs, attention_backend="flash", compute_dtype=jnp.bfloat16))
+    assert (net.num_heads, net.head_dim, net.attention_block,
+            net.remat_blocks) == (20, 256, BLOCK, True)
+
+    class Stack(nn.Module):
+        @nn.compact
+        def __call__(self, x, seg, positions):
+            return lm._blocks(
+                dict(net.attention_kinds)["latent"], "dense", net._sizes(),
+                repeat, net.remat_blocks, "blocks",
+            )(x, seg, positions)
+
+    stack = Stack()
+    x = jax.ShapeDtypeStruct((T, 1, net.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip)
+    positions = jnp.arange(T)
+    params = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=one_chip),
+        jax.eval_shape(stack.init, jax.random.PRNGKey(0), x, seg, positions),
+    )
+
+    def step(params, x, seg):
+        def loss(params, x):
+            return stack.apply(params, x, seg, positions).astype(
+                jnp.float32).sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1))(params, x)
+
+    text = jax.jit(step).lower(params, x, seg).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == kernels
+    assert all("moolib.lm.attn_core" in line for line in calls)

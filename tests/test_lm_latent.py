@@ -425,6 +425,66 @@ def test_a_stack_is_its_blocks_one_after_the_other(whole):
     close(baseline, f_baseline, 1e-5)
 
 
+def test_a_rebuilt_block_keeps_its_attention_core(monkeypatch):
+    """``remat_blocks`` with the flash kernels (in Pallas' interpreter):
+    every block program traced counts under ``attention_cores_kept_total``
+    and none does without the rebuild; loss and every gradient leaf are,
+    bit for bit, those of the same rebuild with nothing named kept (the
+    program before the policy), and those of the model that rebuilds
+    nothing to the last bits XLA's own regrouping leaves on the CPU."""
+    import functools
+
+    from flax import linen as nn
+
+    from moolib_tpu.ops import attention as attn_ops
+    from moolib_tpu.telemetry import global_telemetry
+
+    monkeypatch.setattr(attn_ops, "flash_attention", functools.partial(
+        attn_ops.flash_attention, interpret=True))
+    registry = global_telemetry().registry
+
+    def counts():
+        return (
+            registry.value("attention_cores_kept_total") or 0,
+            registry.value("attention_calls_traced_total", backend="flash")
+            or 0,
+        )
+
+    def loss_and_grads(remat):
+        net, model = tiny(remat_blocks=remat, attention_backend="flash",
+                          attention_block=16)
+        params, batch = inputs(net, model, 7)
+        kept, calls = counts()
+        (loss, _), grads = jax.value_and_grad(impala_loss, has_aux=True)(
+            params, learn_apply(net), batch, ImpalaConfig(**LOSS)
+        )
+        after = counts()
+        return loss, grads, after[0] - kept, after[1] - calls
+
+    loss, grads, kept, calls = loss_and_grads(True)
+    # the dense block, the stack's one block (flax traces a scan's body
+    # twice) and the module's: every flash call of the trace, and no fewer
+    # than the model has block programs
+    assert kept == calls >= 3
+    p_loss, p_grads, p_kept, p_calls = loss_and_grads(False)
+    assert p_kept == 0 and p_calls == calls
+
+    remat = nn.remat
+    monkeypatch.setattr(  # the same rebuild, no policy: nothing kept
+        nn, "remat", lambda cls, prevent_cse, policy: remat(
+            cls, prevent_cse=prevent_cse))
+    r_loss, r_grads, _, _ = loss_and_grads(True)
+    assert float(loss) == float(r_loss) == float(p_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    assert len(flat) == 55
+    for (path, g), r, p in zip(flat, jax.tree_util.tree_leaves(r_grads),
+                               jax.tree_util.tree_leaves(p_grads)):
+        name = jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(g, r, err_msg=name)
+        scale = max(float(jnp.max(jnp.abs(p))), 1e-12)
+        assert float(jnp.max(jnp.abs(g - p))) <= 2e-6 * scale, name
+
+
 def test_the_other_decoder_configuration_did_not_move():
     """``mellum2_share8``: the parameter tree at the benchmark's size, and
     at the rehearsal's size the tree, the step's program (its jaxpr, so
