@@ -19,8 +19,16 @@ each naming its attention kind and its MLP kind, and ``attention_kinds``
 says what a kind is (its window, its rotary parameters, and for latent
 attention its ranks and the split of its head). A model with three
 windowed layers to one full layer is a list, not a constructor flag.
-Blocks are pre-norm (RMS) on the residual skeleton and the one attention
-call site of :mod:`moolib_tpu.models.transformer`.
+Blocks are pre-norm (RMS) on a residual skeleton and the one attention
+call site of :mod:`moolib_tpu.models.transformer`. ``residual`` says which
+skeleton: absent, one stream and ``x + F(norm(x))``; ``{"streams": n,
+...}`` (:class:`Residual`), ``n`` streams that every sublayer reads through a
+learned mixture, writes through a learned gate and remixes by a matrix that
+Sinkhorn iterations make doubly stochastic
+(:func:`~moolib_tpu.models.transformer.hyper_residual_block`). The
+embedding is then replicated into the streams, the blocks, their scan and
+their rebuild carry ``[n, T, B, d]``, and the streams are summed before
+the final norm.
 
 Attention kinds. Without ``latent``: rotary positions (plain, or
 YaRN-scaled) over the whole head, grouped key/value heads, three dense
@@ -31,9 +39,13 @@ projections. With ``latent`` (multi-head latent attention, DeepSeek-V2):
     [k_nope | v] a head = c_kv W_kvb;  k = [k_nope | rotary(k_r)]
 
 one rotary key a position shared by every head, rotary over the ``rope``
-part only, scores scaled by ``(nope + rope)^-1/2``. This is the
-decompressed form a training forward computes; the absorbed form with one
-key head belongs to a cache, which the repo has none of.
+part only (plain or YaRN-scaled, as the kind's ``rope`` says), scores
+scaled by ``(nope + rope)^-1/2`` or by the kind's ``softmax_scale`` (YaRN's
+``mscale^2`` goes there). The value head ``v`` may be narrower than the
+query/key head ``nope + rope``: every attention backend takes the two
+sizes apart. This is the decompressed form a training forward computes;
+the absorbed form with one key head belongs to a cache, which the repo has
+none of.
 
 MLP kinds. ``sparse``: gated experts through
 :func:`moolib_tpu.parallel.moe.moe_dropless`, scored and chosen as
@@ -62,15 +74,18 @@ absent chips.
 
 Counters of the expert layers (assignments held and total, tokens no held
 expert served, the fullest expert's load, layers that ran over the
-worst-case buffer) are sown into ``intermediates``; :func:`learn_apply`
-gives the learner the three-element ``apply_fn`` that sums them over
-layers into the step's metrics.
+worst-case buffer) and of the stream mixing (how far the remix matrices'
+rows and columns are from summing to 1, entries at the clip) are sown
+into ``intermediates``; :func:`learn_apply` gives the learner the
+three-element ``apply_fn`` that reduces them over layers into the step's
+metrics.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 from typing import Callable, Optional, Tuple
 
@@ -81,13 +96,14 @@ from flax import linen as nn
 
 from ..ops import attention as attn_ops
 from ..parallel.moe import moe_dropless
-from .transformer import (attend, residual_block, segment_ids_from_done,
-                          sown_dicts)
+from .transformer import (attend, hyper_coefficients, hyper_residual_block,
+                          residual_block, segment_ids_from_done, sown_dicts)
 
 __all__ = [
     "AttentionKind",
     "DecoderLM",
     "Latent",
+    "Residual",
     "Rope",
     "Router",
     "decoder_lm",
@@ -115,13 +131,16 @@ class Rope:
 @dataclasses.dataclass(frozen=True)
 class Latent:
     """The sizes of latent attention (the keys of a DeepSeek-style
-    config): both low ranks and the split of a head."""
+    config): both low ranks and the split of a head. ``softmax_scale``:
+    what the scores are multiplied by, where it is not ``(nope +
+    rope)^-1/2`` (under YaRN, that times ``mscale^2``)."""
 
     q_lora_rank: int
     kv_lora_rank: int
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
+    softmax_scale: Optional[float] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,6 +148,19 @@ class AttentionKind:
     window: Optional[int]  # None: full causal attention
     rope: Rope
     latent: Optional[Latent] = None  # None: three dense projections
+
+
+@dataclasses.dataclass(frozen=True)
+class Residual:
+    """A residual skeleton with several streams (the ``hc_*`` keys of a
+    config), see :func:`~moolib_tpu.models.transformer.hyper_coefficients`:
+    how many streams, the Sinkhorn iterations and their ``eps``, and the
+    clip of the remix matrix's logits."""
+
+    streams: int
+    sinkhorn_iters: int
+    eps: float
+    res_clamp: Tuple[float, float]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -250,8 +282,8 @@ class _Attention(nn.Module):
 
 class _LatentAttention(nn.Module):
     """Latent attention in its decompressed form; see the module
-    docstring. The flash kernels have one head size, so the query/key head
-    (``nope + rope``) and the value head have to agree."""
+    docstring. The core runs at a query/key head of ``nope + rope`` and a
+    value head of ``v_head_dim``, whatever the two are."""
 
     kind: AttentionKind
     num_heads: int
@@ -267,12 +299,6 @@ class _LatentAttention(nn.Module):
         nope, rot, dv = (
             lat.qk_nope_head_dim, lat.qk_rope_head_dim, lat.v_head_dim
         )
-        if nope + rot != dv:
-            raise ValueError(
-                f"latent attention with a query/key head of {nope + rot} "
-                f"and a value head of {dv}: the kernels have one head size"
-            )
-
         def dense(name, width):
             return _dense(name, width, self.dtype)
 
@@ -306,8 +332,8 @@ class _LatentAttention(nn.Module):
         with jax.named_scope("moolib.lm.attn_core"):
             o = attend(
                 q, k, v, seg_bt, backend=self.backend,
-                window=self.kind.window, block_q=self.block,
-                block_k=self.block,
+                window=self.kind.window, scale=lat.softmax_scale,
+                block_q=self.block, block_k=self.block,
             )
         with jax.named_scope("moolib.lm.mla_proj"):
             o = o.transpose(2, 0, 1, 3).reshape(T, B, H * dv)
@@ -395,6 +421,36 @@ class _Sizes:
     router: Router
     shared_expert_size: Optional[int]
     intermediate_size: Optional[int]
+    residual: Optional[Residual] = None
+
+
+class _HyperMix(nn.Module):
+    """One sublayer's mixing parameters on the skeleton with several
+    streams: ``phi`` [n d, n^2 + 2n], ``b`` [n^2 + 2n] and ``alpha``
+    (three scales), float32. ``streams [n, N, d] -> (pre, post, res)``;
+    the mixing's counters are sown."""
+
+    spec: Residual
+    norm_eps: float
+
+    @nn.compact
+    def __call__(self, streams):
+        n, _, d = streams.shape
+        k = n * n + 2 * n
+        phi = self.param(
+            "phi", nn.initializers.normal((n * d) ** -0.5), (n * d, k)
+        )
+        b = self.param("b", nn.initializers.zeros, (k,))
+        alpha = self.param("alpha", nn.initializers.ones, (3,))
+        # rebuilt in the backward pass from the streams as they are stored:
+        # kept, the float32 copy of them would be the block's largest array
+        pre, post, res, counters = jax.checkpoint(functools.partial(
+            hyper_coefficients, norm_eps=self.norm_eps,
+            sinkhorn_iters=self.spec.sinkhorn_iters, eps=self.spec.eps,
+            res_clamp=self.spec.res_clamp,
+        ))(streams, phi, b, alpha)
+        self.sow("intermediates", "hc_counters", counters)
+        return pre, post, res
 
 
 class _Block(nn.Module):
@@ -440,10 +496,18 @@ class _Block(nn.Module):
             raise ValueError(
                 f"unknown mlp kind {self.mlp!r}; have 'sparse', 'dense'"
             )
-        out = residual_block(
-            x, norm("norm1"), lambda h: attention(h, seg_bt, positions),
-            norm("norm2"), mlp,
-        )
+        def mixer(h):
+            return attention(h, seg_bt, positions)
+
+        if net.residual is None:
+            out = residual_block(x, norm("norm1"), mixer, norm("norm2"), mlp)
+        else:
+            out = hyper_residual_block(
+                x, _HyperMix(net.residual, net.rms_norm_eps, name="hc_attn"),
+                norm("norm1"), mixer,
+                _HyperMix(net.residual, net.rms_norm_eps, name="hc_mlp"),
+                norm("norm2"), mlp,
+            )
         return (out, None) if self.scanned else out
 
 
@@ -454,7 +518,9 @@ def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
     backward pass from its input and, where its attention ran the flash
     kernels, its attention core's output and row statistics (what the
     forward kernel alone can make, so the rebuild runs no such kernel):
-    nothing else is kept. ``(x, seg_bt, positions) -> x``."""
+    nothing else is kept. ``(x, seg_bt, positions) -> x``, with ``x`` the
+    skeleton's carry: one stream ``[T, B, d]`` or several ``[n, T, B,
+    d]``."""
     cls, traced = _Block, contextlib.nullcontext
     if remat:
         cls = nn.remat(_Block, prevent_cse=False, policy=attn_ops.KEEP_CORES)
@@ -574,6 +640,8 @@ class DecoderLM(nn.Module):
     # input and, where the flash kernels ran it, its attention core's
     # output and row statistics.
     remat_blocks: bool = False
+    # The residual skeleton: None is one stream and ``x + F(norm(x))``.
+    residual: Optional[Residual] = None
 
     def _sizes(self) -> _Sizes:
         return _Sizes(
@@ -582,7 +650,7 @@ class DecoderLM(nn.Module):
             self.top_k, self.moe_intermediate_size, self.moe_buffer_rows,
             self.rms_norm_eps, jnp.dtype(self.compute_dtype),
             self.attention_backend, self.attention_block, self.router,
-            self.shared_expert_size, self.intermediate_size,
+            self.shared_expert_size, self.intermediate_size, self.residual,
         )
 
     @nn.compact
@@ -594,6 +662,14 @@ class DecoderLM(nn.Module):
             name="embed",
         )
         x = embedded = embed(obs)
+        if self.residual is not None:
+            if self.mtp is not None:
+                raise ValueError(
+                    "a multi-token-prediction module beside a residual "
+                    "skeleton of several streams is not built"
+                )
+            # every stream starts as the token's embedding
+            x = jnp.broadcast_to(x, (self.residual.streams,) + x.shape)
         seg_bt = segment_ids_from_done(done)
         positions = jnp.arange(T)
         kinds, sizes = dict(self.attention_kinds), self._sizes()
@@ -602,6 +678,11 @@ class DecoderLM(nn.Module):
                 kinds[attention], mlp, sizes, *(repeat or [1]),
                 self.remat_blocks, f"block_{i}",
             )(x, seg_bt, positions)
+        if self.residual is not None:
+            # and the streams' sum is what the final norm reads
+            x = jnp.sum(x.astype(jnp.float32), axis=0).astype(
+                self.compute_dtype
+            )
         head = _dense("head", self.vocab_size, self.compute_dtype)
         hidden = x
         with jax.named_scope("moolib.lm.head"):
@@ -631,7 +712,7 @@ class DecoderLM(nn.Module):
 
 
 def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
-               mtp=None, **kwargs) -> DecoderLM:
+               mtp=None, residual=None, **kwargs) -> DecoderLM:
     """A :class:`DecoderLM` from JSON-shaped arguments: ``layers`` a list
     of ``{"attention": kind, "mlp": "sparse" | "dense"}``, an entry with
     ``"repeat": n`` standing for ``n`` identical blocks run as a scan;
@@ -640,7 +721,8 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
     fields of :class:`Rope` and whose ``latent`` those of
     :class:`Latent`; ``router`` the fields of :class:`Router`; ``mtp`` the
     multi-token-prediction module's block, an entry like one of
-    ``layers``."""
+    ``layers``; ``residual`` the fields of :class:`Residual` (absent: the
+    skeleton with one stream)."""
     kinds = tuple(
         (name, AttentionKind(
             spec.get("window"), Rope(**spec["rope"]),
@@ -659,6 +741,9 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
         experts_held=None if experts_held is None else tuple(experts_held),
         router=Router(**(router or {})),
         mtp=None if mtp is None else (mtp["attention"], mtp["mlp"]),
+        residual=None if residual is None else Residual(**dict(
+            residual, res_clamp=tuple(residual["res_clamp"]),
+        )),
         **kwargs,
     )
 
@@ -680,6 +765,13 @@ def _sum_counters(intermediates) -> dict:
             total[name] = total[name] / layers
     for sown in sown_dicts(intermediates, "mtp_loss"):
         total.update(sown)
+    # the stream mixing's, a dict a sublayer: the worst gap, every entry
+    for sown in sown_dicts(intermediates, "hc_res_clamped"):
+        for name, value in sown.items():
+            if name == "hc_res_clamped":
+                total[name] = total.get(name, 0.0) + jnp.sum(value)
+            else:
+                total[name] = jnp.maximum(total.get(name, 0.0), jnp.max(value))
     return total
 
 

@@ -40,7 +40,14 @@ from ..ops import attention as attn_ops
 from ..ops import ring_attention as ring_ops
 from ..parallel.moe import moe_ffn
 
-__all__ = ["TransformerNet", "attend", "moe_aux_losses", "residual_block"]
+__all__ = [
+    "TransformerNet",
+    "attend",
+    "hyper_coefficients",
+    "hyper_residual_block",
+    "moe_aux_losses",
+    "residual_block",
+]
 
 
 def segment_ids_from_done(done) -> jax.Array:
@@ -51,18 +58,21 @@ def segment_ids_from_done(done) -> jax.Array:
 
 
 def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
-           window: Optional[int] = None, **blocks):
+           window: Optional[int] = None, scale: Optional[float] = None,
+           **blocks):
     """The one attention call site of the models: causal, cut at segment
-    boundaries, ``[B, H, T, D]`` in and out (``k``/``v`` may carry fewer
-    heads). ``ring`` / ``zigzag`` run across the ``ring_axis`` mesh axis
-    inside shard_map; everything else is :func:`attn_ops.attention` and
-    what its ``backend`` resolves to. ``blocks``: ``block_q``/``block_k``
-    where the caller sets them."""
+    boundaries, ``[B, H, T, D]`` in and ``[B, H, T, Dv]`` out (``k``/``v``
+    may carry fewer heads, ``v`` another head size). ``ring`` / ``zigzag``
+    run across the ``ring_axis`` mesh axis inside shard_map; everything
+    else is :func:`attn_ops.attention` and what its ``backend`` resolves
+    to. ``scale``: of the scores, where it is not ``D ** -0.5``.
+    ``blocks``: ``block_q``/``block_k`` where the caller sets them."""
     if backend in ("ring", "zigzag"):
-        if window is not None or k.shape[1] != q.shape[1]:
+        if (window is not None or scale is not None
+                or k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1]):
             raise ValueError(
-                f"the {backend} backend has neither a window nor grouped "
-                "heads"
+                f"the {backend} backend has no window, no grouped heads, "
+                "one head size and one score scale"
             )
         if backend == "ring":
             return ring_ops.ring_attention(
@@ -76,6 +86,8 @@ def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
             q, k, v, axis_name=ring_axis, segment_ids=seg_bt,
             kv_segment_ids=seg_bt,
         )
+    if scale is not None:
+        blocks["scale"] = scale
     return attn_ops.attention(
         q, k, v, backend=backend, causal=True, segment_ids=seg_bt,
         window=window, **blocks,
@@ -83,10 +95,102 @@ def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
 
 
 def residual_block(x, norm1, mixer, norm2, mlp):
-    """The pre-norm residual skeleton every block shares:
+    """The pre-norm residual skeleton of a block with one stream:
     ``h = x + mixer(norm1(x)); out = h + mlp(norm2(h))``."""
     x = x + mixer(norm1(x))
     return x + mlp(norm2(x))
+
+
+def hyper_coefficients(streams, phi, b, alpha, *, norm_eps: float,
+                       sinkhorn_iters: int, eps: float, res_clamp):
+    """The mixing coefficients of one sublayer on the residual skeleton
+    with several streams (hyper-connections, arXiv:2409.19606, the residual
+    matrix made doubly stochastic by Sinkhorn-Knopp, arXiv:2512.24880).
+    All of it a function of the token's own streams, in float32:
+
+        xt    = vec(X) / sqrt(mean(vec(X)^2) + norm_eps)
+        m     = xt phi                          phi [n C, n^2 + 2n]
+        pre   = sigmoid(a_pre m[:n] + b[:n])
+        post  = 2 sigmoid(a_post m[n:2n] + b[n:2n])
+        M     = exp(clip(a_res mat(m[2n:]) + mat(b[2n:]), *res_clamp))
+        sinkhorn_iters times: M /= rowsum(M) + eps; M /= colsum(M) + eps
+
+    ``streams`` [n, N, C] (N tokens); ``alpha = (a_pre, a_post, a_res)``.
+    Returns ``pre`` [n, N], ``post`` [n, N], ``res`` [n, n, N] (``res[i,
+    j]`` weighs stream j in new stream i) with the tokens on the minor
+    axis, where a 4 x 4 matrix a token costs no padding, and the counters
+    of the mixing: the largest ``|sum - 1|`` over ``res``'s rows and over
+    its columns, and how many entries stood at the clip. The gradient goes
+    through every iteration and through the clip (zero outside it)."""
+    with jax.named_scope("moolib.lm.hc_mix"):
+        n, N, C = streams.shape
+        x32 = streams.astype(jnp.float32)
+        inv_rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=(0, 2)) + norm_eps)
+        # vec(X) is stream-major, so phi's rows are n blocks of C: the
+        # product is taken before the division (one number a token), in
+        # float32 proper.
+        m = jnp.einsum(
+            "inc,ick->kn", x32, phi.astype(jnp.float32).reshape(n, C, -1),
+            precision=jax.lax.Precision.HIGHEST,
+        ) * inv_rms
+        a = alpha.astype(jnp.float32)
+        bias = b.astype(jnp.float32)[:, None]  # beside m [n^2 + 2n, N]
+        pre = jax.nn.sigmoid(a[0] * m[:n] + bias[:n])
+        post = 2.0 * jax.nn.sigmoid(a[1] * m[n:2 * n] + bias[n:2 * n])
+        raw = (a[2] * m[2 * n:] + bias[2 * n:]).reshape(n, n, N)
+        lo, hi = res_clamp
+        res = jnp.exp(jnp.clip(raw, lo, hi))
+        for _ in range(sinkhorn_iters):
+            res = res / (jnp.sum(res, axis=1, keepdims=True) + eps)
+            res = res / (jnp.sum(res, axis=0, keepdims=True) + eps)
+        made, logits = jax.lax.stop_gradient((res, raw))
+        counters = {
+            "hc_row_sum_gap": jnp.max(jnp.abs(made.sum(axis=1) - 1.0)),
+            "hc_col_sum_gap": jnp.max(jnp.abs(made.sum(axis=0) - 1.0)),
+            "hc_res_clamped": jnp.sum(
+                jnp.logical_or(logits <= lo, logits >= hi)
+            ).astype(jnp.float32),
+        }
+        return pre, post, res, counters
+
+
+def hyper_residual_block(streams, mix1, norm1, mixer, mix2, norm2, mlp):
+    """The residual skeleton of a block with ``n`` streams, ``streams``
+    [n, T, B, C]: each of the two sublayers reads a learned mixture of the
+    streams and writes back through a learned gate, and the streams are
+    remixed by a doubly stochastic matrix,
+
+        h = sum_i pre[i] X[i];  y = F(norm(h))
+        X'[i] = sum_j res[i, j] X[j] + post[i] y
+
+    ``mix1`` / ``mix2``: ``streams [n, N, C] -> (pre, post, res)``, the
+    sublayer's own coefficients (:func:`hyper_coefficients`). The weighted
+    sums are taken in float32 and the streams stored in their own dtype."""
+    n, T, B, C = streams.shape
+
+    @jax.checkpoint
+    def read(flat, pre):
+        with jax.named_scope("moolib.lm.hc_pre"):
+            x32 = flat.astype(jnp.float32)
+            return sum(pre[i][:, None] * x32[i] for i in range(n))
+
+    @jax.checkpoint
+    def write(flat, res, post, y):
+        with jax.named_scope("moolib.lm.hc_post"):
+            x32 = flat.astype(jnp.float32)
+            y32 = y.astype(jnp.float32)
+            return jnp.stack([
+                sum(res[i, j][:, None] * x32[j] for j in range(n))
+                + post[i][:, None] * y32
+                for i in range(n)
+            ]).astype(flat.dtype)
+
+    flat = streams.reshape(n, T * B, C)
+    for mix, norm, f in ((mix1, norm1, mixer), (mix2, norm2, mlp)):
+        pre, post, res = mix(flat)
+        y = f(norm(read(flat, pre).reshape(T, B, C)))
+        flat = write(flat, res, post, y.reshape(T * B, C))
+    return flat.reshape(n, T, B, C)
 
 
 class _SelfAttention(nn.Module):

@@ -30,6 +30,11 @@ carry fewer heads than ``q`` (``H % Hkv == 0``; query head h reads key/value
 head ``h // (H // Hkv)``), and no backend materialises the repeats. The
 flash kernels visit only the key blocks a window can reach, and skip the
 tiles that lie wholly above the diagonal or wholly in another segment.
+
+The value head may be narrower or wider than the query/key head: ``q`` and
+``k`` are ``[.., T, D]``, ``v`` and the output ``[.., T, Dv]`` (latent
+attention with a rotary part on its queries and keys only). ``scale``
+multiplies the scores; None is ``D ** -0.5``.
 """
 
 from __future__ import annotations
@@ -61,8 +66,10 @@ __all__ = [
 _NEG_INF = -1e30
 
 
-def _scale(q):
-    return q / np.sqrt(q.shape[-1])
+def _scale(q, scale=None):
+    if scale is None:
+        return q / np.sqrt(q.shape[-1])
+    return q * scale
 
 
 def _check_window(window, causal: bool):
@@ -115,12 +122,14 @@ def dense_attention(
     segment_ids: Optional[jax.Array] = None,
     kv_segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ):
-    """Oracle attention. q [B, H, Tq, D], k/v [B, Hkv, Tk, D],
-    segment_ids [B, Tq] / kv_segment_ids [B, Tk] (defaults to segment_ids)."""
+    """Oracle attention. q [B, H, Tq, D], k [B, Hkv, Tk, D], v
+    [B, Hkv, Tk, Dv], segment_ids [B, Tq] / kv_segment_ids [B, Tk]
+    (defaults to segment_ids)."""
     _check_window(window, causal)
-    shape = q.shape
-    q = _group_heads(_scale(q.astype(jnp.float32)), k)
+    shape = q.shape[:-1] + v.shape[-1:]
+    q = _group_heads(_scale(q.astype(jnp.float32), scale), k)
     k = k.astype(jnp.float32)
     scores = jnp.einsum("bhgqd,bhkd->bhgqk", q, k)
     seg_q = seg_k = None
@@ -182,6 +191,7 @@ def blockwise_attention(
     block_k: int = 512,
     kv_position_offset: int = 0,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ):
     """Memory-efficient attention: lax.scan over key blocks.
 
@@ -192,10 +202,11 @@ def blockwise_attention(
     orig_dtype = v.dtype
     # Grouped heads: the G query heads of one key/value head are G more
     # rows of queries against the same keys.
-    qf = _group_heads(_scale(q.astype(jnp.float32)), k)
+    qf = _group_heads(_scale(q.astype(jnp.float32), scale), k)
     kf = k.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     B, _, Tq, D = q.shape
+    Dv = v.shape[-1]
     H, G = qf.shape[1:3]
     Tk = k.shape[-2]
     block_k = min(block_k, Tk)
@@ -217,7 +228,7 @@ def blockwise_attention(
         )
 
     kb = kf.reshape(B, H, n_blocks, block_k, D).transpose(2, 0, 1, 3, 4)
-    vb = vf.reshape(B, H, n_blocks, block_k, D).transpose(2, 0, 1, 3, 4)
+    vb = vf.reshape(B, H, n_blocks, block_k, Dv).transpose(2, 0, 1, 3, 4)
     if segment_ids is not None:
         sb = kv_seg.reshape(B, n_blocks, block_k).transpose(1, 0, 2)
     else:
@@ -249,11 +260,11 @@ def blockwise_attention(
 
     m0 = jnp.full((B, H, G, Tq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, H, G, Tq), jnp.float32)
-    a0 = jnp.zeros((B, H, G, Tq, D), jnp.float32)
+    a0 = jnp.zeros((B, H, G, Tq, Dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0), (jnp.arange(n_blocks), kb, vb, sb)
     )
-    return _finalize(m, l, acc, orig_dtype).reshape(q.shape)
+    return _finalize(m, l, acc, orig_dtype).reshape(q.shape[:-1] + (Dv,))
 
 
 # ---------------------------------------------------------------------------
@@ -327,6 +338,7 @@ class _Tiles(NamedTuple):
     n_k: int
     H: int  # query heads
     Hkv: int  # key/value heads
+    scale: float  # of the scores
 
     @property
     def G(self) -> int:
@@ -409,7 +421,7 @@ def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
     log-sum-exp (lse) the backward kernels rebuild P from."""
     b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     ki = t.first_k(qi) + j
-    head_dim = q_ref.shape[-1]
+    dv = v_ref.shape[-1]  # the value head's size, and the output's
     block_k = t.block_k
 
     @pl.when(j == 0)
@@ -424,7 +436,7 @@ def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         ki < t.n_k, t.visible(qi, ki, q_rng, k_rng, b // t.H)
     ))
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * (1.0 / np.sqrt(head_dim))
+        q = q_ref[0].astype(jnp.float32) * t.scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
 
@@ -444,7 +456,7 @@ def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         )
         m_sc[...] = m_new
         l_sc[...] = l_sc[...] * scale_old + jnp.sum(p, axis=-1, keepdims=True)
-        acc_sc[...] = acc_sc[...] * _lanes(scale_old, head_dim) + jnp.dot(
+        acc_sc[...] = acc_sc[...] * _lanes(scale_old, dv) + jnp.dot(
             p, v, preferred_element_type=jnp.float32
         )
 
@@ -452,7 +464,7 @@ def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
     def _done():
         l = l_sc[...]
         safe_l = jnp.where(l > 0, l, 1.0)
-        o_ref[0] = (acc_sc[...] / _lanes(safe_l, head_dim)).astype(
+        o_ref[0] = (acc_sc[...] / _lanes(safe_l, dv)).astype(
             o_ref.dtype
         )
         # lse = m + log(l). Fully-masked rows get the finite sentinel
@@ -487,8 +499,8 @@ def _check_blocks(Tq, Tk, block_q, block_k):
     return block_q, block_k
 
 
-def _tiles(q, k, causal, window, block_q, block_k) -> _Tiles:
-    _, H, Tq, _ = q.shape
+def _tiles(q, k, causal, window, block_q, block_k, scale=None) -> _Tiles:
+    _, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     if H % Hkv:
         raise ValueError(
@@ -496,7 +508,8 @@ def _tiles(q, k, causal, window, block_q, block_k) -> _Tiles:
         )
     block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
     return _Tiles(causal, window, block_q, block_k, Tq // block_q,
-                  Tk // block_k, H, Hkv)
+                  Tk // block_k, H, Hkv,
+                  1.0 / np.sqrt(D) if scale is None else scale)
 
 
 def _block_ranges(seg, n_blocks: int):
@@ -519,19 +532,22 @@ _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
 def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
-                   interpret):
+                   interpret, scale):
     B, H, Tq, D = q.shape
-    t = _tiles(q, k, causal, window, block_q, block_k)
+    Dv = v.shape[-1]
+    t = _tiles(q, k, causal, window, block_q, block_k, scale)
     block_q, block_k = t.block_q, t.block_k
     Tk = k.shape[-2]
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * t.Hkv, Tk, D)
-    vr = v.reshape(B * t.Hkv, Tk, D)
+    vr = v.reshape(B * t.Hkv, Tk, Dv)
 
-    k_spec = pl.BlockSpec(
-        (1, block_k, D),
-        lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
-    )
+    def k_spec(width):
+        return pl.BlockSpec(
+            (1, block_k, width),
+            lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
+        )
+
     out, lse = pl.pallas_call(
         functools.partial(_flash_kernel, t=t),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -539,8 +555,8 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
             grid=(B * H, t.n_q, t.n_kw),
             in_specs=[
                 pl.BlockSpec((1, block_q, D), lambda b, qi, j, *_: (b, qi, 0)),
-                k_spec,
-                k_spec,
+                k_spec(D),
+                k_spec(Dv),
                 # Segment ids are per batch row, shared by its H heads.
                 pl.BlockSpec(
                     (1, block_q, _LANES),
@@ -552,7 +568,9 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
                 ),
             ],
             out_specs=[
-                pl.BlockSpec((1, block_q, D), lambda b, qi, j, *_: (b, qi, 0)),
+                pl.BlockSpec(
+                    (1, block_q, Dv), lambda b, qi, j, *_: (b, qi, 0)
+                ),
                 pl.BlockSpec(
                     (1, block_q, _LANES), lambda b, qi, j, *_: (b, qi, 0)
                 ),
@@ -560,11 +578,11 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
             scratch_shapes=[
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
                 pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, D), jnp.float32),
+                pltpu.VMEM((block_q, Dv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, Tq, D), v.dtype),
+            jax.ShapeDtypeStruct((B * H, Tq, Dv), v.dtype),
             jax.ShapeDtypeStruct((B * H, Tq, _LANES), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
@@ -574,7 +592,7 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
     )(_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k),
       qr, kr, vr, _col_form(seg_q), _row_form(seg_k))
     # The residual keeps one lane: O(T) memory, not O(128 T).
-    return out.reshape(B, H, Tq, D), lse[:, :, 0]
+    return out.reshape(B, H, Tq, Dv), lse[:, :, 0]
 
 
 def _flash_bwd_dq_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
@@ -594,7 +612,7 @@ def _flash_bwd_dq_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
         ki < t.n_k, t.visible(qi, ki, q_rng, k_rng, b // t.H)
     ))
     def _compute():
-        scale = 1.0 / np.sqrt(q_ref.shape[-1])
+        scale = t.scale
         q = q_ref[0].astype(jnp.float32) * scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
@@ -639,8 +657,7 @@ def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
         qi < t.n_q, t.visible(qi, kj, q_rng, k_rng, b // t.Hkv)
     ))
     def _compute():
-        scale = 1.0 / np.sqrt(q_ref.shape[-1])
-        q = q_ref[0].astype(jnp.float32) * scale
+        q = q_ref[0].astype(jnp.float32) * t.scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
@@ -669,15 +686,15 @@ def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
 
 
 def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
-                    block_q, block_k, interpret):
+                    block_q, block_k, interpret, scale):
     B, H, Tq, D = q.shape
-    Tk = k.shape[-2]
-    t = _tiles(q, k, causal, window, block_q, block_k)
+    Tk, Dv = k.shape[-2], v.shape[-1]
+    t = _tiles(q, k, causal, window, block_q, block_k, scale)
     block_q, block_k, Hkv = t.block_q, t.block_k, t.Hkv
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * Hkv, Tk, D)
-    vr = v.reshape(B * Hkv, Tk, D)
-    gr = g.reshape(B * H, Tq, D)
+    vr = v.reshape(B * Hkv, Tk, Dv)
+    gr = g.reshape(B * H, Tq, Dv)
     # delta_i = rowsum(dO * O): the softmax-jacobian correction term.
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
@@ -686,11 +703,19 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
     ranges = (_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k))
 
     # dQ: score tile [block_q, block_k]; per-query stats are columns.
-    q_spec = pl.BlockSpec((1, block_q, D), lambda b, qi, j, *_: (b, qi, 0))
-    k_spec = pl.BlockSpec(
-        (1, block_k, D),
-        lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
-    )
+    # Queries, keys and their gradients are D wide; values, the output's
+    # cotangent and the values' gradient Dv.
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, block_q, width), lambda b, qi, j, *_: (b, qi, 0)
+        )
+
+    def k_spec(width):
+        return pl.BlockSpec(
+            (1, block_k, width),
+            lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
+        )
+
     q_col = pl.BlockSpec(
         (1, block_q, _LANES), lambda b, qi, j, *_: (b, qi, 0)
     )
@@ -700,9 +725,9 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
             num_scalar_prefetch=2,
             grid=(B * H, t.n_q, t.n_kw),
             in_specs=[
-                q_spec,
-                k_spec,
-                k_spec,
+                q_spec(D),
+                k_spec(D),
+                k_spec(Dv),
                 pl.BlockSpec(
                     (1, block_q, _LANES),
                     lambda b, qi, j, *_: (b // H, qi, 0),
@@ -713,9 +738,9 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
                 ),
                 q_col,
                 q_col,
-                q_spec,
+                q_spec(Dv),
             ],
-            out_specs=q_spec,
+            out_specs=q_spec(D),
             scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
@@ -728,11 +753,19 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
     def q_row_of(b, step):  # the query head this step walks
         return (b // Hkv) * H + (b % Hkv) * t.G + step // t.n_qw
 
-    q_spec = pl.BlockSpec(
-        (1, block_q, D),
-        lambda b, kj, step, *_: (q_row_of(b, step), t.q_block(kj, step), 0),
-    )
-    k_spec = pl.BlockSpec((1, block_k, D), lambda b, kj, step, *_: (b, kj, 0))
+    def q_spec(width):
+        return pl.BlockSpec(
+            (1, block_q, width),
+            lambda b, kj, step, *_: (
+                q_row_of(b, step), t.q_block(kj, step), 0
+            ),
+        )
+
+    def k_spec(width):
+        return pl.BlockSpec(
+            (1, block_k, width), lambda b, kj, step, *_: (b, kj, 0)
+        )
+
     q_row = pl.BlockSpec(
         (1, 1, block_q),
         lambda b, kj, step, *_: (q_row_of(b, step), 0, t.q_block(kj, step)),
@@ -743,9 +776,9 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
             num_scalar_prefetch=2,
             grid=(B * Hkv, t.n_k, t.G * t.n_qw),
             in_specs=[
-                q_spec,
-                k_spec,
-                k_spec,
+                q_spec(D),
+                k_spec(D),
+                k_spec(Dv),
                 pl.BlockSpec(
                     (1, 1, block_q),
                     lambda b, kj, step, *_: (
@@ -758,17 +791,17 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
                 ),
                 q_row,
                 q_row,
-                q_spec,
+                q_spec(Dv),
             ],
-            out_specs=[k_spec, k_spec],
+            out_specs=[k_spec(D), k_spec(Dv)],
             scratch_shapes=[
                 pltpu.VMEM((block_k, D), jnp.float32),
-                pltpu.VMEM((block_k, D), jnp.float32),
+                pltpu.VMEM((block_k, Dv), jnp.float32),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B * Hkv, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, Tk, D), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, Tk, Dv), v.dtype),
         ],
         compiler_params=semantics,
         interpret=interpret,
@@ -778,17 +811,18 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
     return (
         dq.reshape(B, H, Tq, D),
         dk.reshape(B, Hkv, Tk, D),
-        dv.reshape(B, Hkv, Tk, D),
+        dv.reshape(B, Hkv, Tk, Dv),
     )
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
 )
 def _flash_attention(q, k, v, seg_q, seg_k, causal, window, block_q,
-                     block_k, interpret):
+                     block_k, interpret, scale):
     out, _lse = _flash_forward(
-        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
+        scale,
     )
     return out
 
@@ -807,20 +841,21 @@ KEEP_CORES = jax.checkpoint_policies.save_only_these_names(
 
 
 def _flash_fwd(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
-               interpret):
+               interpret, scale):
     out, lse = _flash_forward(
-        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
+        scale,
     )
     out = checkpoint_name(out, _CORE_OUT)
     lse = checkpoint_name(lse, _CORE_LSE)
     return out, (q, k, v, seg_q, seg_k, out, lse)
 
 
-def _flash_bwd(causal, window, block_q, block_k, interpret, res, g):
+def _flash_bwd(causal, window, block_q, block_k, interpret, scale, res, g):
     q, k, v, seg_q, seg_k, out, lse = res
     dq, dk, dv = _flash_backward(
         q, k, v, seg_q, seg_k, out, lse, g, causal, window, block_q,
-        block_k, interpret,
+        block_k, interpret, scale,
     )
     return dq, dk, dv, None, None
 
@@ -839,8 +874,11 @@ def flash_attention(
     block_k: int = 256,
     interpret: bool = False,
     window: Optional[int] = None,
+    scale: Optional[float] = None,
 ):
     """Pallas flash attention (custom VJP backward), compiled by Mosaic.
+    q [B, H, Tq, D], k [B, Hkv, Tk, D], v [B, Hkv, Tk, Dv]: the three
+    kernels compute at both head sizes as they are given, nothing padded.
 
     ``interpret=True`` runs the same kernel logic in the Pallas interpreter
     for CPU tests; it is an error on a TPU, where nothing may quietly
@@ -866,7 +904,8 @@ def flash_attention(
         )
     )
     return _flash_attention(
-        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
+        scale,
     )
 
 

@@ -61,33 +61,41 @@ def no_compile_cache():
 
 
 # mellum2_share8's windowed and full layers (4 query heads on 1 key/value
-# head of 128), and glm47_flash_share8's decompressed latent attention (20
-# heads of 192 + 64 = 256, as many key and value heads)
-@pytest.mark.parametrize("window,H,HKV,D", [
-    (1024, 4, 1, 128), (None, 4, 1, 128), (None, 20, 20, 256),
+# head of 128), glm47_flash_share8's decompressed latent attention (20
+# heads of 192 + 64 = 256, as many key and value heads), and
+# xing4_share8's (32 heads of 128 + 64 = 192 queries and keys, one and a
+# half lane tiles, and 128 values, over 4,096 tokens, YaRN's score scale)
+@pytest.mark.parametrize("window,H,HKV,D,DV,tokens,scale", [
+    (1024, 4, 1, 128, 128, T, None), (None, 4, 1, 128, 128, T, None),
+    (None, 20, 20, 256, 256, T, None), (None, 32, 32, 192, 128, 4096, 0.1447),
 ])
 def test_flash_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache,
-                                                  window, H, HKV, D):
-    def shape(heads, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct((1, heads, T, D), dtype,
+                                                  window, H, HKV, D, DV,
+                                                  tokens, scale):
+    def shape(heads, width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, heads, tokens, width), dtype,
                                     sharding=one_chip)
 
-    seg = jax.ShapeDtypeStruct((1, T), jnp.int32, sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=one_chip)
 
     def step(q, k, v, seg):
         def loss(q, k, v):
             return flash_attention(
                 q, k, v, causal=True, segment_ids=seg, window=window,
-                block_q=BLOCK, block_k=BLOCK,
+                block_q=BLOCK, block_k=BLOCK, scale=scale,
             ).astype(jnp.float32).sum()
 
         return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
     compiled = jax.jit(step).lower(
-        shape(H), shape(HKV), shape(HKV), seg
+        shape(H, D), shape(HKV, D), shape(HKV, DV), seg
     ).compile()
     # forward, dQ and dK/dV, each a Mosaic kernel
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= 3
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 3
+    # both head sizes as given: nothing is padded on the way to a kernel
+    assert f"bf16[{H},{tokens},{D}]" in text.replace(" ", "")
+    assert f"bf16[{HKV},{tokens},{DV}]" in text.replace(" ", "")
 
 
 # mellum2_share8's expert layer (softmax top-8) by both products, and

@@ -340,19 +340,29 @@ def test_the_default_router_is_untouched_and_an_unknown_rule_refused():
         moe_dropless(share, z, top_k=2, scoring="tanh")
 
 
-def test_the_eight_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
-    """64 experts over 8 shares of 8, top-4: the routed parts every share
-    gives, added, plus the shared expert counted once, are the reference's
-    uncut layer (its spec holding all 64)."""
-    E, d, f = 64, 32, 24
+@pytest.mark.parametrize("name,f,scale", [
+    ("glm47_flash_share8", 24, 1.8), ("xing4_share8", 16, 2.0),
+])
+def test_the_eight_shares_and_the_shared_expert_add_up_to_the_uncut_layer(
+        name, f, scale):
+    """64 experts over 8 shares of 8, top-4, the configuration's gate
+    scale: the routed parts every share gives, added, plus the shared
+    expert counted once, are the reference's uncut layer (its spec holding
+    all 64)."""
+    E, d = 64, 32
     moe, z = moe_parts(seed=13, E=E, d=d, f=f)
-    spec = dict(TINY, top_k=4, first_expert=0)
+    spec = dict(TINY, top_k=4, first_expert=0, routed_scaling_factor=scale)
     whole = ref.experts(z, moe, spec, CAST)
-    parts = [dropless(moe, z, (8 * s, 8), top_k=4)[0] for s in range(8)]
+    parts = [dropless(moe, z, (8 * s, 8), top_k=4, gate_scale=scale)[0]
+             for s in range(8)]
     shared = ref.gated(z, moe["shared"], CAST)
     close(sum(parts) + shared, whole)
-    # a share alone is not the layer, nor are seven of them
+    # a share alone is not the layer, nor are seven of them, nor is the
+    # layer under the other configuration's scale
     assert float(jnp.max(jnp.abs(sum(parts[:7]) + shared - whole))) > 1e-3
+    other = dict(spec, routed_scaling_factor=3.8 - scale)
+    assert float(jnp.max(jnp.abs(
+        ref.experts(z, moe, other, CAST) - whole))) > 1e-3
 
 
 def test_the_comparison_sees_a_missing_part(whole):
@@ -529,6 +539,48 @@ def test_the_other_decoder_configuration_did_not_move():
                         ("moe_assignments_held", "0x1.08p+6")):
         assert float(metrics[name]) == pytest.approx(
             float.fromhex(value), rel=1e-6), name
+
+
+def test_this_configurations_step_did_not_move():
+    """``glm47_flash_share8`` at the rehearsal's size: the parameter tree,
+    the step's program (its jaxpr, but for the addresses of the policy
+    functions it prints) and the first step's numbers are what the commit
+    before the residual skeleton with several streams and the two head
+    sizes gave (19a573a, read there): a description without ``residual``
+    and with equal heads traces the program it traced."""
+    import re
+
+    with open(os.path.join(REPO, "benchmark", "tests", "rehearsal_latent",
+                           "benchmark", "configs", "tiny_latent.json")) as f:
+        cfg = json.load(f)
+    net = program.build_model(cfg)
+    shapes = seeded_latent.param_shapes(net)
+    tree = [(jax.tree_util.keystr(p), tuple(l.shape), str(l.dtype))
+            for p, l in jax.tree_util.tree_flatten_with_path(shapes)[0]]
+    assert hashlib.sha256(repr(tree).encode()).hexdigest()[:16] == (
+        "23fb33e259ba39c3")
+    params = seeded_latent.make_params(
+        shapes, 7, cfg["model"]["kwargs"],
+        cfg["seeding"]["correction_bias_scale"])
+    batch = seeded_latent.make_learn_batch(7, cfg, 31, 1, 0.05)
+    optimizer = program.build_optimizer(cfg)
+    step = make_impala_train_step(
+        learn_apply(net), optimizer, program.loss_config(cfg), mesh=None,
+        donate=False,
+    )
+    state = make_train_state(params, optimizer)
+    jaxpr = re.sub(r" at 0x[0-9a-f]+", "", str(
+        jax.make_jaxpr(lambda s, b: step(s, b))(state, batch)))
+    assert hashlib.sha256(jaxpr.encode()).hexdigest()[:16] == (
+        "bfc3da5f8c0fef2d")
+    _, metrics = step(state, batch)
+    for name, value in (("total_loss", "0x1.df3a9cp-4"),
+                        ("grad_norm", "0x1.2db8bcp+1"),
+                        ("mtp_loss", "0x1.086d14p+2"),
+                        ("moe_assignments_held", "0x1.7cp+6")):
+        assert float(metrics[name]) == pytest.approx(
+            float.fromhex(value), rel=1e-6), name
+    assert not [k for k in metrics if k.startswith("hc_")]
 
 
 def test_the_benchmarks_configuration_is_the_published_model():
