@@ -85,7 +85,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import math
 from typing import Callable, Optional, Tuple
 
@@ -95,9 +94,11 @@ import numpy as np
 from flax import linen as nn
 
 from ..ops import attention as attn_ops
+from ..ops import hyper_mix
 from ..parallel.moe import moe_dropless
-from .transformer import (attend, hyper_coefficients, hyper_residual_block,
-                          residual_block, segment_ids_from_done, sown_dicts)
+from .transformer import (attend, hyper_coefficients, hyper_read,
+                          hyper_residual_block, residual_block,
+                          segment_ids_from_done, sown_dicts)
 
 __all__ = [
     "AttentionKind",
@@ -427,30 +428,39 @@ class _Sizes:
 class _HyperMix(nn.Module):
     """One sublayer's mixing parameters on the skeleton with several
     streams: ``phi`` [n d, n^2 + 2n], ``b`` [n^2 + 2n] and ``alpha``
-    (three scales), float32. ``streams [n, N, d] -> (pre, post, res)``;
-    the mixing's counters are sown."""
+    (three scales), float32, and its read side: ``streams [n, N, d] -> (h,
+    streams, coef)`` as :func:`hyper_residual_block` takes it; the mixing's
+    counters are sown. Which path computes it (``hyper_mix.mix_path``) is
+    counted in ``residual_mix_calls_traced_total{path=}``."""
 
     spec: Residual
     norm_eps: float
 
     @nn.compact
     def __call__(self, streams):
-        n, _, d = streams.shape
+        n, N, d = streams.shape
         k = n * n + 2 * n
         phi = self.param(
             "phi", nn.initializers.normal((n * d) ** -0.5), (n * d, k)
         )
         b = self.param("b", nn.initializers.zeros, (k,))
         alpha = self.param("alpha", nn.initializers.ones, (3,))
-        # rebuilt in the backward pass from the streams as they are stored:
-        # kept, the float32 copy of them would be the block's largest array
-        pre, post, res, counters = jax.checkpoint(functools.partial(
-            hyper_coefficients, norm_eps=self.norm_eps,
-            sinkhorn_iters=self.spec.sinkhorn_iters, eps=self.spec.eps,
-            res_clamp=self.spec.res_clamp,
-        ))(streams, phi, b, alpha)
+        spec = self.spec
+        if hyper_mix.traced_path(streams.shape, streams.dtype) == "fused":
+            h, streams, coef, counters = hyper_mix.read(
+                streams, phi, b, alpha, self.norm_eps, spec.sinkhorn_iters,
+                spec.eps, tuple(spec.res_clamp),
+            )
+        else:
+            pre, post, res, counters = hyper_coefficients(
+                streams, phi, b, alpha, norm_eps=self.norm_eps,
+                sinkhorn_iters=spec.sinkhorn_iters, eps=spec.eps,
+                res_clamp=spec.res_clamp,
+            )
+            h = hyper_read(streams, pre)
+            coef = jnp.concatenate([pre, post, res.reshape(n * n, N)])
         self.sow("intermediates", "hc_counters", counters)
-        return pre, post, res
+        return h, streams, coef
 
 
 class _Block(nn.Module):

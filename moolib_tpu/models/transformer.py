@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from ..ops import attention as attn_ops
+from ..ops import hyper_mix
 from ..ops import ring_attention as ring_ops
 from ..parallel.moe import moe_ffn
 
@@ -44,7 +45,9 @@ __all__ = [
     "TransformerNet",
     "attend",
     "hyper_coefficients",
+    "hyper_read",
     "hyper_residual_block",
+    "hyper_write",
     "moe_aux_losses",
     "residual_block",
 ]
@@ -154,6 +157,34 @@ def hyper_coefficients(streams, phi, b, alpha, *, norm_eps: float,
         return pre, post, res, counters
 
 
+def hyper_read(streams, pre):
+    """``h = sum_i pre[i] X[i]`` in float32: ``streams`` [n, N, C],
+    ``pre`` [n, N]."""
+    with jax.named_scope("moolib.lm.hc_pre"):
+        x32 = streams.astype(jnp.float32)
+        return sum(pre[i][:, None] * x32[i] for i in range(len(pre)))
+
+
+def hyper_write(streams, coef, y):
+    """``X'[i] = sum_j res[i, j] X[j] + post[i] y`` summed in float32 and
+    stored in the streams' dtype: ``streams`` [n, N, C], ``coef`` [n^2 +
+    2n, N] (``pre``, ``post``, the rows of ``res``), ``y`` [N, C]. The
+    fused pass of :mod:`moolib_tpu.ops.hyper_mix` where
+    ``hyper_mix.mix_path`` says so, these lines otherwise."""
+    if hyper_mix.mix_path(streams.shape, streams.dtype) == "fused":
+        return hyper_mix.write(streams, coef, y)
+    with jax.named_scope("moolib.lm.hc_post"):
+        n = streams.shape[0]
+        post, res = coef[n:2 * n], coef[2 * n:].reshape(n, n, -1)
+        x32 = streams.astype(jnp.float32)
+        y32 = y.astype(jnp.float32)
+        return jnp.stack([
+            sum(res[i, j][:, None] * x32[j] for j in range(n))
+            + post[i][:, None] * y32
+            for i in range(n)
+        ]).astype(streams.dtype)
+
+
 def hyper_residual_block(streams, mix1, norm1, mixer, mix2, norm2, mlp):
     """The residual skeleton of a block with ``n`` streams, ``streams``
     [n, T, B, C]: each of the two sublayers reads a learned mixture of the
@@ -163,33 +194,19 @@ def hyper_residual_block(streams, mix1, norm1, mixer, mix2, norm2, mlp):
         h = sum_i pre[i] X[i];  y = F(norm(h))
         X'[i] = sum_j res[i, j] X[j] + post[i] y
 
-    ``mix1`` / ``mix2``: ``streams [n, N, C] -> (pre, post, res)``, the
-    sublayer's own coefficients (:func:`hyper_coefficients`). The weighted
-    sums are taken in float32 and the streams stored in their own dtype."""
+    ``mix1`` / ``mix2``: ``streams [n, N, C] -> (h, streams, coef)``, the
+    sublayer's read side: its input ``h`` [N, C] in float32, the streams
+    for its write side to take, and its coefficients ``[n^2 + 2n, N]``
+    (``pre``, ``post``, the rows of ``res``: :func:`hyper_coefficients`).
+    The weighted sums are taken in float32 and the streams stored in their
+    own dtype; where the shapes tile on a TPU both sides are the fused
+    passes of :mod:`moolib_tpu.ops.hyper_mix` (``hyper_mix.mix_path``)."""
     n, T, B, C = streams.shape
-
-    @jax.checkpoint
-    def read(flat, pre):
-        with jax.named_scope("moolib.lm.hc_pre"):
-            x32 = flat.astype(jnp.float32)
-            return sum(pre[i][:, None] * x32[i] for i in range(n))
-
-    @jax.checkpoint
-    def write(flat, res, post, y):
-        with jax.named_scope("moolib.lm.hc_post"):
-            x32 = flat.astype(jnp.float32)
-            y32 = y.astype(jnp.float32)
-            return jnp.stack([
-                sum(res[i, j][:, None] * x32[j] for j in range(n))
-                + post[i][:, None] * y32
-                for i in range(n)
-            ]).astype(flat.dtype)
-
     flat = streams.reshape(n, T * B, C)
     for mix, norm, f in ((mix1, norm1, mixer), (mix2, norm2, mlp)):
-        pre, post, res = mix(flat)
-        y = f(norm(read(flat, pre).reshape(T, B, C)))
-        flat = write(flat, res, post, y.reshape(T * B, C))
+        h, flat, coef = mix(flat)
+        y = f(norm(h.reshape(T, B, C)))
+        flat = hyper_write(flat, coef, y.reshape(T * B, C))
     return flat.reshape(n, T, B, C)
 
 

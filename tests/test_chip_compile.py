@@ -289,3 +289,50 @@ def test_a_rebuilt_latent_block_runs_the_forward_core_once(
              if 'custom_call_target="tpu_custom_call"' in line]
     assert len(calls) == kernels
     assert all("moolib.lm.attn_core" in line for line in calls)
+
+
+@pytest.mark.parametrize("dtype,n,tokens,width", [
+    ("bfloat16", 4, 4096, 3584),  # xing4_learner_4k's streams
+    ("float32", 4, 4096, 3584),
+    ("bfloat16", 2, 1024, 512),
+])
+def test_the_residual_mixings_kernels_compile_at_the_cells_shape(
+        one_chip, no_compile_cache, monkeypatch, dtype, n, tokens, width):
+    """The four fused passes of ``ops/hyper_mix.py`` (a sublayer's read and
+    write sides, forward and backward) through the value and gradient of
+    one sublayer: Mosaic takes their tiles, their transposes, the rows of the
+    coefficients read in pieces and the coefficients' backward traced into
+    the kernel, within the fast memory they ask for; and the program's
+    temporaries stay under three arrays of the streams' size (the new
+    streams, their gradient's two parts)."""
+    from moolib_tpu.ops import hyper_mix
+
+    # jax.default_backend() is the CPU here: say what the chip would run
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    dtype = jnp.dtype(dtype)
+    assert hyper_mix.mix_path((n, tokens, width), dtype) == "fused"
+    k = n * n + 2 * n
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def sublayer(x, y, phi, b, alpha):
+        h, x, coef, counters = hyper_mix.read(
+            x, phi, b, alpha, 1e-6, 20, 1e-6, (-30.0, 30.0))
+        out = hyper_mix.write(x, coef, y + h.astype(y.dtype))
+        return out.astype(jnp.float32).sum() + counters["hc_row_sum_gap"]
+
+    compiled = jax.jit(
+        jax.value_and_grad(sublayer, argnums=(0, 1, 2, 3, 4))).lower(
+        s((n, tokens, width), dtype), s((tokens, width), dtype),
+        s((n * width, k), jnp.float32), s((k,), jnp.float32),
+        s((3,), jnp.float32),
+    ).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4
+    assert sum("moolib.lm.hc_mix" in c for c in calls) == 2
+    assert sum("moolib.lm.hc_post" in c for c in calls) == 2
+    streams = n * tokens * width * dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 * streams

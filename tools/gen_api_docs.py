@@ -118,6 +118,8 @@ MODULES = [
     ("moolib_tpu.ops.vtrace", "V-trace off-policy corrections"),
     ("moolib_tpu.ops.embed", "embedding lookup; the table's gradient as a "
      "blocked one-hot product on the MXU for small narrow tables"),
+    ("moolib_tpu.ops.hyper_mix", "the residual mixing of several streams as "
+     "fused Pallas passes, forward and backward"),
     ("moolib_tpu.ops.attention", "dense/blockwise/flash attention (pallas "
      "kernels)"),
     ("moolib_tpu.ops.ring_attention", "ring + zigzag sequence-parallel "
