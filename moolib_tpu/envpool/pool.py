@@ -201,13 +201,22 @@ class _Sup:
       worker when it finishes its slice of that buffer (before the done
       post/message) — how the parent attributes completion per worker, so
       a failed batch knows exactly which slices finished and a retry
-      never re-steps them.
+      never re-steps them;
+    - one f64 *finish stamp* per (worker, batch): the worker's
+      ``time.monotonic()`` when it finished its slice of that buffer,
+      written just before the mark. ``CLOCK_MONOTONIC`` is one clock
+      for the parent and its workers, so the latest of a batch's stamps
+      is when the envs' own step ended, however late the parent looks.
     """
 
     def __init__(self, base: int, n_workers: int, num_batches: int):
         self.num_batches = num_batches
         self.hb = [base + w * 8 for w in range(n_workers)]
-        marks_base = base + n_workers * 8
+        stamps_base = base + n_workers * 8
+        self.stamps = [
+            stamps_base + w * num_batches * 8 for w in range(n_workers)
+        ]
+        marks_base = stamps_base + n_workers * num_batches * 8
         self.marks = [
             marks_base + w * num_batches * 4 for w in range(n_workers)
         ]
@@ -215,6 +224,10 @@ class _Sup:
 
     def hb_view(self, buf, w: int) -> np.ndarray:
         return np.ndarray((1,), np.uint64, buffer=buf, offset=self.hb[w])
+
+    def stamps_view(self, buf, w: int) -> np.ndarray:
+        return np.ndarray((self.num_batches,), np.float64, buffer=buf,
+                          offset=self.stamps[w])
 
     def marks_view(self, buf, w: int) -> np.ndarray:
         return np.ndarray((self.num_batches,), np.uint32, buffer=buf,
@@ -331,6 +344,7 @@ def _worker_main(conn, env_fn_bytes: bytes, first: int, count: int, rank: int):
             ]
             hb = sup.hb_view(shm.buf, rank)
             marks = sup.marks_view(shm.buf, rank)
+            stamps = sup.stamps_view(shm.buf, rank)
             episode_step = np.zeros(count, np.int64)
             episode_return = np.zeros(count, np.float64)
             fails = [0] * count        # consecutive step/reset failures
@@ -431,6 +445,10 @@ def _worker_main(conn, env_fn_bytes: bytes, first: int, count: int, rank: int):
                     if done:
                         episode_step[i] = 0
                         episode_return[i] = 0.0
+                # When this slice's envs were done stepping, for the
+                # parent's split of a batch's wall into the envs' own
+                # step and the time the batch then lay ready.
+                stamps[b] = time.monotonic()
                 # Completion mark LAST — written before the done post /
                 # message, so a mark the parent observes means the whole
                 # slice (including every row write above) is in place.
@@ -629,7 +647,7 @@ class EnvStepperFuture:
                 return value
             raise value
         try:
-            out = pool._collect(self._batch_index, wait_s)
+            out = pool._collect(self._batch_index, wait_s, t_wait)
         except Exception as e:
             self._outcome = ("error", e)
             raise
@@ -825,6 +843,10 @@ class EnvPool:
         self._hb_views = [
             self._sup.hb_view(self._shm.buf, w) for w in range(num_processes)
         ]
+        self._stamp_views = [
+            self._sup.stamps_view(self._shm.buf, w)
+            for w in range(num_processes)
+        ]
         self._mark_views = [
             self._sup.marks_view(self._shm.buf, w)
             for w in range(num_processes)
@@ -897,11 +919,15 @@ class EnvPool:
         reg = self._tel.registry
         self._m_steps = reg.counter("envpool_steps_total")
         self._m_step_dur = reg.histogram("envpool_step_seconds")
+        # Dispatch to the last worker's finish stamp: the envs' own step,
+        # without the time a finished batch lay ready.
+        self._m_env_step_dur = reg.histogram("envpool_env_step_seconds")
         # Step-phase attribution (docs/observability.md): each collected
         # batch is one "step" of the envpool loop, its wall time split
         # into env_wait (caller blocked in result()), staging (the H2D
-        # device_put in _collect), and batch_fill (the remainder — the
-        # workers filling the slab while the caller was elsewhere).
+        # device_put in _collect), batch_fill (the workers filling the
+        # slab while the caller was elsewhere) and ready_idle (the
+        # filled slab waiting for the caller to come back for it).
         # observe_step is the overlap-safe path: double-buffered batches
         # overlap in wall time, so each carries its own stamps.
         from ..telemetry.stepscope import StepScope
@@ -928,6 +954,13 @@ class EnvPool:
         reg.gauge_fn("envpool_quarantined_envs",
                      lambda: len(wself()._quarantined), pool=name)
         self._step_t0 = [0.0] * num_batches
+        # Read before the commands go out (under the lock): what the envs'
+        # own step is counted from, so that no slice can end before it.
+        self._dispatch_t = [0.0] * num_batches
+        # Cumulative, over every collected batch (guarded by self._lock;
+        # they stand still while telemetry is off): step_times().
+        self._env_step_s = 0.0
+        self._ready_idle_s = 0.0
         self._callbacks: Dict[int, list] = {}
         self._notify_thread = None
         self._waiter = None
@@ -1056,6 +1089,7 @@ class EnvPool:
             for w in fill:
                 self._fill_terminal_locked(batch_index, w)
             now = time.monotonic()
+            self._dispatch_t[batch_index] = now
             aw: Dict[int, tuple] = {}
             send_failed = []
             for w, exp, push in targets:
@@ -1705,7 +1739,16 @@ class EnvPool:
             self._callbacks.clear()
         self._run_callbacks(pending)
 
-    def _collect(self, batch_index: int, wait_s: float = 0.0):
+    def step_times(self) -> tuple:
+        """Cumulative ``(env_step_s, ready_idle_s)`` over the batches
+        collected so far: the envs' own step (dispatch to the slowest
+        worker's finish stamp), and how long finished batches lay ready
+        before ``result()`` was called for them."""
+        with self._lock:
+            return self._env_step_s, self._ready_idle_s
+
+    def _collect(self, batch_index: int, wait_s: float = 0.0,
+                 wait_t0: float = 0.0):
         with self._lock:
             err = self._batch_error[batch_index]
         if err is not None:
@@ -1722,10 +1765,24 @@ class EnvPool:
         # racing next step() of this buffer restamps _step_t0 and the
         # observed duration would be ~0 or negative.
         t0 = self._step_t0[batch_index] if self._tel.on else 0.0
+        env_step_s = ready_idle_s = 0.0
+        if t0:
+            # The stamps too, for the same reason. The slowest slice set
+            # the envs' own step, counted from the reading before the
+            # dispatch; a slot that is down keeps an old stamp and sets
+            # nothing. t0 is stamped after the dispatch, outside the
+            # lock, so a quick slice can end before it.
+            finish = max(float(v[batch_index]) for v in self._stamp_views)
+            env_step_s = max(finish - self._dispatch_t[batch_index], 0.0)
+            if wait_t0:
+                ready_idle_s = max(wait_t0 - max(finish, t0), 0.0)
         with self._lock:
             self._busy[batch_index] = False
+            self._env_step_s += env_step_s
+            self._ready_idle_s += ready_idle_s
         if t0:
             self._m_step_dur.observe(time.monotonic() - t0)
+            self._m_env_step_dur.observe(env_step_s)
         stage_s = 0.0
         if self.device is not None:
             import jax
@@ -1747,7 +1804,10 @@ class EnvPool:
             self._scope.observe_step(wall, {
                 "env_wait": wait_s,
                 "staging": stage_s,
-                "batch_fill": max(wall - wait_s - stage_s, 0.0),
+                "batch_fill": max(
+                    wall - wait_s - stage_s - ready_idle_s, 0.0
+                ),
+                "ready_idle": ready_idle_s,
             })
         return out
 

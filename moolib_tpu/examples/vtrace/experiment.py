@@ -522,8 +522,23 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         bs.core_state,
                     )
                 with scope.phase("host_sync"):
-                    a = np.asarray(a)  # hotlint: sync -- actions must reach the host NOW to feed the envpool slab: the Sebulba actor-loop boundary, not a stray sync
-                    bs.record_action(a, np.asarray(logits), core)  # hotlint: sync -- behavior logits ride the host-side unroll buffer with the action that produced them
+                    # Four parts, to say what the thread waits for: the
+                    # device (the copy in, the act step, whatever is
+                    # queued ahead of it), each copy out, the slab.
+                    with scope.part("act_wait"):
+                        # Asked for before the wait, as np.asarray on a
+                        # pending array asks for it: the copy out then
+                        # follows the act step with no trip to the host
+                        # between them (to wait first and copy after
+                        # cost 2.9% of the loop's rate: PERF.md, PR 37).
+                        a.copy_to_host_async()
+                        jax.block_until_ready(a)  # hotlint: sync -- actions must reach the host NOW to feed the envpool slab: the Sebulba actor-loop boundary, not a stray sync
+                    with scope.part("action_readback"):
+                        a = np.asarray(a)  # hotlint: sync -- the action's copy out, the act step already done
+                    with scope.part("logits_readback"):
+                        logits = np.asarray(logits)  # hotlint: sync -- behavior logits ride the host-side unroll buffer with the action that produced them
+                    with scope.part("unroll_write"):
+                        bs.record_action(a, logits, core)
                 with scope.phase("env_submit"):
                     actions[i][:] = a
                     futures[i] = pool.step(i, actions[i])
@@ -615,6 +630,10 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                     window["sps"].add((env_steps - s_mark) / (now - t_mark + 1e-9))
                     last_sps_mark = (now, env_steps)
                     g = gsa.global_stats.results()
+                    # The envs' own step and how long finished batches lay
+                    # ready, cumulative, as the pool stamped them at the
+                    # end of each env_wait: the room under the turn.
+                    env_step_s, env_ready_idle_s = pool.step_times()
                     row = dict(
                         window.results(),
                         time=now,
@@ -624,6 +643,8 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         updates=stats["updates"].result(),
                         skips=stats["skips"].result(),
                         dropped_unrolls=stats["dropped_unrolls"].result(),
+                        env_step_s=env_step_s,
+                        env_ready_idle_s=env_ready_idle_s,
                         model_version=accumulator.model_version,
                         leader=accumulator.is_leader(),
                     )

@@ -43,13 +43,22 @@ thread (accumulator rounds) use the thread-safe low-level API instead::
 
     scope.observe_step(wall_s, {"env_wait": w, "staging": s})
 
-One ``with``, two readings of it: ``step()`` and ``phase()`` also open
-the telemetry layer's span (:class:`~moolib_tpu.telemetry.trace
-.ProgramSpan`) named ``moolib.<loop>.step`` / ``moolib.<loop>.<phase>``
-from the same two clock readings as the ledger entry. The counter is
-what a long window reads with no profiler on; the span is what a
-profiler capture (or the ``TraceBuffer``, while tracing is on) shows in
-place, beside the device's operations. ``observe_step`` producers keep
+A phase read as it stands can still be divided: ``with scope.part(name)``
+inside a phase times a named part of it. Parts change nothing the phase
+reads (its ledger entry, its class fraction, ``other`` and the closure
+are the phase's own clock readings); their seconds go to a counter of
+their own (``stepscope_part_seconds_total``, labelled by loop, phase and
+part) and into the summary under ``"parts"`` as ``"<phase>.<part>"``.
+Outside a phase, or with telemetry off at step entry, ``part`` is a
+no-op.
+
+One ``with``, two readings of it: ``step()``, ``phase()`` and ``part()``
+also open the telemetry layer's span (:class:`~moolib_tpu.telemetry.trace
+.ProgramSpan`) named ``moolib.<loop>.step`` / ``moolib.<loop>.<phase>`` /
+``moolib.<loop>.<phase>.<part>`` from the same two clock readings as the
+ledger entry. The counter is what a long window reads with no profiler
+on; the span is what a profiler capture (or the ``TraceBuffer``, while
+tracing is on) shows in place, beside the device's operations. ``observe_step`` producers keep
 their counters and have no span: their steps overlap or end on another
 thread, so there is no one line to draw them on.
 
@@ -67,6 +76,7 @@ composition onto the merged incident timeline.
 
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 import time
@@ -141,6 +151,7 @@ class _StepCM:
         if not s._active:
             return self
         s._ledger = {}
+        s._parts = {}
         s._stack.clear()
         s._step_t0 = time.monotonic()
         self._span.begin()
@@ -153,7 +164,7 @@ class _StepCM:
         s._active = False
         wall = time.monotonic() - s._step_t0
         self._span.end(wall)
-        s._finish_step(wall, s._ledger)
+        s._finish_step(wall, s._ledger, s._parts)
         return False
 
 
@@ -194,17 +205,49 @@ class _PhaseCM:
         return False
 
 
+class _PartCM:
+    """Reusable ``with scope.part(name):`` context manager for one part
+    of one phase. :meth:`StepScope.part` hands it out only inside an
+    open phase of an active step, so it has no gate of its own; it
+    touches neither the phase stack nor the ledger."""
+
+    __slots__ = ("_s", "key", "_span", "_t0")
+
+    def __init__(self, scope: "StepScope", key: str):
+        self._s = scope
+        self.key = key
+        self._span = scope._span(key)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_PartCM":
+        self._t0 = time.monotonic()
+        self._span.begin()
+        return self
+
+    def __exit__(self, *exc: Any) -> bool:
+        dt = time.monotonic() - self._t0
+        self._span.end(dt)
+        parts = self._s._parts
+        parts[self.key] = parts.get(self.key, 0.0) + dt
+        return False
+
+
+#: What :meth:`StepScope.part` returns where there is no phase to divide.
+_NO_PART = contextlib.nullcontext()
+
+
 class StepScope:
     """Per-loop phase attribution: context managers on the owner thread,
     :meth:`observe_step` for overlapping/off-thread producers, derived
     critical-path fractions as windowed registry gauges.
 
     Threading contract (racelint-shaped): ``_active`` / ``_stack`` /
-    ``_ledger`` / ``_step_t0`` and the step and phase context managers
-    (each with its span) belong to the loop's owner thread and are NEVER
-    touched under ``_lock``; the cumulative and windowed aggregates live
-    only under ``_lock``. Registry metric objects are internally
-    thread-safe and are recorded outside the scope lock.
+    ``_ledger`` / ``_parts`` / ``_step_t0`` and the step, phase and part
+    context managers (each with its span) belong to the loop's owner
+    thread and are NEVER touched under ``_lock``; the cumulative and
+    windowed aggregates live only under ``_lock``. Registry metric
+    objects are internally thread-safe and are recorded outside the
+    scope lock.
     """
 
     def __init__(self, loop: str, telemetry=None, window: int = 32,
@@ -222,6 +265,7 @@ class StepScope:
         self._active = False
         self._stack: List[List[Any]] = []
         self._ledger: Dict[str, float] = {}
+        self._parts: Dict[str, float] = {}  # "<phase>.<part>" -> seconds
         self._step_t0 = 0.0
 
         # Shared aggregates — guarded by _lock.
@@ -229,6 +273,7 @@ class StepScope:
         self._steps = 0
         self._cum_wall = 0.0
         self._cum: Dict[str, float] = {}
+        self._cum_parts: Dict[str, float] = {}
         # (wall, comms, host, env, attributed, overrun) per recent step.
         self._win: Deque[Tuple[float, ...]] = deque()
         self._win_sums = [0.0] * 6
@@ -255,7 +300,9 @@ class StepScope:
             "stepscope_ledger_overrun_fraction", loop=self.loop
         )
         self._phase_m: Dict[str, Any] = {}
+        self._part_m: Dict[str, Any] = {}
         self._phase_cm: Dict[str, _PhaseCM] = {}
+        self._part_cm: Dict[str, _PartCM] = {}
         # Made on first use: only a loop that runs step() on its own
         # thread has spans (and with them the profiler's import).
         self._step_cm: Optional[_StepCM] = None
@@ -282,6 +329,20 @@ class StepScope:
         cm = self._phase_cm.get(name)
         if cm is None:
             cm = self._phase_cm.setdefault(name, _PhaseCM(self, name))
+        return cm
+
+    def part(self, name: str):
+        """Context manager timing a named part of the phase that is open
+        on this thread: a ``moolib.<loop>.<phase>.<part>`` span and
+        seconds under the summary's ``"parts"``, while the phase reads
+        what it read. No-op outside a phase (or when telemetry was off
+        at step entry)."""
+        if not self._active or not self._stack:
+            return _NO_PART
+        key = f"{self._stack[-1][0]}.{name}"
+        cm = self._part_cm.get(key)
+        if cm is None:
+            cm = self._part_cm.setdefault(key, _PartCM(self, key))
         return cm
 
     def note(self, name: str, seconds: float) -> None:
@@ -321,7 +382,18 @@ class StepScope:
             )
         return m
 
-    def _finish_step(self, wall: float, ledger: Dict[str, float]) -> None:
+    def _part_seconds(self, key: str):
+        m = self._part_m.get(key)
+        if m is None:
+            phase, _, part = key.partition(".")
+            m = self._part_m[key] = self._tel.registry.counter(
+                "stepscope_part_seconds_total", loop=self.loop,
+                phase=phase, part=part,
+            )
+        return m
+
+    def _finish_step(self, wall: float, ledger: Dict[str, float],
+                     parts: Optional[Dict[str, float]] = None) -> None:
         wall = max(wall, 1e-9)
         explicit = sum(ledger.values())
         residual = wall - explicit
@@ -340,6 +412,9 @@ class StepScope:
             cls = PHASE_CLASS.get(name)
             if cls is not None:
                 by_class[cls] += secs
+        if parts:
+            for key, secs in parts.items():
+                self._part_seconds(key).inc(secs)
 
         row = (wall, by_class["comms"], by_class["host"], by_class["env"],
                explicit if residual > 0.0 else wall, overrun)
@@ -350,6 +425,10 @@ class StepScope:
             cum = self._cum
             for name, secs in ledger.items():
                 cum[name] = cum.get(name, 0.0) + secs
+            if parts:
+                cum_parts = self._cum_parts
+                for key, secs in parts.items():
+                    cum_parts[key] = cum_parts.get(key, 0.0) + secs
             win, sums = self._win, self._win_sums
             win.append(row)
             for i, v in enumerate(row):
@@ -385,12 +464,14 @@ class StepScope:
 
     def summary(self) -> Dict[str, Any]:
         """Cumulative attribution summary: loop, step count, total wall
-        seconds, per-phase seconds, and lifetime class fractions."""
+        seconds, per-phase seconds, the seconds of the phases' parts
+        (``"<phase>.<part>"``), and lifetime class fractions."""
         with self._lock:
             steps = self._steps
             wall = self._cum_wall
             phases = dict(self._cum)
-        return _summarize(self.loop, steps, wall, phases)
+            parts = dict(self._cum_parts)
+        return _summarize(self.loop, steps, wall, phases, parts)
 
     def close(self) -> None:
         """Unregister the per-loop gauges so a closed component's scope
@@ -410,7 +491,8 @@ class StepScope:
 # -- snapshot analysis (tools / reports) -------------------------------------
 
 def _summarize(loop: str, steps: int, wall: float,
-               phases: Dict[str, float]) -> Dict[str, Any]:
+               phases: Dict[str, float],
+               parts: Optional[Dict[str, float]] = None) -> Dict[str, Any]:
     wall_div = wall if wall > 0.0 else 1e-9
     by_class = dict.fromkeys(_CLASSES, 0.0)
     for name, secs in phases.items():
@@ -422,6 +504,7 @@ def _summarize(loop: str, steps: int, wall: float,
         "steps": steps,
         "wall_s": wall,
         "phases": dict(sorted(phases.items())),
+        "parts": dict(sorted((parts or {}).items())),
         "fractions": {
             "exposed_comms": by_class["comms"] / wall_div,
             "host_blocked": by_class["host"] / wall_div,
@@ -465,6 +548,7 @@ def summarize_metrics(
     steps: Dict[str, int] = {}
     wall: Dict[str, float] = {}
     phases: Dict[str, Dict[str, float]] = {}
+    parts: Dict[str, Dict[str, float]] = {}
     window: Dict[str, Dict[str, float]] = {}
     gauge_keys = {v: k for k, v in FRACTION_GAUGES.items()}
     gauge_keys["stepscope_attributed_fraction"] = "attributed"
@@ -485,12 +569,16 @@ def summarize_metrics(
             phase = labels.get("phase", OTHER_PHASE)
             d = phases.setdefault(loop, {})
             d[phase] = d.get(phase, 0.0) + float(value)
+        elif name == "stepscope_part_seconds_total":
+            key = ".".join(labels.get(k, OTHER_PHASE) for k in ("phase", "part"))
+            d = parts.setdefault(loop, {})
+            d[key] = d.get(key, 0.0) + float(value)
         elif name in gauge_keys:
             window.setdefault(loop, {})[gauge_keys[name]] = float(value)
     out: Dict[str, Dict[str, Any]] = {}
     for loop in sorted(set(steps) | set(wall) | set(phases)):
         s = _summarize(loop, steps.get(loop, 0), wall.get(loop, 0.0),
-                       phases.get(loop, {}))
+                       phases.get(loop, {}), parts.get(loop))
         if loop in window:
             s["window"] = window[loop]
         out[loop] = s
@@ -519,13 +607,15 @@ def merge_summaries(
                 continue
             seen.add(key)
             a = agg.setdefault(loop, {"steps": 0, "wall_s": 0.0,
-                                      "phases": {}})
+                                      "phases": {}, "parts": {}})
             a["steps"] += s["steps"]
             a["wall_s"] += s["wall_s"]
-            for ph, secs in s["phases"].items():
-                a["phases"][ph] = a["phases"].get(ph, 0.0) + secs
+            for kind in ("phases", "parts"):
+                for name, secs in s.get(kind, {}).items():
+                    a[kind][name] = a[kind].get(name, 0.0) + secs
     return {
-        loop: _summarize(loop, a["steps"], a["wall_s"], a["phases"])
+        loop: _summarize(loop, a["steps"], a["wall_s"], a["phases"],
+                         a["parts"])
         for loop, a in sorted(agg.items())
     }
 

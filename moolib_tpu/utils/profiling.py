@@ -32,22 +32,12 @@ __all__ = ["profile_trace", "StepWindowProfiler"]
 def _record_window(logdir: str, wall0: float, args: Optional[dict] = None):
     """Mark a finished capture window on the shared telemetry timeline.
     Unconditional (capture is rare and deliberate — no hot-path gate)."""
-    from ..telemetry import global_telemetry, summarize_stepscope
+    from ..telemetry import global_telemetry
 
     span_args = {"logdir": logdir}
     if args:
         span_args.update(args)
-    # Stamp the step-phase composition as of window close: the device
-    # trace in the logdir shows what the chip did, the stepscope ledger
-    # shows what the host loops were blocked on around the same window.
-    tel = global_telemetry()
-    stepscope = summarize_stepscope(tel.snapshot())
-    if stepscope:
-        span_args["stepscope"] = {
-            loop: {"steps": s["steps"], **s["fractions"]}
-            for loop, s in stepscope.items()
-        }
-    tel.traces.add_span(
+    global_telemetry().traces.add_span(
         "jax_profiler_capture", "profiler", pid="profiler",
         ts_us=int(wall0 * 1e6), dur_us=int((time.time() - wall0) * 1e6),
         args=span_args,

@@ -297,7 +297,8 @@ def test_a_rebuilt_latent_block_runs_the_forward_core_once(
     ("bfloat16", 2, 1024, 512),
 ])
 def test_the_residual_mixings_kernels_compile_at_the_cells_shape(
-        one_chip, no_compile_cache, monkeypatch, dtype, n, tokens, width):
+        one_chip, no_compile_cache, monkeypatch, request, dtype, n, tokens,
+        width):
     """The four fused passes of ``ops/hyper_mix.py`` (a sublayer's read and
     write sides, forward and backward) through the value and gradient of
     one sublayer: Mosaic takes their tiles, their transposes, the rows of the
@@ -309,6 +310,16 @@ def test_the_residual_mixings_kernels_compile_at_the_cells_shape(
 
     # jax.default_backend() is the CPU here: say what the chip would run
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # The four rules are jits of their own and decide between Mosaic and
+    # Pallas' interpreter when they are traced: a trace of these shapes
+    # made earlier in this process under the CPU's answer (tests/
+    # test_hyper_mix.py traces the cell's step) would be found again, and
+    # one made here would be found by a later CPU test.
+    rules = (hyper_mix._read_fwd, hyper_mix._read_bwd,
+             hyper_mix._write_fwd, hyper_mix._write_bwd)
+    for rule in rules:
+        rule.clear_cache()
+        request.addfinalizer(rule.clear_cache)
     dtype = jnp.dtype(dtype)
     assert hyper_mix.mix_path((n, tokens, width), dtype) == "fused"
     k = n * n + 2 * n

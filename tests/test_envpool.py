@@ -456,3 +456,62 @@ def test_abandoned_pool_is_collected_and_workers_reaped():
         time.sleep(0.05)
     else:
         raise AssertionError("abandoned pool's worker still alive")
+
+
+@pytest.mark.parametrize("plane", ["native", "pipe"])
+def test_envs_own_step_and_ready_idle_are_stamped_by_the_workers(
+        plane, monkeypatch):
+    """The workers stamp where their slice ends, so a batch's wall splits
+    into the envs' own step (no shorter than the env's sleep) and the time
+    the finished batch lay ready (no shorter than the caller's delay less
+    that step), in both data-plane modes; the envpool ledger closes."""
+    from moolib_tpu.envpool import pool as pool_mod
+    from moolib_tpu.telemetry import global_telemetry, summarize_stepscope
+
+    if plane == "pipe":
+        monkeypatch.setattr(pool_mod, "_get_native", lambda: None)
+    elif pool_mod._get_native() is None:
+        pytest.skip("native data plane unavailable (pipe mode)")
+    delay, turns = 0.6, 3
+
+    def ledger():
+        s = summarize_stepscope(global_telemetry().snapshot()).get(
+            "envpool", {"wall_s": 0.0, "phases": {}}
+        )
+        return s["wall_s"], dict(s["phases"])
+
+    reg = global_telemetry().registry
+    hist = lambda: reg.snapshot()["envpool_env_step_seconds"]  # noqa: E731
+    with EnvPool(SlowEnv, num_processes=2, batch_size=2, num_batches=2,
+                 name=f"t-stamps-{plane}") as pool:
+        assert (pool._ctrl is None) == (plane == "pipe")
+        assert pool.step_times() == (0.0, 0.0)
+        a = np.zeros(2, np.int64)
+        wall0, phases0 = ledger()
+        count0 = hist()["count"]
+        for turn in range(turns):
+            fut = pool.step(turn % 2, a)
+            time.sleep(delay)
+            fut.result(timeout=30)
+            own, idle = pool.step_times()
+            # One env a worker: the slice is one sleep of the env's.
+            assert own >= (turn + 1) * SlowEnv.STEP_SECONDS
+            assert idle >= (turn + 1) * delay - own - 0.01
+        # Collected at once: the caller waits, nothing lies ready.
+        pool.step(0, a).result(timeout=30)
+        own2, idle2 = pool.step_times()
+        assert own2 >= own + SlowEnv.STEP_SECONDS
+        assert idle2 == idle
+        wall1, phases1 = ledger()
+    spent = {k: v - phases0.get(k, 0.0) for k, v in phases1.items()}
+    assert sum(spent.values()) == pytest.approx(wall1 - wall0, rel=1e-6)
+    # The ledger's `ready_idle` is the pool's own count of it, and
+    # `batch_fill` stops where the workers stopped.
+    assert spent["ready_idle"] == pytest.approx(idle2, rel=1e-6)
+    assert spent["batch_fill"] + spent["env_wait"] == pytest.approx(
+        wall1 - wall0 - idle2 - spent.get("staging", 0.0), rel=1e-6
+    )
+    # None of the three turns' 0.45 s of lying ready is in it (what a
+    # collect does after its wait is, so a loaded host gets room).
+    assert spent["batch_fill"] < own2 + delay
+    assert hist()["count"] - count0 == turns + 1

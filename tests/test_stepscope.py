@@ -277,6 +277,138 @@ def test_profiler_capture_holds_the_step_and_its_phases(tmp_path):
     assert first[1] - first[0] >= 2e6 and second[1] - second[0] >= 2e6
 
 
+def _turn_with_host_sync(scope, clock, parts: bool):
+    """One turn with a `host_sync` of 1.0 s (0.5 + 0.25 + 0.125 + 0.125),
+    0.25 s of `env_wait` and 0.5 s nobody claims; `parts` says whether the
+    phase is divided."""
+    def part(name):
+        return scope.part(name) if parts else scope.phase("host_sync")
+
+    with scope.step():
+        with scope.phase("env_wait"):
+            clock.advance(0.25)
+        with scope.phase("host_sync"):
+            for name, dt in (("act_wait", 0.5), ("action_readback", 0.25),
+                             ("logits_readback", 0.125),
+                             ("unroll_write", 0.125)):
+                if parts:
+                    with scope.part(name):
+                        clock.advance(dt)
+                else:
+                    clock.advance(dt)
+        clock.advance(0.5)
+
+
+def test_parts_divide_a_phase_and_change_nothing_it_reads(clock, monkeypatch):
+    monkeypatch.setattr(time, "time", lambda: 1_000.0 + clock.t)
+    plain_tel, tel = Telemetry("plain"), Telemetry("t", tracing=True)
+    plain, scope = _scope(telemetry=plain_tel), _scope(telemetry=tel)
+    for _ in range(3):
+        _turn_with_host_sync(plain, clock, parts=False)
+        _turn_with_host_sync(scope, clock, parts=True)
+    want, got = plain.summary(), scope.summary()
+    # The phase, `other`, the class fractions and the closure read as
+    # they do in a scope that never heard of parts.
+    assert want.pop("parts") == {}
+    parts = got.pop("parts")
+    assert got == want
+    assert got["phases"] == {"env_wait": pytest.approx(0.75),
+                             "host_sync": pytest.approx(3.0),
+                             "other": pytest.approx(1.5)}
+    assert sum(got["phases"].values()) == pytest.approx(got["wall_s"])
+    assert got["fractions"]["host_blocked"] == pytest.approx(3.0 / 5.25)
+    divided = tel.snapshot()
+    for sid, series in plain_tel.snapshot().items():
+        if sid.startswith("stepscope_") and "value" in series:
+            assert divided[sid]["value"] == pytest.approx(series["value"])
+    # The parts, under the summary's own key, cover the phase.
+    assert parts == {
+        "host_sync.act_wait": pytest.approx(1.5),
+        "host_sync.action_readback": pytest.approx(0.75),
+        "host_sync.logits_readback": pytest.approx(0.375),
+        "host_sync.unroll_write": pytest.approx(0.375),
+    }
+    assert sum(parts.values()) == pytest.approx(got["phases"]["host_sync"])
+    # ... as a counter labelled by loop, phase and part, from which a
+    # frozen snapshot gives the live summary back.
+    snap = tel.snapshot()
+    series = [sid for sid in snap
+              if sid.startswith("stepscope_part_seconds_total")]
+    assert len(series) == 4
+    for sid in series:
+        assert 'loop="loop"' in sid and 'phase="host_sync"' in sid
+    recon = summarize_stepscope(snap)["loop"]
+    recon.pop("window")
+    assert recon == scope.summary()
+    assert not [sid for sid in plain_tel.snapshot()
+                if sid.startswith("stepscope_part_seconds_total")]
+    _turn_with_host_sync(plain, clock, parts=False)  # a peer of its own
+    merged = merge_summaries({"a": {"loop": scope.summary()},
+                              "b": {"loop": plain.summary()}})["loop"]
+    assert merged["parts"] == parts
+    assert merged["phases"]["host_sync"] == pytest.approx(7.0)
+    # ... and as spans from the same clock readings, each inside the
+    # phase's span, the phase inside the turn's.
+    spans = [s for s in tel.traces.spans() if s.cat == "stepscope"]
+    names = sorted({s.name for s in spans})
+    assert names == [
+        "moolib.loop.env_wait", "moolib.loop.host_sync",
+        "moolib.loop.host_sync.act_wait",
+        "moolib.loop.host_sync.action_readback",
+        "moolib.loop.host_sync.logits_readback",
+        "moolib.loop.host_sync.unroll_write", "moolib.loop.step",
+    ]
+    first = {}
+    for s in sorted(spans, key=lambda s: s.ts):
+        first.setdefault(s.name, s)
+    step, phase = first["moolib.loop.step"], first["moolib.loop.host_sync"]
+    assert step.ts <= phase.ts and phase.ts + phase.dur <= step.ts + step.dur
+    at = phase.ts
+    for name, dur in (("act_wait", 500_000), ("action_readback", 250_000),
+                      ("logits_readback", 125_000),
+                      ("unroll_write", 125_000)):
+        part = first["moolib.loop.host_sync." + name]
+        assert (part.ts, part.dur) == (at, dur)
+        at += dur
+    assert at == phase.ts + phase.dur
+
+
+def test_part_outside_a_phase_or_with_telemetry_off_is_a_noop(clock):
+    tel = Telemetry("t", tracing=True)
+    scope = _scope(telemetry=tel)
+    with scope.part("stray"):  # no step at all
+        clock.advance(1.0)
+    with scope.step():
+        with scope.part("stray"):  # a step, but no phase to divide
+            clock.advance(1.0)
+        with scope.phase("p"):
+            clock.advance(1.0)
+    assert scope.summary()["parts"] == {}
+    assert scope.summary()["phases"] == {"p": pytest.approx(1.0),
+                                         "other": pytest.approx(1.0)}
+    assert "moolib.loop.p.stray" not in {s.name for s in tel.traces.spans()}
+    # Telemetry off when the step was entered: the flip inside it turns
+    # nothing on, for the phase or for its part.
+    tel.set_enabled(False)
+    with scope.step():
+        tel.set_enabled(True)
+        with scope.phase("p"):
+            with scope.part("late"):
+                clock.advance(1.0)
+    assert scope.summary()["steps"] == 1
+    assert scope.summary()["parts"] == {}
+    assert not [sid for sid in tel.snapshot()
+                if sid.startswith("stepscope_part_seconds_total")]
+    # On at entry: the part is there, nested in whichever phase is open.
+    with scope.step():
+        with scope.phase("p"):
+            with scope.phase("q"):
+                with scope.part("late"):
+                    clock.advance(0.5)
+    assert scope.summary()["parts"] == {"q.late": pytest.approx(0.5)}
+    assert scope.summary()["phases"]["q"] == pytest.approx(0.5)
+
+
 def test_gate_off_enters_no_annotation_and_records_no_span(monkeypatch):
     entered = []
 
@@ -505,8 +637,12 @@ def test_acceptance_a2c_cohort_fractions_everywhere():
             continue
         err = abs(sum(s["phases"].values()) - s["wall_s"]) / s["wall_s"]
         assert err <= 0.05, f"{loop}: ledger closure {err:.1%}"
-    # Envpool attribution rode along from the worker tier.
-    assert summaries["envpool"]["fractions"]["env_wait"] > 0.5
+    # Envpool attribution rode along from the worker tier. CartPole
+    # steps in microseconds, so most of a batch's wall is the finished
+    # batch waiting for the learner, which is no starvation.
+    envpool = summaries["envpool"]
+    assert 0.0 < envpool["fractions"]["env_wait"] < 0.5
+    assert envpool["phases"]["ready_idle"] > envpool["phases"]["batch_fill"]
 
     # Flightrec: the frozen bundle carries both the step_phases stamps
     # and enough metrics to reconstruct the fractions after death.
@@ -609,3 +745,50 @@ def test_vtrace_train_names_every_part_of_its_turn(tmp_path):
         assert PHASE_CLASS[name] == "host"
     for name in ("act_dispatch", "grad_dispatch", "apply_dispatch"):
         assert name not in PHASE_CLASS
+
+
+@pytest.mark.integration
+def test_vtrace_train_divides_host_sync_and_times_the_envs(tmp_path):
+    """`host_sync`'s four parts cover it, and the rows carry the envs' own
+    step and the time their batches lay ready, both rising."""
+    from moolib_tpu.examples.vtrace.experiment import VtraceConfig, train
+    from moolib_tpu.telemetry import global_telemetry
+
+    def reading():
+        return summarize_stepscope(global_telemetry().snapshot()).get(
+            "vtrace_learner", {"phases": {}, "parts": {}}
+        )
+
+    before = reading()
+    logs = train(
+        VtraceConfig(
+            env="synthetic", num_actions=4, episode_length=40,
+            total_steps=1_920, actor_batch_size=16, learn_batch_size=16,
+            virtual_batch_size=16, num_actor_processes=2,
+            num_actor_batches=2, unroll_length=4, log_interval_steps=640,
+            stats_interval=1e9, seed=0,
+        ),
+        log_fn=lambda *a, **k: None,
+    )
+    after = reading()
+    parts = {
+        key: secs - before["parts"].get(key, 0.0)
+        for key, secs in after["parts"].items()
+    }
+    assert set(parts) == {
+        "host_sync.act_wait", "host_sync.action_readback",
+        "host_sync.logits_readback", "host_sync.unroll_write",
+    }
+    assert all(secs > 0.0 for secs in parts.values()), parts
+    host_sync = (after["phases"]["host_sync"]
+                 - before["phases"].get("host_sync", 0.0))
+    assert sum(parts.values()) <= host_sync
+    assert sum(parts.values()) >= 0.98 * host_sync, (parts, host_sync)
+    # The wait for the device comes first and is the long one: what the
+    # two readbacks then copy is already computed.
+    assert parts["host_sync.act_wait"] == max(parts.values())
+    assert len(logs) >= 2
+    for name in ("env_step_s", "env_ready_idle_s"):
+        column = [row[name] for row in logs]
+        assert column[0] > 0.0, (name, column)
+        assert all(b > a for a, b in zip(column, column[1:])), (name, column)
