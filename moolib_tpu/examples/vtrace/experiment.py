@@ -38,7 +38,7 @@ from typing import List, Optional
 import numpy as np
 
 import moolib_tpu
-from moolib_tpu.telemetry import StepScope, publish_metrics
+from moolib_tpu.telemetry import StepScope, global_telemetry, publish_metrics
 from moolib_tpu.examples.common import EnvBatchState, StatMean, StatSum, Stats
 from moolib_tpu.examples import common
 from moolib_tpu.examples.common.record import TsvLogger, write_metadata
@@ -466,6 +466,51 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         m["moe_drop_fraction"]
                     )
 
+    # One act call may be in flight: (batch, action, logits, core state),
+    # dispatched, both copies out asked for, nothing of it read yet. The
+    # turn dispatches the next batch's call before it comes back for this
+    # one, so the chip runs one batch's act step while the host stages
+    # the other's frame (PERF.md section 5, atari_loop).
+    in_flight = None
+    act_calls = {
+        overlapped: global_telemetry().registry.counter(
+            "vtrace_act_calls_total", overlapped=overlapped
+        )
+        for overlapped in ("0", "1")
+    }
+
+    def finish_act(call, overlapped: str):
+        """Wait for an act call, write its actions and logits into row t
+        of the batch's window and submit the batch's envs. ``overlapped``
+        is "1" when another batch's call was dispatched meanwhile."""
+        nonlocal env_steps
+        i, a, logits, core = call
+        bs = batch_states[i]
+        with scope.phase("host_sync"):
+            # Four parts, to say what the thread waits for: the device
+            # (the copy in, the act step, whatever is queued ahead of
+            # it), each copy out, the slab.
+            with scope.part("act_wait"):
+                jax.block_until_ready(a)  # hotlint: sync -- actions must reach the host NOW to feed the envpool slab: the Sebulba actor-loop boundary, not a stray sync
+            with scope.part("action_readback"):
+                a = np.asarray(a)  # hotlint: sync -- the action's copy out, asked for at dispatch, the act step already done
+            with scope.part("logits_readback"):
+                logits = np.asarray(logits)  # hotlint: sync -- behavior logits ride the host-side unroll buffer with the action that produced them
+            with scope.part("unroll_write"):
+                bs.record_action(a, logits, core)
+        # Only now, its own act step done: the copy in of the batch's
+        # frame (jnp.asarray may alias the EnvPool's view) is consumed
+        # before a worker writes the next frame over it.
+        with scope.phase("env_submit"):
+            actions[i][:] = a
+            futures[i] = pool.step(i, actions[i])
+        act_calls[overlapped].inc()
+        env_steps += cfg.actor_batch_size
+        stats["env_steps"] += cfg.actor_batch_size
+        for r in bs.recent_returns():
+            stats["episode_returns"] += r
+            window["episode_returns"] += r
+
     next_log = cfg.log_interval_steps
     last_stats_enqueue = 0.0
     t_start = time.monotonic()
@@ -478,7 +523,7 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
             or time.monotonic() - t_start < cfg.max_seconds
         ):
           with scope.step():
-            # -- acting (double-buffered) -----------------------------------
+            # -- acting (double-buffered, one act call in flight) -----------
             for i in range(cfg.num_actor_batches):
                 # Bounded wait: a dead env worker must surface as an
                 # error, not hang the acting loop forever. WorkerDied is
@@ -521,32 +566,25 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         state.params, act_rng, obs_now, done_now,
                         bs.core_state,
                     )
-                with scope.phase("host_sync"):
-                    # Four parts, to say what the thread waits for: the
-                    # device (the copy in, the act step, whatever is
-                    # queued ahead of it), each copy out, the slab.
-                    with scope.part("act_wait"):
-                        # Asked for before the wait, as np.asarray on a
-                        # pending array asks for it: the copy out then
-                        # follows the act step with no trip to the host
-                        # between them (to wait first and copy after
-                        # cost 2.9% of the loop's rate: PERF.md, PR 37).
-                        a.copy_to_host_async()
-                        jax.block_until_ready(a)  # hotlint: sync -- actions must reach the host NOW to feed the envpool slab: the Sebulba actor-loop boundary, not a stray sync
-                    with scope.part("action_readback"):
-                        a = np.asarray(a)  # hotlint: sync -- the action's copy out, the act step already done
-                    with scope.part("logits_readback"):
-                        logits = np.asarray(logits)  # hotlint: sync -- behavior logits ride the host-side unroll buffer with the action that produced them
-                    with scope.part("unroll_write"):
-                        bs.record_action(a, logits, core)
-                with scope.phase("env_submit"):
-                    actions[i][:] = a
-                    futures[i] = pool.step(i, actions[i])
-                env_steps += cfg.actor_batch_size
-                stats["env_steps"] += cfg.actor_batch_size
-                for r in bs.recent_returns():
-                    stats["episode_returns"] += r
-                    window["episode_returns"] += r
+                    # Both copies out asked for at once, as np.asarray on
+                    # a pending array asks for one: they follow the act
+                    # step with no trip to the host between them (to wait
+                    # first and copy after cost 2.9% of the loop's rate:
+                    # PERF.md, PR 37), and are one round trip, not two.
+                    a.copy_to_host_async()
+                    logits.copy_to_host_async()
+                # The other batch's call, dispatched before this one: the
+                # chip ran it while this batch's frame was staged.
+                if in_flight is not None:
+                    finish_act(in_flight, "1")
+                in_flight = (i, a, logits, core)
+                # Never wait for the envs of a batch whose act call is
+                # still in flight, they have not been submitted: where the
+                # next batch to wait for is this one there is nothing to
+                # overlap with, and the call is finished at once.
+                if (i + 1) % cfg.num_actor_batches == i:
+                    finish_act(in_flight, "0")
+                    in_flight = None
 
             # -- learning (Accumulator-driven) ------------------------------
             with scope.phase("acc_update"):
@@ -622,7 +660,16 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                             "config": dataclasses.asdict(cfg),
                         }
                     )
-            if env_steps >= next_log:
+            # A row is due by the act calls made, the one in flight with
+            # them: the rows then come in the turns they came in while a
+            # turn ended with every batch submitted, not a turn later (in
+            # a loop whose rows and updates are as many env steps apart
+            # that is the turn that has just dispatched a gradient step,
+            # and the drain below would wait for it).
+            acted = env_steps + (
+                cfg.actor_batch_size if in_flight is not None else 0
+            )
+            if acted >= next_log:
                 next_log += cfg.log_interval_steps
                 drain_metrics()
                 with scope.phase("log"):
