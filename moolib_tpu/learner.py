@@ -61,7 +61,8 @@ class ImpalaConfig:
     moe_lb_cost: float = 0.01
     moe_z_cost: float = 0.001
     # Weight of a model's own multi-token-prediction cross-entropy, where
-    # its aux carries one (DecoderLM with ``mtp``).
+    # its aux carries one (DecoderLM with ``mtp``, or with further
+    # prediction heads, ``num_pred_heads``).
     mtp_cost: float = 0.1
 
 
@@ -112,8 +113,8 @@ def impala_loss(
     capacity-factor MoE's, from
     :func:`moolib_tpu.models.transformer.moe_aux_losses`) they are folded
     into the total with ``config.moe_lb_cost`` / ``config.moe_z_cost``;
-    a multi-token-prediction module's ``mtp_loss`` with
-    ``config.mtp_cost``; ``drop_fraction`` is surfaced so capacity drops
+    a multi-token-prediction module's or the further prediction heads'
+    ``mtp_loss`` with ``config.mtp_cost``; ``drop_fraction`` is surfaced so capacity drops
     are visible in training logs; every other entry (the dropless layer's ``moe_*`` counters, from
     :func:`moolib_tpu.models.lm.learn_apply`) passes through to the
     metrics as a counter.

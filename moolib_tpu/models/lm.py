@@ -47,6 +47,49 @@ sizes apart. This is the decompressed form a training forward computes;
 the absorbed form with one key head belongs to a cache, which the repo has
 none of.
 
+With ``eva`` (chunk-summary attention, EVA as EvaByte ships it; dense
+projections and the rotary as the first kind has them) the kind's
+``window`` W is a *block* of positions and ``chunk_size`` c the length of
+a chunk; ``phi`` and ``mu`` are ``[D]`` a key/value head, learned:
+
+    chunk j  = positions [c j, c j + c - 1];  P_j = those of them in the
+               episode of the chunk's last position
+    a_{j,s}  = softmax over s in P_j of (k_s . phi) D^-1/2
+    kt_j     = sum_s a_{j,s} k_s + mu;      vt_j = sum_s a_{j,s} v_s
+    L_t      = {s: floor(s/W) = floor(t/W), s <= t, episode(s) = episode(t)}
+    R_t      = {j: floor(c j / W) < floor(t/W),
+                   episode(c j + c - 1) = episode(t)}
+    o_t      = ONE softmax over the scores q_t . k_s (s in L_t) and
+               q_t . kt_j (j in R_t), applied to the v_s and the vt_j
+
+a query reads its own window exactly and causally, and every earlier
+window through one summary key and value a chunk. The pooling
+(:func:`eva_summaries`) runs under the scope ``moolib.lm.eva_summary``;
+the two key sets are two calls of the one attention call site, both
+under ``moolib.lm.attn_core`` with their row statistics as outputs
+(``return_lse``), the local one causal with (episode, window) as its
+segment id (and W as its reach, which hides nothing more and lets the
+kernels walk a window's key blocks only), the summaries' with the ids as
+group and rank
+(``rank_bits``: an equal episode and an earlier window), so the flash
+kernels skip every tile outside a window and every summary tile of a
+window not yet past; ``ops.attention.merge_attention`` then makes the
+one softmax of the two results, exactly, under ``moolib.lm.eva_merge``.
+No ``[T, T]`` or ``[T, T / c]`` array is built on the flash path.
+:func:`eva_pair_counts` counts what a block reads (``eva_local_pairs``,
+``eva_summary_pairs``, ``eva_chunks_cut``, every block's, in the step's
+metrics).
+
+``norm_unit_offset``: every RMS norm's gain is ``1 + scale``, its
+parameter starting at 0. ``num_pred_heads`` n > 1: the head is ``hidden ->
+n x vocab``, head-major; head 0's columns are the policy's logits and head
+``i`` of position ``t`` is asked for token ``t + 1 + i``: the further
+heads' cross-entropy, over the (position, head) pairs whose token lies in
+the position's episode, is sown as ``mtp_loss`` with its count
+``mtp_positions``, the seam a multi-token-prediction module feeds (a
+model has one or the other). A stack needs no sparse layer: the expert
+layers' counters are then absent from the step's metrics.
+
 MLP kinds. ``sparse``: gated experts through
 :func:`moolib_tpu.parallel.moe.moe_dropless`, scored and chosen as
 ``router`` says (softmax top-k; or sigmoid scores, a selection bias that
@@ -86,7 +129,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import math
-from typing import Callable, Optional, Tuple
+from typing import Callable, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -103,6 +146,7 @@ from .transformer import (attend, hyper_coefficients, hyper_read,
 __all__ = [
     "AttentionKind",
     "DecoderLM",
+    "Eva",
     "Latent",
     "Residual",
     "Rope",
@@ -145,10 +189,21 @@ class Latent:
 
 
 @dataclasses.dataclass(frozen=True)
+class Eva:
+    """Chunk-summary attention (EVA as EvaByte ships it): the kind's
+    ``window`` is then a *block* of positions and not a sliding reach, and
+    every ``chunk_size`` positions have one learned summary key and
+    value."""
+
+    chunk_size: int
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionKind:
     window: Optional[int]  # None: full causal attention
     rope: Rope
     latent: Optional[Latent] = None  # None: three dense projections
+    eva: Optional[Eva] = None  # windows as blocks, read through summaries
 
 
 @dataclasses.dataclass(frozen=True)
@@ -231,12 +286,21 @@ def _dense(name: str, width: int, dtype):
 
 
 class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * scale``; with ``unit_offset`` the
+    parameter is the gain's distance from 1, ``* (1 + scale)``, and starts
+    at 0."""
+
     eps: float
     dtype: jnp.dtype
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        init = nn.initializers.zeros if self.unit_offset else (
+            nn.initializers.ones)
+        scale = self.param("scale", init, (x.shape[-1],))
+        if self.unit_offset:
+            scale = 1.0 + scale
         x32 = x.astype(jnp.float32)
         x32 = x32 * jax.lax.rsqrt(
             jnp.mean(x32 * x32, axis=-1, keepdims=True) + self.eps
@@ -281,6 +345,132 @@ class _Attention(nn.Module):
             return _dense("o", x.shape[-1], self.dtype)(o)
 
 
+def eva_ids(seg_bt, T: int, window: int, chunk: int):
+    """The ids both calls of chunk-summary attention mask by, as
+    ``group << bits | rank`` with group = episode and rank = window
+    (:mod:`moolib_tpu.ops.attention`, ``rank_bits``): ``ids_q`` [B, T] of
+    the positions, ``ids_k`` [B, ceil(T / chunk)] of the chunks (the
+    episode of a chunk's last position, the window it lies in), ``own``
+    [B, chunks, chunk] the positions of a chunk that are of its last
+    position's episode, and ``bits``. Equal ``ids_q`` is "my episode and my
+    window"; an ``ids_k`` of equal group and lower rank "a chunk of my
+    episode in an earlier window". Positions past ``T`` (a last chunk cut
+    short) are of no chunk."""
+    n = -(-T // chunk)
+    bits = max(1, (-(-T // window)).bit_length())
+    pos = jnp.arange(n * chunk)
+    seg = jnp.pad(seg_bt, ((0, 0), (0, n * chunk - T)), mode="edge")
+    ids_q = (seg_bt << bits) | (pos[:T] // window)
+    seg_c = seg.reshape(-1, n, chunk)
+    ids_k = (seg_c[:, :, -1] << bits) | (pos[::chunk] // window)
+    own = jnp.logical_and(
+        seg_c == seg_c[:, :, -1:], (pos < T).reshape(n, chunk)
+    )
+    return ids_q, ids_k, own, bits
+
+
+def eva_summaries(k, v, own, phi, mu):
+    """One summary key and value a chunk. ``k`` [B, H, T, D], ``v`` [B, H,
+    T, Dv], ``own`` [B, n, c] (:func:`eva_ids`), ``phi`` and ``mu`` [H, D]:
+
+        a_s = softmax over the chunk's own positions s of (k_s . phi) D^-1/2
+        kt  = sum_s a_s k_s + mu;    vt = sum_s a_s v_s
+
+    in float32, returned [B, H, n, D] and [B, H, n, Dv] in the dtypes that
+    came. Written as multiplies and sums over the chunk, not as products:
+    a chunk is 16 rows, and XLA fuses each into one pass over ``k`` or
+    ``v``."""
+    B, H, T, D = k.shape
+    n, c = own.shape[1:]
+
+    def chunks(x):
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, n * c - T), (0, 0)))
+        return x.astype(jnp.float32).reshape(B, H, n, c, x.shape[-1])
+
+    kc, vc = chunks(k), chunks(v)
+    s = jnp.sum(kc * phi.astype(jnp.float32)[None, :, None, None], axis=-1)
+    a = jax.nn.softmax(
+        jnp.where(own[:, None], s * D ** -0.5, -jnp.inf), axis=-1
+    )[..., None]
+    kt = jnp.sum(a * kc, axis=-2) + mu.astype(jnp.float32)[None, :, None]
+    return kt.astype(k.dtype), jnp.sum(a * vc, axis=-2).astype(v.dtype)
+
+
+def eva_pair_counts(seg_bt, T: int, window: int, chunk: int) -> dict:
+    """What one block of chunk-summary attention reads, counted from the
+    episode boundaries, int32: ``eva_local_pairs`` (query, key) pairs of
+    one episode and window with the key not after the query,
+    ``eva_summary_pairs`` (query, chunk) pairs with the chunk of the
+    query's episode and an earlier window, ``eva_chunks_cut`` chunks that
+    straddle a boundary."""
+    ids_q, ids_k, own, bits = eva_ids(seg_bt, T, window, chunk)
+    first = jax.vmap(lambda a, x: jnp.searchsorted(a, x, side="left"))
+    local = jnp.arange(T) - first(ids_q, ids_q) + 1
+    group = (ids_q >> bits) << bits
+    summaries = first(ids_k, ids_q) - first(ids_k, group)
+    whole = (jnp.arange(own.shape[1]) + 1) * chunk <= T
+    return {
+        "eva_local_pairs": jnp.sum(local),
+        "eva_summary_pairs": jnp.sum(summaries),
+        "eva_chunks_cut": jnp.sum(jnp.logical_and(~own[:, :, 0], whole)),
+    }
+
+
+class _EvaAttention(nn.Module):
+    """Chunk-summary attention; see the module docstring. Two calls of the
+    one attention call site, merged by their row statistics."""
+
+    kind: AttentionKind
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    backend: str
+    block: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x, seg_bt, positions):
+        T, B, _ = x.shape
+        H, Hkv, D = self.num_heads, self.num_kv_heads, self.head_dim
+
+        def proj(name, heads):
+            return _dense(name, heads * D, self.dtype)(x).reshape(
+                T, B, heads, D
+            )
+
+        phi = self.param("phi", nn.initializers.normal(D ** -0.5), (Hkv, D))
+        mu = self.param("mu", nn.initializers.zeros, (Hkv, D))
+        with jax.named_scope("moolib.lm.attn_proj"):
+            cos, sin = _rotary_tables(self.kind.rope, positions, D)
+            q = _rotary(proj("q", H), cos, sin)
+            k = _rotary(proj("k", Hkv), cos, sin)
+            v = proj("v", Hkv)
+            q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
+        with jax.named_scope("moolib.lm.eva_summary"):
+            ids_q, ids_k, own, bits = eva_ids(
+                seg_bt, T, self.kind.window, self.kind.eva.chunk_size
+            )
+            kt, vt = eva_summaries(k, v, own, phi, mu)
+        blocks = dict(
+            backend=self.backend, block_q=self.block, block_k=self.block,
+            return_lse=True,
+        )
+        with jax.named_scope("moolib.lm.attn_core"):
+            # a key of the query's own window lies less than a window
+            # back: saying so changes no mask, and the flash kernels then
+            # walk the key blocks a window can reach and not the sequence
+            local = attend(q, k, v, ids_q, window=self.kind.window, **blocks)
+            earlier = attend(
+                q, kt, vt, ids_q, kv_seg_bt=ids_k, causal=False,
+                rank_bits=bits, **blocks,
+            )
+        with jax.named_scope("moolib.lm.eva_merge"):
+            o = attn_ops.merge_attention(*local, *earlier)
+        with jax.named_scope("moolib.lm.attn_proj"):
+            o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
+            return _dense("o", x.shape[-1], self.dtype)(o)
+
+
 class _LatentAttention(nn.Module):
     """Latent attention in its decompressed form; see the module
     docstring. The core runs at a query/key head of ``nope + rope`` and a
@@ -292,6 +482,7 @@ class _LatentAttention(nn.Module):
     block: int
     eps: float
     dtype: jnp.dtype
+    norm_unit_offset: bool = False
 
     @nn.compact
     def __call__(self, x, seg_bt, positions):
@@ -304,7 +495,9 @@ class _LatentAttention(nn.Module):
             return _dense(name, width, self.dtype)
 
         def norm(name):
-            return RMSNorm(self.eps, self.dtype, name=name)
+            return RMSNorm(
+                self.eps, self.dtype, self.norm_unit_offset, name=name
+            )
 
         with jax.named_scope("moolib.lm.mla_proj"):
             c_q = norm("q_a_norm")(dense("q_a", lat.q_lora_rank)(x))
@@ -423,6 +616,13 @@ class _Sizes:
     shared_expert_size: Optional[int]
     intermediate_size: Optional[int]
     residual: Optional[Residual] = None
+    norm_unit_offset: bool = False
+
+    def norm(self, name: str) -> RMSNorm:
+        return RMSNorm(
+            self.rms_norm_eps, self.compute_dtype, self.norm_unit_offset,
+            name=name,
+        )
 
 
 class _HyperMix(nn.Module):
@@ -472,11 +672,19 @@ class _Block(nn.Module):
     @nn.compact
     def __call__(self, x, seg_bt, positions):
         net = self.net
-
-        def norm(name):
-            return RMSNorm(net.rms_norm_eps, net.compute_dtype, name=name)
-
-        if self.kind.latent is None:
+        norm = net.norm
+        if self.kind.eva is not None:
+            if self.kind.latent is not None or self.kind.window is None:
+                raise ValueError(
+                    "chunk-summary attention has dense projections and a "
+                    "window"
+                )
+            attention = _EvaAttention(
+                self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
+                net.attention_backend, net.attention_block,
+                net.compute_dtype, name="attn",
+            )
+        elif self.kind.latent is None:
             attention = _Attention(
                 self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
                 net.attention_backend, net.attention_block,
@@ -486,7 +694,7 @@ class _Block(nn.Module):
             attention = _LatentAttention(
                 self.kind, net.num_heads, net.attention_backend,
                 net.attention_block, net.rms_norm_eps, net.compute_dtype,
-                name="attn",
+                net.norm_unit_offset, name="attn",
             )
         if self.mlp == "sparse":
             mlp = _SparseMlp(
@@ -522,19 +730,27 @@ class _Block(nn.Module):
 
 
 def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
-            remat: bool, name: str):
+            remat, name: str):
     """One block, or ``repeat`` of them as one scan over parameters
-    stacked on a leading axis; with ``remat`` each block is rebuilt in the
-    backward pass from its input and, where its attention ran the flash
-    kernels, its attention core's output and row statistics (what the
-    forward kernel alone can make, so the rebuild runs no such kernel):
-    nothing else is kept. ``(x, seg_bt, positions) -> x``, with ``x`` the
+    stacked on a leading axis. ``remat`` is the model's ``remat_blocks``,
+    the one decision of what a block keeps for the backward pass: false,
+    everything; ``"cores"`` (or true), its input and, where its attention
+    ran the flash kernels, its attention cores' outputs and row statistics
+    (what the forward kernel alone can make, so the rebuild runs no such
+    kernel); ``"input"``, its input alone, and the rebuild runs the
+    forward kernels again. ``(x, seg_bt, positions) -> x``, with ``x`` the
     skeleton's carry: one stream ``[T, B, d]`` or several ``[n, T, B,
     d]``."""
     cls, traced = _Block, contextlib.nullcontext
-    if remat:
+    if remat == "input":
+        cls = nn.remat(_Block, prevent_cse=False)
+    elif remat in (True, "cores"):
         cls = nn.remat(_Block, prevent_cse=False, policy=attn_ops.KEEP_CORES)
         traced = attn_ops.keeping_cores
+    elif remat:
+        raise ValueError(
+            f"remat_blocks is false, 'cores' (or true) or 'input': {remat!r}"
+        )
     if repeat == 1:
         block = cls(kind, mlp, sizes, name=name)
     else:
@@ -561,17 +777,14 @@ class _Mtp(nn.Module):
     kind: AttentionKind
     mlp: str
     net: _Sizes
-    remat: bool
+    remat: Union[bool, str]
     loss_rows: int
 
     @nn.compact
     def __call__(self, hidden, embedded, obs, seg_bt, positions, head_kernel):
         net = self.net
         T, B, d = hidden.shape
-
-        def norm(name):
-            return RMSNorm(net.rms_norm_eps, net.compute_dtype, name=name)
-
+        norm = net.norm
         # Position t reads token t+1's embedding and is asked for token
         # t+2; the last two positions have no such token and are masked.
         u = _dense("eh_proj", d, net.compute_dtype)(jnp.concatenate(
@@ -612,6 +825,32 @@ class _Mtp(nn.Module):
         return total / jnp.maximum(count, 1.0), count
 
 
+def _further_heads_nll(logits, obs, seg) -> dict:
+    """The cross-entropy of the prediction heads after the policy's.
+    ``logits`` [T, B, n, V] float32, head ``i`` of position ``t`` asked for
+    ``obs[t + 2 + i]``; a position counts for a head if that token exists
+    and lies in its episode (``seg`` [T, B] never decreases, so then every
+    token between does). The mean over every (position, head) that counts,
+    and their number."""
+    T, n = logits.shape[0], logits.shape[2]
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    total = count = 0.0
+    for i in range(n):
+        ahead = i + 2
+        valid = jnp.logical_and(
+            jnp.roll(seg, -ahead, axis=0) == seg,
+            (jnp.arange(T) < T - ahead)[:, None],
+        )
+        nll = -jnp.take_along_axis(
+            logp[:, :, i], jnp.roll(obs, -ahead, axis=0)[..., None], axis=-1
+        )[..., 0]
+        total = total + jnp.sum(jnp.where(valid, nll, 0.0))
+        count = count + jnp.sum(valid).astype(jnp.float32)
+    return {
+        "mtp_loss": total / jnp.maximum(count, 1.0), "mtp_positions": count,
+    }
+
+
 class DecoderLM(nn.Module):
     """Causal, segment-masked decoder over token ids; see the module
     docstring. Build it from a configuration's JSON with
@@ -646,12 +885,20 @@ class DecoderLM(nn.Module):
     # pass, so no second logits array is ever held.
     mtp: Optional[Tuple[str, str]] = None
     mtp_loss_rows: int = 1024
-    # Every block rebuilt in the backward pass from what it keeps: its
-    # input and, where the flash kernels ran it, its attention core's
-    # output and row statistics.
-    remat_blocks: bool = False
+    # What a block keeps for the backward pass: false, everything; "cores"
+    # (or true), its input and, where the flash kernels ran it, its
+    # attention cores' outputs and row statistics (a block's worth of
+    # ``[B, H, T, Dv]`` a call), everything else rebuilt; "input", its
+    # input alone, and the rebuild runs the forward kernels again.
+    remat_blocks: Union[bool, str] = False
     # The residual skeleton: None is one stream and ``x + F(norm(x))``.
     residual: Optional[Residual] = None
+    # Every RMS norm's gain as ``1 + scale``.
+    norm_unit_offset: bool = False
+    # The head's width in vocabularies: head ``i`` of position ``t`` is
+    # asked for token ``t + 1 + i``. Head 0 is the policy; the others'
+    # cross-entropy is the loss's ``mtp_loss`` term.
+    num_pred_heads: int = 1
 
     def _sizes(self) -> _Sizes:
         return _Sizes(
@@ -661,6 +908,7 @@ class DecoderLM(nn.Module):
             self.rms_norm_eps, jnp.dtype(self.compute_dtype),
             self.attention_backend, self.attention_block, self.router,
             self.shared_expert_size, self.intermediate_size, self.residual,
+            self.norm_unit_offset,
         )
 
     @nn.compact
@@ -693,16 +941,40 @@ class DecoderLM(nn.Module):
             x = jnp.sum(x.astype(jnp.float32), axis=0).astype(
                 self.compute_dtype
             )
-        head = _dense("head", self.vocab_size, self.compute_dtype)
+        heads = self.num_pred_heads
+        if heads > 1 and self.mtp is not None:
+            raise ValueError(
+                "further prediction heads beside a multi-token-prediction "
+                "module: the loss has one such term"
+            )
+        head = _dense("head", heads * self.vocab_size, self.compute_dtype)
         hidden = x
         with jax.named_scope("moolib.lm.head"):
-            x = RMSNorm(
-                self.rms_norm_eps, self.compute_dtype, name="final_norm"
-            )(x)
+            x = sizes.norm("final_norm")(x)
             logits = head(x).astype(jnp.float32)
             baseline = nn.Dense(1, name="baseline")(
                 x.astype(jnp.float32)
             ).squeeze(-1)
+            if heads > 1:
+                # head-major columns: the first vocabulary is the policy
+                logits = logits.reshape(T, -1, heads, self.vocab_size)
+                self.sow("intermediates", "mtp_terms", _further_heads_nll(
+                    logits[:, :, 1:], obs, seg_bt.T
+                ))
+                logits = logits[:, :, 0]
+        eva_counters: dict = {}
+        for attention, _, *repeat in self.layers:
+            kind = kinds[attention]
+            if kind.eva is not None:
+                # every block of the entry reads the same pairs
+                for name, value in eva_pair_counts(
+                    seg_bt, T, kind.window, kind.eva.chunk_size
+                ).items():
+                    eva_counters[name] = eva_counters.get(name, 0) + (
+                        (repeat or [1])[0] * value
+                    )
+        if eva_counters:
+            self.sow("intermediates", "eva_counters", eva_counters)
         if self.mtp is not None:
             with jax.named_scope("moolib.lm.mtp"):
                 loss, count = _Mtp(
@@ -727,9 +999,10 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
     of ``{"attention": kind, "mlp": "sparse" | "dense"}``, an entry with
     ``"repeat": n`` standing for ``n`` identical blocks run as a scan;
     ``attention_kinds`` a mapping ``kind -> {"window": int or null,
-    "rope": {...}, "latent": {...} or absent}`` whose ``rope`` holds the
-    fields of :class:`Rope` and whose ``latent`` those of
-    :class:`Latent`; ``router`` the fields of :class:`Router`; ``mtp`` the
+    "rope": {...}, "latent": {...} or absent, "eva": {...} or absent}``
+    whose ``rope`` holds the fields of :class:`Rope`, whose ``latent``
+    those of :class:`Latent` and whose ``eva`` those of :class:`Eva`;
+    ``router`` the fields of :class:`Router`; ``mtp`` the
     multi-token-prediction module's block, an entry like one of
     ``layers``; ``residual`` the fields of :class:`Residual` (absent: the
     skeleton with one stream)."""
@@ -737,6 +1010,7 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
         (name, AttentionKind(
             spec.get("window"), Rope(**spec["rope"]),
             Latent(**spec["latent"]) if spec.get("latent") else None,
+            Eva(**spec["eva"]) if spec.get("eva") else None,
         ))
         for name, spec in sorted(attention_kinds.items())
     )
@@ -773,8 +1047,9 @@ def _sum_counters(intermediates) -> dict:
     for name in ("moe_load_max", "moe_load_mean"):
         if name in total:
             total[name] = total[name] / layers
-    for sown in sown_dicts(intermediates, "mtp_loss"):
-        total.update(sown)
+    for name in ("mtp_loss", "eva_local_pairs"):
+        for sown in sown_dicts(intermediates, name):
+            total.update(sown)
     # the stream mixing's, a dict a sublayer: the worst gap, every entry
     for sown in sown_dicts(intermediates, "hc_res_clamped"):
         for name, value in sown.items():

@@ -62,20 +62,30 @@ def segment_ids_from_done(done) -> jax.Array:
 
 def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
            window: Optional[int] = None, scale: Optional[float] = None,
-           **blocks):
+           kv_seg_bt=None, causal: bool = True, rank_bits=None,
+           return_lse: bool = False, **blocks):
     """The one attention call site of the models: causal, cut at segment
     boundaries, ``[B, H, T, D]`` in and ``[B, H, T, Dv]`` out (``k``/``v``
     may carry fewer heads, ``v`` another head size). ``ring`` / ``zigzag``
     run across the ``ring_axis`` mesh axis inside shard_map; everything
     else is :func:`attn_ops.attention` and what its ``backend`` resolves
     to. ``scale``: of the scores, where it is not ``D ** -0.5``.
-    ``blocks``: ``block_q``/``block_k`` where the caller sets them."""
+    ``kv_seg_bt`` / ``causal=False``: a second key set with ids of its own
+    and no positions (chunk summaries); ``rank_bits``: how many low bits of
+    both sets' ids are a rank (a key is seen where the high bits, the
+    group, equal the query's and its rank is strictly lower, as
+    :mod:`attn_ops` has it); ``return_lse``: ``(o, lse)`` and
+    not ``o``, the row statistics differentiable. ``blocks``: ``block_q``
+    / ``block_k`` where the caller sets them."""
     if backend in ("ring", "zigzag"):
-        if (window is not None or scale is not None
+        if (window is not None or scale is not None or not causal
+                or kv_seg_bt is not None or rank_bits is not None
+                or return_lse
                 or k.shape[1] != q.shape[1] or v.shape[-1] != q.shape[-1]):
             raise ValueError(
                 f"the {backend} backend has no window, no grouped heads, "
-                "one head size and one score scale"
+                "one head size, one score scale, one causal key set and "
+                "no row statistics"
             )
         if backend == "ring":
             return ring_ops.ring_attention(
@@ -91,8 +101,14 @@ def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
         )
     if scale is not None:
         blocks["scale"] = scale
+    if kv_seg_bt is not None:
+        blocks["kv_segment_ids"] = kv_seg_bt
+    if rank_bits is not None:
+        blocks["rank_bits"] = rank_bits
+    if return_lse:
+        blocks["return_lse"] = True
     return attn_ops.attention(
-        q, k, v, backend=backend, causal=True, segment_ids=seg_bt,
+        q, k, v, backend=backend, causal=causal, segment_ids=seg_bt,
         window=window, **blocks,
     )
 

@@ -35,6 +35,31 @@ The value head may be narrower or wider than the query/key head: ``q`` and
 ``k`` are ``[.., T, D]``, ``v`` and the output ``[.., T, Dv]`` (latent
 attention with a rotary part on its queries and keys only). ``scale``
 multiplies the scores; None is ``D ** -0.5``.
+
+**One softmax over two key sets** (chunk-summary attention: a query reads
+the positions of its own window and, of every earlier window, one summary
+key and value a chunk). Two things make it two calls of what is here:
+
+- ``return_lse=True``: a call returns ``(out, lse)``, ``lse`` [B, H, Tq]
+  float32 the log of the row's sum of ``e^score`` over the keys it saw
+  (-1e30 and a row of zeros where it saw none), *differentiable*: since
+  ``d lse_i / d s_ij = p_ij``, its cotangent is taken off the backward
+  pass's ``delta`` and the flash kernels are the same three.
+  :func:`merge_attention` then gives ``(o1 e^lse1 + o2 e^lse2) / (e^lse1 +
+  e^lse2)``, the one softmax over both sets, exactly. Where nothing asks
+  for ``lse`` a flash call traces the program it always traced: that is
+  why ``_flash_attention_lse`` is a second ``custom_vjp`` beside
+  ``_flash_attention`` over the same three kernels, and the two change
+  together (an operand, a tile rule or a residual added to one's forward
+  and backward goes into the other's).
+- ``rank_bits=b`` (with ``causal=False`` and both id arrays): an id is
+  two numbers, ``group << b | rank``, and a query sees a key iff the
+  groups are equal and the key's rank is *strictly lower* than its own:
+  group = episode, rank = window says "the summaries of my episode's
+  earlier windows". The flash kernels skip a tile whose blocks' groups do
+  not meet or whose least key rank is not under the largest query rank
+  (ids that never decrease along an axis, in group or in rank), so the
+  summaries of windows not yet past cost nothing.
 """
 
 from __future__ import annotations
@@ -57,6 +82,7 @@ __all__ = [
     "dense_attention",
     "blockwise_attention",
     "flash_attention",
+    "merge_attention",
     "resolve_backend",
     "attention",
     "KEEP_CORES",
@@ -92,8 +118,19 @@ def _group_heads(q, k):
     return q.reshape(B, Hkv, H // Hkv, Tq, D)
 
 
+def _same_segment(seg_q, seg_k, rank_bits=None):
+    """Which (query, key) pairs the ids allow, broadcasting ``seg_q``
+    against ``seg_k``: equal ids, or, with ``rank_bits``, an equal group
+    and a key of strictly lower rank (see the module docstring)."""
+    if rank_bits is None:
+        return seg_q == seg_k
+    low = (1 << rank_bits) - 1
+    same = (seg_q >> rank_bits) == (seg_k >> rank_bits)
+    return jnp.logical_and(same, (seg_k & low) < (seg_q & low))
+
+
 def _mask_bias(Tq: int, Tk: int, causal: bool, seg_q, seg_k, q_offset=0,
-               window=None):
+               window=None, rank_bits=None):
     """[.., Tq, Tk] additive bias: 0 where allowed, -inf where masked.
 
     ``q_offset`` is the absolute position of q row 0 relative to k row 0
@@ -108,10 +145,22 @@ def _mask_bias(Tq: int, Tk: int, causal: bool, seg_q, seg_k, q_offset=0,
             seen = jnp.logical_and(seen, qpos - kpos < window)
         bias = jnp.where(seen, 0.0, _NEG_INF)
     if seg_q is not None:
-        same = seg_q[..., :, None] == seg_k[..., None, :]
+        same = _same_segment(
+            seg_q[..., :, None], seg_k[..., None, :], rank_bits
+        )
         seg_bias = jnp.where(same, 0.0, _NEG_INF)
         bias = seg_bias if bias is None else bias + seg_bias
     return bias
+
+
+def _check_ranks(rank_bits, causal: bool, segment_ids, kv_segment_ids):
+    if rank_bits is not None and (
+        causal or segment_ids is None or kv_segment_ids is None
+    ):
+        raise ValueError(
+            "rank_bits orders keys by their ids' low bits: it needs "
+            "segment_ids, kv_segment_ids and causal=False"
+        )
 
 
 def dense_attention(
@@ -123,11 +172,15 @@ def dense_attention(
     kv_segment_ids: Optional[jax.Array] = None,
     window: Optional[int] = None,
     scale: Optional[float] = None,
+    rank_bits: Optional[int] = None,
+    return_lse: bool = False,
 ):
     """Oracle attention. q [B, H, Tq, D], k [B, Hkv, Tk, D], v
     [B, Hkv, Tk, Dv], segment_ids [B, Tq] / kv_segment_ids [B, Tk]
-    (defaults to segment_ids)."""
+    (defaults to segment_ids). ``rank_bits``, ``return_lse``: the module
+    docstring."""
     _check_window(window, causal)
+    _check_ranks(rank_bits, causal, segment_ids, kv_segment_ids)
     shape = q.shape[:-1] + v.shape[-1:]
     q = _group_heads(_scale(q.astype(jnp.float32), scale), k)
     k = k.astype(jnp.float32)
@@ -138,10 +191,21 @@ def dense_attention(
         seg_q = segment_ids[:, None, None, :]  # [B, 1, 1, Tq]
         seg_k = kv_seg[:, None, None, :]
     bias = _mask_bias(
-        q.shape[-2], k.shape[-2], causal, seg_q, seg_k, window=window
+        q.shape[-2], k.shape[-2], causal, seg_q, seg_k, window=window,
+        rank_bits=rank_bits,
     )
     if bias is not None:
         scores = scores + bias
+    if return_lse:
+        # a row that sees no key: zeros and the statistic of an empty set
+        seen = jnp.max(scores, axis=-1) > _NEG_INF / 2
+        lse = jnp.where(
+            seen, jax.nn.logsumexp(scores, axis=-1), _NEG_INF
+        )
+        w = jnp.where(seen[..., None], jnp.exp(scores - lse[..., None]), 0.0)
+        out = jnp.einsum("bhgqk,bhkd->bhgqd", w, v.astype(jnp.float32))
+        return (out.reshape(shape).astype(v.dtype),
+                lse.reshape(shape[:-1]))
     w = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum(
         "bhgqk,bhkd->bhgqd", w, v.astype(jnp.float32)
@@ -192,13 +256,17 @@ def blockwise_attention(
     kv_position_offset: int = 0,
     window: Optional[int] = None,
     scale: Optional[float] = None,
+    rank_bits: Optional[int] = None,
+    return_lse: bool = False,
 ):
     """Memory-efficient attention: lax.scan over key blocks.
 
     ``kv_position_offset``: absolute position of k row 0 relative to q row 0
     (negative when keys precede queries — the ring-attention case).
+    ``rank_bits``, ``return_lse``: the module docstring.
     """
     _check_window(window, causal)
+    _check_ranks(rank_bits, causal, segment_ids, kv_segment_ids)
     orig_dtype = v.dtype
     # Grouped heads: the G query heads of one key/value head are G more
     # rows of queries against the same keys.
@@ -247,9 +315,9 @@ def blockwise_attention(
                 seen = jnp.logical_and(seen, qpos - kpos < window)
             bias = jnp.where(seen, 0.0, _NEG_INF)  # [Tq, block_k]
         if segment_ids is not None:
-            same = (
-                segment_ids[:, None, None, :, None]
-                == segk[:, None, None, None, :]
+            same = _same_segment(
+                segment_ids[:, None, None, :, None],
+                segk[:, None, None, None, :], rank_bits,
             )  # [B, 1, 1, Tq, block_k]
             seg_bias = jnp.where(same, 0.0, _NEG_INF)
             bias = seg_bias if bias is None else bias + seg_bias
@@ -264,7 +332,14 @@ def blockwise_attention(
     (m, l, acc), _ = jax.lax.scan(
         step, (m0, l0, a0), (jnp.arange(n_blocks), kb, vb, sb)
     )
-    return _finalize(m, l, acc, orig_dtype).reshape(q.shape[:-1] + (Dv,))
+    out = _finalize(m, l, acc, orig_dtype).reshape(q.shape[:-1] + (Dv,))
+    if not return_lse:
+        return out
+    lse = jnp.where(
+        l > 0, jnp.where(m > _NEG_INF / 2, m, 0.0)
+        + jnp.log(jnp.where(l > 0, l, 1.0)), _NEG_INF,
+    )
+    return out, lse.reshape(q.shape[:-1])
 
 
 # ---------------------------------------------------------------------------
@@ -303,13 +378,19 @@ def _lanes(x, n: int):
 
 
 def _tile_mask(causal, q_axis, q_start, k_start, seg_rows, seg_cols,
-               window=None):
+               window=None, rank_bits=None):
     """Visibility of one score tile — the ONE definition shared by the
     forward and both backward kernels, so the masks can never diverge.
     ``seg_rows`` [rows, cols] / ``seg_cols`` [1, cols] are the segment ids
     of the tile's row and column axes; ``q_axis`` says which axis carries
     the queries (0 for s, 1 for the dK/dV kernel's sᵀ)."""
-    mask = seg_rows == seg_cols
+    if rank_bits is None:
+        mask = seg_rows == seg_cols
+    else:
+        seg_q, seg_k = (
+            (seg_rows, seg_cols) if q_axis == 0 else (seg_cols, seg_rows)
+        )
+        mask = _same_segment(seg_q, seg_k, rank_bits)
     if causal:
         qpos = q_start + jax.lax.broadcasted_iota(
             jnp.int32, mask.shape, q_axis
@@ -339,6 +420,7 @@ class _Tiles(NamedTuple):
     H: int  # query heads
     Hkv: int  # key/value heads
     scale: float  # of the scores
+    rank_bits: Optional[int] = None  # ids as group and rank
 
     @property
     def G(self) -> int:
@@ -401,7 +483,19 @@ class _Tiles(NamedTuple):
         hi_q = q_rng[(b * self.n_q + qi) * 2 + 1]
         lo_k = k_rng[(b * self.n_k + ki) * 2]
         hi_k = k_rng[(b * self.n_k + ki) * 2 + 1]
-        seen = jnp.logical_and(lo_k <= hi_q, hi_k >= lo_q)
+        if self.rank_bits is None:
+            seen = jnp.logical_and(lo_k <= hi_q, hi_k >= lo_q)
+        else:
+            # Ids that never decrease along either axis, in group and in
+            # rank: a block's least id holds its least group and its least
+            # rank, its largest id the largest of both. Groups that meet,
+            # and a key's rank under some query's.
+            bits, low = self.rank_bits, (1 << self.rank_bits) - 1
+            seen = jnp.logical_and(
+                jnp.logical_and((lo_k >> bits) <= (hi_q >> bits),
+                                (hi_k >> bits) >= (lo_q >> bits)),
+                (lo_k & low) < (hi_q & low),
+            )
         if self.causal:
             q_last = qi * self.block_q + self.block_q - 1
             seen = jnp.logical_and(seen, ki * self.block_k <= q_last)
@@ -444,6 +538,7 @@ def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
         mask = _tile_mask(
             t.causal, 0, qi * t.block_q, ki * block_k,
             _lanes(seg_q_ref[0], block_k), seg_k_ref[0], t.window,
+            t.rank_bits,
         )
         s = jnp.where(mask, s, _NEG_INF)
 
@@ -499,7 +594,8 @@ def _check_blocks(Tq, Tk, block_q, block_k):
     return block_q, block_k
 
 
-def _tiles(q, k, causal, window, block_q, block_k, scale=None) -> _Tiles:
+def _tiles(q, k, causal, window, block_q, block_k, scale=None,
+           rank_bits=None) -> _Tiles:
     _, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
     if H % Hkv:
@@ -509,7 +605,7 @@ def _tiles(q, k, causal, window, block_q, block_k, scale=None) -> _Tiles:
     block_q, block_k = _check_blocks(Tq, Tk, block_q, block_k)
     return _Tiles(causal, window, block_q, block_k, Tq // block_q,
                   Tk // block_k, H, Hkv,
-                  1.0 / np.sqrt(D) if scale is None else scale)
+                  1.0 / np.sqrt(D) if scale is None else scale, rank_bits)
 
 
 def _block_ranges(seg, n_blocks: int):
@@ -532,10 +628,10 @@ _SEMANTICS = ("parallel", "parallel", "arbitrary")
 
 
 def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
-                   interpret, scale):
+                   interpret, scale, rank_bits=None):
     B, H, Tq, D = q.shape
     Dv = v.shape[-1]
-    t = _tiles(q, k, causal, window, block_q, block_k, scale)
+    t = _tiles(q, k, causal, window, block_q, block_k, scale, rank_bits)
     block_q, block_k = t.block_q, t.block_k
     Tk = k.shape[-2]
     qr = q.reshape(B * H, Tq, D)
@@ -622,6 +718,7 @@ def _flash_bwd_dq_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
         mask = _tile_mask(
             t.causal, 0, qi * t.block_q, ki * block_k,
             _lanes(seg_q_ref[0], block_k), seg_k_ref[0], t.window,
+            t.rank_bits,
         )
         s = jnp.where(mask, s, _NEG_INF)
         p = jnp.exp(s - _lanes(lse_ref[0], block_k))
@@ -668,6 +765,7 @@ def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
         mask = _tile_mask(
             t.causal, 1, qi * block_q, kj * t.block_k,
             _lanes(seg_k_ref[0], block_q), seg_q_ref[0], t.window,
+            t.rank_bits,
         )
         st = jnp.where(mask, st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[0])
@@ -686,10 +784,11 @@ def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
 
 
 def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
-                    block_q, block_k, interpret, scale):
+                    block_q, block_k, interpret, scale, rank_bits=None,
+                    dlse=None):
     B, H, Tq, D = q.shape
     Tk, Dv = k.shape[-2], v.shape[-1]
-    t = _tiles(q, k, causal, window, block_q, block_k, scale)
+    t = _tiles(q, k, causal, window, block_q, block_k, scale, rank_bits)
     block_q, block_k, Hkv = t.block_q, t.block_k, t.Hkv
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * Hkv, Tk, D)
@@ -699,6 +798,10 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
     delta = jnp.sum(
         g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
     ).reshape(B * H, Tq)
+    if dlse is not None:
+        # d lse_i / d s_ij = p_ij, so the row statistic's cotangent enters
+        # both kernels where delta does: ds = p (dp - (delta - dlse)).
+        delta = delta - dlse.astype(jnp.float32).reshape(B * H, Tq)
     semantics = pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
     ranges = (_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k))
 
@@ -816,13 +919,13 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
 
 
 @functools.partial(
-    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10)
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
 )
 def _flash_attention(q, k, v, seg_q, seg_k, causal, window, block_q,
-                     block_k, interpret, scale):
+                     block_k, interpret, scale, rank_bits):
     out, _lse = _flash_forward(
         q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
-        scale,
+        scale, rank_bits,
     )
     return out
 
@@ -841,26 +944,64 @@ KEEP_CORES = jax.checkpoint_policies.save_only_these_names(
 
 
 def _flash_fwd(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
-               interpret, scale):
+               interpret, scale, rank_bits):
     out, lse = _flash_forward(
         q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
-        scale,
+        scale, rank_bits,
     )
     out = checkpoint_name(out, _CORE_OUT)
     lse = checkpoint_name(lse, _CORE_LSE)
     return out, (q, k, v, seg_q, seg_k, out, lse)
 
 
-def _flash_bwd(causal, window, block_q, block_k, interpret, scale, res, g):
+def _flash_bwd(causal, window, block_q, block_k, interpret, scale,
+               rank_bits, res, g, dlse=None):
     q, k, v, seg_q, seg_k, out, lse = res
     dq, dk, dv = _flash_backward(
         q, k, v, seg_q, seg_k, out, lse, g, causal, window, block_q,
-        block_k, interpret, scale,
+        block_k, interpret, scale, rank_bits, dlse,
     )
     return dq, dk, dv, None, None
 
 
 _flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _merge_form(lse, q):
+    """The kernels' row statistic [B * H, Tq] as a caller reads it, [B,
+    H, Tq]: a row that saw no key carries the kernels' sentinel, +1e30
+    (their backward's ``exp(s - lse)`` is then exactly 0), and reads as
+    the empty set's, -1e30."""
+    return jnp.where(lse > -_NEG_INF / 2, _NEG_INF, lse).reshape(
+        q.shape[:-1])
+
+
+@functools.partial(
+    jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11)
+)
+def _flash_attention_lse(q, k, v, seg_q, seg_k, causal, window, block_q,
+                         block_k, interpret, scale, rank_bits):
+    """:func:`_flash_attention` with the row statistics as a second,
+    differentiable output: the same three kernels, the statistic's
+    cotangent taken off ``delta``."""
+    out, lse = _flash_forward(
+        q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
+        scale, rank_bits,
+    )
+    return out, _merge_form(lse, q)
+
+
+def _flash_lse_fwd(*args):
+    out, res = _flash_fwd(*args)
+    return (out, _merge_form(res[-1], args[0])), res
+
+
+def _flash_lse_bwd(*args):
+    *static, res, (g, dlse) = args
+    return _flash_bwd(*static, res, g, dlse)
+
+
+_flash_attention_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
 def flash_attention(
@@ -875,10 +1016,15 @@ def flash_attention(
     interpret: bool = False,
     window: Optional[int] = None,
     scale: Optional[float] = None,
+    rank_bits: Optional[int] = None,
+    return_lse: bool = False,
 ):
     """Pallas flash attention (custom VJP backward), compiled by Mosaic.
     q [B, H, Tq, D], k [B, Hkv, Tk, D], v [B, Hkv, Tk, Dv]: the three
     kernels compute at both head sizes as they are given, nothing padded.
+    ``rank_bits``, ``return_lse``: the module docstring; with ``rank_bits``
+    the ids may not decrease along either axis, in group or in rank (the
+    kernels skip a tile by its blocks' least and largest id).
 
     ``interpret=True`` runs the same kernel logic in the Pallas interpreter
     for CPU tests; it is an error on a TPU, where nothing may quietly
@@ -887,6 +1033,7 @@ def flash_attention(
     if interpret and jax.default_backend() == "tpu":
         raise ValueError("flash_attention(interpret=True) on a TPU backend")
     _check_window(window, causal)
+    _check_ranks(rank_bits, causal, segment_ids, kv_segment_ids)
     B, _, Tq, _ = q.shape
     Tk = k.shape[-2]
     seg_q = (
@@ -903,10 +1050,29 @@ def flash_attention(
             else jnp.zeros((B, Tk), jnp.int32)
         )
     )
-    return _flash_attention(
+    core = _flash_attention_lse if return_lse else _flash_attention
+    return core(
         q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
-        scale,
+        scale, rank_bits,
     )
+
+
+def merge_attention(out_a, lse_a, out_b, lse_b):
+    """One softmax over two key sets from the two sets' own results:
+    ``(out_a e^lse_a + out_b e^lse_b) / (e^lse_a + e^lse_b)``, what a
+    single call over the union of the keys gives, exactly. ``out`` [..,
+    T, Dv], ``lse`` [.., T] as ``return_lse`` gives them (an empty set:
+    zeros and -1e30); a row that saw no key in either set is zeros."""
+    top = jax.lax.stop_gradient(jnp.maximum(lse_a, lse_b))  # a shift only
+    top = jnp.where(top > _NEG_INF / 2, top, 0.0)
+    w_a, w_b = jnp.exp(lse_a - top), jnp.exp(lse_b - top)
+    total = w_a + w_b
+    total = jnp.where(total > 0, total, 1.0)
+    out = (
+        out_a.astype(jnp.float32) * (w_a / total)[..., None]
+        + out_b.astype(jnp.float32) * (w_b / total)[..., None]
+    )
+    return out.astype(out_a.dtype)
 
 
 def resolve_backend(Tq: int, Tk: int, block_q: int = 256,

@@ -347,3 +347,61 @@ def test_the_residual_mixings_kernels_compile_at_the_cells_shape(
     assert sum("moolib.lm.hc_post" in c for c in calls) == 2
     streams = n * tokens * width * dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * streams
+
+
+def test_evabyte_learner_16ks_step_compiles_within_the_chips_memory(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole train step of ``evabyte_learner_16k`` as the cell runs it
+    (16,384 bytes, 821M parameters donated, every block rebuilt, both
+    attention calls on the flash kernels), compiled ahead of time for a
+    v5e: Mosaic takes the kernels with a strictly-earlier rank in their
+    masks and the row statistics as an output, and the compiler's plan
+    stays under the 15.75 GiB it gives a program."""
+    import json
+
+    from benchmark.lib import program, seeded_eva
+    from moolib_tpu.learner import make_train_state
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "evabyte_pp8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "workloads",
+            "evabyte_learner_16k.json")) as f:
+        cell = json.load(f)
+    net = program.build_model(config)
+    shapes = seeded_eva.param_shapes(net)
+    # jax.default_backend() is the CPU here: say what the chip would run
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    optimizer = program.build_optimizer(config)
+    step = program.resolve(config["step_factory"])(
+        program.resolve(config["apply_factory"])(net), optimizer,
+        program.loss_config(config), mesh=None, donate=True,
+    )
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda p: make_train_state(p, optimizer), shapes),
+    )
+    T, B, A = cell["unroll_length"], cell["batch_per_chip"], 320
+    batch = {
+        "obs": s((T + 1, B), jnp.int32), "done": s((T + 1, B), jnp.bool_),
+        "rewards": s((T + 1, B), jnp.float32),
+        "actions": s((T, B), jnp.int32),
+        "behavior_logits": s((T, B, A), jnp.float32), "core_state": (),
+    }
+    compiled = step.lower(state, batch).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # a scanned block: two forward kernels, the same two again in its
+    # rebuild (keep_cores false: memory), two backward kernels for each
+    assert len(calls) == 8
+    assert all("moolib.lm.attn_core" in line for line in calls)
+    for scope in ("moolib.lm.eva_summary", "moolib.lm.eva_merge"):
+        assert scope in text
