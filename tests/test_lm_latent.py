@@ -484,7 +484,10 @@ def test_a_rebuilt_block_keeps_its_attention_core(monkeypatch):
         nn, "remat", lambda cls, prevent_cse, policy: remat(
             cls, prevent_cse=prevent_cse))
     r_loss, r_grads, _, _ = loss_and_grads(True)
-    assert float(loss) == float(r_loss) == float(p_loss)
+    assert float(loss) == float(r_loss)
+    # the model that rebuilds nothing hands the loss logits and baselines
+    # 1e-6 from these in their last bits, as it hands the gradients below
+    assert float(loss) == pytest.approx(float(p_loss), rel=2e-6)
     flat, _ = jax.tree_util.tree_flatten_with_path(grads)
     assert len(flat) == 55
     for (path, g), r, p in zip(flat, jax.tree_util.tree_leaves(r_grads),
@@ -499,7 +502,11 @@ def test_the_other_decoder_configuration_did_not_move():
     """``mellum2_share8``: the parameter tree at the benchmark's size, and
     at the rehearsal's size the tree, the step's program (its jaxpr, so
     every number of it, bit for bit) and the first step's numbers, are
-    what the commit before this model gave (cb3acd4, read there)."""
+    what the commit before this model gave (cb3acd4, read there), but for
+    the V-trace recursion, an associative scan since PR 41: the two
+    jaxprs' sequences of primitives differ in that one region (the
+    ``scan`` of 31 steps against the levels' slices, multiplies and adds),
+    and ``grad_norm`` by one unit in the last place."""
 
     def tree_hash(net):
         flat = jax.tree_util.tree_flatten_with_path(
@@ -530,7 +537,7 @@ def test_the_other_decoder_configuration_did_not_move():
     state = make_train_state(params, optimizer)
     jaxpr = str(jax.make_jaxpr(lambda s, b: step(s, b))(state, batch))
     assert hashlib.sha256(jaxpr.encode()).hexdigest()[:16] == (
-        "71ca7e30d21c77ac")
+        "472ba3b4503e85a1")
     _, metrics = step(state, batch)
     assert "mtp_loss" not in metrics
     for name, value in (("total_loss", "0x1.6b148cp+0"),
@@ -547,7 +554,11 @@ def test_this_configurations_step_did_not_move():
     functions it prints) and the first step's numbers are what the commit
     before the residual skeleton with several streams and the two head
     sizes gave (19a573a, read there): a description without ``residual``
-    and with equal heads traces the program it traced."""
+    and with equal heads traces the program it traced, but for the
+    V-trace recursion, an associative scan since PR 41: the two jaxprs'
+    sequences of primitives differ in that one region, and ``total_loss``,
+    a sum of cancelling terms, by 16 units in the last place (read
+    here)."""
     import re
 
     with open(os.path.join(REPO, "benchmark", "tests", "rehearsal_latent",
@@ -572,9 +583,9 @@ def test_this_configurations_step_did_not_move():
     jaxpr = re.sub(r" at 0x[0-9a-f]+", "", str(
         jax.make_jaxpr(lambda s, b: step(s, b))(state, batch)))
     assert hashlib.sha256(jaxpr.encode()).hexdigest()[:16] == (
-        "bfc3da5f8c0fef2d")
+        "409c43e961a0cc80")
     _, metrics = step(state, batch)
-    for name, value in (("total_loss", "0x1.df3a9cp-4"),
+    for name, value in (("total_loss", "0x1.df3a8cp-4"),
                         ("grad_norm", "0x1.2db8bcp+1"),
                         ("mtp_loss", "0x1.086d14p+2"),
                         ("moe_assignments_held", "0x1.7cp+6")):

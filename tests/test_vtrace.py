@@ -1,10 +1,13 @@
 """V-trace correctness vs a naive numpy oracle.
 
 Oracle implements the IMPALA paper's eq. 1 n-step sum form directly
-(double loop over s, t), independent of the scan recursion in
-moolib_tpu.ops.vtrace — mirroring the reference's test approach of comparing
-against ground-truth math (reference: examples/common/vtrace.py provenance).
+(a loop over s, the sum over t vectorised: O(T^2), float64), independent of
+the recursion in moolib_tpu.ops.vtrace — mirroring the reference's test
+approach of comparing against ground-truth math (reference:
+examples/common/vtrace.py provenance).
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -16,25 +19,32 @@ from moolib_tpu.ops import vtrace
 
 def _oracle_vtrace(
     log_rhos, discounts, rewards, values, bootstrap_value,
-    clip_rho=1.0, clip_pg_rho=1.0, lambda_=1.0,
+    clip_rho=1.0, clip_pg_rho=1.0, lambda_=1.0, stepwise=False,
 ):
+    """Eq. 1's n-step sums; ``stepwise`` runs the backwards recursion one
+    step at a time instead, in the inputs' dtype: the form the module ran
+    as a ``lax.scan``, what the log-depth form's rounding is held against."""
     T, B = rewards.shape
     rhos = np.exp(log_rhos)
     clipped = np.minimum(clip_rho, rhos) if clip_rho is not None else rhos
     cs = lambda_ * np.minimum(1.0, rhos)
     values_tp1 = np.concatenate([values[1:], bootstrap_value[None]], 0)
     deltas = clipped * (rewards + discounts * values_tp1 - values)
-    vs = np.zeros_like(values)
-    for s in range(T):
-        acc = np.zeros(B)
-        for t in range(s, T):
-            prod_c = np.ones(B)
-            gamma_prod = np.ones(B)
-            for i in range(s, t):
-                prod_c *= cs[i]
-                gamma_prod *= discounts[i]
-            acc += gamma_prod * prod_c * deltas[t]
-        vs[s] = values[s] + acc
+    a = discounts * cs
+    vs = np.empty_like(values)
+    if stepwise:
+        acc = np.zeros_like(bootstrap_value)
+        for t in reversed(range(T)):
+            acc = deltas[t] + a[t] * acc
+            vs[t] = values[t] + acc
+    else:
+        # vs_s = V_s + sum_{t>=s} (prod_{s<=i<t} gamma_i c_i) delta_t, one
+        # s at a time, time on the contiguous axis
+        a, deltas_bt = a.T, deltas.T
+        for s in range(T):
+            weights = np.cumprod(a[:, s:T - 1], axis=1)
+            vs[s] = values[s] + deltas_bt[:, s] + np.sum(
+                weights * deltas_bt[:, s + 1:], axis=1)
     vs_tp1 = np.concatenate([vs[1:], bootstrap_value[None]], 0)
     pg_rhos = np.minimum(clip_pg_rho, rhos) if clip_pg_rho is not None else rhos
     pg_adv = pg_rhos * (rewards + discounts * vs_tp1 - values)
@@ -85,6 +95,52 @@ def test_no_clipping_thresholds():
     np.testing.assert_allclose(
         np.asarray(out.pg_advantages), ref_pg, rtol=1e-5, atol=1e-5
     )
+
+
+@pytest.mark.parametrize("thresholds", [1.0, None])
+@pytest.mark.parametrize("lambda_", [1.0, 0.9])
+@pytest.mark.parametrize("B", [1, 4])
+@pytest.mark.parametrize("T", [4095, 8191, 16383])
+def test_a_long_unroll_in_log_depth_matches_oracle(T, B, lambda_, thresholds):
+    """The decoder cells' unrolls (one packed sequence, an episode's end a
+    discount of 0 at 1 step in 2,048), where the recursion runs as an
+    associative scan of some 14 levels."""
+    rng = np.random.default_rng(T + B)
+    args = (
+        rng.uniform(-1.5, 1.5, (T, B)),
+        0.99 * (rng.uniform(size=(T, B)) > 1 / 2048),
+        rng.standard_normal((T, B)),
+        rng.standard_normal((T, B)),
+        rng.standard_normal(B),
+    )
+    args32 = [x.astype(np.float32) for x in args]
+    out = vtrace.from_importance_weights(
+        *map(jnp.asarray, args32), clip_rho_threshold=thresholds,
+        clip_pg_rho_threshold=thresholds, lambda_=lambda_,
+    )
+    assert out.vs.dtype == out.pg_advantages.dtype == jnp.float32
+    kw = dict(clip_rho=thresholds, clip_pg_rho=thresholds, lambda_=lambda_)
+    ref = _oracle_vtrace(*(x.astype(np.float64) for x in args32), **kw)
+    stepwise = _oracle_vtrace(*args32, **kw, stepwise=True)
+    assert stepwise[0].dtype == np.float32
+    for got, want, one_at_a_time in zip(out, ref, stepwise):
+        # float32 sums regrouped over 14 levels: 1.2e-6 absolute on values
+        # of 11 at these lengths, the margin the file's other tests have
+        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+        # and no less exact than one step at a time
+        assert np.max(np.abs(got - want)) <= (
+            2 * np.max(np.abs(one_at_a_time - want)) + 1e-6)
+
+
+@pytest.mark.parametrize("T", [8191, 20])
+def test_the_recursion_compiles_to_no_loop(T):
+    """One form at every length: the ledger read 8,191 dependent iterations
+    as 23.65 ms of a 212.75 ms step, and at IMPALA's 20 steps the chip read
+    the log-depth form no slower than the scan it replaced."""
+    B = 2
+    args = [jnp.zeros((T, B), jnp.float32)] * 4 + [jnp.zeros(B, jnp.float32)]
+    text = jax.jit(vtrace.from_importance_weights).lower(*args).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
 
 
 def test_from_logits_on_policy_is_td_lambda_like():
