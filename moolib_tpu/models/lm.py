@@ -7,10 +7,16 @@ Same agent calling convention as every other model
 
     (logits_TBA, baseline_TB), state = net.apply(params, obs, done, state)
 
-with ``obs`` [T, B] integer token ids and ``initial_state`` ``()``: the
-unroll is the context, causal over T and cut at episode boundaries
+with ``obs`` [T, B] integer token ids. For a softmax block the unroll is
+the context, causal over T and cut at episode boundaries
 (``segment_ids_from_done``), as in :class:`TransformerNet`; positions run
-0..T-1 over the unroll and are not reset at a boundary.
+0..T-1 over the unroll and are not reset at a boundary; such a block
+carries nothing from call to call, and a stack of them has
+``initial_state`` ``()``. A delta-rule block (below) carries a state, and
+``initial_state`` is then no longer ``()``: a flat tuple of float32
+leaves ``[B, ...]``, two a stateful entry of ``layers``, which the call
+returns as it stands after its last position (the learner hands it in
+with the batch, ``make_act_step`` to the next call, as the LSTM's).
 
 The stack is **described by data**: ``layers`` is a list, one entry a
 block (or, with ``repeat``, that many identical blocks run as one scan
@@ -80,6 +86,46 @@ No ``[T, T]`` or ``[T, T / c]`` array is built on the flash path.
 ``eva_summary_pairs``, ``eva_chunks_cut``, every block's, in the step's
 metrics).
 
+Without ``rope`` (null) a softmax kind has no position encoding at all;
+with ``output_gate`` its heads' output is multiplied by ``sigmoid(x
+W_g)``, elementwise over ``heads x head_dim``, before ``W_o``.
+
+With ``delta`` (:class:`Delta`; the gated delta rule with a per-channel
+decay, Kimi Delta Attention, arXiv:2510.26692) a kind has no softmax, no
+window and no rotary. A head has a state ``S`` [D, D], float32;
+``conv`` is a causal depthwise convolution over time of ``conv_size``
+taps (:func:`causal_conv`):
+
+    q_t = l2norm(silu(conv(x W_q)))   k_t = l2norm(silu(conv(x W_k)))
+    v_t = silu(conv(x W_v))
+    g_t = -exp(A) softplus(x W_f1 W_f2 + dt_bias)   [D] a head, <= 0
+    beta_t = 2 sigmoid(x W_b)      (1 sigmoid without ``allow_neg_eigval``)
+    S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = D^-1/2 S_t^T q_t
+    y_t = [rms_head(o_t) * sigmoid(x W_g1 W_g2)] W_o
+
+``W_f1``, ``W_g1`` are ``[d, gate_rank]``; ``rms_head`` has one gain
+``[D]`` for all heads; l2norm's eps is 1e-6. The projections, the
+convolutions, both gates and the output run under
+``moolib.lm.kda_proj``; the recurrence, in chunks and with no loop over
+positions (:mod:`moolib_tpu.ops.delta_rule`), under ``moolib.lm.kda_core``.
+**The episode rule**: a position reads nothing of an earlier episode. At
+an episode's first position ``S_{t-1}`` is zero and the convolution's taps
+that would reach an earlier episode read zeros. At the call's first
+position, unless ``done[0]``, ``S_{-1}`` and the ``conv_size - 1`` rows
+of ``[x W_q | x W_k | x W_v]`` before it come from the state handed in
+(``[B, heads, D, D]`` and ``[B, conv_size - 1, 3 heads D]``; a repeated
+entry's blocks on the axis after ``B``), and the call returns the same
+after its last position, rows of an earlier episode than the last
+position's as zeros. A softmax block in the same stack still has the
+unroll for its context: the repo has no key/value cache, so what a state
+carries across calls is the delta-rule blocks' part alone. The step's
+metrics carry ``kda_state_resets`` and ``kda_chunks_cut`` (positions at
+which the state is dropped, chunks of the recurrence that hold two
+episodes: :func:`kda_boundary_counts`, every block's),
+``kda_log_decay_min`` (the most negative sum of ``g`` over one chunk)
+and ``kda_state_rms`` (of the state handed on).
+
 ``norm_unit_offset``: every RMS norm's gain is ``1 + scale``, its
 parameter starting at 0. ``num_pred_heads`` n > 1: the head is ``hidden ->
 n x vocab``, head-major; head 0's columns are the policy's logits and head
@@ -137,7 +183,7 @@ import numpy as np
 from flax import linen as nn
 
 from ..ops import attention as attn_ops
-from ..ops import hyper_mix
+from ..ops import delta_rule, hyper_mix
 from ..parallel.moe import moe_dropless
 from .transformer import (attend, hyper_coefficients, hyper_read,
                           hyper_residual_block, residual_block,
@@ -146,6 +192,7 @@ from .transformer import (attend, hyper_coefficients, hyper_read,
 __all__ = [
     "AttentionKind",
     "DecoderLM",
+    "Delta",
     "Eva",
     "Latent",
     "Residual",
@@ -199,11 +246,30 @@ class Eva:
 
 
 @dataclasses.dataclass(frozen=True)
+class Delta:
+    """The gated delta rule as a token mixer (the keys of a
+    ``linear_attn_config`` and the ``kda_*`` keys beside it): the heads
+    held here and their size (key and value alike), the taps of the causal
+    depthwise convolutions, the rank of the two low-rank gates, and whether
+    ``beta`` runs to 2 (a transition with a negative eigenvalue) or to
+    1."""
+
+    num_heads: int
+    head_dim: int
+    conv_size: int
+    gate_rank: int
+    allow_neg_eigval: bool
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionKind:
     window: Optional[int]  # None: full causal attention
-    rope: Rope
+    rope: Optional[Rope]  # None: no position encoding at all
     latent: Optional[Latent] = None  # None: three dense projections
     eva: Optional[Eva] = None  # windows as blocks, read through summaries
+    # the softmax kind's output times sigmoid(x W_g), elementwise
+    output_gate: bool = False
+    delta: Optional[Delta] = None  # no softmax: the delta rule's state
 
 
 @dataclasses.dataclass(frozen=True)
@@ -328,10 +394,11 @@ class _Attention(nn.Module):
             )
 
         with jax.named_scope("moolib.lm.attn_proj"):
-            cos, sin = _rotary_tables(self.kind.rope, positions, D)
-            q = _rotary(proj("q", H), cos, sin)
-            k = _rotary(proj("k", Hkv), cos, sin)
-            v = proj("v", Hkv)
+            turn = lambda t: t  # noqa: E731  (no position encoding)
+            if self.kind.rope is not None:
+                cos, sin = _rotary_tables(self.kind.rope, positions, D)
+                turn = lambda t: _rotary(t, cos, sin)  # noqa: E731
+            q, k, v = turn(proj("q", H)), turn(proj("k", Hkv)), proj("v", Hkv)
             # [T, B, heads, D] -> [B, heads, T, D]
             q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
         with jax.named_scope("moolib.lm.attn_core"):
@@ -342,6 +409,8 @@ class _Attention(nn.Module):
             )
         with jax.named_scope("moolib.lm.attn_proj"):
             o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
+            if self.kind.output_gate:
+                o = o * jax.nn.sigmoid(_dense("gate", H * D, self.dtype)(x))
             return _dense("o", x.shape[-1], self.dtype)(o)
 
 
@@ -394,6 +463,22 @@ def eva_summaries(k, v, own, phi, mu):
     )[..., None]
     kt = jnp.sum(a * kc, axis=-2) + mu.astype(jnp.float32)[None, :, None]
     return kt.astype(k.dtype), jnp.sum(a * vc, axis=-2).astype(v.dtype)
+
+
+def kda_boundary_counts(seg_bt) -> dict:
+    """What the episode boundaries ask of one delta-rule block, counted
+    from the ids ``[B, T]``, int32: ``kda_state_resets`` positions at which
+    the state is dropped (the call's first among them where it does not
+    continue the state handed in), ``kda_chunks_cut`` chunks of the
+    recurrence that hold positions of two episodes."""
+    T = seg_bt.shape[1]
+    C = delta_rule.chunk_of(T)
+    seg = jnp.pad(seg_bt, ((0, 0), (0, -T % C)), mode="edge")
+    seg = seg.reshape(seg.shape[0], -1, C)
+    return {
+        "kda_state_resets": jnp.sum(seg_bt[:, -1]),
+        "kda_chunks_cut": jnp.sum(seg[:, :, 0] != seg[:, :, -1]),
+    }
 
 
 def eva_pair_counts(seg_bt, T: int, window: int, chunk: int) -> dict:
@@ -469,6 +554,100 @@ class _EvaAttention(nn.Module):
         with jax.named_scope("moolib.lm.attn_proj"):
             o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
             return _dense("o", x.shape[-1], self.dtype)(o)
+
+
+def causal_conv(x, w, seg_tb, tail):
+    """A causal depthwise convolution over time that reads nothing across
+    an episode boundary. ``x`` [T, B, C]; ``w`` [K, C], tap ``K - 1`` on
+    the position itself and tap ``j`` on the one ``K - 1 - j`` before it;
+    ``seg_tb`` [T, B] episode ids; ``tail`` [B, K - 1, C] the rows before
+    the call's first, of episode 0 (zeros where that episode had none).
+    A tap that would reach another episode reads zero. Returns ``y`` [T,
+    B, C] float32 and the next call's ``tail``, float32: the last ``K -
+    1`` rows, those of an earlier episode than the last row's zeroed."""
+    T, K = x.shape[0], w.shape[0]
+    rows = jnp.concatenate(
+        [tail.transpose(1, 0, 2).astype(jnp.float32), x.astype(jnp.float32)]
+    )
+    seg = jnp.pad(seg_tb, ((K - 1, 0), (0, 0)))
+    y = sum(
+        w[j].astype(jnp.float32) * jnp.where(
+            (seg[j:j + T] == seg_tb)[..., None], rows[j:j + T], 0.0
+        )
+        for j in range(K)
+    )
+    tail = jnp.where(
+        (seg[T:] == seg[-1:])[..., None], rows[T:], 0.0
+    ).transpose(1, 0, 2)
+    return y, tail
+
+
+class _DeltaAttention(nn.Module):
+    """The gated delta rule as a token mixer (Kimi Delta Attention); see
+    the module docstring. ``state``: ``(S [B, H, D, D], rows [B, K - 1,
+    3 H D])``, float32; returns ``(y, state)``."""
+
+    kind: AttentionKind
+    eps: float
+    dtype: jnp.dtype
+    norm_unit_offset: bool = False
+
+    @nn.compact
+    def __call__(self, x, seg_bt, state):
+        T, B, d = x.shape
+        spec = self.kind.delta
+        H, D, K = spec.num_heads, spec.head_dim, spec.conv_size
+        S, rows = state
+
+        def dense(name, width):
+            return _dense(name, width, self.dtype)
+
+        def heads(t):  # [T, B, H D] -> [B, H, T, D]
+            return t.reshape(T, B, H, D).transpose(1, 2, 0, 3)
+
+        def l2norm(t):
+            return t * jax.lax.rsqrt(
+                jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6
+            )
+
+        a_log = self.param("A_log", nn.initializers.zeros, (H,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (H * D,))
+        with jax.named_scope("moolib.lm.kda_proj"):
+            qkv = jnp.concatenate(
+                [dense(n, H * D)(x) for n in ("q", "k", "v")], axis=-1
+            )
+            taps = jnp.concatenate([
+                self.param(
+                    f"conv_{n}", nn.initializers.normal(K ** -0.5), (K, H * D)
+                ) for n in ("q", "k", "v")
+            ], axis=-1)
+            mixed, rows = causal_conv(qkv, taps, seg_bt.T, rows)
+            q, k, v = (
+                heads(t) for t in jnp.split(jax.nn.silu(mixed), 3, axis=-1)
+            )
+            q, k = l2norm(q), l2norm(k)
+            decay = dense("f_b", H * D)(dense("f_a", spec.gate_rank)(x))
+            g = -jnp.exp(a_log.astype(jnp.float32))[None, :, None, None] * (
+                jax.nn.softplus(heads(decay.astype(jnp.float32) + dt_bias))
+            )
+            beta = jax.nn.sigmoid(
+                dense("b", H)(x).astype(jnp.float32)
+            ).transpose(1, 2, 0)
+            if spec.allow_neg_eigval:
+                beta = 2.0 * beta
+        with jax.named_scope("moolib.lm.kda_core"):
+            o, S = delta_rule.gated_delta_rule(q, k, v, g, beta, seg_bt, S)
+            self.sow("intermediates", "kda_gauges", {
+                "kda_log_decay_min": delta_rule.log_decay_min(g),
+                "kda_state_sq": jnp.mean(S * S),
+            })
+        with jax.named_scope("moolib.lm.kda_proj"):
+            o = RMSNorm(
+                self.eps, self.dtype, self.norm_unit_offset, name="o_norm"
+            )(o.transpose(2, 0, 1, 3))
+            gate = dense("g_b", H * D)(dense("g_a", spec.gate_rank)(x))
+            o = o.reshape(T, B, H * D) * jax.nn.sigmoid(gate)
+            return dense("o", d)(o), (S, rows)
 
 
 class _LatentAttention(nn.Module):
@@ -670,10 +849,21 @@ class _Block(nn.Module):
     scanned: bool = False  # the body of a scan: returns (carry, None)
 
     @nn.compact
-    def __call__(self, x, seg_bt, positions):
+    def __call__(self, x, seg_bt, positions, state=()):
         net = self.net
         norm = net.norm
-        if self.kind.eva is not None:
+        if self.kind.delta is not None:
+            if (self.kind.latent, self.kind.eva, self.kind.window,
+                    self.kind.rope) != (None,) * 4 or self.kind.output_gate:
+                raise ValueError(
+                    "the delta rule has no softmax: no window, rotary, "
+                    "latent ranks, summaries or output gate of that kind"
+                )
+            attention = _DeltaAttention(
+                self.kind, net.rms_norm_eps, net.compute_dtype,
+                net.norm_unit_offset, name="attn",
+            )
+        elif self.kind.eva is not None:
             if self.kind.latent is not None or self.kind.window is None:
                 raise ValueError(
                     "chunk-summary attention has dense projections and a "
@@ -715,7 +905,11 @@ class _Block(nn.Module):
                 f"unknown mlp kind {self.mlp!r}; have 'sparse', 'dense'"
             )
         def mixer(h):
-            return attention(h, seg_bt, positions)
+            nonlocal state
+            if self.kind.delta is None:
+                return attention(h, seg_bt, positions)
+            y, state = attention(h, seg_bt, state)
+            return y
 
         if net.residual is None:
             out = residual_block(x, norm("norm1"), mixer, norm("norm2"), mlp)
@@ -726,6 +920,8 @@ class _Block(nn.Module):
                 _HyperMix(net.residual, net.rms_norm_eps, name="hc_mlp"),
                 norm("norm2"), mlp,
             )
+        if self.kind.delta is not None:
+            return out, state
         return (out, None) if self.scanned else out
 
 
@@ -740,7 +936,11 @@ def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
     kernel); ``"input"``, its input alone, and the rebuild runs the
     forward kernels again. ``(x, seg_bt, positions) -> x``, with ``x`` the
     skeleton's carry: one stream ``[T, B, d]`` or several ``[n, T, B,
-    d]``."""
+    d]``; for the delta rule, the one kind that carries something from
+    call to call, ``(x, seg_bt, positions, state) -> (x, state)``, the
+    state's leaves ``[B, ...]`` a block and ``[B, repeat, ...]`` a scan
+    (the blocks on the axis after the batch's)."""
+    stateful = kind.delta is not None
     cls, traced = _Block, contextlib.nullcontext
     if remat == "input":
         cls = nn.remat(_Block, prevent_cse=False)
@@ -756,15 +956,19 @@ def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
     else:
         scan = nn.scan(
             cls, variable_axes={"params": 0, "intermediates": 0},
-            split_rngs={"params": True}, in_axes=nn.broadcast, length=repeat,
+            split_rngs={"params": True},
+            in_axes=(nn.broadcast, nn.broadcast, 1) if stateful
+            else nn.broadcast,
+            out_axes=1 if stateful else 0, length=repeat,
         )(kind, mlp, sizes, True, name=name)
 
-        def block(x, seg_bt, positions):
-            return scan(x, seg_bt, positions)[0]
+        def block(*args):  # a stateless body's second output is None
+            x, state = scan(*args)
+            return (x, state) if stateful else x
 
-    def run(x, seg_bt, positions):
+    def run(*args):
         with traced():  # the body is traced inside this call
-            return block(x, seg_bt, positions)
+            return block(*args)
 
     return run
 
@@ -931,11 +1135,20 @@ class DecoderLM(nn.Module):
         seg_bt = segment_ids_from_done(done)
         positions = jnp.arange(T)
         kinds, sizes = dict(self.attention_kinds), self._sizes()
+        # a stateful entry's leaves, in the order the entries run
+        states, handed_on = list(core_state), []
         for i, (attention, mlp, *repeat) in enumerate(self.layers):
-            x = _blocks(
-                kinds[attention], mlp, sizes, *(repeat or [1]),
-                self.remat_blocks, f"block_{i}",
-            )(x, seg_bt, positions)
+            kind = kinds[attention]
+            run = _blocks(
+                kind, mlp, sizes, *(repeat or [1]), self.remat_blocks,
+                f"block_{i}",
+            )
+            if kind.delta is None:
+                x = run(x, seg_bt, positions)
+            else:
+                x, state = run(x, seg_bt, positions, tuple(states[:2]))
+                states = states[2:]
+                handed_on += state
         if self.residual is not None:
             # and the streams' sum is what the final norm reads
             x = jnp.sum(x.astype(jnp.float32), axis=0).astype(
@@ -975,6 +1188,15 @@ class DecoderLM(nn.Module):
                     )
         if eva_counters:
             self.sow("intermediates", "eva_counters", eva_counters)
+        if handed_on:
+            blocks = sum(
+                (repeat or [1])[0] for attention, _, *repeat in self.layers
+                if kinds[attention].delta is not None
+            )
+            self.sow("intermediates", "kda_counters", {
+                name: blocks * value
+                for name, value in kda_boundary_counts(seg_bt).items()
+            })
         if self.mtp is not None:
             with jax.named_scope("moolib.lm.mtp"):
                 loss, count = _Mtp(
@@ -987,10 +1209,27 @@ class DecoderLM(nn.Module):
             self.sow("intermediates", "mtp_terms", {
                 "mtp_loss": loss, "mtp_positions": count,
             })
-        return (logits, baseline), core_state
+        return (logits, baseline), tuple(handed_on) or core_state
 
     def initial_state(self, batch_size: int) -> Tuple:
-        return ()
+        """``()`` for a stack without a delta-rule layer; with, a flat
+        tuple of two float32 leaves an entry of ``layers`` of that kind,
+        zeros: the rule's state ``[B, heads, D, D]`` and the ``conv_size -
+        1`` rows before its convolutions ``[B, conv_size - 1, 3 heads D]``,
+        a repeated entry's blocks stacked on the axis after ``B``."""
+        kinds, state = dict(self.attention_kinds), []
+        for attention, _, *repeat in self.layers:
+            spec = kinds[attention].delta
+            if spec is not None:
+                lead = (batch_size,) + tuple(repeat)
+                H, D = spec.num_heads, spec.head_dim
+                state += [
+                    jnp.zeros(lead + (H, D, D), jnp.float32),
+                    jnp.zeros(
+                        lead + (spec.conv_size - 1, 3 * H * D), jnp.float32
+                    ),
+                ]
+        return tuple(state)
 
 
 def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
@@ -999,18 +1238,24 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
     of ``{"attention": kind, "mlp": "sparse" | "dense"}``, an entry with
     ``"repeat": n`` standing for ``n`` identical blocks run as a scan;
     ``attention_kinds`` a mapping ``kind -> {"window": int or null,
-    "rope": {...}, "latent": {...} or absent, "eva": {...} or absent}``
-    whose ``rope`` holds the fields of :class:`Rope`, whose ``latent``
-    those of :class:`Latent` and whose ``eva`` those of :class:`Eva`;
+    "rope": {...} or null, "latent": {...} or absent, "eva": {...} or
+    absent, "output_gate": bool or absent, "delta": {...} or absent}``
+    whose ``rope`` holds the fields of :class:`Rope` (null: no position
+    encoding), whose ``latent`` those of :class:`Latent`, whose ``eva``
+    those of :class:`Eva` and whose ``delta`` those of :class:`Delta`
+    (the kind is then the delta rule and has no window);
     ``router`` the fields of :class:`Router`; ``mtp`` the
     multi-token-prediction module's block, an entry like one of
     ``layers``; ``residual`` the fields of :class:`Residual` (absent: the
     skeleton with one stream)."""
     kinds = tuple(
         (name, AttentionKind(
-            spec.get("window"), Rope(**spec["rope"]),
+            spec.get("window"),
+            Rope(**spec["rope"]) if spec.get("rope") else None,
             Latent(**spec["latent"]) if spec.get("latent") else None,
             Eva(**spec["eva"]) if spec.get("eva") else None,
+            spec.get("output_gate", False),
+            Delta(**spec["delta"]) if spec.get("delta") else None,
         ))
         for name, spec in sorted(attention_kinds.items())
     )
@@ -1047,9 +1292,18 @@ def _sum_counters(intermediates) -> dict:
     for name in ("moe_load_max", "moe_load_mean"):
         if name in total:
             total[name] = total[name] / layers
-    for name in ("mtp_loss", "eva_local_pairs"):
+    for name in ("mtp_loss", "eva_local_pairs", "kda_state_resets"):
         for sown in sown_dicts(intermediates, name):
             total.update(sown)
+    # the delta rule's gauges, an element a block: the worst, and the mean
+    gauges = sown_dicts(intermediates, "kda_log_decay_min")
+    if gauges:
+        total["kda_log_decay_min"] = jnp.min(jnp.stack([
+            jnp.min(sown["kda_log_decay_min"]) for sown in gauges
+        ]))
+        total["kda_state_rms"] = jnp.sqrt(jnp.mean(jnp.concatenate([
+            jnp.ravel(sown["kda_state_sq"]) for sown in gauges
+        ])))
     # the stream mixing's, a dict a sublayer: the worst gap, every entry
     for sown in sown_dicts(intermediates, "hc_res_clamped"):
         for name, value in sown.items():

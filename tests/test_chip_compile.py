@@ -405,3 +405,67 @@ def test_evabyte_learner_16ks_step_compiles_within_the_chips_memory(
     assert all("moolib.lm.attn_core" in line for line in calls)
     for scope in ("moolib.lm.eva_summary", "moolib.lm.eva_merge"):
         assert scope in text
+
+
+def test_solar2_learner_4ks_step_compiles_within_the_chips_memory(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole train step of ``solar2_learner_4k`` as the cell runs it
+    (4,096 tokens, 841M parameters donated, a state handed in with the
+    batch, every block rebuilt from its input, the softmax block on the
+    flash kernels and the three delta-rule blocks as one scan over stacked
+    parameters), compiled ahead of time for a v5e: the compiler's plan
+    stays under the 15.75 GiB it gives a program, and both of the rule's
+    scopes are in the program."""
+    import json
+
+    from benchmark.lib import program, seeded_kda
+    from moolib_tpu.learner import make_train_state
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "solar_open2_share8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "workloads",
+            "solar2_learner_4k.json")) as f:
+        cell = json.load(f)
+    net = program.build_model(config)
+    shapes = seeded_kda.param_shapes(net)
+    # jax.default_backend() is the CPU here: say what the chip would run
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    optimizer = program.build_optimizer(config)
+    step = program.resolve(config["step_factory"])(
+        program.resolve(config["apply_factory"])(net), optimizer,
+        program.loss_config(config), mesh=None, donate=True,
+    )
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda p: make_train_state(p, optimizer), shapes),
+    )
+    T, B, A = cell["unroll_length"], cell["batch_per_chip"], config[
+        "num_actions"]
+    batch = {
+        "obs": s((T + 1, B), jnp.int32), "done": s((T + 1, B), jnp.bool_),
+        "rewards": s((T + 1, B), jnp.float32),
+        "actions": s((T, B), jnp.int32),
+        "behavior_logits": s((T, B, A), jnp.float32),
+        "core_state": tuple(
+            s(x.shape, x.dtype) for x in net.initial_state(B)
+        ),
+    }
+    compiled = step.lower(state, batch).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    flash = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "moolib.lm.attn_core" in line]
+    # the one softmax block: its forward kernel (the block is no scan's
+    # body, so the compiler folds the rebuilt call into the first) and two
+    # backward kernels
+    assert len(flash) == 3
+    for scope in ("moolib.lm.kda_core", "moolib.lm.kda_proj"):
+        assert scope in text
