@@ -102,7 +102,13 @@ class EnvBatchState:
 
     Frames and actions are written in place, once each, into buffers shaped
     as the learner wants them (:class:`moolib_tpu.ops.batcher.LearnSlabs`);
-    nothing is stacked when an unroll completes.
+    nothing is stacked when an unroll completes. With shared slabs, an
+    observation that the caller has staged on the device for its act call
+    (``observe(out, staged_obs)``) is not copied on the host at all: the
+    device arrays are kept for the frame's row, frame T of one unroll is
+    row 0 of the next by the same reference, and the learn batch's ``obs``
+    is assembled from them on the device (``LearnSlabs.stage``). ``done``,
+    ``rewards``, actions and logits are copied on the host either way.
 
     Protocol, once per pool step (one `i` of the double buffer)::
 
@@ -119,10 +125,12 @@ class EnvBatchState:
     itself: ``observe`` then returns True when the columns are complete,
     and the caller answers, before it acts, with ``start_unroll(keep)``::
 
-        if state.observe(out):
+        obs = stage_frame(out["obs"])              # on the device, its own memory
+        if state.observe(out, obs):
             state.start_unroll(keep=learner_wants_it)  # False: columns written again
+        a, logits, core = act(params, rng, obs, ...)
         ...
-        if not slabs.empty(): slab = slabs.get()   # the learn batch, in place
+        if not slabs.empty(): batch = slabs.stage(slabs.get())  # on the device
     """
 
     def __init__(self, unroll_length: int, initial_core_state: Any,
@@ -136,20 +144,27 @@ class EnvBatchState:
         self._t = -1  # row of the newest frame
         self._n_actions = 0
         # The newest frame as the pool handed it out: views over shared
-        # memory, good until this batch is stepped again. Held from the
-        # frame that completes an unroll to start_unroll, which copies it
-        # once more, as row 0 of the next.
+        # memory, good until this batch is stepped again (the observation
+        # as the caller staged it, where it did). Held from the frame that
+        # completes an unroll to start_unroll, which writes it once more,
+        # as row 0 of the next.
         self._frame: Optional[tuple] = None
         # Episode stats harvested from done transitions, drained by
         # recent_returns()/recent_lengths().
         self._completed_returns: List[float] = []
         self._completed_lengths: List[float] = []
 
-    def observe(self, env_out: Dict[str, np.ndarray]):
+    def observe(self, env_out: Dict[str, np.ndarray], staged_obs: Any = None):
         """Feed one EnvPool output dict (frame t). Every ``unroll_length``
         frames an unroll completes: returns it (time-major, buffers of its
         own), or True where the unroll is columns of shared slabs; else
-        None."""
+        None.
+
+        ``staged_obs`` is the frame's observation as device arrays that own
+        their memory (:func:`moolib_tpu.ops.batcher.stage_frame`: what the
+        act call is given). Shared slabs keep it in
+        place of a host copy; an unroll with buffers of its own is host
+        arrays, and copies the observation from ``env_out`` as ever."""
         done = np.asarray(env_out["done"])
         if done.any():
             rets = np.asarray(env_out["episode_return"])[done]
@@ -170,7 +185,11 @@ class EnvBatchState:
             self._window = self._slabs.window(len(done))
         # The copy EnvPool's zero-copy views need (the next step into this
         # buffer overwrites them), made straight into the frame's row.
-        frame = (obs_from_env_out(env_out), done, env_out["reward"])
+        obs = (
+            staged_obs if self._shared and staged_obs is not None
+            else obs_from_env_out(env_out)
+        )
+        frame = (obs, done, env_out["reward"])
         self._t += 1
         self._slabs.write_frame(self._window, self._t, *frame)
         if self._t < self.T:
@@ -194,7 +213,7 @@ class EnvBatchState:
             self._slabs.commit(self._window, self._unroll_start_state)
             self._window = self._slabs.window(len(self._frame[1]))
         else:
-            self._slabs.rewind()
+            self._slabs.rewind(self._window)
         self._unroll_start_state = self.core_state
         self._t = 0
         self._n_actions = 0

@@ -14,10 +14,12 @@ overrides; main loop at :364-529), redesigned TPU-first:
 - the elastic cross-peer path (virtual batch, joiners/leavers, leader model
   push) is the :class:`moolib_tpu.Accumulator` over the broker group — DCN
   control plane only;
-- the two batching stages are one: every env frame is copied once, into
-  its row and columns of a reusable learn slab
-  (:class:`moolib_tpu.ops.batcher.LearnSlabs`), and rollout→HBM staging is
-  one ``jax.device_put`` per learn batch + ``shard_batch``.
+- the two batching stages are one, and a frame goes to the device once:
+  the array staged for its act call is kept for its row of the learn batch
+  (:class:`moolib_tpu.ops.batcher.LearnSlabs`), whose ``obs`` one program
+  puts together on the device; the small leaves are written in place into
+  a reusable host slab, and rollout→HBM staging is one ``jax.device_put``
+  of those per learn batch + ``shard_batch``.
 
 Run (one peer, starts its own broker):
     python -m moolib_tpu.examples.vtrace.experiment total_steps=200000
@@ -205,7 +207,7 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         make_train_state,
         replicate_state,
     )
-    from moolib_tpu.ops.batcher import LearnSlabs
+    from moolib_tpu.ops.batcher import LearnSlabs, stage_frame
     from moolib_tpu.parallel import GlobalStatsAccumulator, make_mesh
     from moolib_tpu.parallel.mesh import shard_batch
     from moolib_tpu.utils import Checkpointer
@@ -425,12 +427,17 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         num_batches=cfg.num_actor_batches,
         action_dtype=np.int64,
     )
-    # The learn batch is assembled where it is staged from: each actor
-    # batch writes its unroll straight into a window of columns of a
-    # reusable [T+1, learn_batch_size, ...] slab, so a frame is copied once
-    # and nothing is stacked or concatenated when the columns fill
-    # (reference: examples/common/__init__.py:154-207 + Batcher, which copy
-    # it three times). core_state's [B, ...] leaves are joined on axis 0.
+    # The learn batch is assembled in place: each actor batch writes its
+    # unroll straight into a window of columns of a reusable
+    # [T+1, learn_batch_size, ...] host slab (done, rewards, actions,
+    # logits: 0.2 MB a batch), and nothing is stacked or concatenated on
+    # the host when the columns fill. The observation never enters the
+    # slab: a frame goes to the device once, for its act call, the slab
+    # keeps that device array for the frame's row, and the batch's obs is
+    # put together from the T+1 frames of each window by one program on the
+    # device (LearnSlabs.stage; reference: examples/common/__init__.py:154-207
+    # + Batcher, which copy a frame three times on the host and transfer it
+    # twice). core_state's [B, ...] leaves are joined on axis 0.
     learn_slabs = LearnSlabs(cfg.unroll_length, cfg.learn_batch_size)
     batch_states = [
         EnvBatchState(
@@ -499,8 +506,8 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
             with scope.part("unroll_write"):
                 bs.record_action(a, logits, core)
         # Only now, its own act step done: the copy in of the batch's
-        # frame (jnp.asarray may alias the EnvPool's view) is consumed
-        # before a worker writes the next frame over it.
+        # frame, which reads the EnvPool's view, is over before a worker
+        # writes the next frame over it.
         with scope.phase("env_submit"):
             actions[i][:] = a
             futures[i] = pool.step(i, actions[i])
@@ -538,8 +545,20 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                             pool, i, actions[i], timeout=300.0
                         )
                 bs = batch_states[i]
+                # The key's split is a dispatch of its own, and stays where
+                # it was, ahead of the staging.
+                with scope.phase("act_dispatch"):
+                    rng, act_rng = jax.random.split(rng)
+                # The frame's one copy in. Its batch's slab keeps obs_now
+                # for T+1 turns, until the learn batch is put together from
+                # it, and a worker writes the next frame over the pool's
+                # view one turn from now: stage_frame's arrays own their
+                # memory on every backend.
+                with scope.phase("obs_stage"):
+                    obs_now = stage_frame(common.obs_from_env_out(out))
+                    done_now = jnp.asarray(out["done"])
                 with scope.phase("unroll_cat"):
-                    if bs.observe(out):
+                    if bs.observe(out, obs_now):
                         # Backpressure: while disconnected/electing/syncing
                         # the learner consumes nothing — drop rollouts
                         # rather than queue stale off-policy data without
@@ -552,15 +571,6 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         bs.start_unroll(keep)
                         if not keep:
                             stats["dropped_unrolls"] += 1
-                # The key's split is a dispatch of its own, and stays where
-                # it was, ahead of the staging.
-                with scope.phase("act_dispatch"):
-                    rng, act_rng = jax.random.split(rng)
-                with scope.phase("obs_stage"):
-                    obs_now = jax.tree_util.tree_map(
-                        jnp.asarray, common.obs_from_env_out(out)
-                    )
-                    done_now = jnp.asarray(out["done"])
                 with scope.phase("act_dispatch"):
                     a, logits, core = act(
                         state.params, act_rng, obs_now, done_now,
@@ -595,12 +605,11 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                         with scope.phase("learn_batch_get"):
                             slab = learn_slabs.get()
                         with scope.phase("learn_stage"):
-                            # device_put, not jnp.asarray: the slab is
-                            # written again, and on the CPU backend asarray
-                            # aliases a host array. The slab goes back into
-                            # use once these arrays are ready.
-                            batch = jax.device_put(slab.batch)
-                            learn_slabs.recycle(slab, batch)
+                            # The slab's small leaves copied in, obs put
+                            # together on the device from the frames the
+                            # act calls were given; the slab goes back
+                            # into use once its own leaves are copied.
+                            batch = learn_slabs.stage(slab)
                             if mesh is not None:
                                 batch = shard_batch(mesh, batch)
                         # No host sync between grad_step dispatch and

@@ -15,23 +15,28 @@ copies, which is what keeps actor→HBM staging off the critical path.
 :class:`LearnSlabs` is the in-place assembler of learn batches: where a
 ``Batcher`` copies what it is handed into a new batch, a slab *is* the batch,
 and each env frame is copied once, from the EnvPool's view into its row and
-columns.
+columns. A frame that is on the device already, staged there for its act
+call, is not copied on the host at all: the slab keeps the device arrays, and
+the batch's observation is put together from them by one program on the
+device (:meth:`LearnSlabs.stage`).
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import deque
 from typing import Any, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..telemetry import global_telemetry
 from ..utils import nest
 
-__all__ = ["Batcher", "LearnSlabs", "stage_batch"]
+__all__ = ["Batcher", "LearnSlabs", "stage_batch", "stage_frame"]
 
 
 def stage_batch(batch: Any, device: Optional[Any]) -> Any:
@@ -45,6 +50,20 @@ def stage_batch(batch: Any, device: Optional[Any]) -> Any:
     return jax.device_put(
         jax.tree_util.tree_map(np.asarray, batch), device
     )
+
+
+def stage_frame(obs: Any) -> Any:
+    """An env frame's observation (host arrays, as a rule views over the
+    EnvPool's shared memory) as device arrays that own their memory: good
+    for the act call, and for :meth:`LearnSlabs.write_frame` to keep after
+    the pool has written the next frame over the views. A transfer to an
+    accelerator owns what it wrote. The CPU backend aliases a host array
+    that is aligned to its liking (``jnp.asarray`` and ``jax.device_put``
+    alike, ``may_alias=False`` or not: jax 0.9.0), so there the frame is
+    copied on the host first."""
+    if jax.default_backend() == "cpu":
+        obs = jax.tree_util.tree_map(np.array, obs)
+    return jax.device_put(obs)
 
 
 class _Slot:
@@ -398,16 +417,41 @@ class Batcher:
 class _Slab:
     """One learn batch on the host, and what its pool keeps with it."""
 
-    __slots__ = ("arrays", "core", "cols_done", "batch", "staged")
+    __slots__ = ("arrays", "frames", "core", "cols_done", "batch", "staged")
 
     def __init__(self):
         # key -> tree of host arrays [rows, B, ...], made at the key's first
         # write and kept for every later fill.
         self.arrays: dict = {}
+        # first column of a piece -> (its T+1 frames kept on the device, row
+        # t at index t, and the rows ``src:src+n`` of each that are the
+        # piece's columns), for observations that were never copied here.
+        self.frames: dict = {}
         self.core: list = []  # (first column, core state) per committed piece
         self.cols_done = 0
         self.batch: Any = None  # the learn batch, once every column is in
         self.staged: Any = None  # device arrays staged from ``arrays``
+
+
+@functools.partial(jax.jit, static_argnames="cuts")
+def _assemble_obs(pieces, cuts):
+    """A learn batch's observation, ``[T+1, B, ...]`` per leaf, from frames
+    that are on the device. ``pieces[k]`` is the ``T+1`` frames of the
+    ``k``-th piece in column order, rows ``src:src+n`` of each
+    (``cuts[k]``) being its columns: what stacking a piece's frames on
+    axis 0 and joining the pieces on axis 1 gives, made a row at a time
+    (the pieces' frames ``t`` joined, the rows stacked), which writes the
+    batch once and needs no room beside it."""
+    rows = [
+        jax.tree_util.tree_map(
+            lambda *frames: jnp.concatenate(
+                [x[src:src + n] for x, (src, n) in zip(frames, cuts)]
+            ),
+            *frames_t,
+        )
+        for frames_t in zip(*pieces)
+    ]
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *rows)
 
 
 class LearnSlabs:
@@ -420,10 +464,28 @@ class LearnSlabs:
     columns, writes frame and action ``t`` of its unroll straight into row
     ``t`` of them, and once row ``T`` is in either commits the columns or
     writes them again (a dropped unroll). A slab whose columns are all
-    committed is a learn batch: nothing is stacked or concatenated, every
-    frame was copied once. ``batch_size`` need not be a multiple of a
-    window's width: a window may end one slab and begin the next, and a
-    write is then two slice assignments per leaf.
+    committed is a learn batch: nothing is stacked or concatenated on the
+    host, every frame was copied once. ``batch_size`` need not be a
+    multiple of a window's width: a window may end one slab and begin the
+    next, and a write is then two slice assignments per leaf.
+
+    **A frame goes to the device once.** What :meth:`write_frame` copies
+    is decided by what it is handed. An observation of host arrays is
+    copied into row ``t``. An observation of device arrays (the frame as it
+    was staged for its act call) is not copied: the slab keeps the arrays
+    for row ``t``, and the batch's ``obs`` is made from them on the device,
+    by one jitted program, when the batch is staged (:meth:`stage`): a
+    piece's ``T+1`` frames stacked on axis 0, cut where a window ends one
+    slab and begins the next, the pieces joined on axis 1 in column order,
+    per leaf; bit for bit what the host slab would have held, and never
+    transferred a second time. ``done``, ``rewards``, ``actions`` and
+    ``behavior_logits`` (small) are written on the host either way. A kept
+    frame has to stay whole until its batch is staged, ``T+1`` turns and
+    more, while the EnvPool's view it came from is written again one turn
+    later: it must own its memory, which :func:`stage_frame` sees to on
+    every backend. A learn batch's observation is all of one kind: a slab
+    that completes with frames kept for some of its rows and copied for
+    others raises.
 
     ``core_state`` (``[B_actor, ...]`` device arrays, ``()`` without an
     RNN) is not written anywhere: each committed unroll's start state is
@@ -441,6 +503,9 @@ class LearnSlabs:
 
     Telemetry (process-global, label ``slabs=``):
     ``learn_slab_batches_total`` (learn batches completed in place),
+    ``learn_slab_device_obs_batches_total`` (those of them whose
+    observation was assembled on the device; the difference went the
+    host's way),
     ``learn_slab_reuse_waits_total`` / ``learn_slab_reuse_wait_seconds_total``
     (slabs taken back into use, and the seconds that waited for their
     transfer), ``learn_slab_rewinds_total`` (unrolls whose columns were
@@ -460,6 +525,8 @@ class LearnSlabs:
         self._tel = global_telemetry()
         reg = self._tel.registry
         self._m_batches = reg.counter("learn_slab_batches_total", slabs=name)
+        self._m_device_obs = reg.counter(
+            "learn_slab_device_obs_batches_total", slabs=name)
         self._m_reuses = reg.counter("learn_slab_reuse_waits_total",
                                      slabs=name)
         self._m_reuse_wait = reg.counter(
@@ -485,8 +552,17 @@ class LearnSlabs:
 
     def write_frame(self, window: list, t: int, obs: Any, done: Any,
                     rewards: Any) -> None:
-        """Copy frame ``t`` of the window's unroll into row ``t``."""
-        self._write(window, "obs", t, obs, self.T + 1)
+        """Frame ``t`` of the window's unroll into row ``t``: ``done`` and
+        ``rewards`` copied, ``obs`` copied too where it is host arrays and
+        kept as it is where it is device arrays."""
+        if all(isinstance(x, jax.Array) for x in nest.flatten(obs)):
+            for slab, lo, hi, src in window:
+                rows, _, _ = slab.frames.setdefault(
+                    lo, ([None] * (self.T + 1), src, hi - lo)
+                )
+                rows[t] = obs
+        else:
+            self._write(window, "obs", t, obs, self.T + 1)
         self._write(window, "done", t, done, self.T + 1)
         self._write(window, "rewards", t, rewards, self.T + 1, np.float32)
 
@@ -511,6 +587,10 @@ class LearnSlabs:
             ))
             slab.cols_done += n
             if slab.cols_done == self.batch_size:
+                if slab.frames:
+                    self._check_frames(slab)
+                    # An earlier fill's host copy has no place in this batch.
+                    slab.arrays.pop("obs", None)
                 pieces = [c for _, c in sorted(slab.core, key=lambda p: p[0])]
                 slab.batch = dict(
                     slab.arrays,
@@ -521,9 +601,11 @@ class LearnSlabs:
                 if self._tel.on:
                     self._m_batches.inc()
 
-    def rewind(self) -> None:
-        """Count an unroll that is dropped: its producer keeps the window
-        and writes the columns again."""
+    def rewind(self, window: list) -> None:
+        """An unroll is dropped: its producer keeps the window and writes
+        the columns again; the frames kept for it are let go."""
+        for slab, lo, _, _ in window:
+            slab.frames.pop(lo, None)
         if self._tel.on:
             self._m_rewinds.inc()
 
@@ -539,23 +621,57 @@ class LearnSlabs:
 
     def get(self) -> _Slab:
         """The oldest completed slab; its ``batch`` is the learn batch
-        (host arrays, ``core_state`` as committed). Give the slab back
-        with :meth:`recycle`, or keep the batch for good."""
+        (host arrays, ``core_state`` as committed; no ``obs`` where the
+        frames were kept on the device: :meth:`stage` makes it). Give the
+        slab back with :meth:`stage` or :meth:`recycle`, or keep the batch
+        for good."""
         if not self._ready:
             raise RuntimeError("no completed learn batch")
         return self._ready.popleft()
+
+    def stage(self, slab: _Slab) -> dict:
+        """The slab's learn batch as device arrays, and the slab given back
+        for another fill. What the slab holds on the host goes in by one
+        ``jax.device_put`` (not ``jnp.asarray``: the slab is written again),
+        and the slab is reused once that transfer is done; ``obs`` is
+        assembled on the device where the frames were kept there, one
+        program for a loop's shapes."""
+        frames = [slab.frames[lo] for lo in sorted(slab.frames)]
+        batch = jax.device_put(slab.batch)
+        self.recycle(slab, batch)
+        if frames:
+            batch["obs"] = _assemble_obs(
+                [rows for rows, _, _ in frames],
+                cuts=tuple((src, n) for _, src, n in frames),
+            )
+            if self._tel.on:
+                self._m_device_obs.inc()
+        return batch
 
     def recycle(self, slab: _Slab, staged: Any) -> None:
         """Give ``slab`` back for another fill. ``staged`` is what was
         made from its batch and may still be reading it: the slab is not
         written before ``jax.block_until_ready(staged)`` returns."""
         slab.batch = None
+        slab.frames = {}
         slab.core = []
         slab.cols_done = 0
         slab.staged = staged
         self._free.append(slab)
 
     # -- internals ----------------------------------------------------------
+
+    def _check_frames(self, slab: _Slab) -> None:
+        """A completed slab that kept frames on the device kept every row
+        of every piece there."""
+        kept = sum(None not in rows for rows, _, _ in slab.frames.values())
+        if kept != len(slab.core):
+            raise ValueError(
+                "a learn batch's observation is either kept on the device "
+                "or copied on the host, in every row of every window: "
+                f"{kept} of {len(slab.core)} windows of this one were kept "
+                "whole"
+            )
 
     def _take(self) -> _Slab:
         if not self._free:

@@ -366,13 +366,17 @@ class _FakePool:
         self.length = [5 + (np.arange(B) + i) % 4 for i in range(num_batches)]
         self.ep_step = [np.zeros(B, np.int64) for _ in range(num_batches)]
         self.ep_ret = [np.zeros(B, np.float32) for _ in range(num_batches)]
+        # obs at a multiple of 64, which the CPU backend takes without a
+        # copy, as it may the pool's views: a frame staged by reference to
+        # it reads as the next one.
+        raw = [np.zeros(B * 4 + 16, np.float32) for _ in range(num_batches)]
         self.out = [
-            {"obs": np.zeros((B, 4), np.float32),
+            {"obs": r[(-r.ctypes.data % 64) // 4:][:B * 4].reshape(B, 4),
              "reward": np.zeros(B, np.float32),
              "done": np.zeros(B, bool),
              "episode_step": np.zeros(B, np.int64),
              "episode_return": np.zeros(B, np.float32)}
-            for _ in range(num_batches)
+            for r in raw
         ]
 
     def step(self, i, actions):
@@ -665,26 +669,57 @@ def _sequential_vtrace_reference(cfg, keeps, count):
     return learn[:count]
 
 
-@pytest.mark.parametrize("num_actor_batches,use_lstm", [
-    (1, False), (2, False), (2, True), (3, True),
+@pytest.mark.parametrize("num_actor_batches,use_lstm,learn_batch_size", [
+    (1, False, 7), (2, False, 7), (2, True, 7), (3, True, 7),
+    (2, False, 9),  # whole windows: three actor unrolls to a learn batch
 ])
 def test_vtrace_learn_batches_are_the_sequential_turns(
-        monkeypatch, num_actor_batches, use_lstm):
+        monkeypatch, num_actor_batches, use_lstm, learn_batch_size):
     """With the learning rate at 0 the parameters never move, so the run is
     a function of its seed: its learn batches and their losses are those
     of the plain sequential turn, bit for bit, given the same answers to
-    ``start_unroll`` (the Accumulator connects when it connects)."""
+    ``start_unroll`` (the Accumulator connects when it connects).
+
+    ``train()`` hands its slabs the frame it staged for the act call, and
+    the learn batch's observation is put together on the device; the
+    sequential turn hands over the env output alone, and its slabs copy
+    every frame on the host. The fake pool writes a batch's next frame
+    over its buffers when the batch is submitted, as a worker does."""
+    import math
+
     import jax
+
+    from moolib_tpu.ops import batcher
+    from moolib_tpu.telemetry import global_telemetry
 
     cfg = _vtrace_fake_cfg(
         num_actor_batches=num_actor_batches, use_lstm=use_lstm,
-        log_interval_steps=96,
+        learn_batch_size=learn_batch_size,
+        virtual_batch_size=learn_batch_size, log_interval_steps=96,
     )
+    counters = {
+        name: global_telemetry().registry.counter(
+            f"learn_slab_{name}_total", slabs="learn_slabs"
+        )
+        for name in ("batches", "device_obs_batches")
+    }
+    before = {name: c.value for name, c in counters.items()}
+    batcher._assemble_obs.clear_cache()
     rec = _run_vtrace_on_fakes(monkeypatch, cfg, until_updates=3)
     assert rec.raised is None
     assert len(rec.learn) >= 3
+    # Every learn batch train() took was assembled on the device, by a
+    # program traced once for each way its windows of 3 columns are cut.
+    staged = counters["device_obs_batches"].value - before["device_obs_batches"]
+    assert staged == len(rec.learn)
+    assert counters["batches"].value - before["batches"] >= staged
+    assert batcher._assemble_obs._cache_size() == (
+        math.lcm(3, learn_batch_size) // learn_batch_size
+    )
     monkeypatch.undo()
     reference = _sequential_vtrace_reference(cfg, rec.keeps, 3)
+    assert counters["device_obs_batches"].value - before[
+        "device_obs_batches"] == staged  # the reference went the host's way
     for (batch, loss), (ref_batch, ref_loss) in zip(rec.learn, reference):
         for name in ("obs", "done", "rewards", "actions", "behavior_logits",
                      "core_state"):

@@ -1,8 +1,12 @@
 """The in-place learn-batch assembler (``LearnSlabs`` + ``EnvBatchState``)
 against the path it replaced, kept here as the plain reference: frame lists
 stacked into an unroll, unrolls concatenated by ``Batcher.cat``. Same
-frames, same learn batches, bit for bit; then the reuse guard, the drop, the
-counters, and ``EnvBatchState``'s contract as a2c and remote_actors use it."""
+frames, same learn batches, bit for bit, whether the slab copies a frame on
+the host or keeps the device array staged for its act call; then the reuse
+guard, the drop, the counters, and ``EnvBatchState``'s contract as a2c and
+remote_actors use it."""
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +14,8 @@ import numpy as np
 import pytest
 
 from moolib_tpu.examples.common import EnvBatchState, obs_from_env_out
-from moolib_tpu.ops.batcher import Batcher, LearnSlabs
+from moolib_tpu.ops import batcher as batcher_module
+from moolib_tpu.ops.batcher import Batcher, LearnSlabs, stage_frame
 from moolib_tpu.telemetry import global_telemetry
 from moolib_tpu.utils import nest
 
@@ -58,6 +63,15 @@ class _StackingBatchState:
             self.core_state = new_core_state
 
 
+def _aligned_zeros(shape, dtype):
+    """Zeros at an address the CPU backend takes without a copy: the case
+    in which ``jnp.asarray`` of a pool's view is that view."""
+    nbytes = int(np.prod(shape)) * np.dtype(dtype).itemsize
+    raw = np.zeros(nbytes + 64, np.uint8)
+    start = -raw.ctypes.data % 64
+    return raw[start:start + nbytes].view(dtype).reshape(shape)
+
+
 class _FakePool:
     """EnvPool-shaped outputs from a seed: per actor batch one set of
     buffers that every step overwrites, handed out as they are, the way the
@@ -75,7 +89,7 @@ class _FakePool:
         )
         self.bufs = [
             dict(
-                {k: v.copy() for k, v in obs.items()},
+                {k: _aligned_zeros(v.shape, v.dtype) for k, v in obs.items()},
                 done=np.zeros(batch_size, bool),
                 reward=np.zeros(batch_size, np.float64),
                 episode_return=np.zeros(batch_size, np.float32),
@@ -128,17 +142,40 @@ def _assert_same(got, want):
         np.testing.assert_array_equal(g, w)
 
 
-def _drive(pool, states, n_turns, on_unroll, after_turn=None):
-    """The acting half of ``train()``'s turn over every actor batch."""
+def _drive(pool, states, n_turns, on_unroll, after_turn=None,
+           device_frames=False):
+    """The acting half of ``train()``'s turn over every actor batch. With
+    ``device_frames`` the frame is staged as for an act call and handed to
+    the batch's state with the env output, and once the act step is done
+    (the copy in has read the pool's view) a worker writes over the view:
+    a frame kept by reference to it would be lost."""
     for _ in range(n_turns):
         for i, bs in enumerate(states):
             out = pool.step(i)
-            on_unroll(bs, bs.observe(out))
+            obs = obs_from_env_out(out)
+            staged = (stage_frame(obs),) if device_frames else ()
+            on_unroll(bs, bs.observe(out, *staged))
             bs.record_action(*pool.act())
+            if device_frames:
+                jax.block_until_ready(staged)
+                for view in jax.tree_util.tree_leaves(obs):
+                    view[...] = 101
         if after_turn is not None:
             after_turn()
 
 
+def _take(slabs, slab, device_frames):
+    """A completed slab's learn batch, on the host, and the slab given back:
+    as ``train()`` stages it where the frames are on the device."""
+    if device_frames:
+        return _snapshot(slabs.stage(slab))
+    batch = _snapshot(slab.batch)
+    slabs.recycle(slab, ())
+    return batch
+
+
+@pytest.mark.parametrize("device_frames", [False, True],
+                         ids=["host_frames", "device_frames"])
 @pytest.mark.parametrize(
     "actor_b,num_batches,learn_b,dict_obs",
     [
@@ -151,8 +188,11 @@ def _drive(pool, states, n_turns, on_unroll, after_turn=None):
     ],
 )
 def test_learn_batches_equal_stack_and_cat(actor_b, num_batches, learn_b,
-                                           dict_obs):
+                                           dict_obs, device_frames):
     n_turns = 6 * T + 3
+    name = f"test_equal_{device_frames}"
+    before = _counters(name)
+    batcher_module._assemble_obs.clear_cache()
     want, got = [], []
 
     ref_pool = _FakePool(7, num_batches, actor_b, dict_obs)
@@ -172,7 +212,7 @@ def test_learn_batches_equal_stack_and_cat(actor_b, num_batches, learn_b,
     )
 
     pool = _FakePool(7, num_batches, actor_b, dict_obs)
-    slabs = LearnSlabs(T, learn_b, name="test_equal")
+    slabs = LearnSlabs(T, learn_b, name=name)
 
     def slab_unroll(bs, complete):
         if complete:
@@ -182,21 +222,30 @@ def test_learn_batches_equal_stack_and_cat(actor_b, num_batches, learn_b,
         # As train() does after the acting half: take what is complete,
         # hand the slab back for the next fill.
         while not slabs.empty():
-            slab = slabs.get()
-            got.append(_snapshot(slab.batch))
-            slabs.recycle(slab, ())
+            got.append(_take(slabs, slabs.get(), device_frames))
 
     _drive(
         pool,
         [EnvBatchState(T, _initial_core(actor_b), slabs=slabs)
          for _ in range(num_batches)],
-        n_turns, slab_unroll, take,
+        n_turns, slab_unroll, take, device_frames,
     )
 
     assert len(want) == (6 * num_batches * actor_b) // learn_b
     assert len(got) == len(want)
     for g, w in zip(got, want):
         _assert_same(g, w)
+    counted = {k: v - before[k] for k, v in _counters(name).items()}
+    assert counted["learn_slab_batches_total"] == len(got)
+    assert counted["learn_slab_device_obs_batches_total"] == (
+        len(got) if device_frames else 0
+    )
+    # The assembly program is traced once for a loop's shapes: once in all
+    # where the learn batch is whole windows, once for each way a window is
+    # cut where it is not (they come round), and never for host frames.
+    assert batcher_module._assemble_obs._cache_size() == (
+        math.lcm(actor_b, learn_b) // learn_b if device_frames else 0
+    )
     # The bootstrap overlap within one actor batch's columns: row T of an
     # unroll is row 0 of the next in the same columns' next batch, when
     # learn = actor keeps one actor batch to one learn batch.
@@ -272,6 +321,7 @@ def test_slab_is_not_written_before_its_transfer_is_ready():
     assert counters["learn_slab_reuse_waits_total"] == 4
     assert 0 <= counters["learn_slab_reuse_wait_seconds_total"] < 5
     assert counters["learn_slab_rewinds_total"] == 0
+    assert counters["learn_slab_device_obs_batches_total"] == 0
 
 
 def _counters(name):
@@ -280,6 +330,7 @@ def _counters(name):
         key: reg.counter(key, slabs=name).value
         for key in (
             "learn_slab_batches_total",
+            "learn_slab_device_obs_batches_total",
             "learn_slab_reuse_waits_total",
             "learn_slab_reuse_wait_seconds_total",
             "learn_slab_rewinds_total",
@@ -287,14 +338,18 @@ def _counters(name):
     }
 
 
-def test_dropped_unroll_leaves_no_rows_and_is_counted():
+@pytest.mark.parametrize("device_frames", [False, True],
+                         ids=["host_frames", "device_frames"])
+def test_dropped_unroll_leaves_no_rows_and_is_counted(device_frames):
     """Backpressure as ``train()`` applies it: the second unroll of actor
     batch 0 is dropped. Its columns are written again, so every learn batch
-    is made of kept unrolls only, in the columns their actor batch holds."""
+    is made of kept unrolls only, in the columns their actor batch holds;
+    of frames kept on the device, the dropped unroll's are let go."""
     num_batches, actor_b = 2, 4
     pool = _FakePool(11, num_batches, actor_b, False)
     ref_pool = _FakePool(11, num_batches, actor_b, False)
-    slabs = LearnSlabs(T, 8, name="test_drop")
+    name = f"test_drop_{device_frames}"
+    slabs = LearnSlabs(T, 8, name=name)
     states = [EnvBatchState(T, _initial_core(actor_b), slabs=slabs)
               for _ in range(num_batches)]
     refs = [_StackingBatchState(T, _initial_core(actor_b))
@@ -320,15 +375,18 @@ def test_dropped_unroll_leaves_no_rows_and_is_counted():
 
     def take():
         while not slabs.empty():
-            got.append(_snapshot(slabs.get().batch))
+            got.append(_take(slabs, slabs.get(), device_frames))
 
     _drive(ref_pool, refs, 4 * T + 1, ref_unroll)
-    _drive(pool, states, 4 * T + 1, on_unroll, take)
+    _drive(pool, states, 4 * T + 1, on_unroll, take, device_frames)
 
     assert dropped_unrolls == 1
-    counters = _counters("test_drop")
+    counters = _counters(name)
     assert counters["learn_slab_rewinds_total"] == 1
     assert counters["learn_slab_batches_total"] == len(got) == 3
+    assert counters["learn_slab_device_obs_batches_total"] == (
+        3 if device_frames else 0
+    )
     # Each learn batch as (actor batch, its unroll) per block of columns.
     # Batch 0's unroll 1 is in none. Dropping it left batch 0 in columns
     # 0:4 of the second slab while batch 1 moved on, so batch 1 was first
@@ -426,3 +484,39 @@ def test_learn_slabs_argument_and_empty_get():
     assert slabs.empty() and slabs.ready() == 0
     with pytest.raises(RuntimeError):
         slabs.get()
+
+
+def test_stage_frame_owns_its_memory():
+    """What the slabs keep for T+1 turns is not the pool's view: the CPU
+    backend would take an aligned host array as it is."""
+    view = {"glyphs": _aligned_zeros((4, 4, 6), np.int16),
+            "blstats": _aligned_zeros((4, 7), np.float32)}
+    staged = stage_frame(view)
+    assert all(isinstance(x, jax.Array)
+               for x in jax.tree_util.tree_leaves(staged))
+    jax.block_until_ready(staged)
+    for v in view.values():
+        v[...] = 7
+    for x in jax.tree_util.tree_leaves(staged):
+        assert not np.asarray(x).any()
+
+
+def test_frames_kept_and_copied_in_one_learn_batch_are_refused():
+    """A learn batch's observation is assembled on the device or in the
+    host slab, not half and half: the slab that completes with both says
+    so, and hands out no batch with rows missing."""
+    pool = _FakePool(19, 2, 4, False)
+    slabs = LearnSlabs(T, 8, name="test_mixed")
+    states = [EnvBatchState(T, (), slabs=slabs) for _ in range(2)]
+    for t in range(T + 1):
+        for i, bs in enumerate(states):
+            out = pool.step(i)
+            staged = stage_frame(obs_from_env_out(out)) if i == 0 else None
+            complete = bs.observe(out, staged)
+            if t < T:
+                bs.record_action(*pool.act())
+    assert complete is True
+    states[0].start_unroll(True)
+    with pytest.raises(ValueError, match="kept on the device or copied"):
+        states[1].start_unroll(True)
+    assert slabs.empty()
