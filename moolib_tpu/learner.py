@@ -102,7 +102,9 @@ def impala_loss(
     - ``done``:  [T+1, B] bool   episode terminations
     - ``rewards``: [T+1, B] f32  rewards (index t = reward entering step t)
     - ``actions``: [T, B] int32  actions taken by the behavior policy
-    - ``behavior_logits``: [T, B, A] f32  behavior policy logits
+    - ``behavior_logits``: [T, B, A] f32  behavior policy logits (how a
+      ``[T, 1, A]`` leaf lies in memory and which pass reads it:
+      ``ops/vtrace.py``'s module docstring and ``action_logprob_path``)
     - ``core_state``: tuple of [B, ...]  RNN state at t=0 (empty for FF)
 
     The model is unrolled over all T+1 frames; frame T provides the

@@ -27,6 +27,23 @@ the inputs' float32 and agrees with a float64 oracle to 1.3e-6 absolute on
 values of 10 at ``T`` = 16,383, as the step-by-step form does
 (``tests/test_vtrace.py``).
 
+**The behaviour policy's log-probabilities** (:func:`action_logprob_path`).
+A learn batch's ``behavior_logits`` float32 ``[T, 1, A]`` reach a step in
+``{2,1,0:T(1,128)}``: a middle axis of 1 tiles by one sublane, so a row is
+``A`` contiguous numbers. XLA emits ``log_softmax``'s row maximum over that
+layout as a bare ``reduce`` on vectors an eighth full: 7.9 ms a step for 402
+MB at ``[8191, 1, 12288]`` and ``[4095, 1, 24576]``, sixteen times the bytes'
+time and the longest operation of two cells, and no re-spelling in
+``jax.numpy`` cures it (PERF.md, Findings "PR 41" and "PR 44"). Where ``A`` is
+a whole number of (8,128) tiles the same bytes are ``[T, A/128, 128]`` tiled
+by (8,128), which XLA takes as a bitcast: such logits, where large, are
+``"streamed"``, one Pallas pass that reads each row once as whole vectors for
+maximum, sum and the action's entry (0.54 ms there, the bytes' time). Every
+other shape, dtype and backend is ``"plain"``: ``[20, 256, 6]``, ``[80, 128,
+23]``, ``[16383, 1, 320]``, and ``[8191, 1, 19360]``, which XLA re-lays out
+for every reader already. The target logits come from the head in a layout
+XLA reads well and need a gradient: they stay :func:`action_log_probs`.
+
 Definitions (paper eq. 1):
     delta_t = rho_t (r_t + gamma_t V(x_{t+1}) - V(x_t))
     v_t     = V(x_t) + delta_t + gamma_t c_t (v_{t+1} - V(x_{t+1}))
@@ -38,13 +55,21 @@ where the rho used for advantages is clipped at ``clip_pg_rho_threshold``.
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..telemetry import global_telemetry
 
 __all__ = ["VTraceReturns", "VTraceFromLogitsReturns", "from_importance_weights",
-           "from_logits", "action_log_probs"]
+           "from_logits", "action_log_probs", "action_logprob_path",
+           "streamed_action_log_probs"]
+
+LANES, SUBLANES = 128, 8
 
 
 class VTraceReturns(NamedTuple):
@@ -64,6 +89,135 @@ def action_log_probs(policy_logits: jax.Array, actions: jax.Array) -> jax.Array:
     """log pi(a|x) for integer actions over a final logits axis."""
     logp = jax.nn.log_softmax(policy_logits, axis=-1)
     return jnp.take_along_axis(logp, actions[..., None], axis=-1).squeeze(-1)
+
+
+# The streamed pass of the behaviour logits, on one TPU v5e (PERF.md,
+# Findings "PR 44"; device time of the function alone, plain / streamed).
+# [8191, 1, 12288] and [4095, 1, 24576], 403 MB: 9.78 / 0.539 and 9.73 /
+# 0.536 ms (747 GB/s); [4095, 1, 16384]: 2.29 / 0.359. Blocks of 2 and 4 MB
+# of logits to a grid step read the same to 1%.
+STREAM_BLOCK_BYTES = 4 * 1024 * 1024
+# Every action lies in scalar memory: 256 KB of its 1 MB at this many rows
+# (compiled for a described v5e; four times as many are refused).
+STREAM_MAX_ROWS = 65_536
+# A row costs the pass three cross-lane reductions whatever its width, so the
+# narrowest row it takes (1,024 actions) decides: 1 / 2 / 4 / 8 / 16 / 32 /
+# 64 MB of [T, 1, 1024] read 7.0 / 15.5 / 30.0 / 58.3 / 115.6 / 230 / 580 us
+# plain and 8.9 / 16.5 / 32.3 / 58.4 / 110.5 / 215 / 425 streamed. From here
+# up no reading has the pass behind (at 16 MB of 4,096 and 16,384 actions
+# 90 / 34 and 108 / 25 us).
+STREAM_MIN_BYTES = 16 * 1024 * 1024
+
+
+def action_logprob_path(shape, dtype) -> str:
+    """``"streamed"`` or ``"plain"``: how ``log pi(a|x)`` of logits ``[...,
+    B, A]`` that need no gradient is computed, from what a trace can see.
+    The streamed pass reads the rows as they lie in row-major memory, which
+    is how XLA holds them when the axis before the actions is 1 and a row
+    is a whole number of (8,128) tiles (wider, ``B`` and ``A`` tile together
+    and XLA reads them well itself); it knows float32; it holds a block of
+    eight rows in fast memory and every action in scalar memory; it is
+    worth its launch on a large array alone; and Mosaic compiles it for a
+    TPU alone."""
+    *lead, columns, actions = shape
+    rows = math.prod(lead) * columns
+    streamed = (
+        jax.default_backend() == "tpu"
+        and jnp.dtype(dtype) == jnp.dtype(jnp.float32)
+        and columns == 1
+        and actions % (SUBLANES * LANES) == 0
+        and SUBLANES * actions * 4 <= STREAM_BLOCK_BYTES
+        and rows <= STREAM_MAX_ROWS
+        and rows * actions * 4 >= STREAM_MIN_BYTES
+    )
+    return "streamed" if streamed else "plain"
+
+
+def _stream_kernel(actions_ref, logits_ref, out_ref):
+    """``out[r] = x[a] - max(x) - log(sum(exp(x - max(x))))`` for the rows
+    ``x = logits[r]`` of a block ``[rows, A/128, 128]``, a row whole vectors,
+    with the row's true maximum as the plain path has it. The actions lie in
+    scalar memory, all of them."""
+    rows, tiles, _ = logits_ref.shape
+    entry = (
+        jax.lax.broadcasted_iota(jnp.int32, (tiles, LANES), 0) * LANES
+        + jax.lax.broadcasted_iota(jnp.int32, (tiles, LANES), 1)
+    )
+    first = pl.program_id(0) * rows
+    last = actions_ref.shape[0] - 1  # a ragged last block reads past it
+
+    # over a row, the same on every lane: Mosaic refuses to broadcast a
+    # (1, 1) along sublanes and lanes at once
+    def whole(v, op):
+        v = op(op(v, axis=0, keepdims=True), axis=1, keepdims=True)
+        return jnp.broadcast_to(v, (1, LANES))
+
+    def eight(g, carry):  # rows apart, so that their reductions overlap
+        for k in range(SUBLANES):
+            r = g * SUBLANES + k
+            x = logits_ref[r]
+            top = whole(x, jnp.max)
+            total = whole(jnp.exp(x - top), jnp.sum)
+            action = actions_ref[jnp.minimum(first + r, last)]
+            taken = whole(jnp.where(entry == action, x, 0.0), jnp.sum)
+            out_ref[pl.ds(r, 1), :] = ((taken - top) - jnp.log(total))[:, :1]
+        return carry
+
+    jax.lax.fori_loop(0, rows // SUBLANES, eight, None)
+
+
+@jax.jit
+def _stream(logits, actions):
+    rows, width = actions.size, logits.shape[-1]
+    tiles = width // LANES
+    block = STREAM_BLOCK_BYTES // (width * 4) // SUBLANES * SUBLANES
+    block = max(SUBLANES, min(block, -(-rows // SUBLANES) * SUBLANES))
+    out = pl.pallas_call(
+        _stream_kernel,
+        out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+        grid=(pl.cdiv(rows, block),),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((block, tiles, LANES), lambda i: (i, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=4 * STREAM_BLOCK_BYTES,
+        ),
+        # Mosaic compiles for a TPU alone. The rule sends every other
+        # backend to the plain path, so only a test gets here off one:
+        # Pallas' interpreter then runs the same arithmetic.
+        interpret=jax.default_backend() != "tpu",
+    )(actions.astype(jnp.int32).reshape(rows),
+      logits.reshape(rows, tiles, LANES))
+    return out.reshape(actions.shape)
+
+
+@jax.custom_vjp
+def streamed_action_log_probs(policy_logits, actions):
+    """:func:`action_log_probs` of float32 logits whose last axis is a whole
+    number of 128-lane tiles, for actions in ``[0, A)``, by one pass that
+    reads every row once and writes ``[..., B]`` alone: equal to the plain
+    path's to float32 rounding (the sum's order), ``-inf`` entries and all.
+    The gradient with respect to the logits is the plain path's, by plain
+    ``jax.numpy``."""
+    return _stream(policy_logits, actions)
+
+
+def _streamed_fwd(policy_logits, actions):
+    return _stream(policy_logits, actions), (policy_logits, actions)
+
+
+def _streamed_bwd(kept, g):
+    policy_logits, actions = kept
+    taken = jax.nn.one_hot(
+        actions, policy_logits.shape[-1], dtype=policy_logits.dtype
+    )
+    return g[..., None] * (taken - jax.nn.softmax(policy_logits, axis=-1)), None
+
+
+streamed_action_log_probs.defvjp(_streamed_fwd, _streamed_bwd)
 
 
 def _compose(later, earlier):
@@ -137,8 +291,23 @@ def from_logits(
     lambda_: float = 1.0,
 ) -> VTraceFromLogitsReturns:
     """V-trace for softmax policies: [T, B, A] logits, [T, B] actions."""
+    # Inside a shard_map no cell's logits are large, the pass has not run
+    # there on a chip and Pallas' interpreter cannot (its grid loop drops
+    # the varying axes, jax 0.9.0): plain, whatever the shape.
+    path = "plain" if jax.typeof(behavior_policy_logits).vma else (
+        action_logprob_path(
+            behavior_policy_logits.shape, behavior_policy_logits.dtype
+        )
+    )
+    # once a trace: once a compile under jit
+    global_telemetry().registry.counter(
+        "vtrace_logprob_calls_traced_total", path=path
+    ).inc()
+    behavior = (
+        streamed_action_log_probs if path == "streamed" else action_log_probs
+    )
     with jax.named_scope("moolib.vtrace"):
-        behavior_log_probs = action_log_probs(behavior_policy_logits, actions)
+        behavior_log_probs = behavior(behavior_policy_logits, actions)
         target_log_probs = action_log_probs(target_policy_logits, actions)
         log_rhos = target_log_probs - behavior_log_probs
         vt = from_importance_weights(

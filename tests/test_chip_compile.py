@@ -349,6 +349,73 @@ def test_the_residual_mixings_kernels_compile_at_the_cells_shape(
     assert compiled.memory_analysis().temp_size_in_bytes < 3 * streams
 
 
+def _readers(text, T, B, A):
+    """The opcodes of the instructions of the entry computation that read
+    its one parameter ``f32[T,B,A]``."""
+    text = text[text.index("\nENTRY "):]
+    names = re.findall(
+        rf"(%\S+) = f32\[{T},{B},{A}\]\S* parameter\(", text)
+    assert len(names) == 1, names
+    return [
+        m.group(1) for m in re.finditer(
+            r"= \S+ ([\w-]+)\(([^)]*)\)", text)
+        if names[0] in m.group(2).split(", ")
+    ]
+
+
+# the nine cells' behaviour logits: three a sequence's rows of a whole
+# number of (8,128) tiles, the others' 19,360 and 320 actions that tile by
+# nothing and IMPALA's wide batch of kilobytes
+@pytest.mark.parametrize("T,B,A,path", [
+    (8191, 1, 12288, "streamed"), (4095, 1, 24576, "streamed"),
+    (4095, 1, 16384, "streamed"), (8191, 1, 19360, "plain"),
+    (16383, 1, 320, "plain"), (20, 256, 6, "plain"),
+])
+def test_the_behaviour_logits_are_read_once_at_the_cells_shape(
+        one_chip, no_compile_cache, monkeypatch, request, T, B, A, path):
+    """``from_logits`` for a v5e with the behaviour logits the module's one
+    ``[T, B, A]`` parameter, as a learn batch hands them over (the target's
+    come from a product, as a head's do). Where the rule streams, the
+    parameter reaches the Pallas pass through a bitcast and nothing else
+    reads it: no copy, no re-layout, and not the bare ``reduce`` over
+    ``{2,1,0:T(1,128)}`` that ``action_log_probs`` compiles to there (7.9 ms
+    a step of two cells, PERF.md, Findings "PR 44"), which the same compile
+    of the plain function shows. The other shapes hold no kernel."""
+    from moolib_tpu.ops import vtrace
+
+    # jax.default_backend() is the CPU here: say what the chip would see
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    vtrace._stream.clear_cache()  # a CPU test's trace runs the interpreter
+    request.addfinalizer(vtrace._stream.clear_cache)
+    assert vtrace.action_logprob_path((T, B, A), jnp.float32) == path
+
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def whole(behaviour, rows, head, actions, rest):
+        target = (rows[..., None] * head).astype(jnp.float32)
+        out = vtrace.from_logits(
+            behaviour, target, actions, rest, rest, rest, rest[0])
+        return out.vs, out.pg_advantages, out.log_rhos
+
+    def plain(behaviour, actions):
+        return vtrace.action_log_probs(behaviour, actions)
+
+    args = (s((T, B, A)), s((T, B)), s((A,)), s((T, B), jnp.int32),
+            s((T, B)))
+    text = jax.jit(whole).lower(*args).compile().as_text()
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    if path == "plain":
+        assert kernels == 0
+        return
+    assert kernels == 1
+    assert "moolib.vtrace" in next(
+        line for line in text.splitlines() if "tpu_custom_call" in line)
+    assert set(_readers(text, T, B, A)) == {"bitcast"}
+    parent = jax.jit(plain).lower(args[0], args[3]).compile().as_text()
+    assert "reduce" in _readers(parent, T, B, A)
+
+
 def test_evabyte_learner_16ks_step_compiles_within_the_chips_memory(
         one_chip, no_compile_cache, monkeypatch):
     """The whole train step of ``evabyte_learner_16k`` as the cell runs it

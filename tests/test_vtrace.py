@@ -204,3 +204,181 @@ def test_jit_and_grad_flow():
 
     g = jax.jit(jax.grad(loss))(jnp.ones((T, B)))
     np.testing.assert_allclose(np.asarray(g), 0.0)
+
+
+# ------------------------------------- the behaviour side's streamed pass
+
+
+def _rows(draw, shape, actions, rng):
+    """Logits ``[T, B, A]`` of one kind of row."""
+    T, B, A = shape
+    x = rng.standard_normal(shape).astype(np.float32) * 3
+    if draw == "pm80":
+        x = x / np.abs(x).max(axis=-1, keepdims=True) * 80
+    elif draw == "equal":
+        x = np.broadcast_to(x[..., :1], shape).copy()
+    elif draw == "neg_inf":
+        # two finite entries a row; on odd rows the action's is not one
+        keep = (actions + np.arange(T * B).reshape(T, B) % 2)[..., None]
+        keep = np.concatenate([keep, keep + A // 2], axis=-1) % A
+        finite = np.take_along_axis(x, keep, axis=-1)
+        x = np.full(shape, -np.inf, np.float32)
+        np.put_along_axis(x, keep, finite, axis=-1)
+    return jnp.asarray(x)
+
+
+def _traced(path):
+    """``vtrace_logprob_calls_traced_total{path=}`` as it stands."""
+    from moolib_tpu.telemetry import global_telemetry
+
+    return global_telemetry().registry.value(
+        "vtrace_logprob_calls_traced_total", path=path) or 0
+
+
+def _ulps(got, want, scale, n=2):
+    """``got`` within ``n`` float32 ulps at ``scale`` of ``want``, an
+    infinity where it has one."""
+    got, want = np.asarray(got), np.asarray(want)
+    finite = np.isfinite(want)
+    np.testing.assert_array_equal(got[~finite], want[~finite])
+    room = n * np.spacing(np.broadcast_to(
+        np.asarray(scale, np.float32), want.shape))
+    gap = np.abs(got[finite] - want[finite])
+    assert (gap <= room[finite]).all(), (gap.max(), room[finite].min())
+
+
+@pytest.mark.parametrize("at", ["first", "last", "edge"])
+@pytest.mark.parametrize("draw", ["normal", "pm80", "neg_inf", "equal"])
+@pytest.mark.parametrize("shape", [(64, 1, 1024), (37, 1, 384), (16, 4, 256)])
+def test_the_streamed_pass_reads_as_log_softmax_does(shape, draw, at,
+                                                     monkeypatch):
+    """The one pass over the behaviour logits (Pallas' interpreter here;
+    37 rows are a ragged block of eight) against ``log_softmax`` and
+    ``take_along_axis`` in float32: values to 2 ulp at the row's scale, a
+    ``-inf`` where the action's entry is one, the gradient with respect to
+    the logits the plain path's, and ``from_logits`` compiled once."""
+    T, B, A = shape
+    rng = np.random.default_rng(A + len(draw))
+    actions = {
+        "first": np.zeros((T, B), np.int64),
+        "last": np.full((T, B), A - 1),
+        # either side of a 128-lane tile's edge
+        "edge": 127 + np.arange(T * B).reshape(T, B) % 2,
+    }[at]
+    logits = _rows(draw, shape, actions, rng)
+    actions = jnp.asarray(actions, jnp.int32)
+
+    want = vtrace.action_log_probs(logits, actions)
+    x = np.asarray(logits)
+    scale = np.maximum(
+        np.abs(np.where(np.isfinite(x), x, 0)).max(axis=-1),
+        np.abs(np.where(np.isfinite(want), want, 0)),
+    )
+    _ulps(vtrace.streamed_action_log_probs(logits, actions), want, scale)
+
+    weights = jnp.asarray(rng.standard_normal((T, B)), jnp.float32)
+    grads = [
+        jax.grad(lambda x: jnp.sum(weights * f(x, actions)))(logits)
+        for f in (vtrace.streamed_action_log_probs, vtrace.action_log_probs)
+    ]
+    _ulps(grads[0], grads[1], np.abs(np.asarray(weights))[..., None])
+
+    monkeypatch.setattr(vtrace, "action_logprob_path", lambda *a: "streamed")
+    f = jax.jit(lambda *args: vtrace.from_logits(*args))  # its own cache
+    zeros = jnp.zeros((T, B), jnp.float32)
+    target = jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    for _ in range(2):
+        out = f(logits, target, actions, zeros + 0.9, zeros, zeros, zeros[0])
+    assert f._cache_size() == 1
+    _ulps(out.behavior_action_log_probs, want, scale)
+    _ulps(out.target_action_log_probs,
+          vtrace.action_log_probs(target, actions), 16.0)
+
+
+def test_under_a_mesh_axis_the_plain_path_runs(monkeypatch):
+    """``atari_learner_dp4`` runs ``from_logits`` inside a ``shard_map``.
+    Its logits are kilobytes; and whatever the rule says of a shape, logits
+    that vary over a mesh axis take the plain path: the streamed pass has
+    met no ``shard_map`` on a chip, and Pallas' interpreter cannot run it
+    there (its grid loop drops the varying axes)."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    T, B, A = 16, 4, 256
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.standard_normal((T, B, A)), jnp.float32)
+    actions = jnp.asarray(rng.integers(0, A, (T, B)), jnp.int32)
+    zeros = jnp.zeros((T, B), jnp.float32)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+    cols = P(None, "dp")
+    monkeypatch.setattr(vtrace, "action_logprob_path", lambda *a: "streamed")
+    before = {p: _traced(p) for p in ("streamed", "plain")}
+    got = jax.jit(jax.shard_map(
+        lambda x, a, z: vtrace.from_logits(
+            x, x, a, z, z, z, z[0]).behavior_action_log_probs,
+        mesh=mesh, in_specs=(cols, cols, cols), out_specs=cols,
+    ))(logits, actions, zeros)
+    assert {p: _traced(p) - before[p] for p in before} == {
+        "streamed": 0, "plain": 1}
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(vtrace.action_log_probs(logits, actions)))
+
+
+def _cell_logits():
+    """Every cell of the benchmark: its behaviour logits' shape on a chip,
+    read from its files, and the path the rule gives it on a TPU."""
+    import json
+    import os
+
+    root = os.path.join(os.path.dirname(__file__), "..")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    streamed = {"mellum2_learner_8k", "solar2_learner_4k", "xing4_learner_4k"}
+    cells = []
+    for w in bench["workloads"]:
+        with open(os.path.join(root, files[w["config"]])) as f:
+            config = json.load(f)
+        with open(os.path.join(
+                root, "benchmark", "workloads", w["name"] + ".json")) as f:
+            cell = json.load(f)
+        # the loop's learn batch is its own file's, one unroll long
+        train = cell.get("train_config", {})
+        T = cell.get("unroll_length", train.get("unroll_length"))
+        B = cell.get("batch_per_chip", train.get("learn_batch_size"))
+        cells.append(pytest.param(
+            (T, B, config["num_actions"]),
+            "streamed" if w["name"] in streamed else "plain", id=w["name"],
+        ))
+    return cells
+
+
+@pytest.mark.parametrize("shape,path", _cell_logits())
+def test_the_rule_sends_each_cells_logits_its_way(shape, path, monkeypatch,
+                                                  request):
+    """Three cells' logits are a sequence's rows of a whole number of
+    (8,128) tiles, 268-403 MB: streamed on a TPU. The six others are plain
+    there (19,360 and 320 actions tile by nothing, the IMPALA-side batches
+    are wide and kilobytes), and every one is plain on the CPU. A trace of
+    ``from_logits`` counts the path once."""
+    assert all(isinstance(n, int) and n > 0 for n in shape), shape
+    assert vtrace.action_logprob_path(shape, jnp.float32) == "plain"
+    T, B, A = shape
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in (
+        (shape, jnp.float32), (shape, jnp.float32), ((T, B), jnp.int32),
+        ((T, B), jnp.float32), ((T, B), jnp.float32), ((T, B), jnp.float32),
+        ((B,), jnp.float32))]
+
+    def traces():  # of one fresh trace of from_logits, by path
+        before = {p: _traced(p) for p in ("streamed", "plain")}
+        out = jax.eval_shape(lambda *a: vtrace.from_logits(*a), *args)
+        assert out.behavior_action_log_probs.shape == (T, B)
+        return {p: _traced(p) - n for p, n in before.items() if _traced(p) > n}
+
+    assert traces() == {"plain": 1}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # the pass decides between Mosaic and the interpreter where it is
+    # traced: a trace made under this answer must not outlive it
+    request.addfinalizer(vtrace._stream.clear_cache)
+    assert vtrace.action_logprob_path(shape, jnp.float32) == path
+    assert vtrace.action_logprob_path(shape, jnp.bfloat16) == "plain"
+    assert traces() == {path: 1}
