@@ -84,8 +84,6 @@ class VtraceConfig:
     # arguments (a benchmark configuration's model.kwargs); the env's
     # observation is then a token id and its action the next token.
     lm_config: Optional[str] = None
-    transformer_mlp: str = "dense"  # dense | moe (Switch blocks + aux loss)
-    num_experts: int = 8
     total_steps: int = 500_000
     max_seconds: Optional[float] = None  # wall-clock stop (benchmarks)
     # infra
@@ -163,10 +161,7 @@ def _make_model(cfg: VtraceConfig):
     if model == "mlp":
         return A2CNet(num_actions=num_actions, use_lstm=cfg.use_lstm)
     if model == "transformer":
-        return TransformerNet(
-            num_actions=num_actions, compute_dtype=dtype,
-            mlp=cfg.transformer_mlp, num_experts=cfg.num_experts,
-        )
+        return TransformerNet(num_actions=num_actions, compute_dtype=dtype)
     if model == "decoder_lm":
         import json
 
@@ -293,17 +288,6 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         from moolib_tpu.models.lm import learn_apply as lm_learn_apply
 
         learn_apply = lm_learn_apply(net)
-    elif getattr(net, "mlp", "dense") == "moe":
-        # MoE models sow per-layer aux (lb/z losses, drop fraction) into
-        # intermediates; the 3-tuple apply convention folds them into the
-        # loss and the training metrics (drops must never be silent).
-        from moolib_tpu.models.transformer import moe_aux_losses
-
-        def learn_apply(params, obs, done, core_state):
-            (out, st), inter = net.apply(
-                params, obs, done, core_state, mutable=["intermediates"]
-            )
-            return out, st, moe_aux_losses(inter)
 
     # grad_scale folds the x batch_size "sum contribution" scaling into the
     # jitted step, so the update loop never touches gradient values on the
@@ -392,7 +376,6 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
         entropy=StatMean(),
         grad_norm=StatMean(),
         sps=StatMean(),
-        moe_drop_fraction=StatMean(),
     )
     gsa = GlobalStatsAccumulator(accumulator.group, stats)
     tsv = (
@@ -466,12 +449,6 @@ def train(cfg: VtraceConfig, log_fn=print) -> List[dict]:
                 window["total_loss"] += float(m["total_loss"])
                 window["entropy"] += float(m["entropy"])
                 window["grad_norm"] += float(m["grad_norm"])
-                if "moe_drop_fraction" in m:
-                    # Capacity drops must be visible in the logs, not
-                    # silently eaten by the residual path.
-                    window["moe_drop_fraction"] += float(
-                        m["moe_drop_fraction"]
-                    )
 
     # One act call may be in flight: (batch, action, logits, core state),
     # dispatched, both copies out asked for, nothing of it read yet. The

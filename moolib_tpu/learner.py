@@ -56,10 +56,6 @@ class ImpalaConfig:
     lambda_: float = 1.0
     clip_rho_threshold: float = 1.0
     clip_pg_rho_threshold: float = 1.0
-    # MoE aux-loss weights (used when apply_fn returns model aux — the
-    # TransformerNet(mlp='moe') path); Switch/ST-MoE defaults.
-    moe_lb_cost: float = 0.01
-    moe_z_cost: float = 0.001
     # Weight of a model's own multi-token-prediction cross-entropy, where
     # its aux carries one (DecoderLM with ``mtp``, or with further
     # prediction heads, ``num_pred_heads``).
@@ -111,13 +107,10 @@ def impala_loss(
     bootstrap value.
 
     ``apply_fn`` may return an optional THIRD element, a dict of model aux.
-    Where it carries ``load_balance_loss`` / ``router_z_loss`` (the
-    capacity-factor MoE's, from
-    :func:`moolib_tpu.models.transformer.moe_aux_losses`) they are folded
-    into the total with ``config.moe_lb_cost`` / ``config.moe_z_cost``;
-    a multi-token-prediction module's or the further prediction heads'
-    ``mtp_loss`` with ``config.mtp_cost``; ``drop_fraction`` is surfaced so capacity drops
-    are visible in training logs; every other entry (the dropless layer's ``moe_*`` counters, from
+    The one loss term it can carry is ``mtp_loss`` (a multi-token-prediction
+    module's or the further prediction heads' cross-entropy), folded into
+    the total with ``config.mtp_cost``; every other entry (the expert
+    layer's ``moe_*`` counters, from
     :func:`moolib_tpu.models.lm.learn_apply`) passes through to the
     metrics as a counter.
     """
@@ -172,19 +165,12 @@ def impala_loss(
         "mean_baseline": jnp.mean(baseline),
     }
     if model_aux is not None:
-        # Loss terms where the model's aux carries them; every other entry
-        # is a counter of the model's own and goes to the metrics as it is.
+        # ``mtp_loss`` is the one loss term; every other entry is a counter
+        # of the model's own and goes to the metrics as it is.
         aux = dict(model_aux)
-        for key, cost, name in (
-            ("load_balance_loss", config.moe_lb_cost, "moe_lb_loss"),
-            ("router_z_loss", config.moe_z_cost, "moe_z_loss"),
-            ("mtp_loss", config.mtp_cost, "mtp_loss"),
-        ):
-            if key in aux:
-                metrics[name] = aux.pop(key)
-                total = total + cost * metrics[name]
-        if "drop_fraction" in aux:
-            metrics["moe_drop_fraction"] = aux.pop("drop_fraction")
+        if "mtp_loss" in aux:
+            metrics["mtp_loss"] = aux.pop("mtp_loss")
+            total = total + config.mtp_cost * metrics["mtp_loss"]
         metrics["total_loss"] = total
         metrics.update(aux)
     return total, metrics

@@ -39,7 +39,6 @@ from flax import linen as nn
 from ..ops import attention as attn_ops
 from ..ops import hyper_mix
 from ..ops import ring_attention as ring_ops
-from ..parallel.moe import moe_ffn
 
 __all__ = [
     "TransformerNet",
@@ -48,7 +47,6 @@ __all__ = [
     "hyper_read",
     "hyper_residual_block",
     "hyper_write",
-    "moe_aux_losses",
     "residual_block",
 ]
 
@@ -251,51 +249,6 @@ class _SelfAttention(nn.Module):
         return nn.Dense(E, use_bias=False, name="out")(o)
 
 
-class _MoEMlp(nn.Module):
-    """Switch/GShard MoE MLP for a transformer block.
-
-    Routing/capacity/losses come from :func:`moolib_tpu.parallel.moe.moe_ffn`;
-    per-call aux (load-balance loss, router z-loss, drop fraction) is sown
-    into the ``intermediates`` collection — train with
-    ``apply(..., mutable=["intermediates"])`` and fold
-    :func:`moe_aux_losses` into the loss so capacity drops are neither
-    silent nor unpenalized. The router param is deliberately NOT named
-    ``kernel`` so tensor-parallel shape derivation (parallel/tp.py) never
-    mistakes it for a projection.
-    """
-
-    num_experts: int
-    mlp_ratio: int
-    top_k: int
-    capacity_factor: float
-
-    @nn.compact
-    def __call__(self, x):  # [T, B, E] -> [T, B, E]
-        T, B, E = x.shape
-        d_hidden = self.mlp_ratio * E
-        init = nn.initializers.lecun_normal()
-        # batch_axis=0: the expert axis is a batch of independent matrices,
-        # not receptive field — without it fan_in becomes E_experts * d_in
-        # and every expert starts sqrt(num_experts)x too small (the
-        # per-expert scaling moe_params uses).
-        expert_init = nn.initializers.lecun_normal(batch_axis=(0,))
-        params = {
-            "router": self.param("router", init, (E, self.num_experts)),
-            "w_up": self.param(
-                "w_up", expert_init, (self.num_experts, E, d_hidden)
-            ),
-            "w_down": self.param(
-                "w_down", expert_init, (self.num_experts, d_hidden, E)
-            ),
-        }
-        y, aux = moe_ffn(
-            params, x.reshape(T * B, E),
-            top_k=self.top_k, capacity_factor=self.capacity_factor,
-        )
-        self.sow("intermediates", "moe_aux", aux)
-        return y.reshape(T, B, E)
-
-
 def sown_dicts(intermediates, marker: str) -> list:
     """Every dict with the key ``marker`` that a module sowed into a flax
     ``intermediates`` collection, in traversal order."""
@@ -316,55 +269,22 @@ def sown_dicts(intermediates, marker: str) -> list:
     return found
 
 
-def moe_aux_losses(intermediates) -> dict:
-    """Aggregate every MoE layer's sown aux from a flax ``intermediates``
-    collection: summed load-balance and router-z losses (add them to the
-    training loss, typically with weights ~1e-2 / ~1e-3) and the mean drop
-    fraction (log it — silent drops are a capacity bug)."""
-    found = sown_dicts(intermediates, "load_balance_loss")
-    if not found:
-        raise ValueError("no MoE aux entries in intermediates — was the "
-                         "model built with mlp='moe' and applied with "
-                         "mutable=['intermediates']?")
-    n = len(found)
-    return {
-        "load_balance_loss": sum(a["load_balance_loss"] for a in found),
-        "router_z_loss": sum(a["router_z_loss"] for a in found),
-        "drop_fraction": sum(a["drop_fraction"] for a in found) / n,
-        "n_moe_layers": n,
-    }
-
-
 class _Block(nn.Module):
     num_heads: int
     mlp_ratio: int
     backend: str
     ring_axis: str
-    mlp: str = "dense"
-    num_experts: int = 8
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
 
     @nn.compact
     def __call__(self, x, seg_bt, positions):
-        if self.mlp not in ("dense", "moe"):
-            raise ValueError(
-                f"unknown mlp type {self.mlp!r}; expected 'dense' or 'moe'"
-            )
         attention = _SelfAttention(
             self.num_heads, self.backend, self.ring_axis, name="attn"
         )
-        if self.mlp == "moe":
-            mlp = _MoEMlp(
-                self.num_experts, self.mlp_ratio, self.moe_top_k,
-                self.moe_capacity_factor, name="moe",
-            )
-        else:
-            width = x.shape[-1]
+        width = x.shape[-1]
 
-            def mlp(h):
-                h = nn.gelu(nn.Dense(self.mlp_ratio * width)(h))
-                return nn.Dense(width)(h)
+        def mlp(h):
+            h = nn.gelu(nn.Dense(self.mlp_ratio * width)(h))
+            return nn.Dense(width)(h)
 
         return residual_block(
             x, nn.LayerNorm(), lambda h: attention(h, seg_bt, positions),
@@ -384,10 +304,6 @@ class TransformerNet(nn.Module):
     attention_backend: str = "auto"  # dense|blockwise|flash|ring|zigzag|auto
     ring_axis: str = "sp"
     compute_dtype: jnp.dtype = jnp.float32
-    mlp: str = "dense"  # dense | moe (Switch/GShard blocks; see _MoEMlp)
-    num_experts: int = 8
-    moe_top_k: int = 2
-    moe_capacity_factor: float = 1.25
 
     @nn.compact
     def __call__(self, obs, done, core_state, segment_ids=None,
@@ -435,10 +351,7 @@ class TransformerNet(nn.Module):
         for i in range(self.num_layers):
             x = _Block(
                 self.num_heads, self.mlp_ratio, self.attention_backend,
-                self.ring_axis, mlp=self.mlp,
-                num_experts=self.num_experts, moe_top_k=self.moe_top_k,
-                moe_capacity_factor=self.moe_capacity_factor,
-                name=f"block_{i}",
+                self.ring_axis, name=f"block_{i}",
             )(x, segment_ids, positions)
 
         x = nn.LayerNorm()(x.astype(jnp.float32))

@@ -9,7 +9,6 @@ from .mesh import (
     replicated_spec,
     shard_batch,
 )
-from .moe import moe_ffn, moe_ffn_sharded, moe_params
 from .pipeline import (
     MICRO_SPEC,
     pipeline_apply,
@@ -40,9 +39,6 @@ __all__ = [
     "shard_params",
     "sharded_init_opt_state",
     "transformer_tp_specs",
-    "moe_ffn",
-    "moe_ffn_sharded",
-    "moe_params",
     "MICRO_SPEC",
     "pipeline_apply",
     "shard_microbatches",

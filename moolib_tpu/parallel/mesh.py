@@ -10,7 +10,8 @@ Axis convention used across the framework:
   - ``tp``: tensor/model parallel (Megatron-sharded params, parallel/tp.py)
   - ``sp``: sequence/context parallel (ring/zigzag attention)
   - ``pp``: pipeline parallel (GPipe microbatching, parallel/pipeline.py)
-  - ``ep``: expert parallel (MoE expert sharding, parallel/moe.py)
+  - ``ep``: expert parallel; no user until the exchange of tokens between
+    chips is written for ``moe_dropless`` (ROADMAP R19)
 """
 
 from __future__ import annotations

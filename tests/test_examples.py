@@ -6,11 +6,14 @@ logs, entropy bounds). Same bar here, on the CPU backend the whole suite
 runs under (conftest.py).
 """
 
+import dataclasses
+
 import numpy as np
 import pytest
 
 import moolib_tpu
 from moolib_tpu.examples.a2c import A2CConfig, train as a2c_train
+from moolib_tpu.examples.vtrace import experiment
 from moolib_tpu.examples.vtrace.experiment import (
     VtraceConfig,
     train as vtrace_train,
@@ -473,7 +476,6 @@ def _run_vtrace_on_fakes(monkeypatch, cfg, *, until_updates=None,
 
     import moolib_tpu.learner as learner
     from moolib_tpu.examples.common import EnvBatchState
-    from moolib_tpu.examples.vtrace import experiment
 
     rec = _TurnRecord()
 
@@ -615,7 +617,6 @@ def _sequential_vtrace_reference(cfg, keeps, count):
     import jax.numpy as jnp
 
     from moolib_tpu.examples.common import EnvBatchState
-    from moolib_tpu.examples.vtrace import experiment
     from moolib_tpu.learner import (
         ImpalaConfig, make_act_step, make_grad_step,
     )
@@ -760,3 +761,47 @@ def test_vtrace_exit_with_an_act_call_in_flight(monkeypatch, exit_by):
         assert submits * B >= B * 61
     # One call was dispatched and never read: dropped, not submitted.
     assert len(rec.of("dispatch")) == submits + 1
+
+
+@pytest.mark.parametrize("base,item,want", [
+    ({}, "total_steps=123", {"total_steps": 123}),
+    ({}, "learning_rate=1e-3", {"learning_rate": 0.001}),
+    ({}, "use_lstm=true", {"use_lstm": True}),
+    ({"use_lstm": True}, "use_lstm=0", {"use_lstm": False}),
+    ({}, "savedir=/tmp/run=1", {"savedir": "/tmp/run=1"}),  # Optional, None
+    ({}, "learn-batch-size=64", {"learn_batch_size": 64}),
+    ({}, "total_steps", "is not key=value"),
+    ({}, "no_such_key=1", "unknown config key 'no_such_key'"),
+    # no such field: sparse blocks are DecoderLM's, described by lm_config
+    ({}, "transformer_mlp=moe", "unknown config key 'transformer_mlp'"),
+])
+def test_vtrace_overrides_onto_the_config(base, item, want):
+    """``key=value`` items keep the field's type; what is no field, or no
+    ``key=value``, exits and says which."""
+    cfg = VtraceConfig(**base)
+    if isinstance(want, str):
+        with pytest.raises(SystemExit, match=want):
+            experiment._apply_overrides(cfg, ["seed=3", item])
+        return
+    got = experiment._apply_overrides(cfg, ["seed=3", item])
+    expected = dataclasses.replace(cfg, seed=3, **want)
+    assert got == expected
+    for key, value in want.items():
+        assert type(getattr(got, key)) is type(value)
+
+
+@pytest.mark.parametrize("env,model,want", [
+    ("cartpole", "auto", "A2CNet"),
+    ("nethack", "auto", "NetHackNet"),
+    ("synthetic", "auto", "ImpalaNet"),
+    ("synthetic", "no_such_model", ValueError),
+])
+def test_vtrace_model_by_name_and_by_environment(env, model, want):
+    cfg = VtraceConfig(env=env, model=model, num_actions=5)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="unknown model 'no_such_model'"):
+            experiment._make_model(cfg)
+        return
+    net = experiment._make_model(cfg)
+    assert type(net).__name__ == want
+    assert net.num_actions == (2 if env == "cartpole" else 5)

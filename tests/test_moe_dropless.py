@@ -1,7 +1,6 @@
 """The dropless expert layer (``parallel.moe.moe_dropless``) against a loop
-over experts under ``jit``, forward and gradients; against the
-capacity-factor ``moe_ffn`` where that one drops nothing; at an imbalance
-that sends every token to one expert; with a buffer smaller than the
+over experts under ``jit``, forward and gradients; at an imbalance that
+sends every token to one expert; with a buffer smaller than the
 routing asks; and with the Pallas grouped matmul (in the interpreter) in
 the place of ``ragged_dot``."""
 
@@ -12,8 +11,7 @@ import pytest
 
 from moolib_tpu.learner import ImpalaConfig, impala_loss
 from moolib_tpu.parallel import moe
-from moolib_tpu.parallel.moe import (moe_dropless, moe_ffn, moe_params,
-                                     resolve_grouped)
+from moolib_tpu.parallel.moe import moe_dropless, resolve_grouped
 
 T, D, F, E = 96, 16, 12, 8
 
@@ -40,7 +38,9 @@ def loop_over_experts(params, x, top_k, first, count):
     return y
 
 
-@pytest.mark.parametrize("top_k,held", [(2, None), (3, (2, 4)), (1, (6, 2))])
+@pytest.mark.parametrize(
+    "top_k,held", [(2, None), (3, (2, 4)), (1, (6, 2)), (1, None)]
+)
 def test_equal_to_a_loop_over_experts_under_jit(top_k, held):
     first, count = held or (0, E)
     params, x = gated_params(0, count)
@@ -60,23 +60,6 @@ def test_equal_to_a_loop_over_experts_under_jit(top_k, held):
     for a, b in zip(jax.tree_util.tree_leaves(g_ours),
                     jax.tree_util.tree_leaves(g_ref)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("top_k", [2, 3])
-def test_equal_to_moe_ffn_where_that_one_drops_nothing(top_k):
-    """``moe_ffn``'s own parameters (plain GELU experts) through both
-    layers, its capacity set to hold every token."""
-    params = moe_params(jax.random.PRNGKey(1), D, F, E)
-    x = jax.random.normal(jax.random.PRNGKey(2), (T, D))
-    y_cap, aux = moe_ffn(params, x, capacity=T, top_k=top_k)
-    assert float(aux["drop_fraction"]) == 0.0
-    y, counters = moe_dropless(params, x, top_k=top_k)
-    np.testing.assert_allclose(y, y_cap, rtol=2e-5, atol=2e-5)
-    assert float(counters["moe_assignments_held"]) == T * top_k
-    # and where moe_ffn's default capacity drops, this one does not
-    y_drop, aux = moe_ffn(params, x, capacity=4, top_k=top_k)
-    assert float(aux["drop_fraction"]) > 0
-    assert float(jnp.max(jnp.abs(y_drop - y))) > 1e-3
 
 
 def test_every_token_to_one_expert_and_nothing_dropped():
@@ -202,8 +185,8 @@ def test_held_has_to_match_the_expert_rows():
 
 
 def test_impala_loss_passes_counters_through_and_folds_only_loss_terms():
-    """A third return value without ``load_balance_loss`` /
-    ``router_z_loss`` used to be a KeyError."""
+    """``mtp_loss`` is the one entry of a model's aux that the loss folds,
+    by ``mtp_cost``; every other key is a counter, whatever its name."""
     Tn, B, A = 4, 3, 5
     batch = {
         "obs": jnp.zeros((Tn + 1, B, 2)), "done": jnp.zeros((Tn + 1, B), bool),
@@ -218,7 +201,7 @@ def test_impala_loss_passes_counters_through_and_folds_only_loss_terms():
             return (logits, jnp.zeros((Tn + 1, B))), state, aux
         return apply
 
-    cfg = ImpalaConfig(moe_lb_cost=0.5, moe_z_cost=0.25)
+    cfg = ImpalaConfig(mtp_cost=0.5)
     plain, m0 = impala_loss(jnp.float32(0.1), apply_with({}), batch, cfg)
     total, m1 = impala_loss(
         jnp.float32(0.1), apply_with({"moe_overflow": jnp.float32(3.0)}),
@@ -227,14 +210,13 @@ def test_impala_loss_passes_counters_through_and_folds_only_loss_terms():
     assert float(total) == float(plain) and float(m1["moe_overflow"]) == 3.0
     total, m2 = impala_loss(
         jnp.float32(0.1),
-        apply_with({"load_balance_loss": jnp.float32(2.0),
-                    "router_z_loss": jnp.float32(4.0),
-                    "drop_fraction": jnp.float32(0.5),
+        apply_with({"mtp_loss": jnp.float32(2.0),
+                    "load_balance_loss": jnp.float32(4.0),
                     "moe_tokens_unserved": jnp.float32(7.0)}),
         batch, cfg,
     )
-    assert float(total) == pytest.approx(float(plain) + 0.5 * 2 + 0.25 * 4)
+    assert float(total) == pytest.approx(float(plain) + 0.5 * 2)
     assert float(m2["total_loss"]) == float(total)
-    assert float(m2["moe_lb_loss"]) == 2.0 and float(m2["moe_z_loss"]) == 4.0
-    assert float(m2["moe_drop_fraction"]) == 0.5
+    assert float(m2["mtp_loss"]) == 2.0
+    assert float(m2["load_balance_loss"]) == 4.0
     assert float(m2["moe_tokens_unserved"]) == 7.0

@@ -106,7 +106,7 @@ MODULES = [
     ("moolib_tpu.parallel.tp", "tensor parallelism (Megatron-style "
      "NamedSharding specs)"),
     ("moolib_tpu.parallel.pipeline", "pipeline parallelism"),
-    ("moolib_tpu.parallel.moe", "expert parallelism (Switch-style MoE)"),
+    ("moolib_tpu.parallel.moe", "sparse experts (the dropless top-k layer)"),
     ("moolib_tpu.parallel.distributed", "multi-controller process groups "
      "over ICI/DCN"),
     ("moolib_tpu.parallel.stats", "cluster-wide stats reduction"),
