@@ -34,7 +34,13 @@ Sinkhorn iterations make doubly stochastic
 (:func:`~moolib_tpu.models.transformer.hyper_residual_block`). The
 embedding is then replicated into the streams, the blocks, their scan and
 their rebuild carry ``[n, T, B, d]``, and the streams are summed before
-the final norm.
+the final norm. ``"scaled"``, the third: one stream whose every sublayer
+``s`` has four learned vectors of the hidden width and joins the stream
+and its own output by
+
+    x <- a_r,s (x + b_r,s) + a_y,s (F_s(norm_s(x)) + b_y,s)
+
+``a`` starting at 1 and ``b`` at 0, where it is the first skeleton.
 
 Attention kinds. Without ``latent``: rotary positions (plain, or
 YaRN-scaled) over the whole head, grouped key/value heads, three dense
@@ -85,6 +91,41 @@ No ``[T, T]`` or ``[T, T / c]`` array is built on the flash path.
 :func:`eva_pair_counts` counts what a block reads (``eva_local_pairs``,
 ``eva_summary_pairs``, ``eva_chunks_cut``, every block's, in the step's
 metrics).
+
+With ``cca`` (:class:`Cca`; compressed convolutional attention with
+grouped heads, arXiv:2510.04476, as ZAYA1 ships it) queries, keys and
+values are computed in two compressed widths, ``H D`` and ``G D`` (``H``
+query heads on ``G`` key/value heads of ``D``, query head ``i`` on
+key/value head ``i // (H / G)``), and mixed over time before the core:
+
+    qt = x W_q  [T, H D];   kt = x W_k  [T, G D]
+    v  = [x_t W_v1 | x_{t-1} W_v2]       half of the key/value heads are
+                                         of the token itself, half of the
+                                         token before it
+    c  = conv1(conv0([qt | kt]))         conv0: causal, depthwise,
+         ``time0`` taps a channel, a bias; conv1: causal, ``time1`` taps,
+         one group of ``D`` channels a head, each tap a ``D x D`` matrix,
+         a bias; nothing between them or after
+    m_i = (qt_i + kt_g(i)) / 2           the query-key mean
+    q_i = c^q_i + m_i;   k_g = c^k_g + mean over i in g of m_i
+    qh_i = sqrt(D) q_i / |q_i|;   kh_g = tau_g sqrt(D) k_g / |k_g|
+    rotary on the first ``D x partial_rotary_factor`` dimensions of qh, kh
+    o = causal softmax(qh kh^T D^-1/2) v;   y = o W_o
+
+``tau`` ``[G]`` is learned and starts at 1; the normalisation is float32
+with l2norm's eps 1e-6. The four input projections, the shift and ``W_o``
+run under ``moolib.lm.cca_proj``; both convolutions
+(:func:`causal_conv`, :func:`grouped_causal_conv`: one boundary rule),
+the mean, the normalisation, the temperature and the rotary under
+``moolib.lm.cca_mix``; the core under ``moolib.lm.attn_core`` on the one
+attention call site. **The episode rule** is the delta rule's: a tap of
+either convolution that would reach an earlier episode reads zero, and so
+does the shifted value at an episode's first position
+(:func:`previous_row`). Such a block is a softmax block: its context is
+the unroll, what lies before the call's first position reads as zero and
+nothing is carried from call to call. ``cca_taps_cut``
+(:func:`cca_taps_cut`, every block's) counts the taps and shifted values
+that read zero, in the step's metrics.
 
 Without ``rope`` (null) a softmax kind has no position encoding at all;
 with ``output_gate`` its heads' output is multiplied by ``sigmoid(x
@@ -137,11 +178,40 @@ model has one or the other). A stack needs no sparse layer: the expert
 layers' counters are then absent from the step's metrics.
 
 MLP kinds. ``sparse``: gated experts through
-:func:`moolib_tpu.parallel.moe.moe_dropless`, scored and chosen as
-``router`` says (softmax top-k; or sigmoid scores, a selection bias that
-takes no gradient, scaled gates), with a shared expert beside them where
-``shared_expert_size`` is set. ``dense``: one gated MLP of
+:func:`moolib_tpu.parallel.moe.moe_dropless`, scored here (one matrix,
+:func:`~moolib_tpu.parallel.moe.linear_scores`, or the MLP router below)
+and chosen as ``router`` says (softmax top-k; or sigmoid scores, a
+selection bias that takes no gradient, scaled gates), with a shared
+expert beside them where ``shared_expert_size`` is set. ``dense``: one gated MLP of
 ``intermediate_size``.
+
+**A router with a state through the depth** (:class:`Router` with
+``hidden_size`` r; ZAYA1's): the scores are not one matrix of the layer's
+but an MLP's, whose r-wide state ``z_l`` layer ``l + 1`` reads:
+
+    z_l = h W_rd + b_rd + gamma_l * z_{l-1}      float32, its products at
+                                                 HIGHEST precision; gamma
+                                                 [r] starts at 1; z before
+                                                 the stack's first layer
+                                                 is 0
+    u = rms_r(z_l);  a1 = gelu(u W_1 + b_1);  a2 = gelu(a1 W_2 + b_2)
+    p = softmax(a2 W_3)  over ``num_experts + skip_choices`` columns
+    e = argmax(p + beta)  (``selection_bias``; top-``top_k`` in general)
+    y = p_e * Expert_e(h)  where e is an expert held here; 0 where it is
+        held elsewhere or, past ``num_experts``, **no expert at all**
+
+``renormalize`` false leaves the gate ``p_e`` as it is: with one expert a
+token a renormalised gate is 1 and the router learns nothing
+(:func:`moe_dropless`). The MLP runs under ``moolib.moe.router_mlp``.
+The blocks then carry ``(x, z)``: through :func:`_blocks`, the scan over
+stacked blocks and a rebuilt block alike; a stack's first block is handed
+zeros, so every block has a ``gamma`` and the first one's has no effect.
+``moe_tokens_skipped``, ``moe_gate_mean`` (the mean chosen probability)
+and ``router_state_rms`` (of ``z`` after the last block) join the step's
+metrics.
+
+``tie_embeddings``: the head is the embedding's rows held, transposed;
+the model has no ``head`` leaf and the one matrix takes both gradients.
 
 ``mtp`` adds one multi-token-prediction module (DeepSeek-V3's form) after
 the stack: from the last block's output ``h_t`` and the next token's
@@ -184,13 +254,14 @@ from flax import linen as nn
 
 from ..ops import attention as attn_ops
 from ..ops import delta_rule, hyper_mix
-from ..parallel.moe import moe_dropless
+from ..parallel.moe import linear_scores, moe_dropless
 from .transformer import (attend, hyper_coefficients, hyper_read,
                           hyper_residual_block, residual_block,
                           segment_ids_from_done, sown_dicts)
 
 __all__ = [
     "AttentionKind",
+    "Cca",
     "DecoderLM",
     "Delta",
     "Eva",
@@ -218,6 +289,9 @@ class Rope:
     beta_slow: float = 1.0
     attention_factor: float = 1.0
     truncate: bool = True
+    # The share of a head (of latent attention's ``rope`` part) that is
+    # turned, its first dimensions; the rest passes as it is.
+    partial_rotary_factor: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -262,6 +336,18 @@ class Delta:
 
 
 @dataclasses.dataclass(frozen=True)
+class Cca:
+    """Compressed convolutional attention (the ``cca_time*`` keys of a
+    config): the taps of the depthwise convolution and of the grouped one
+    that follows it. The two compressed widths are the heads': ``num_heads
+    x head_dim`` for the queries, ``num_kv_heads x head_dim`` for keys and
+    values."""
+
+    time0: int
+    time1: int
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionKind:
     window: Optional[int]  # None: full causal attention
     rope: Optional[Rope]  # None: no position encoding at all
@@ -270,6 +356,7 @@ class AttentionKind:
     # the softmax kind's output times sigmoid(x W_g), elementwise
     output_gate: bool = False
     delta: Optional[Delta] = None  # no softmax: the delta rule's state
+    cca: Optional[Cca] = None  # queries and keys mixed by two convolutions
 
 
 @dataclasses.dataclass(frozen=True)
@@ -288,12 +375,21 @@ class Residual:
 @dataclasses.dataclass(frozen=True)
 class Router:
     """How a sparse MLP scores and chooses (``moe_dropless``'s
-    arguments): ``selection_bias`` adds a parameter ``[num_experts]`` to
-    the scores for the choice alone."""
+    arguments): ``selection_bias`` adds a parameter, one a choice, to the
+    scores for the choice alone. ``hidden_size`` says where the scores
+    come from: None, one matrix of the layer's own; a number, an MLP of
+    that width whose state goes from layer to layer through the depth
+    (module docstring). ``skip_choices`` says how wide they are: that many
+    columns past ``num_experts``, each a choice that is no expert.
+    ``renormalize`` false leaves the gates as the chosen scores, which a
+    router of one expert a token needs to learn at all."""
 
     scoring: str = "softmax"
     selection_bias: bool = False
     gate_scale: float = 1.0
+    hidden_size: Optional[int] = None
+    skip_choices: int = 0
+    renormalize: bool = True
 
 
 def rope_inv_freq(rope: Rope, head_dim: int) -> np.ndarray:
@@ -326,8 +422,14 @@ def rope_inv_freq(rope: Rope, head_dim: int) -> np.ndarray:
 
 
 def _rotary(x, cos, sin):
-    """x [T, B, H, D]; cos/sin [T, D], float32. The half-split form:
-    ``x * cos + rotate_half(x) * sin``."""
+    """x [T, B, H, D]; cos/sin [T, R], float32, R <= D. The half-split
+    form over the first R dimensions, ``x * cos + rotate_half(x) * sin``;
+    the others pass as they are."""
+    rot = cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [_rotary(x[..., :rot], cos, sin), x[..., rot:]], axis=-1
+        )
     x32 = x.astype(jnp.float32)
     x1, x2 = jnp.split(x32, 2, axis=-1)
     rotated = jnp.concatenate([-x2, x1], axis=-1)
@@ -336,9 +438,14 @@ def _rotary(x, cos, sin):
 
 
 def _rotary_tables(rope: Rope, positions, dim: int):
-    """cos and sin [T, dim], float32, of the angles the ``dim`` rotated
-    dimensions turn by at ``positions`` (each frequency twice: the
-    half-split form), times the kind's ``attention_factor``."""
+    """cos and sin [T, R], float32, of the angles the rotated dimensions
+    turn by at ``positions`` (each frequency twice: the half-split form),
+    times the kind's ``attention_factor``. ``dim`` is the head (or latent
+    attention's ``rope`` part); ``R = dim * partial_rotary_factor`` of it
+    are turned, with the frequencies of a head of ``R``, and
+    :func:`_rotary` leaves the rest alone. At a factor of 1, ``R =
+    dim``."""
+    dim = int(dim * rope.partial_rotary_factor)
     angle = positions.astype(jnp.float32)[:, None] * jnp.asarray(
         rope_inv_freq(rope, dim), jnp.float32
     )
@@ -556,6 +663,72 @@ class _EvaAttention(nn.Module):
             return _dense("o", x.shape[-1], self.dtype)(o)
 
 
+def _episode_taps(x, seg_tb, tail, K: int):
+    """The boundary rule of every convolution over time here. ``x`` [T, B,
+    C] after the ``K - 1`` rows of ``tail`` [B, K - 1, C] (of episode 0);
+    returns the rows and their ids so joined, float32, and ``tap(j)``: the
+    row ``K - 1 - j`` positions before each position, [T, B, C], zero
+    where that row is of another episode."""
+    T = x.shape[0]
+    rows = jnp.concatenate(
+        [tail.transpose(1, 0, 2).astype(jnp.float32), x.astype(jnp.float32)]
+    )
+    seg = jnp.pad(seg_tb, ((K - 1, 0), (0, 0)))
+
+    def tap(j):
+        return jnp.where(
+            (seg[j:j + T] == seg_tb)[..., None], rows[j:j + T], 0.0
+        )
+
+    return rows, seg, tap
+
+
+def grouped_causal_conv(x, w, seg_tb):
+    """:func:`causal_conv`'s grouped sibling, with nothing before the
+    call's first row: ``x`` [T, B, G, D]; ``w`` [K, G, D, D], tap ``K - 1``
+    on the position itself; every tap mixes the ``D`` channels of one
+    group by a matrix of its own, ``y_t[g] = sum_j x_{t-(K-1-j)}[g] @ w[j,
+    g]``. A tap that would reach another episode, or before the call,
+    reads zero. Returns ``y`` [T, B, G, D] float32."""
+    T, B, G, D = x.shape
+    K = w.shape[0]
+    _, _, tap = _episode_taps(
+        x.reshape(T, B, G * D), seg_tb, jnp.zeros((B, K - 1, G * D)), K
+    )
+    return sum(
+        jnp.einsum(
+            "tbgd,gde->tbge", tap(j).reshape(T, B, G, D),
+            w[j].astype(jnp.float32),
+        ) for j in range(K)
+    )
+
+
+def previous_row(x, seg_tb):
+    """Row ``t - 1`` of ``x`` [T, B, C] at row ``t``, float32: zero at an
+    episode's first position and at the call's (the convolutions' rule,
+    for a shift by one)."""
+    tail = jnp.zeros((x.shape[1], 1, x.shape[2]))
+    return _episode_taps(x, seg_tb, tail, 2)[2](0)
+
+
+def cca_taps_cut(seg_bt, kind: Cca):
+    """What the episode boundaries (and the call's first row) take from
+    one block of compressed convolutional attention, counted from the ids
+    ``[B, T]``, int32: (position, tap) pairs of either convolution, the
+    position's own tap apart, and shifted values that read zero."""
+    seg = seg_bt.T
+    T = seg.shape[0]
+
+    def cut(back):  # positions whose row `back` before is not theirs
+        padded = jnp.pad(seg, ((back, 0), (0, 0)), constant_values=-1)
+        return jnp.sum(padded[:T] != seg)
+
+    return sum(
+        cut(back) for taps in (kind.time0, kind.time1, 2)
+        for back in range(1, taps)
+    )
+
+
 def causal_conv(x, w, seg_tb, tail):
     """A causal depthwise convolution over time that reads nothing across
     an episode boundary. ``x`` [T, B, C]; ``w`` [K, C], tap ``K - 1`` on
@@ -566,16 +739,8 @@ def causal_conv(x, w, seg_tb, tail):
     B, C] float32 and the next call's ``tail``, float32: the last ``K -
     1`` rows, those of an earlier episode than the last row's zeroed."""
     T, K = x.shape[0], w.shape[0]
-    rows = jnp.concatenate(
-        [tail.transpose(1, 0, 2).astype(jnp.float32), x.astype(jnp.float32)]
-    )
-    seg = jnp.pad(seg_tb, ((K - 1, 0), (0, 0)))
-    y = sum(
-        w[j].astype(jnp.float32) * jnp.where(
-            (seg[j:j + T] == seg_tb)[..., None], rows[j:j + T], 0.0
-        )
-        for j in range(K)
-    )
+    rows, seg, tap = _episode_taps(x, seg_tb, tail, K)
+    y = sum(w[j].astype(jnp.float32) * tap(j) for j in range(K))
     tail = jnp.where(
         (seg[T:] == seg[-1:])[..., None], rows[T:], 0.0
     ).transpose(1, 0, 2)
@@ -648,6 +813,90 @@ class _DeltaAttention(nn.Module):
             gate = dense("g_b", H * D)(dense("g_a", spec.gate_rank)(x))
             o = o.reshape(T, B, H * D) * jax.nn.sigmoid(gate)
             return dense("o", d)(o), (S, rows)
+
+
+class _CcaAttention(nn.Module):
+    """Compressed convolutional attention with grouped heads; see the
+    module docstring. Everything between the projections and the core is
+    float32; the core reads ``qh``, ``kh`` and ``v`` in the compute
+    type."""
+
+    kind: AttentionKind
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    backend: str
+    block: int
+    dtype: jnp.dtype
+
+    @nn.compact
+    def __call__(self, x, seg_bt, positions):
+        T, B, d = x.shape
+        H, G, D = self.num_heads, self.num_kv_heads, self.head_dim
+        spec = self.kind.cca
+        if G % 2 or H % G:
+            raise ValueError(
+                "the value shift takes half of the key/value heads, and "
+                f"a key/value head serves whole query heads: {H} / {G}"
+            )
+        seg_tb = seg_bt.T
+
+        def dense(name, width):
+            return _dense(name, width, self.dtype)
+
+        C = (H + G) * D
+        taps0 = self.param(
+            "conv0", nn.initializers.normal(spec.time0 ** -0.5),
+            (spec.time0, C),
+        )
+        bias0 = self.param("conv0_bias", nn.initializers.zeros, (C,))
+        taps1 = self.param(
+            "conv1", nn.initializers.normal((spec.time1 * D) ** -0.5),
+            (spec.time1, H + G, D, D),
+        )
+        bias1 = self.param("conv1_bias", nn.initializers.zeros, (C,))
+        tau = self.param("temperature", nn.initializers.ones, (G,))
+        with jax.named_scope("moolib.lm.cca_proj"):
+            qt, kt = dense("q", H * D)(x), dense("k", G * D)(x)
+            own = dense("v_own", G * D // 2)(x)
+            before = previous_row(
+                dense("v_prev", G * D // 2)(x), seg_tb
+            ).astype(self.dtype)
+            v = jnp.concatenate([own, before], axis=-1).reshape(T, B, G, D)
+        with jax.named_scope("moolib.lm.cca_mix"):
+            mixed, _ = causal_conv(
+                jnp.concatenate([qt, kt], axis=-1), taps0, seg_tb,
+                jnp.zeros((B, spec.time0 - 1, C)),
+            )
+            mixed = grouped_causal_conv(
+                (mixed + bias0).reshape(T, B, H + G, D), taps1, seg_tb
+            ) + bias1.reshape(H + G, D)
+            qt = qt.astype(jnp.float32).reshape(T, B, G, H // G, D)
+            kt = kt.astype(jnp.float32).reshape(T, B, G, 1, D)
+            mean = (qt + kt) / 2  # the query-key mean, a query head
+            q = mixed[:, :, :H] + mean.reshape(T, B, H, D)
+            k = mixed[:, :, H:] + jnp.mean(mean, axis=3)
+
+            def l2norm(t):
+                return t * (D ** 0.5) * jax.lax.rsqrt(
+                    jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6
+                )
+
+            q = l2norm(q)
+            k = l2norm(k) * tau.astype(jnp.float32)[:, None]
+            cos, sin = _rotary_tables(self.kind.rope, positions, D)
+            q = _rotary(q, cos, sin).astype(self.dtype)
+            k = _rotary(k, cos, sin).astype(self.dtype)
+            q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
+        with jax.named_scope("moolib.lm.attn_core"):
+            o = attend(
+                q, k, v, seg_bt, backend=self.backend,
+                window=self.kind.window, block_q=self.block,
+                block_k=self.block,
+            )
+        with jax.named_scope("moolib.lm.cca_proj"):
+            o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
+            return dense("o", d)(o)
 
 
 class _LatentAttention(nn.Module):
@@ -741,30 +990,68 @@ class _SparseMlp(nn.Module):
     router: Router = Router()
     shared_d_ff: Optional[int] = None
     dtype: jnp.dtype = jnp.float32
+    norm_eps: float = 1e-6  # of the MLP router's norm
 
     @nn.compact
-    def __call__(self, x):  # [T, B, d] -> [T, B, d]
+    def __call__(self, x, z=None):
+        """[T, B, d] -> [T, B, d]; with an MLP router, ``(x, z) -> (y,
+        z)``."""
         T, B, d = x.shape
-        count = self.held[1]
+        tokens = x.reshape(T * B, d)
         init = nn.initializers.lecun_normal()
+        router = self.router
+        choices = self.num_experts + router.skip_choices
+        if router.hidden_size is None:
+            scores = linear_scores(
+                tokens, self.param("router", init, (d, choices)),
+                router.scoring,
+            )
+        elif router.scoring != "softmax":
+            raise ValueError("the MLP router scores by one softmax")
+        else:
+            # The MLP router, float32: this layer's state from the token
+            # and the state of the layer before (zeros for a stack's
+            # first), and the scores [T B, choices] from it. Its products
+            # are float32 in deed (HIGHEST: a TPU's default runs a float32
+            # product in one bfloat16 pass): the choice is an argmax, the
+            # same token id meets the first router alike wherever it
+            # stands, and one rounding that tips it moves every such token
+            # to another expert at once.
+            def dense(name, width, bias=True):
+                return nn.Dense(
+                    width, use_bias=bias, name=name,
+                    precision=jax.lax.Precision.HIGHEST,
+                )
+
+            hidden = router.hidden_size
+            gamma = self.param("router_gamma", nn.initializers.ones, (hidden,))
+            with jax.named_scope("moolib.moe.router_mlp"):
+                z = dense("router_down", hidden)(x.astype(jnp.float32)) + (
+                    gamma * z
+                )
+                u = RMSNorm(self.norm_eps, jnp.float32, name="router_norm")(z)
+                for name in ("router_1", "router_2"):
+                    u = jax.nn.gelu(dense(name, hidden)(u), approximate=False)
+                logits = dense("router_out", choices, bias=False)(u)
+                scores = jax.nn.softmax(logits.reshape(-1, choices), axis=-1)
+        count = self.held[1]
         # batch_axis=0: the expert axis is a batch of matrices, not fan-in.
         expert_init = nn.initializers.lecun_normal(batch_axis=(0,))
         params = {
-            "router": self.param("router", init, (d, self.num_experts)),
             "w_gate": self.param("w_gate", expert_init, (count, d, self.d_ff)),
             "w_up": self.param("w_up", expert_init, (count, d, self.d_ff)),
             "w_down": self.param("w_down", expert_init, (count, self.d_ff, d)),
         }
         select_bias = None
-        if self.router.selection_bias:
+        if router.selection_bias:
             select_bias = self.param(
-                "e_score_correction_bias", nn.initializers.zeros,
-                (self.num_experts,),
+                "e_score_correction_bias", nn.initializers.zeros, (choices,),
             )
         y, aux = moe_dropless(
-            params, x.reshape(T * B, d), top_k=self.top_k, held=self.held,
-            buffer_rows=self.buffer_rows, scoring=self.router.scoring,
-            select_bias=select_bias, gate_scale=self.router.gate_scale,
+            params, tokens, scores, top_k=self.top_k,
+            held=self.held, buffer_rows=self.buffer_rows,
+            select_bias=select_bias, gate_scale=router.gate_scale,
+            skip_choices=router.skip_choices, renormalize=router.renormalize,
         )
         self.sow("intermediates", "moe_router_load", aux.pop("moe_router_load"))
         self.sow("intermediates", "moe_counters", aux)
@@ -772,7 +1059,7 @@ class _SparseMlp(nn.Module):
         if self.shared_d_ff is not None:
             with jax.named_scope("moolib.moe.shared"):
                 y = y + _GatedMlp(self.shared_d_ff, self.dtype, name="shared")(x)
-        return y
+        return y if router.hidden_size is None else (y, z)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -794,8 +1081,14 @@ class _Sizes:
     router: Router
     shared_expert_size: Optional[int]
     intermediate_size: Optional[int]
-    residual: Optional[Residual] = None
+    residual: Union[None, Residual, str] = None
     norm_unit_offset: bool = False
+
+    @property
+    def depth_state(self) -> bool:
+        """Whether the blocks hand a router's state on through the
+        depth."""
+        return self.router.hidden_size is not None
 
     def norm(self, name: str) -> RMSNorm:
         return RMSNorm(
@@ -842,6 +1135,29 @@ class _HyperMix(nn.Module):
         return h, streams, coef
 
 
+class _ResidualScale(nn.Module):
+    """A sublayer's merge on the scaled skeleton: four learned vectors of
+    the hidden width, ``a_r (x + b_r) + a_y (y + b_y)`` with ``x`` the
+    stream and ``y`` the sublayer's output; ``a`` start at 1, ``b`` at 0,
+    where it is ``x + y``. Float32, returned in the stream's type, under
+    ``moolib.lm.residual_scale``."""
+
+    @nn.compact
+    def __call__(self, x, y):
+        d = x.shape[-1]
+        a_r, a_y = (
+            self.param(n, nn.initializers.ones, (d,)) for n in ("a_r", "a_y")
+        )
+        b_r, b_y = (
+            self.param(n, nn.initializers.zeros, (d,)) for n in ("b_r", "b_y")
+        )
+        with jax.named_scope("moolib.lm.residual_scale"):
+            out = a_r * (x.astype(jnp.float32) + b_r) + a_y * (
+                y.astype(jnp.float32) + b_y
+            )
+            return out.astype(x.dtype)
+
+
 class _Block(nn.Module):
     kind: AttentionKind
     mlp: str
@@ -874,6 +1190,17 @@ class _Block(nn.Module):
                 net.attention_backend, net.attention_block,
                 net.compute_dtype, name="attn",
             )
+        elif self.kind.cca is not None:
+            if self.kind.latent is not None or self.kind.rope is None:
+                raise ValueError(
+                    "compressed convolutional attention has projections of "
+                    "its own and a rotary"
+                )
+            attention = _CcaAttention(
+                self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
+                net.attention_backend, net.attention_block,
+                net.compute_dtype, name="attn",
+            )
         elif self.kind.latent is None:
             attention = _Attention(
                 self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
@@ -886,12 +1213,24 @@ class _Block(nn.Module):
                 net.attention_block, net.rms_norm_eps, net.compute_dtype,
                 net.norm_unit_offset, name="attn",
             )
+        # a router with a state through the depth: the carry is (x, z)
+        z = None
+        if net.depth_state:
+            x, z = x
         if self.mlp == "sparse":
-            mlp = _SparseMlp(
+            sparse = _SparseMlp(
                 net.num_experts, net.held, net.top_k,
                 net.moe_intermediate_size, net.moe_buffer_rows, net.router,
-                net.shared_expert_size, net.compute_dtype, name="moe",
+                net.shared_expert_size, net.compute_dtype, net.rms_norm_eps,
+                name="moe",
             )
+
+            def mlp(h):
+                nonlocal z
+                if z is None:
+                    return sparse(h)
+                y, z = sparse(h, z)
+                return y
         elif self.mlp == "dense":
             dense = _GatedMlp(
                 net.intermediate_size, net.compute_dtype, name="mlp"
@@ -913,6 +1252,12 @@ class _Block(nn.Module):
 
         if net.residual is None:
             out = residual_block(x, norm("norm1"), mixer, norm("norm2"), mlp)
+        elif net.residual == "scaled":
+            out = residual_block(
+                x, norm("norm1"), mixer, norm("norm2"), mlp,
+                _ResidualScale(name="scale_attn"),
+                _ResidualScale(name="scale_mlp"),
+            )
         else:
             out = hyper_residual_block(
                 x, _HyperMix(net.residual, net.rms_norm_eps, name="hc_attn"),
@@ -920,6 +1265,8 @@ class _Block(nn.Module):
                 _HyperMix(net.residual, net.rms_norm_eps, name="hc_mlp"),
                 norm("norm2"), mlp,
             )
+        if z is not None:
+            out = (out, z)
         if self.kind.delta is not None:
             return out, state
         return (out, None) if self.scanned else out
@@ -935,11 +1282,13 @@ def _blocks(kind: AttentionKind, mlp: str, sizes: _Sizes, repeat: int,
     (what the forward kernel alone can make, so the rebuild runs no such
     kernel); ``"input"``, its input alone, and the rebuild runs the
     forward kernels again. ``(x, seg_bt, positions) -> x``, with ``x`` the
-    skeleton's carry: one stream ``[T, B, d]`` or several ``[n, T, B,
-    d]``; for the delta rule, the one kind that carries something from
-    call to call, ``(x, seg_bt, positions, state) -> (x, state)``, the
-    state's leaves ``[B, ...]`` a block and ``[B, repeat, ...]`` a scan
-    (the blocks on the axis after the batch's)."""
+    skeleton's carry: one stream ``[T, B, d]``, several ``[n, T, B, d]``,
+    or, where the router has a state through the depth, the pair ``(x,
+    z)`` with ``z`` ``[T, B, hidden]`` float32; for the delta rule, the
+    one kind that carries something from call to call, ``(x, seg_bt,
+    positions, state) -> (x, state)``, the state's leaves ``[B, ...]`` a
+    block and ``[B, repeat, ...]`` a scan (the blocks on the axis after
+    the batch's)."""
     stateful = kind.delta is not None
     cls, traced = _Block, contextlib.nullcontext
     if remat == "input":
@@ -1095,14 +1444,19 @@ class DecoderLM(nn.Module):
     # ``[B, H, T, Dv]`` a call), everything else rebuilt; "input", its
     # input alone, and the rebuild runs the forward kernels again.
     remat_blocks: Union[bool, str] = False
-    # The residual skeleton: None is one stream and ``x + F(norm(x))``.
-    residual: Optional[Residual] = None
+    # The residual skeleton: None is one stream and ``x + F(norm(x))``;
+    # a :class:`Residual`, several streams; "scaled", one stream whose
+    # sublayers scale and shift both the stream and what they add to it.
+    residual: Union[None, Residual, str] = None
     # Every RMS norm's gain as ``1 + scale``.
     norm_unit_offset: bool = False
     # The head's width in vocabularies: head ``i`` of position ``t`` is
     # asked for token ``t + 1 + i``. Head 0 is the policy; the others'
     # cross-entropy is the loss's ``mtp_loss`` term.
     num_pred_heads: int = 1
+    # The head is the embedding, transposed: one matrix over the rows
+    # held, which takes both gradients; no ``head`` leaf.
+    tie_embeddings: bool = False
 
     def _sizes(self) -> _Sizes:
         return _Sizes(
@@ -1124,7 +1478,23 @@ class DecoderLM(nn.Module):
             name="embed",
         )
         x = embedded = embed(obs)
-        if self.residual is not None:
+        if self.residual not in (None, "scaled") and not isinstance(
+            self.residual, Residual
+        ):
+            raise ValueError(
+                f"residual is absent, a Residual or 'scaled': "
+                f"{self.residual!r}"
+            )
+        streams = isinstance(self.residual, Residual)
+        kinds, sizes = dict(self.attention_kinds), self._sizes()
+        if (sizes.depth_state or self.tie_embeddings) and (
+            streams or self.mtp is not None or self.num_pred_heads > 1
+        ):
+            raise ValueError(
+                "a router's state through the depth and a tied head are "
+                "built for one stream and one prediction head"
+            )
+        if streams:
             if self.mtp is not None:
                 raise ValueError(
                     "a multi-token-prediction module beside a residual "
@@ -1134,7 +1504,11 @@ class DecoderLM(nn.Module):
             x = jnp.broadcast_to(x, (self.residual.streams,) + x.shape)
         seg_bt = segment_ids_from_done(done)
         positions = jnp.arange(T)
-        kinds, sizes = dict(self.attention_kinds), self._sizes()
+        if sizes.depth_state:
+            # the stack's first router reads no state of a layer before it
+            x = (x, jnp.zeros(
+                x.shape[:2] + (self.router.hidden_size,), jnp.float32
+            ))
         # a stateful entry's leaves, in the order the entries run
         states, handed_on = list(core_state), []
         for i, (attention, mlp, *repeat) in enumerate(self.layers):
@@ -1149,7 +1523,12 @@ class DecoderLM(nn.Module):
                 x, state = run(x, seg_bt, positions, tuple(states[:2]))
                 states = states[2:]
                 handed_on += state
-        if self.residual is not None:
+        if sizes.depth_state:
+            x, z = x
+            self.sow("intermediates", "router_state", {
+                "router_state_rms": jnp.sqrt(jnp.mean(z * z)),
+            })
+        if streams:
             # and the streams' sum is what the final norm reads
             x = jnp.sum(x.astype(jnp.float32), axis=0).astype(
                 self.compute_dtype
@@ -1160,7 +1539,9 @@ class DecoderLM(nn.Module):
                 "further prediction heads beside a multi-token-prediction "
                 "module: the loss has one such term"
             )
-        head = _dense("head", heads * self.vocab_size, self.compute_dtype)
+        head = embed.attend if self.tie_embeddings else _dense(
+            "head", heads * self.vocab_size, self.compute_dtype
+        )
         hidden = x
         with jax.named_scope("moolib.lm.head"):
             x = sizes.norm("final_norm")(x)
@@ -1188,6 +1569,15 @@ class DecoderLM(nn.Module):
                     )
         if eva_counters:
             self.sow("intermediates", "eva_counters", eva_counters)
+        cut = [
+            (repeat or [1])[0] * cca_taps_cut(seg_bt, kinds[attention].cca)
+            for attention, _, *repeat in self.layers
+            if kinds[attention].cca is not None
+        ]
+        if cut:
+            self.sow(
+                "intermediates", "cca_counters", {"cca_taps_cut": sum(cut)}
+            )
         if handed_on:
             blocks = sum(
                 (repeat or [1])[0] for attention, _, *repeat in self.layers
@@ -1239,15 +1629,22 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
     ``"repeat": n`` standing for ``n`` identical blocks run as a scan;
     ``attention_kinds`` a mapping ``kind -> {"window": int or null,
     "rope": {...} or null, "latent": {...} or absent, "eva": {...} or
-    absent, "output_gate": bool or absent, "delta": {...} or absent}``
+    absent, "output_gate": bool or absent, "delta": {...} or absent,
+    "cca": {...} or absent}``
     whose ``rope`` holds the fields of :class:`Rope` (null: no position
-    encoding), whose ``latent`` those of :class:`Latent`, whose ``eva``
-    those of :class:`Eva` and whose ``delta`` those of :class:`Delta`
-    (the kind is then the delta rule and has no window);
-    ``router`` the fields of :class:`Router`; ``mtp`` the
+    encoding; ``partial_rotary_factor`` the share of a head it turns),
+    whose ``latent`` those of :class:`Latent`, whose ``eva``
+    those of :class:`Eva`, whose ``delta`` those of :class:`Delta`
+    (the kind is then the delta rule and has no window) and whose ``cca``
+    those of :class:`Cca` (compressed convolutional attention);
+    ``router`` the fields of :class:`Router` (with ``hidden_size`` an MLP
+    whose state goes through the depth; ``skip_choices``;
+    ``renormalize``); ``mtp`` the
     multi-token-prediction module's block, an entry like one of
-    ``layers``; ``residual`` the fields of :class:`Residual` (absent: the
-    skeleton with one stream)."""
+    ``layers``; ``residual`` the fields of :class:`Residual`, or
+    ``"scaled"`` (absent: the skeleton with one stream and a plain sum);
+    ``tie_embeddings`` (a keyword like the other sizes) makes the head
+    the embedding."""
     kinds = tuple(
         (name, AttentionKind(
             spec.get("window"),
@@ -1256,6 +1653,7 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
             Eva(**spec["eva"]) if spec.get("eva") else None,
             spec.get("output_gate", False),
             Delta(**spec["delta"]) if spec.get("delta") else None,
+            Cca(**spec["cca"]) if spec.get("cca") else None,
         ))
         for name, spec in sorted(attention_kinds.items())
     )
@@ -1270,9 +1668,9 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
         experts_held=None if experts_held is None else tuple(experts_held),
         router=Router(**(router or {})),
         mtp=None if mtp is None else (mtp["attention"], mtp["mlp"]),
-        residual=None if residual is None else Residual(**dict(
-            residual, res_clamp=tuple(residual["res_clamp"]),
-        )),
+        residual=residual if not isinstance(residual, dict) else Residual(
+            **dict(residual, res_clamp=tuple(residual["res_clamp"]))
+        ),
         **kwargs,
     )
 
@@ -1289,10 +1687,11 @@ def _sum_counters(intermediates) -> dict:
             if value.ndim:
                 value = jnp.sum(value)
             total[name] = total.get(name, 0.0) + value
-    for name in ("moe_load_max", "moe_load_mean"):
+    for name in ("moe_load_max", "moe_load_mean", "moe_gate_mean"):
         if name in total:
             total[name] = total[name] / layers
-    for name in ("mtp_loss", "eva_local_pairs", "kda_state_resets"):
+    for name in ("mtp_loss", "eva_local_pairs", "kda_state_resets",
+                 "cca_taps_cut", "router_state_rms"):
         for sown in sown_dicts(intermediates, name):
             total.update(sown)
     # the delta rule's gauges, an element a block: the worst, and the mean
@@ -1353,7 +1752,9 @@ def router_loads(net: DecoderLM) -> Callable:
         if net.mtp is not None and net.mtp[1] == "sparse":
             sparse.append(blocks["mtp"]["block"])
         return jnp.concatenate([
-            b["moe"]["moe_router_load"][0].reshape(-1, net.num_experts)
+            b["moe"]["moe_router_load"][0].reshape(
+                -1, net.num_experts + net.router.skip_choices
+            )
             for b in sparse
         ])
 

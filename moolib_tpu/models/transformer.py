@@ -111,11 +111,15 @@ def attend(q, k, v, seg_bt, *, backend: str, ring_axis: str = "sp",
     )
 
 
-def residual_block(x, norm1, mixer, norm2, mlp):
+def residual_block(x, norm1, mixer, norm2, mlp, merge1=jnp.add,
+                   merge2=jnp.add):
     """The pre-norm residual skeleton of a block with one stream:
-    ``h = x + mixer(norm1(x)); out = h + mlp(norm2(h))``."""
-    x = x + mixer(norm1(x))
-    return x + mlp(norm2(x))
+    ``h = x + mixer(norm1(x)); out = h + mlp(norm2(h))``. ``merge1`` /
+    ``merge2``: what joins the stream and a sublayer's output where it is
+    not their sum (``(x, y) -> x'``: a skeleton that scales and shifts
+    both)."""
+    x = merge1(x, mixer(norm1(x)))
+    return merge2(x, mlp(norm2(x)))
 
 
 def hyper_coefficients(streams, phi, b, alpha, *, norm_eps: float,

@@ -7,7 +7,9 @@ experts **held here** (``held=(first, count)`` of the router's width) are
 gathered in expert order, one grouped product a projection runs the gated
 (SwiGLU) experts (on a TPU the Pallas grouped matmul that ships with jax,
 elsewhere ``jax.lax.ragged_dot``: :func:`resolve_grouped`), and a
-scatter-add combines by the renormalised top-k gates. With ``held`` a
+scatter-add combines by the renormalised top-k gates (or, for a router
+that says so, by the chosen scores as they are). The scores are the
+caller's (:func:`linear_scores` for a router of one matrix). With ``held`` a
 strict share it is one chip's part of an expert-parallel layer (what the
 absent experts would add is left out, and no code stands in for their
 chips); with ``held=None`` it is the whole layer. Its buffer may be sized
@@ -25,7 +27,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-__all__ = ["moe_dropless", "resolve_grouped"]
+__all__ = ["linear_scores", "moe_dropless", "resolve_grouped"]
 
 
 _LANES = 128
@@ -101,38 +103,68 @@ _SCORING = {
 }
 
 
+def linear_scores(x: jax.Array, router: jax.Array,
+                  scoring: str = "softmax") -> jax.Array:
+    """The scores of a router that is one matrix: ``x`` [T, d_model] and
+    ``router`` [d_model, E] give ``p`` [T, E] in float32, ``softmax(x @
+    router)`` or, with ``scoring="sigmoid"``, each expert scored by itself,
+    ``sigmoid(x @ router)``."""
+    if scoring not in _SCORING:
+        raise ValueError(f"scoring={scoring!r}; have {sorted(_SCORING)}")
+    with jax.named_scope("moolib.moe.route"):
+        logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+        return _SCORING[scoring](logits)
+
+
 def moe_dropless(
     params: Dict[str, Any],
     x: jax.Array,
+    scores: jax.Array,
     *,
     top_k: int,
     held: Optional[Tuple[int, int]] = None,
     buffer_rows: Optional[int] = None,
-    scoring: str = "softmax",
     select_bias: Optional[jax.Array] = None,
     gate_scale: float = 1.0,
+    skip_choices: int = 0,
+    renormalize: bool = True,
 ):
     """Dropless top-``top_k`` MoE with gated experts. ``x``: [T, d_model].
 
-    ``params``: ``router`` [d_model, E] (scores every expert, in float32),
-    and the experts held here, ``w_gate``/``w_up`` [count, d_model, d_ff]
-    and ``w_down`` [count, d_ff, d_model]: expert ``first + i`` of the
-    router is row ``i``. ``held=(first, count)`` defaults to all ``E``.
+    ``scores`` [T, E'] float32 are the router's, ``p`` below: the caller
+    scores, by one matrix (:func:`linear_scores`) or by whatever its router
+    is (an MLP, a state carried through the depth). ``params``: the experts
+    held here, ``w_gate``/``w_up`` [count, d_model, d_ff] and ``w_down``
+    [count, d_ff, d_model]: expert ``first + i`` of the router is row
+    ``i``. ``held=(first, count)`` defaults to all ``E``.
 
-        p = softmax(x @ router);  S = top_k(p);  g_e = p_e / sum_S p
+        S = top_k(p);  g_e = p_e / sum_S p
         y = sum over e in S that are held of
             g_e * (silu(x @ w_gate_e) * (x @ w_up_e)) @ w_down_e
 
     A token none of whose choices is held gets ``y = 0``.
 
-    The router's rule is the call's: ``scoring`` is ``softmax`` (above) or
-    ``sigmoid``, each expert scored by itself, ``p = sigmoid(x @ router)``.
-    ``select_bias`` [E] moves the *choice* and nothing else: ``S =
+    ``select_bias`` [E'] moves the *choice* and nothing else: ``S =
     top_k(p + select_bias)``, the gates still ``p_e / sum_S p``. It enters
     under ``stop_gradient``, so its gradient is exactly zero and whoever
     balances the experts with it does so outside the loss (the
     auxiliary-loss-free rule of Wang et al. 2024; no such rule is built
-    here). ``gate_scale`` multiplies the renormalised gates.
+    here). ``gate_scale`` multiplies the gates.
+
+    The last ``skip_choices`` columns of ``scores`` are choices that are
+    **no expert** (``E = E' - skip_choices``): a token whose choice falls
+    there is served by nobody, here or elsewhere. Such an assignment is
+    sorted with those of the experts held elsewhere, past the rows in use,
+    and counted apart (``moe_tokens_skipped``); ``moe_tokens_unserved``
+    counts it too.
+
+    ``renormalize=False`` leaves the gates as the chosen scores, ``g_e =
+    p_e``. With ``top_k`` 1 the renormalised gate is ``p_e / p_e``, 1
+    whatever the router says: the output then does not depend on the
+    scores but through the choice, which has no derivative, and **the
+    router's gradient is zero** (in floating point, the rounding of ``1 /
+    p - p / p^2``). A top-1 router learns through its unrenormalised gate
+    or not at all, so ``top_k=1`` with ``renormalize=True`` is refused.
 
     Shapes are static. The gathered buffer has ``T * top_k`` rows, every
     assignment there is: the worst any routing can ask of the experts
@@ -163,27 +195,30 @@ def moe_dropless(
     (assignments of the fullest held expert and their mean),
     ``moe_spills``, and ``moe_overflow``: the assignments held that the
     buffer that ran had no row for, counted by the branch that ran; 0, or
-    the layer is wrong. And one array, ``moe_router_load`` [E] int32: the
-    assignments the router sent to each of its ``E`` experts, held here or
-    not.
+    the layer is wrong. With ``skip_choices``, ``moe_tokens_skipped``;
+    with ``renormalize=False``, ``moe_gate_mean``, the mean chosen score
+    (``1 / E'`` at a flat softmax router, 1 at a collapsed one). And one
+    array, ``moe_router_load`` [E'] int32: the assignments the router sent
+    to each of its choices, an expert held here or not, or none.
     """
     T, _ = x.shape
-    E = params["router"].shape[-1]
+    width = scores.shape[-1]
+    E = width - skip_choices
     first, count = (0, E) if held is None else held
     if params["w_up"].shape[0] != count or not 0 <= first <= E - count:
         raise ValueError(
             f"held={held!r} against {params['w_up'].shape[0]} expert "
             f"rows and a router over {E}"
         )
-    if scoring not in _SCORING:
-        raise ValueError(f"scoring={scoring!r}; have {sorted(_SCORING)}")
+    if renormalize and top_k == 1:
+        raise ValueError(
+            "top_k=1 with renormalize=True: the gate is p / p, 1 whatever "
+            "the router says, and the router's gradient is zero"
+        )
     worst = T * top_k
     bound = worst if buffer_rows is None else min(buffer_rows, worst)
-    experts = {k: v for k, v in params.items() if k != "router"}
 
     with jax.named_scope("moolib.moe.route"):
-        logits = x.astype(jnp.float32) @ params["router"].astype(jnp.float32)
-        scores = _SCORING[scoring](logits)
         if select_bias is None:
             top_p, top_i = jax.lax.top_k(scores, top_k)
         else:
@@ -192,18 +227,22 @@ def moe_dropless(
                 top_k,
             )
             top_p = jnp.take_along_axis(scores, top_i, axis=-1)
-        gates = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)).reshape(-1)
+        gates = top_p
+        if renormalize:
+            gates = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+        gates = gates.reshape(-1)
         if gate_scale != 1.0:
             gates = gates * gate_scale
         # One key an assignment: its expert's row here, or `count` when the
-        # expert lives elsewhere; a stable sort puts the held ones first,
+        # expert lives elsewhere (or the choice is no expert: its id is
+        # past every expert's); a stable sort puts the held ones first,
         # in expert order, each expert's tokens in token order.
         local = top_i.reshape(-1) - first
         is_held = jnp.logical_and(local >= 0, local < count)
         key = jnp.where(is_held, local, count)
         order = jnp.argsort(key, stable=True)
         router_load = jnp.sum(
-            top_i.reshape(-1)[:, None] == jnp.arange(E)[None, :], axis=0,
+            top_i.reshape(-1)[:, None] == jnp.arange(width)[None, :], axis=0,
             dtype=jnp.int32,
         )
         load = router_load[first:first + count]
@@ -214,7 +253,7 @@ def moe_dropless(
         of the assignments held it seated."""
 
         how = resolve_grouped(
-            rows, x.shape[-1], experts["w_up"].shape[-1], x.dtype
+            rows, x.shape[-1], params["w_up"].shape[-1], x.dtype
         )
 
         def run(x, experts, gates):
@@ -259,11 +298,11 @@ def moe_dropless(
 
     if bound == worst:
         spills = jnp.zeros((), bool)
-        y, seated = over(worst)(x, experts, gates)
+        y, seated = over(worst)(x, params, gates)
     else:
         spills = held_total > bound
         y, seated = jax.lax.cond(
-            spills, over(worst), over(bound), x, experts, gates
+            spills, over(worst), over(bound), x, params, gates
         )
 
     f32 = jnp.float32
@@ -279,4 +318,8 @@ def moe_dropless(
         "moe_overflow": (held_total - seated).astype(f32),
         "moe_router_load": router_load,
     }
+    if skip_choices:
+        aux["moe_tokens_skipped"] = jnp.sum(top_i >= E).astype(f32)
+    if not renormalize:
+        aux["moe_gate_mean"] = jnp.mean(top_p)
     return y, aux

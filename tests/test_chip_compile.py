@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 from moolib_tpu.ops.attention import flash_attention
 from moolib_tpu.ops.embed import embed_lookup
 from moolib_tpu.parallel import moe
-from moolib_tpu.parallel.moe import moe_dropless
+from moolib_tpu.parallel.moe import linear_scores, moe_dropless
 
 T, BLOCK = 8192, 512  # both decoder cells: 8,192 tokens, tiles of 512
 
@@ -129,9 +129,13 @@ def test_grouped_products_compile_at_the_cells_shape(one_chip,
 
     def step(params, x, bias):
         def loss(params, x):
+            params, kw = dict(params), dict(router)
+            scores = linear_scores(
+                x, params.pop("router"), kw.pop("scoring", "softmax")
+            )
             y, _ = moe_dropless(
-                params, x, top_k=top_k, held=held, buffer_rows=rows,
-                select_bias=bias if router else None, **router,
+                params, x, scores, top_k=top_k, held=held, buffer_rows=rows,
+                select_bias=bias if router else None, **kw,
             )
             return y.astype(jnp.float32).sum()
 
@@ -535,4 +539,67 @@ def test_solar2_learner_4ks_step_compiles_within_the_chips_memory(
     # backward kernels
     assert len(flash) == 3
     for scope in ("moolib.lm.kda_core", "moolib.lm.kda_proj"):
+        assert scope in text
+
+
+def test_zaya1_learner_8ks_step_compiles_within_the_chips_memory(
+        one_chip, no_compile_cache, monkeypatch):
+    """The whole train step of ``zaya1_learner_8k`` as the cell runs it
+    (8,192 tokens, 602M parameters donated, five blocks of compressed
+    convolutional attention and top-1 experts as one scan over stacked
+    parameters whose carry is the stream and the router's state, every
+    block rebuilt but its attention core), compiled ahead of time for a
+    v5e: the compiler's plan stays under the 15.75 GiB it gives a
+    program, the core runs the grouped-head flash kernels at 8 / 2 heads
+    of 128 (forward, dQ, dK/dV: no forward kernel in the rebuild), the
+    experts the grouped matmul, and the model's three scopes are in the
+    program."""
+    import json
+
+    from benchmark.lib import program, seeded_cca
+    from moolib_tpu.learner import make_train_state
+
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "configs",
+            "zaya1_share8.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "benchmark", "workloads",
+            "zaya1_learner_8k.json")) as f:
+        cell = json.load(f)
+    assert config["model"]["kwargs"]["remat_blocks"] == "cores"
+    net = program.build_model(config)
+    shapes = seeded_cca.param_shapes(net)
+    # jax.default_backend() is the CPU here: say what the chip would run
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    optimizer = program.build_optimizer(config)
+    step = program.resolve(config["step_factory"])(
+        program.resolve(config["apply_factory"])(net), optimizer,
+        program.loss_config(config), mesh=None, donate=True,
+    )
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda p: make_train_state(p, optimizer), shapes),
+    )
+    T, B, A = cell["unroll_length"], cell["batch_per_chip"], config[
+        "num_actions"]
+    batch = {
+        "obs": s((T + 1, B), jnp.int32), "done": s((T + 1, B), jnp.bool_),
+        "rewards": s((T + 1, B), jnp.float32),
+        "actions": s((T, B), jnp.int32),
+        "behavior_logits": s((T, B, A), jnp.float32), "core_state": (),
+    }
+    compiled = step.lower(state, batch).compile()
+    assert compiled.memory_analysis().peak_memory_in_bytes < 15.75 * 2 ** 30
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sum("moolib.lm.attn_core" in line for line in calls) == 3
+    assert sum("moolib.moe.experts" in line for line in calls) == 30
+    for scope in ("moolib.lm.cca_proj", "moolib.lm.cca_mix",
+                  "moolib.moe.router_mlp"):
         assert scope in text

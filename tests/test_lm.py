@@ -26,7 +26,7 @@ from moolib_tpu.models.lm import (  # noqa: E402
     router_loads,
 )
 from moolib_tpu.models.transformer import segment_ids_from_done  # noqa: E402
-from moolib_tpu.parallel.moe import moe_dropless  # noqa: E402
+from moolib_tpu.parallel.moe import linear_scores, moe_dropless  # noqa: E402
 
 VOCAB, T, B = 48, 31, 2
 LOSS = {"discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
@@ -267,9 +267,10 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     parts = []
     for s in range(4):
         rows = slice(2 * s, 2 * s + 2)
-        share = {"router": moe["router"], "w_gate": moe["w_gate"][rows],
-                 "w_up": moe["w_up"][rows], "w_down": moe["w_down"][rows]}
-        y, _ = moe_dropless(share, z, top_k=top_k, held=(2 * s, 2))
+        share = {"w_gate": moe["w_gate"][rows], "w_up": moe["w_up"][rows],
+                 "w_down": moe["w_down"][rows]}
+        y, _ = moe_dropless(share, z, linear_scores(z, moe["router"]),
+                            top_k=top_k, held=(2 * s, 2))
         parts.append(y)
     whole = ref.experts(z, moe, spec, cast)
     np.testing.assert_allclose(sum(parts), whole, rtol=2e-4, atol=2e-4)

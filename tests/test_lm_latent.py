@@ -30,7 +30,7 @@ from moolib_tpu.learner import (ImpalaConfig, impala_loss,  # noqa: E402
 from moolib_tpu.models.lm import (DecoderLM, _Block, _LatentAttention,  # noqa: E402
                                   _Mtp, decoder_lm, learn_apply, router_loads)
 from moolib_tpu.models.transformer import segment_ids_from_done  # noqa: E402
-from moolib_tpu.parallel.moe import moe_dropless  # noqa: E402
+from moolib_tpu.parallel.moe import linear_scores, moe_dropless  # noqa: E402
 
 VOCAB, T, B = 48, 31, 2
 LOSS = {"discounting": 0.99, "baseline_cost": 0.5, "entropy_cost": 0.0006,
@@ -272,12 +272,14 @@ def moe_parts(seed=11, E=8, d=32, f=24):
 def dropless(moe, z, held, bias=True, **over):
     first, count = held
     rows = slice(first, first + count)
-    share = {"router": moe["router"], "w_gate": moe["w_gate"][rows],
-             "w_up": moe["w_up"][rows], "w_down": moe["w_down"][rows]}
-    kw = dict(top_k=2, held=held, scoring="sigmoid", gate_scale=1.8,
+    share = {"w_gate": moe["w_gate"][rows], "w_up": moe["w_up"][rows],
+             "w_down": moe["w_down"][rows]}
+    kw = dict(top_k=2, held=held, gate_scale=1.8,
               select_bias=moe["e_score_correction_bias"] if bias else None)
     kw.update(over)
-    return moe_dropless(share, z, **kw)
+    return moe_dropless(
+        share, z, linear_scores(z, moe["router"], "sigmoid"), **kw
+    )
 
 
 def test_the_bias_moves_the_selection_and_not_the_gates():
@@ -325,8 +327,8 @@ def test_the_bias_takes_exactly_no_gradient():
 
 def test_the_default_router_is_untouched_and_an_unknown_rule_refused():
     moe, z = moe_parts()
-    share = {k: moe[k] for k in ("router", "w_gate", "w_up", "w_down")}
-    y, _ = moe_dropless(share, z, top_k=2)
+    share = {k: moe[k] for k in ("w_gate", "w_up", "w_down")}
+    y, _ = moe_dropless(share, z, linear_scores(z, moe["router"]), top_k=2)
     probs = jax.nn.softmax(z @ moe["router"], axis=-1)
     top_p, top_i = jax.lax.top_k(probs, 2)
     gates = top_p / top_p.sum(-1, keepdims=True)
@@ -337,7 +339,7 @@ def test_the_default_router_is_untouched_and_an_unknown_rule_refused():
         want = want + g[:, None] * (hidden @ moe["w_down"][e])
     close(y, want)
     with pytest.raises(ValueError, match="scoring"):
-        moe_dropless(share, z, top_k=2, scoring="tanh")
+        linear_scores(z, moe["router"], "tanh")
 
 
 @pytest.mark.parametrize("name,f,scale", [

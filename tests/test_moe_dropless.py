@@ -11,7 +11,9 @@ import pytest
 
 from moolib_tpu.learner import ImpalaConfig, impala_loss
 from moolib_tpu.parallel import moe
-from moolib_tpu.parallel.moe import moe_dropless, resolve_grouped
+from moolib_tpu.parallel.moe import (
+    linear_scores, moe_dropless, resolve_grouped,
+)
 
 T, D, F, E = 96, 16, 12, 8
 
@@ -26,10 +28,21 @@ def gated_params(seed, count=E):
     }, jax.random.normal(ks[4], (T, D))
 
 
+def routed(params, x, **kw):
+    """The layer behind a router of one matrix, ``params["router"]``; one
+    expert a token takes its gate as it is (renormalised it is 1, and
+    refused)."""
+    experts = {k: v for k, v in params.items() if k != "router"}
+    kw.setdefault("renormalize", kw["top_k"] > 1)
+    return moe_dropless(
+        experts, x, linear_scores(x, params["router"]), **kw
+    )
+
+
 def loop_over_experts(params, x, top_k, first, count):
     probs = jax.nn.softmax(x @ params["router"], axis=-1)
     top_p, top_i = jax.lax.top_k(probs, top_k)
-    gates = top_p / top_p.sum(-1, keepdims=True)
+    gates = top_p / top_p.sum(-1, keepdims=True) if top_k > 1 else top_p
     y = jnp.zeros_like(x)
     for e in range(count):
         g = jnp.sum(jnp.where(top_i == first + e, gates, 0.0), axis=-1)
@@ -45,7 +58,7 @@ def test_equal_to_a_loop_over_experts_under_jit(top_k, held):
     first, count = held or (0, E)
     params, x = gated_params(0, count)
     ours = jax.jit(
-        lambda p, x: moe_dropless(p, x, top_k=top_k, held=held)[0]
+        lambda p, x: routed(p, x, top_k=top_k, held=held)[0]
     )
     ref = lambda p, x: loop_over_experts(p, x, top_k, first, count)  # noqa
     np.testing.assert_allclose(ours(params, x), ref(params, x),
@@ -68,7 +81,7 @@ def test_every_token_to_one_expert_and_nothing_dropped():
     router = jnp.zeros((D, E)).at[0, 5].set(9.0).at[0, 1].set(4.0)
     params = dict(params, router=router)
     y, counters = jax.jit(
-        lambda p, x: moe_dropless(p, x, top_k=2)
+        lambda p, x: routed(p, x, top_k=2)
     )(params, x)
     np.testing.assert_allclose(
         y, loop_over_experts(params, x, 2, 0, E), rtol=1e-5, atol=1e-5
@@ -87,17 +100,17 @@ def test_a_buffer_too_small_spills_to_the_worst_case(rows, spills):
     and its gradients are the unbounded layer's whichever runs."""
     params, x = gated_params(5, count=3)
     kw = dict(top_k=2, held=(1, 3))
-    full, counters = moe_dropless(params, x, **kw)
+    full, counters = routed(params, x, **kw)
     held = int(counters["moe_assignments_held"])
     rows = eval(rows, {"held": held}) if isinstance(rows, str) else rows
     w = jax.random.normal(jax.random.PRNGKey(8), (T, D))
 
     def loss(rows):
-        return lambda p, x: jnp.sum(w * moe_dropless(
+        return lambda p, x: jnp.sum(w * routed(
             p, x, buffer_rows=rows, **kw)[0])
 
     g_full = jax.grad(loss(None), argnums=(0, 1))(params, x)
-    y, c = jax.jit(lambda p, x: moe_dropless(
+    y, c = jax.jit(lambda p, x: routed(
         p, x, buffer_rows=rows, **kw))(params, x)
     np.testing.assert_allclose(y, full, rtol=1e-5, atol=1e-6)
     assert float(c["moe_spills"]) == spills
@@ -115,7 +128,7 @@ def test_a_stated_buffer_keeps_none_of_its_rows_for_the_backward_pass():
     params, x = gated_params(6, count=3)
 
     def products(rows):
-        text = str(jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(moe_dropless(
+        text = str(jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(routed(
             p, x, top_k=2, held=(1, 3), buffer_rows=rows)[0]),
             argnums=(0, 1)))(params, x))
         return text.count("= ragged_dot_general["), text.count("= cond[")
@@ -148,7 +161,7 @@ def test_the_pallas_grouped_matmul_equals_ragged_dot(held, buffer_rows,
         monkeypatch.setattr(moe, "resolve_grouped", lambda *a: how)
 
         def loss(p, x):
-            y, aux = moe_dropless(p, x, top_k=top_k, held=held,
+            y, aux = routed(p, x, top_k=top_k, held=held,
                                   buffer_rows=buffer_rows)
             return jnp.sum(w * y), (y, aux)
 
@@ -170,7 +183,7 @@ def test_the_pallas_grouped_matmul_equals_ragged_dot(held, buffer_rows,
 def test_auto_is_ragged_dot_off_the_chip_and_the_router_load_is_whole():
     assert resolve_grouped(16384, 2304, 896, jnp.bfloat16) == "ragged_dot"
     params, x = gated_params(8, count=3)
-    _, aux = moe_dropless(params, x, top_k=3, held=(2, 3))
+    _, aux = routed(params, x, top_k=3, held=(2, 3))
     load = np.asarray(aux["moe_router_load"])
     assert load.shape == (E,) and load.sum() == T * 3
     assert load[2:5].sum() == float(aux["moe_assignments_held"])
@@ -179,9 +192,9 @@ def test_auto_is_ragged_dot_off_the_chip_and_the_router_load_is_whole():
 def test_held_has_to_match_the_expert_rows():
     params, x = gated_params(4, count=3)
     with pytest.raises(ValueError, match="held"):
-        moe_dropless(params, x, top_k=2, held=(6, 3))
+        routed(params, x, top_k=2, held=(6, 3))
     with pytest.raises(ValueError, match="held"):
-        moe_dropless(params, x, top_k=2, held=(1, 4))
+        routed(params, x, top_k=2, held=(1, 4))
 
 
 def test_impala_loss_passes_counters_through_and_folds_only_loss_terms():
