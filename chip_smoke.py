@@ -15,7 +15,7 @@ One process, every local TPU device, the normal entry points, full width:
    in-process broker, Accumulator, act / grad / apply steps, until at least
    three updates have been applied — with the workers shown to have stayed
    off the chip and the state shown to live on every chip;
-4. ``flash``  the three Pallas flash-attention kernels as compiled by Mosaic
+4. ``flash``  the two Pallas flash-attention kernels as compiled by Mosaic
    against ``dense_attention`` at the default (256, 256) blocks;
 5. ``auto_backend``  what ``attention(backend="auto")`` resolves to inside
    the jitted learner step of a TransformerNet at the shipped unroll
@@ -474,7 +474,7 @@ def phase_trainer(devices, compiles: CompileLog, total_steps: int = 20_000,
 
 
 def phase_flash(compiles: CompileLog, shapes, **flash_kw) -> dict:
-    """Forward, dQ and dK/dV kernels vs ``dense_attention`` (float32,
+    """The forward and the backward kernel vs ``dense_attention`` (float32,
     "highest" matmul precision), causal with segment ids — the way
     TransformerNet calls them — at the default (256, 256) blocks."""
     import jax

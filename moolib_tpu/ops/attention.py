@@ -18,9 +18,10 @@ Three implementations, one contract ``[B, H, T, D] -> [B, H, T, D]``:
 - :func:`flash_attention` — pallas TPU kernels for BOTH passes: forward
   (grid over (batch·heads, q-blocks, k-blocks), f32 VMEM accumulators,
   online softmax, per-row log-sum-exp emitted for the backward) and the
-  FlashAttention backward (a dQ kernel and a dK/dV kernel that rebuild P
-  from the saved lse — no second softmax, no O(T²) residuals), O(T)
-  memory end to end with causal block skipping in all three kernels.
+  FlashAttention backward (ONE kernel that rebuilds P from the saved lse
+  once a visible tile and makes dQ, dK and dV from it — no second
+  softmax, no O(T²) residuals), O(T) memory end to end with causal block
+  skipping in both kernels.
 
 All three support causal masking and ``segment_ids`` (attention is blocked
 across segment boundaries — used by the transformer agent to stop attention
@@ -44,12 +45,12 @@ key and value a chunk). Two things make it two calls of what is here:
   float32 the log of the row's sum of ``e^score`` over the keys it saw
   (-1e30 and a row of zeros where it saw none), *differentiable*: since
   ``d lse_i / d s_ij = p_ij``, its cotangent is taken off the backward
-  pass's ``delta`` and the flash kernels are the same three.
+  pass's ``delta`` and the flash kernels are the same two.
   :func:`merge_attention` then gives ``(o1 e^lse1 + o2 e^lse2) / (e^lse1 +
   e^lse2)``, the one softmax over both sets, exactly. Where nothing asks
   for ``lse`` a flash call traces the program it always traced: that is
   why ``_flash_attention_lse`` is a second ``custom_vjp`` beside
-  ``_flash_attention`` over the same three kernels, and the two change
+  ``_flash_attention`` over the same two kernels, and the two change
   together (an operand, a tile rule or a residual added to one's forward
   and backward goes into the other's).
 - ``rank_bits=b`` (with ``causal=False`` and both id arrays): an id is
@@ -343,7 +344,7 @@ def blockwise_attention(
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash-attention kernels (forward, dQ, dK/dV)
+# Pallas flash-attention kernels (forward, backward)
 # ---------------------------------------------------------------------------
 #
 # Mosaic layout rules the kernels are written to (every value is 2-D):
@@ -355,10 +356,18 @@ def blockwise_attention(
 #   be turned back into a column on the chip (lane -> sublane relayout);
 # - a per-column quantity is a [1, cols] row, broadcast along sublanes;
 # - q·kᵀ is an NT ``dot_general`` (contract both minor dims), never an
-#   in-kernel transpose. The dK/dV kernel works on the TRANSPOSED tile
+#   in-kernel transpose. The backward kernel works on the TRANSPOSED tile
 #   sᵀ = k·qᵀ [block_k, block_q] so that its products pᵀ·dO and dsᵀ·q are
 #   plain NN matmuls; per-query statistics are rows there and the key
-#   segment ids are the lane-replicated columns.
+#   segment ids are the lane-replicated columns;
+# - the backward kernel's grid keeps a key block resident (dk, dv in
+#   scratch) and walks the query blocks under it, so dq, which belongs to
+#   the query blocks, stays in VMEM for a grid row's whole walk: the
+#   float32 [G * Tq, D] of the G query heads that share the row's key/value
+#   head, scaled, cast and written once at the row's last step. Its size
+#   is the shape's (:func:`_dq_resident_bytes`, which also sets the
+#   kernel's ``vmem_limit_bytes``); heads that do not fit together are
+#   spread over more rows (:func:`_dq_passes`).
 
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
@@ -380,10 +389,10 @@ def _lanes(x, n: int):
 def _tile_mask(causal, q_axis, q_start, k_start, seg_rows, seg_cols,
                window=None, rank_bits=None):
     """Visibility of one score tile — the ONE definition shared by the
-    forward and both backward kernels, so the masks can never diverge.
+    forward and the backward kernel, so the masks can never diverge.
     ``seg_rows`` [rows, cols] / ``seg_cols`` [1, cols] are the segment ids
     of the tile's row and column axes; ``q_axis`` says which axis carries
-    the queries (0 for s, 1 for the dK/dV kernel's sᵀ)."""
+    the queries (0 for s, 1 for the backward kernel's sᵀ)."""
     if rank_bits is None:
         mask = seg_rows == seg_cols
     else:
@@ -512,7 +521,7 @@ def _flash_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref, seg_k_ref,
     """Grid: (B*H, n_q, n_kw); the k-axis is the sequential ('arbitrary')
     dimension carrying the online-softmax state in VMEM scratch. q/k/v
     blocks arrive pre-staged by BlockSpec. Also emits the per-row
-    log-sum-exp (lse) the backward kernels rebuild P from."""
+    log-sum-exp (lse) the backward kernel rebuilds P from."""
     b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     ki = t.first_k(qi) + j
     dv = v_ref.shape[-1]  # the value head's size, and the output's
@@ -691,64 +700,41 @@ def _flash_forward(q, k, v, seg_q, seg_k, causal, window, block_q, block_k,
     return out.reshape(B, H, Tq, Dv), lse[:, :, 0]
 
 
-def _flash_bwd_dq_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
-                         seg_k_ref, lse_ref, delta_ref, do_ref, dq_ref,
-                         dq_sc, *, t: _Tiles):
-    """dQ pass. Grid (B*H, n_q, n_kw); k-axis sequential, dq accumulates in
-    VMEM scratch. P is rebuilt from the saved lse (no second softmax)."""
-    b, qi, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    ki = t.first_k(qi) + j
-    block_k = t.block_k
-
-    @pl.when(j == 0)
-    def _init():
-        dq_sc[...] = jnp.zeros_like(dq_sc)
-
-    @pl.when(jnp.logical_and(
-        ki < t.n_k, t.visible(qi, ki, q_rng, k_rng, b // t.H)
-    ))
-    def _compute():
-        scale = t.scale
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-
-        s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
-        mask = _tile_mask(
-            t.causal, 0, qi * t.block_q, ki * block_k,
-            _lanes(seg_q_ref[0], block_k), seg_k_ref[0], t.window,
-            t.rank_bits,
-        )
-        s = jnp.where(mask, s, _NEG_INF)
-        p = jnp.exp(s - _lanes(lse_ref[0], block_k))
-        dp = jax.lax.dot_general(
-            do, v, _NT, preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - _lanes(delta_ref[0], block_k))
-        dq_sc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32) * scale
-
-    @pl.when(j == t.n_kw - 1)
-    def _done():
-        dq_ref[0] = dq_sc[...].astype(dq_ref.dtype)
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
-def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
-                           seg_k_ref, lse_ref, delta_ref, do_ref, dk_ref,
-                           dv_ref, dk_sc, dv_sc, *, t: _Tiles):
-    """dK/dV pass on the transposed tile sᵀ [block_k, block_q]. Grid
-    (B*Hkv, n_k, G*n_qw); the last axis is sequential and walks the G
-    query heads of this key/value head, each over the query blocks that
-    can see key block kj: dk/dv accumulate over all of them in VMEM
-    scratch."""
+def _flash_bwd_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
+                      seg_k_ref, lse_ref, delta_ref, do_ref, dq_ref, dk_ref,
+                      dv_ref, dq_sc, dk_sc, dv_sc, *, t: _Tiles):
+    """The whole backward pass on the transposed tile sᵀ [block_k, block_q],
+    each visible tile visited once. Grid (B*Hkv, n_k, G*n_qw); the last
+    axis walks the G query heads of this key/value head, each over the
+    query blocks that can see key block kj, and dk/dv accumulate over all of
+    them in VMEM scratch. dq of those G heads stays in scratch [G*Tq, D]
+    for the whole walk over kj (:func:`_dq_resident_bytes`) and is scaled,
+    cast and written once, at the row's last step. P is rebuilt from the
+    saved lse (no second softmax)."""
     b, kj, step = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     qi = t.first_q(kj) + step % t.n_qw
     block_q = t.block_q
+    last = t.G * t.n_qw - 1
+
+    def q_rows(i):  # block i of the resident dq, heads one after the other
+        return pl.ds(pl.multiple_of(i * block_q, block_q), block_q)
 
     @pl.when(step == 0)
     def _init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
+
+    @pl.when(jnp.logical_and(kj == 0, step == 0))
+    def _init_dq():
+        def zero(i, carry):
+            dq_sc[q_rows(i), :] = jnp.zeros((block_q, dq_sc.shape[-1]),
+                                            jnp.float32)
+            return carry
+
+        jax.lax.fori_loop(0, t.G * t.n_q, zero, 0)
 
     @pl.when(jnp.logical_and(
         qi < t.n_q, t.visible(qi, kj, q_rng, k_rng, b // t.Hkv)
@@ -776,11 +762,62 @@ def _flash_bwd_dkdv_kernel(q_rng, k_rng, q_ref, k_ref, v_ref, seg_q_ref,
         dst = pt * (dpt - delta_ref[0])
         # q already carries the softmax scale: dK = dSᵀ · (scale · Q).
         dk_sc[...] += jnp.dot(dst, q, preferred_element_type=jnp.float32)
+        # dQ = scale · dS · K, the one product over the tile's row axis.
+        rows = q_rows((step // t.n_qw) * t.n_q + qi)
+        dq_sc[rows, :] += jax.lax.dot_general(
+            dst, k, _TN, preferred_element_type=jnp.float32
+        )
 
-    @pl.when(step == t.G * t.n_qw - 1)
+    @pl.when(step == last)
     def _done():
         dk_ref[0] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_sc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(kj == t.n_k - 1, step == last))
+    def _done_dq():
+        def cast(i, carry):
+            rows = q_rows(i)
+            dq_ref[0, rows, :] = (dq_sc[rows, :] * t.scale).astype(
+                dq_ref.dtype
+            )
+            return carry
+
+        jax.lax.fori_loop(0, t.G * t.n_q, cast, 0)
+
+
+# The backward kernel's VMEM, of a v5e core's 128 MiB: what it may hold for
+# dq from a row's first step to its last, and what it needs beside that (the
+# operands' blocks, dk and dv, the float32 tiles of one step: five of
+# block_k x block_q).
+_DQ_VMEM_BUDGET = 64 * 2 ** 20
+_BWD_VMEM_MARGIN = 32 * 2 ** 20
+
+
+def _dq_resident_bytes(heads: int, Tq: int, D: int, itemsize: int) -> int:
+    """VMEM the backward kernel holds for the dq of ``heads`` query heads:
+    their float32 accumulator [heads * Tq, D] and the output block it is
+    cast into, in the queries' dtype and double-buffered like every output
+    block."""
+    return heads * Tq * D * (4 + 2 * itemsize)
+
+
+def _dq_passes(G: int, Tq: int, D: int, itemsize: int) -> int:
+    """Over how many rows of the backward kernel's grid the ``G`` query
+    heads of a key/value head are spread so that a row's resident dq fits
+    :data:`_DQ_VMEM_BUDGET`: 1 wherever a cell runs (one head of 8,192 x
+    256: 16 MiB; 4 heads on one of 8,192 x 128: 32 MiB), more for longer
+    rows, and a row then makes a partial dk/dv."""
+    for passes in range(1, G + 1):
+        if G % passes == 0 and _dq_resident_bytes(
+                G // passes, Tq, D, itemsize) <= _DQ_VMEM_BUDGET:
+            return passes
+    raise ValueError(
+        f"flash attention's backward holds one query head's dq in fast "
+        f"memory: {Tq} x {D} needs "
+        f"{_dq_resident_bytes(1, Tq, D, itemsize) / 2 ** 20:.0f} MiB of "
+        f"{_DQ_VMEM_BUDGET / 2 ** 20:.0f}; shard the sequence "
+        f"(ops.ring_attention) or use backend='blockwise'"
+    )
 
 
 def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
@@ -789,10 +826,16 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
     B, H, Tq, D = q.shape
     Tk, Dv = k.shape[-2], v.shape[-1]
     t = _tiles(q, k, causal, window, block_q, block_k, scale, rank_bits)
-    block_q, block_k, Hkv = t.block_q, t.block_k, t.Hkv
+    block_q, block_k = t.block_q, t.block_k
+    # A grid row walks the query heads whose dq it holds: all G of a
+    # key/value head in one pass wherever that fits, else G / passes of them,
+    # and the geometry is that of passes times as many key/value heads.
+    passes = _dq_passes(t.G, Tq, D, q.dtype.itemsize)
+    kv_heads, Hkv = t.Hkv, t.Hkv * passes
+    t = t._replace(Hkv=Hkv)
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * Hkv, Tk, D)
-    vr = v.reshape(B * Hkv, Tk, Dv)
+    kr = k.reshape(B * kv_heads, Tk, D)
+    vr = v.reshape(B * kv_heads, Tk, Dv)
     gr = g.reshape(B * H, Tq, Dv)
     # delta_i = rowsum(dO * O): the softmax-jacobian correction term.
     delta = jnp.sum(
@@ -800,61 +843,18 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
     ).reshape(B * H, Tq)
     if dlse is not None:
         # d lse_i / d s_ij = p_ij, so the row statistic's cotangent enters
-        # both kernels where delta does: ds = p (dp - (delta - dlse)).
+        # the kernel where delta does: ds = p (dp - (delta - dlse)).
         delta = delta - dlse.astype(jnp.float32).reshape(B * H, Tq)
-    semantics = pltpu.CompilerParams(dimension_semantics=_SEMANTICS)
-    ranges = (_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k))
+    global_telemetry().registry.counter(
+        "attention_backward_traced_total", form="fused"
+    ).inc()
 
-    # dQ: score tile [block_q, block_k]; per-query stats are columns.
-    # Queries, keys and their gradients are D wide; values, the output's
-    # cotangent and the values' gradient Dv.
-    def q_spec(width):
-        return pl.BlockSpec(
-            (1, block_q, width), lambda b, qi, j, *_: (b, qi, 0)
-        )
-
-    def k_spec(width):
-        return pl.BlockSpec(
-            (1, block_k, width),
-            lambda b, qi, j, *_: (_kv_row(t, b), t.k_block(qi, j), 0),
-        )
-
-    q_col = pl.BlockSpec(
-        (1, block_q, _LANES), lambda b, qi, j, *_: (b, qi, 0)
-    )
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, t=t),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B * H, t.n_q, t.n_kw),
-            in_specs=[
-                q_spec(D),
-                k_spec(D),
-                k_spec(Dv),
-                pl.BlockSpec(
-                    (1, block_q, _LANES),
-                    lambda b, qi, j, *_: (b // H, qi, 0),
-                ),
-                pl.BlockSpec(
-                    (1, 1, block_k),
-                    lambda b, qi, j, *_: (b // H, 0, t.k_block(qi, j)),
-                ),
-                q_col,
-                q_col,
-                q_spec(Dv),
-            ],
-            out_specs=q_spec(D),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        compiler_params=semantics,
-        interpret=interpret,
-    )(*ranges, qr, kr, vr, _col_form(seg_q), _row_form(seg_k),
-      _col_form(lse), _col_form(delta), gr)
-
-    # dK/dV: transposed tile [block_k, block_q]; per-query stats are rows.
+    # Transposed tile [block_k, block_q]: per-query statistics are rows, the
+    # keys' segment ids the lane-replicated columns. Queries, keys and their
+    # gradients are D wide; values, the output's cotangent and the values'
+    # gradient Dv.
     def q_row_of(b, step):  # the query head this step walks
-        return (b // Hkv) * H + (b % Hkv) * t.G + step // t.n_qw
+        return b * t.G + step // t.n_qw
 
     def q_spec(width):
         return pl.BlockSpec(
@@ -864,17 +864,21 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
             ),
         )
 
-    def k_spec(width):
+    def k_spec(width, rows_a_head=passes):
         return pl.BlockSpec(
-            (1, block_k, width), lambda b, kj, step, *_: (b, kj, 0)
+            (1, block_k, width),
+            lambda b, kj, step, *_: (b // rows_a_head, kj, 0),
         )
+
+    def partial_dtype(x):  # a pass's dk or dv is summed below: not rounded
+        return jnp.float32 if passes > 1 else x.dtype
 
     q_row = pl.BlockSpec(
         (1, 1, block_q),
         lambda b, kj, step, *_: (q_row_of(b, step), 0, t.q_block(kj, step)),
     )
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, t=t),
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, t=t),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B * Hkv, t.n_k, t.G * t.n_qw),
@@ -896,26 +900,40 @@ def _flash_backward(q, k, v, seg_q, seg_k, out, lse, g, causal, window,
                 q_row,
                 q_spec(Dv),
             ],
-            out_specs=[k_spec(D), k_spec(Dv)],
+            out_specs=[
+                # the G query heads of key/value head b, one after the other
+                pl.BlockSpec(
+                    (1, t.G * Tq, D), lambda b, kj, step, *_: (b, 0, 0)
+                ),
+                k_spec(D, 1),
+                k_spec(Dv, 1),
+            ],
             scratch_shapes=[
+                pltpu.VMEM((t.G * Tq, D), jnp.float32),
                 pltpu.VMEM((block_k, D), jnp.float32),
                 pltpu.VMEM((block_k, Dv), jnp.float32),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, Tk, D), k.dtype),
-            jax.ShapeDtypeStruct((B * Hkv, Tk, Dv), v.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, t.G * Tq, D), q.dtype),
+            jax.ShapeDtypeStruct((B * Hkv, Tk, D), partial_dtype(k)),
+            jax.ShapeDtypeStruct((B * Hkv, Tk, Dv), partial_dtype(v)),
         ],
-        compiler_params=semantics,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_dq_resident_bytes(
+                t.G, Tq, D, q.dtype.itemsize) + _BWD_VMEM_MARGIN,
+        ),
         interpret=interpret,
-    )(*ranges, qr, kr, vr, _row_form(seg_q), _col_form(seg_k),
+    )(_block_ranges(seg_q, t.n_q), _block_ranges(seg_k, t.n_k),
+      qr, kr, vr, _row_form(seg_q), _col_form(seg_k),
       _row_form(lse), _row_form(delta), gr)
 
-    return (
-        dq.reshape(B, H, Tq, D),
-        dk.reshape(B, Hkv, Tk, D),
-        dv.reshape(B, Hkv, Tk, Dv),
-    )
+    def whole(x, like):  # [B * Hkv, Tk, width] from the passes' partials
+        x = x.reshape(B, kv_heads, passes, Tk, x.shape[-1])
+        return x[:, :, 0] if passes == 1 else x.sum(2).astype(like.dtype)
+
+    return dq.reshape(B, H, Tq, D), whole(dk, k), whole(dv, v)
 
 
 @functools.partial(
@@ -982,7 +1000,7 @@ def _merge_form(lse, q):
 def _flash_attention_lse(q, k, v, seg_q, seg_k, causal, window, block_q,
                          block_k, interpret, scale, rank_bits):
     """:func:`_flash_attention` with the row statistics as a second,
-    differentiable output: the same three kernels, the statistic's
+    differentiable output: the same two kernels, the statistic's
     cotangent taken off ``delta``."""
     out, lse = _flash_forward(
         q, k, v, seg_q, seg_k, causal, window, block_q, block_k, interpret,
@@ -1020,8 +1038,9 @@ def flash_attention(
     return_lse: bool = False,
 ):
     """Pallas flash attention (custom VJP backward), compiled by Mosaic.
-    q [B, H, Tq, D], k [B, Hkv, Tk, D], v [B, Hkv, Tk, Dv]: the three
-    kernels compute at both head sizes as they are given, nothing padded.
+    q [B, H, Tq, D], k [B, Hkv, Tk, D], v [B, Hkv, Tk, Dv]: the forward
+    and the backward kernel compute at both head sizes as they are given,
+    nothing padded.
     ``rank_bits``, ``return_lse``: the module docstring; with ``rank_bits``
     the ids may not decrease along either axis, in group or in rank (the
     kernels skip a tile by its blocks' least and largest id).

@@ -41,11 +41,14 @@ _faulthandler_fd = None
 def pytest_configure(config):
     """Arm a whole-session faulthandler watchdog: if the suite is still
     running when the timer fires — i.e. something deadlocked and is about
-    to eat the tier-1 870s window silently — every thread's stack is
-    dumped so the hang is diagnosable from the CI log. The default sits
-    just under the outer ``timeout -k 10 870`` so the dump lands BEFORE
-    SIGKILL; ``MOOLIB_FAULTHANDLER_TIMEOUT=0`` disables, any other value
-    re-tunes (tools/ci_check.sh documents the pairing).
+    to eat the tier-1 window silently — every thread's stack is dumped so
+    the hang is diagnosable from the CI log. The default sits just under
+    the outer ``timeout -k 10 1470`` the driver runs tier-1 under, so the
+    dump lands BEFORE SIGKILL and never in the middle of a sound run (at
+    840 it fired 36 s after a sound run's end, and a dump taken while jax's
+    threads run can take its worker down: PR 47's first whole run lost one
+    so); ``MOOLIB_FAULTHANDLER_TIMEOUT=0`` disables, any other value
+    re-tunes (tools/ci_check.sh pairs 840 with its own 870).
 
     The dump must go to the REAL stderr, not pytest's capture: a
     SIGKILLed session never flushes capture temp files, so a dump
@@ -54,7 +57,7 @@ def pytest_configure(config):
     faulthandler plugin does."""
     import faulthandler
 
-    timeout = float(os.environ.get("MOOLIB_FAULTHANDLER_TIMEOUT", "840"))
+    timeout = float(os.environ.get("MOOLIB_FAULTHANDLER_TIMEOUT", "1440"))
     if timeout <= 0:
         return
     try:

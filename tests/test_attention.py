@@ -454,7 +454,7 @@ def test_transformer_zigzag_training_keeps_sharded_layout():
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_flash_backward_kernel_with_segments(rng, causal):
-    """The pallas backward (dQ + dK/dV kernels rebuilt from the saved lse)
+    """The pallas backward (one kernel, P rebuilt from the saved lse)
     must match oracle gradients under segment masking, including
     fully-masked rows (unmatchable q segment => zero gradient, not NaN).
     Reference is blockwise_attention: like flash it returns zeros for
@@ -490,6 +490,118 @@ def test_flash_backward_kernel_with_segments(rng, causal):
     np.testing.assert_array_equal(np.asarray(g_fl[0])[0, :, :4, :], 0.0)
 
 
+def _rank_ids(Tq, Tk, bits=4):
+    """Ids as ``group << bits | rank`` that never decrease along either
+    axis, in group or in rank: a query's rank is its window of 8, a key
+    summarises a quarter of one, and the second episode starts at 5/8."""
+    qpos, kpos = np.arange(Tq), np.arange(Tk) * Tq // Tk
+    ids = [((pos >= Tq * 5 // 8) << bits) | (pos // 8)
+           for pos in (qpos, kpos)]
+    return tuple(jnp.asarray(i[None], jnp.int32) for i in ids) + (bits,)
+
+
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["out", "out_and_lse"])
+@pytest.mark.parametrize("H,Hkv,D,Dv,Tk,kw", [
+    (2, 2, 16, 16, 64, dict(causal=True)),
+    (2, 2, 16, 16, 64, dict(causal=False, segs=True)),
+    (2, 2, 16, 16, 32, dict(causal=False)),
+    (4, 1, 16, 16, 64, dict(causal=True, segs=True)),
+    (4, 1, 16, 16, 64, dict(causal=True, window=24, segs=True)),
+    (2, 2, 16, 16, 64, dict(causal=True, window=40)),
+    (2, 2, 16, 16, 32, dict(causal=False, ranks=True)),
+    (8, 2, 16, 16, 32, dict(causal=False, ranks=True)),
+    (2, 2, 192, 128, 64, dict(causal=True, segs=True)),
+    (4, 1, 192, 128, 64, dict(causal=True, window=24, segs=True)),
+], ids=["causal", "segments", "short_keys", "grouped", "grouped_window",
+        "window", "ranks", "grouped_ranks", "wide_keys",
+        "wide_keys_grouped_window"])
+def test_flash_backward_matches_the_oracles_gradient(rng, H, Hkv, D, Dv, Tk,
+                                                     kw, with_lse):
+    """The one backward kernel against ``jax.grad`` of ``dense_attention``:
+    every geometry that reaches it (the diagonal, a window's walk, episodes,
+    ids as group and rank, ``G`` query heads on a key/value head whose dq it
+    holds together, keys wider than values, fewer keys than queries), with
+    and without a cotangent on the row statistics."""
+    B, Tq = 1, 64
+    q = jnp.asarray(rng.standard_normal((B, H, Tq, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, Hkv, Tk, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, Hkv, Tk, Dv)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((B, H, Tq, Dv)), jnp.float32)
+    w_lse = jnp.asarray(rng.standard_normal((B, H, Tq)), jnp.float32)
+    kw = dict(kw)
+    if kw.pop("segs", False):
+        kw["segment_ids"] = _segs(rng, B=B, T=Tq)
+    if kw.pop("ranks", False):
+        kw["segment_ids"], kw["kv_segment_ids"], kw["rank_bits"] = _rank_ids(
+            Tq, Tk)
+
+    def loss(fn, need_lse=True, **more):
+        def f(q, k, v):
+            if not need_lse:  # the custom_vjp without the statistics
+                return jnp.sum(fn(q, k, v, **kw, **more) * w)
+            out, lse = fn(q, k, v, return_lse=True, **kw, **more)
+            if not with_lse:
+                return jnp.sum(out * w)
+            # a row that saw no key carries no statistic
+            return jnp.sum(out * w) + jnp.sum(
+                jnp.where(lse > -1e29, lse, 0.0) * w_lse)
+
+        return jax.jit(jax.grad(f, argnums=(0, 1, 2)))(q, k, v)
+
+    # the oracle's rows that see no key are zeros only with the statistics
+    want = loss(dense_attention)
+    got = loss(flash_attention, need_lse=with_lse, block_q=16, block_k=16,
+               interpret=True)
+    for a, b, name in zip(want, got, "qkv"):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=5e-5, err_msg=name
+        )
+
+
+@pytest.mark.parametrize("passes", [1, 2, 4])
+def test_flash_backward_spreads_grouped_heads_over_passes(rng, monkeypatch,
+                                                          passes):
+    """Four query heads on a key/value head whose dq does not fit the
+    kernel's fast memory together: the backward walks them in two or four
+    passes of its grid, each a partial dk/dv, and gives the gradient it
+    gives in one. A single head that does not fit is refused by name."""
+    from moolib_tpu.ops import attention as ops
+    from moolib_tpu.telemetry import global_telemetry
+
+    B, H, Hkv, T, D = 1, 8, 2, 64, 16
+    q = jnp.asarray(rng.standard_normal((B, H, T, D)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((B, Hkv, T, D)), jnp.float32)
+            for _ in range(2))
+    seg = _segs(rng, B=B, T=T)
+    one_head = ops._dq_resident_bytes(1, T, D, 4)
+    monkeypatch.setattr(ops, "_DQ_VMEM_BUDGET", one_head * 4 // passes)
+    assert ops._dq_passes(H // Hkv, T, D, 4) == passes
+
+    def loss(fn, **kw):
+        def f(q, k, v):
+            return jnp.sum(fn(q, k, v, causal=True, window=24,
+                              segment_ids=seg, **kw) ** 2)
+
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    def traced():
+        return global_telemetry().registry.value(
+            "attention_backward_traced_total", form="fused") or 0
+
+    before = traced()
+    got = loss(flash_attention, block_q=16, block_k=16, interpret=True)
+    assert traced() - before == 1  # one kernel whatever the passes
+    for a, b in zip(loss(dense_attention), got):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=2e-4, atol=5e-5
+        )
+    monkeypatch.setattr(ops, "_DQ_VMEM_BUDGET", one_head - 1)
+    with pytest.raises(ValueError, match="one query head's dq"):
+        loss(flash_attention, block_q=16, block_k=16, interpret=True)
+
+
 # -- what a rebuilt caller keeps of the flash kernels -----------------------
 
 
@@ -505,8 +617,8 @@ def _pallas_calls(fn, *args):
 
 
 @pytest.mark.parametrize("backend,kernels", [
-    # forward, the rebuilt forward, dQ, dK/dV; the policy drops the second
-    ("flash", {"plain": 3, "rebuilt": 4, "kept": 3}),
+    # forward, the rebuilt forward, the backward; the policy drops the second
+    ("flash", {"plain": 2, "rebuilt": 3, "kept": 2}),
     # no kernel and no named residual: the policy keeps nothing
     ("dense", {"plain": 0, "rebuilt": 0, "kept": 0}),
 ])

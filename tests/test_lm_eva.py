@@ -307,11 +307,16 @@ def test_ranks_need_two_id_arrays_and_no_causal_mask():
             dense_attention(q, q, q, rank_bits=2, **kw)
 
 
-def test_the_flash_call_without_lse_traces_the_jaxpr_it_traced_before():
-    """Grouped heads, a window, a score scale and a narrower value head,
-    value and gradient: the program's text is the parent commit's
-    (8e3f08f, read there), so no cell that asks for no ``lse`` and no
-    ranks runs another program."""
+@pytest.mark.parametrize("what,digest", [
+    ("forward", "e4242b21c52596c0"), ("gradient", "f0a776201a8c78e9")])
+def test_the_flash_call_without_lse_traces_the_jaxpr_it_traced_before(
+        what, digest):
+    """Grouped heads, a window, a score scale and a narrower value head: a
+    call that asks for no ``lse`` and no ranks traces the forward program
+    of the commit before the statistics became an output (8e3f08f, read
+    there), and with its gradient the program of the commit that made the
+    backward one kernel (PR 47, read there); whoever changes either kernel
+    reads the new text here."""
     q = jnp.zeros((1, 4, 256, 16))
     k = jnp.zeros((1, 2, 256, 16))
     v = jnp.zeros((1, 2, 256, 8))
@@ -322,10 +327,9 @@ def test_the_flash_call_without_lse_traces_the_jaxpr_it_traced_before():
             q, k, v, causal=True, segment_ids=seg, window=96, block_q=128,
             block_k=128, interpret=True, scale=0.3).sum()
 
-    text = re.sub(r" at 0x[0-9a-f]+", "", str(
-        jax.make_jaxpr(jax.value_and_grad(f, argnums=(0, 1, 2)))(q, k, v)))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
-        "f045fe985e684723")
+    fn = f if what == "forward" else jax.value_and_grad(f, argnums=(0, 1, 2))
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jax.make_jaxpr(fn)(q, k, v)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
 
 
 @pytest.mark.parametrize("remat,keeps", [

@@ -302,10 +302,10 @@ def qkv(seed=0, Bq=2, H=4, Hkv=4, Tq=64, D=24, Dv=16):
 @pytest.mark.parametrize("Hkv,window", [(4, None), (2, None), (4, 20)])
 @pytest.mark.parametrize("what", ["forward", "dq", "dk", "dv"])
 def test_flash_kernels_with_a_narrower_value_head(what, Hkv, window):
-    """Forward, dQ and dK/dV kernels in Pallas' interpreter at a query/key
-    head of 24 and a value head of 16, with segments and a score scale of
-    their own, against plain attention; grouped heads and a window
-    besides."""
+    """The forward and the backward kernel in Pallas' interpreter at a
+    query/key head of 24 and a value head of 16, with segments and a score
+    scale of their own, against plain attention; grouped heads and a
+    window besides."""
     q, k, v, seg = qkv(Hkv=Hkv)
     kw = dict(causal=True, segment_ids=seg, window=window, scale=0.3)
     weights = jax.random.normal(jax.random.PRNGKey(9), (2, 4, 64, 16))
