@@ -8,7 +8,8 @@ gathered in expert order, one grouped product a projection runs the gated
 (SwiGLU) experts (on a TPU the Pallas grouped matmul that ships with jax,
 elsewhere ``jax.lax.ragged_dot``: :func:`resolve_grouped`), and a
 scatter-add combines by the renormalised top-k gates (or, for a router
-that says so, by the chosen scores as they are). The scores are the
+that says so, by the chosen scores as they are); both move the rows in use
+and no others (:func:`take_rows`, :func:`add_rows`). The scores are the
 caller's (:func:`linear_scores` for a router of one matrix). With ``held`` a
 strict share it is one chip's part of an expert-parallel layer (what the
 absent experts would add is left out, and no code stands in for their
@@ -22,6 +23,7 @@ yet (ROADMAP R2, R19).
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -95,6 +97,128 @@ def _grouped_product(a, w, group_sizes, how: str):
         a, w, group_sizes, a.dtype, _gmm_tiling,
         interpret=how == "gmm_interpret",
     )
+
+
+# The rows one trip of the row walks moves, and the most bytes such a tile
+# may hold: a v5e ran XLA's scatter-add of a tile of 7 MB or more some
+# three times slower a row than one of 4.7 MB or less, and tiles of 256 to
+# 1,024 rows under that size alike (PERF.md, Findings PR 48).
+_WALK_TILE = 512
+_WALK_TILE_BYTES = 4 << 20
+
+
+def _walk_tile(rows: int, tokens: int, width: int, dtype) -> int:
+    """The tile a layer's :func:`take_rows` and :func:`add_rows` walk its
+    buffer of ``rows`` rows of ``width`` over ``tokens`` tokens in, from
+    the shapes alone: ``_WALK_TILE`` where it divides the buffer, a tile is
+    no more than ``_WALK_TILE_BYTES`` and the buffer has at least as many
+    rows as there are tokens; else ``rows``: one walk of the whole buffer,
+    no loop. A loop costs the step a pass or two over ``[tokens, width]``
+    at its edges, whatever it skips, and saves the spare rows' movement: on
+    a v5e buffers of 1.25 and 2.5 rows a token gained and buffers of half a
+    row a token lost 1.5% of their step (PERF.md, Findings PR 48). Rows
+    wider than the chip was read at keep XLA's whole-buffer passes."""
+    tile_bytes = _WALK_TILE * width * jnp.dtype(dtype).itemsize
+    if (rows % _WALK_TILE == 0 and rows > _WALK_TILE and rows >= tokens
+            and tile_bytes <= _WALK_TILE_BYTES):
+        return _WALK_TILE
+    return rows
+
+
+def _rows_walked(tile: int, n: jax.Array) -> jax.Array:
+    """Rows a walk in tiles of ``tile`` moves when ``n`` are in use: the
+    tiles that hold one, whole."""
+    return (n + tile - 1) // tile * tile
+
+
+def _walk(tile: int, rows: int, n: jax.Array, trip, carry):
+    """``carry`` through ``trip(carry, start, keep)`` for every ``tile``
+    rows of a ``rows``-row buffer that hold a row below ``n``, in order;
+    ``start`` is the tile's first row and ``keep`` [tile, 1] says which of
+    its rows are below ``n``. With ``tile`` the whole buffer it is one
+    call; else the trip count is read on the device."""
+    if tile == rows:
+        return trip(carry, 0, (jnp.arange(rows) < n)[:, None])
+
+    def body(i, carry):
+        start = i * tile
+        return trip(carry, start, (start + jnp.arange(tile) < n)[:, None])
+
+    return jax.lax.fori_loop(0, _rows_walked(tile, n) // tile, body, carry)
+
+
+def _take(scope: str, x, token, n, tile: int):
+    rows, width = token.shape[0], x.shape[1]
+
+    def trip(buffer, start, keep):
+        tokens = jax.lax.dynamic_slice(token, (start,), (tile,))
+        return jax.lax.dynamic_update_slice(
+            buffer, jnp.where(keep, x[tokens], 0), (start, 0)
+        )
+
+    with jax.named_scope(scope):
+        return _walk(tile, rows, n, trip, jnp.zeros((rows, width), x.dtype))
+
+
+def _add(scope: str, v, token, n, T: int, tile: int):
+    rows, width = v.shape
+
+    def trip(total, start, keep):
+        tokens = jax.lax.dynamic_slice(token, (start,), (tile,))
+        part = jax.lax.dynamic_slice(v, (start, 0), (tile, width))
+        return total.at[tokens].add(jnp.where(keep, part, 0))
+
+    with jax.named_scope(scope):
+        return _walk(tile, rows, n, trip, jnp.zeros((T, width), v.dtype))
+
+
+# The two row movers of the layer, each the other's transpose in its array
+# argument, so each one's backward rule is the other (under the scope of the
+# forward it belongs to: a custom rule's backward inherits none). ``token``
+# and ``n`` are integers: no gradient, and the only residuals. ``tile``
+# (static) divides the buffer's rows: :func:`_walk_tile`'s answer.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _take_rows(x, token, n, T, tile):
+    return _take("moolib.moe.gather", x, token, n, tile)
+
+
+def _take_rows_fwd(x, token, n, T, tile):
+    return _take("moolib.moe.gather", x, token, n, tile), (token, n)
+
+
+def _take_rows_bwd(T, tile, residuals, g):
+    return _add("moolib.moe.gather", g, *residuals, T, tile), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def add_rows(v, token, n, T, tile):
+    """``v`` [rows, d] summed into [T, d]: row ``r`` into row ``token[r]``,
+    over ``r < n`` only. It walks the tiles of ``tile`` rows that hold such
+    a row and no others; its transpose is :func:`take_rows`."""
+    return _add("moolib.moe.combine", v, token, n, T, tile)
+
+
+def _add_rows_fwd(v, token, n, T, tile):
+    return _add("moolib.moe.combine", v, token, n, T, tile), (token, n)
+
+
+def _add_rows_bwd(T, tile, residuals, g):
+    return _take("moolib.moe.combine", g, *residuals, tile), None, None
+
+
+add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
+
+
+def take_rows(x, token, n, tile):
+    """``x`` [T, d] gathered into [rows, d]: row ``r < n`` is
+    ``x[token[r]]``, every row from ``n`` on is zero. It walks the tiles of
+    ``tile`` rows that hold a row below ``n`` and no others; its transpose
+    is :func:`add_rows`."""
+    return _take_rows(x, token, n, x.shape[0], tile)
 
 
 _SCORING = {
@@ -172,8 +296,14 @@ def moe_dropless(
     sent on average, so ``buffer_rows`` may state the size that usually
     does. The layer is then built twice, over ``buffer_rows`` rows and over
     the worst case, and a ``lax.cond`` runs the first where the assignments
-    held fit it (gather and combine cost by the row). Nothing is dropped
-    whichever runs; ``moe_spills`` is 1 where it was the second. The
+    held fit it. Nothing is dropped whichever runs; ``moe_spills`` is 1
+    where it was the second. Gather and combine cost by the rows in use,
+    whichever buffer runs, where the stated buffer has a row a token or
+    more (:func:`_walk_tile`): :func:`take_rows` and :func:`add_rows` walk
+    it in tiles of ``R`` rows and stop after the tile that holds the last
+    assignment seated, so the spare rows are written as zeros once and
+    never moved; a smaller buffer is moved whole, as it saves less than
+    the loops cost. The products still cost by the buffer. The
     stated buffer is a budget of work as well as of rows: the grouped
     products multiply all of it, spare rows (zeros) included, so that a
     step costs the same whichever experts the tokens chose, as long as they
@@ -193,9 +323,13 @@ def moe_dropless(
     scalars): ``moe_assignments_held`` / ``moe_assignments_total``,
     ``moe_tokens_unserved``, ``moe_load_max`` / ``moe_load_mean``
     (assignments of the fullest held expert and their mean),
-    ``moe_spills``, and ``moe_overflow``: the assignments held that the
+    ``moe_spills``, ``moe_overflow``: the assignments held that the
     buffer that ran had no row for, counted by the branch that ran; 0, or
-    the layer is wrong. With ``skip_choices``, ``moe_tokens_skipped``;
+    the layer is wrong, and ``moe_rows_moved``: the rows one gather of
+    this layer walked, ``ceil(seated / R) * R`` (beside
+    ``moe_assignments_held``: near it where the walk stops early, the
+    buffer's rows where the buffer is one walk). With ``skip_choices``,
+    ``moe_tokens_skipped``;
     with ``renormalize=False``, ``moe_gate_mean``, the mean chosen score
     (``1 / E'`` at a flat softmax router, 1 at a collapsed one). And one
     array, ``moe_router_load`` [E'] int32: the assignments the router sent
@@ -248,9 +382,15 @@ def moe_dropless(
         load = router_load[first:first + count]
         held_total = jnp.sum(load)
 
+    # One answer a layer, its stated buffer's: the worst case's buffer is
+    # walked as that one is, where the tile divides it.
+    walk = _walk_tile(bound, T, x.shape[1], x.dtype)
+
     def over(rows: int):
-        """The layer over a buffer of ``rows`` rows: ``y``, and how many
-        of the assignments held it seated."""
+        """The layer over a buffer of ``rows`` rows: ``y``, how many of
+        the assignments held it seated, and the rows its gather walked."""
+
+        tile = walk if walk < bound and rows % walk == 0 else rows
 
         how = resolve_grouped(
             rows, x.shape[-1], params["w_up"].shape[-1], x.dtype
@@ -270,8 +410,7 @@ def moe_dropless(
                     group_sizes = group_sizes.at[-1].add(rows - ends[-1])
                 token = order[:rows] // top_k
                 gate = gates[order[:rows]].astype(x.dtype)[:, None]
-            with jax.named_scope("moolib.moe.gather"):
-                xg = jnp.where(used, x[token], 0)  # [rows, d_model]
+            xg = take_rows(x, token, ends[-1], tile)  # [rows, d_model]
             with jax.named_scope("moolib.moe.experts"):
                 def grouped(a, w):
                     # The product writes only the rows of its groups,
@@ -291,17 +430,18 @@ def moe_dropless(
                 h = jax.nn.silu(grouped(xg, experts["w_gate"])) * h
                 ye = grouped(h, experts["w_down"])
             with jax.named_scope("moolib.moe.combine"):
-                y = jnp.zeros_like(x).at[token].add(ye * gate)
-            return y, ends[-1]
+                ye = ye * gate
+            y = add_rows(ye, token, ends[-1], T, tile)
+            return y, ends[-1], _rows_walked(tile, ends[-1])
 
         return run if bound == worst else jax.checkpoint(run)
 
     if bound == worst:
         spills = jnp.zeros((), bool)
-        y, seated = over(worst)(x, params, gates)
+        y, seated, walked = over(worst)(x, params, gates)
     else:
         spills = held_total > bound
-        y, seated = jax.lax.cond(
+        y, seated, walked = jax.lax.cond(
             spills, over(worst), over(bound), x, params, gates
         )
 
@@ -316,6 +456,7 @@ def moe_dropless(
         "moe_load_mean": jnp.mean(load.astype(f32)),
         "moe_spills": spills.astype(f32),
         "moe_overflow": (held_total - seated).astype(f32),
+        "moe_rows_moved": walked.astype(f32),
         "moe_router_load": router_load,
     }
     if skip_choices:

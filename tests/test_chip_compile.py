@@ -171,6 +171,14 @@ def test_grouped_products_compile_at_the_cells_shape(one_chip,
     else:
         assert "ragged-dot" in text
     assert "conditional" in text  # the worst case waits behind a cond
+    # rows move by the tile, in loops the device ends: a branch's rebuilt
+    # gather and the two transposes (the forward's own are dead code in a
+    # gradient alone), and no gather of a whole buffer
+    walks = [line for line in text.splitlines() if " while(" in line
+             and re.search(r"moolib\.moe\.(gather|combine)/while", line)]
+    assert len(walks) == 2 * 3
+    gathered = set(re.findall(rf"= \w+\[(\d+),{d}\]\S* gather\(", text))
+    assert gathered == {str(moe._WALK_TILE)}  # a tile, never a buffer
 
 
 def test_glyph_embedding_backward_at_the_cells_size(one_chip, no_compile_cache):

@@ -26,6 +26,7 @@ from moolib_tpu.models.lm import (  # noqa: E402
     router_loads,
 )
 from moolib_tpu.models.transformer import segment_ids_from_done  # noqa: E402
+from moolib_tpu.parallel import moe  # noqa: E402
 from moolib_tpu.parallel.moe import linear_scores, moe_dropless  # noqa: E402
 
 VOCAB, T, B = 48, 31, 2
@@ -343,6 +344,43 @@ def test_a_buffer_too_small_spills_and_drops_nothing():
     assert float(counters["moe_spills"]) == 2.0  # both layers spilled
     assert float(counters["moe_overflow"]) == 0.0
     np.testing.assert_allclose(logits, whole, rtol=1e-5, atol=1e-5)
+
+
+def _row_moves(jaxpr, in_while=False):
+    """``(rows, in a while)`` of every gather and scatter of two-dimensional
+    rows in ``jaxpr`` and the programs its equations hold."""
+    found = []
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" and eqn.outvars[0].aval.ndim == 2:
+            found.append((eqn.outvars[0].aval.shape[0], in_while))
+        if name.startswith("scatter") and eqn.invars[2].aval.ndim == 2:
+            found.append((eqn.invars[2].aval.shape[0], in_while))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _row_moves(sub, in_while or name == "while")
+    return found
+
+
+def test_no_pass_over_a_whole_expert_buffer_stands_outside_a_loop(
+        monkeypatch):
+    """Counts, not timings: in the gradient step of the two-layer stack
+    with a stated buffer of six tiles, every gather and scatter-add of
+    rows moves one tile inside a ``while`` whose trip count is the rows in
+    use; none moves the buffer (or the worst case's) at once."""
+    tile, buffer_rows, worst = 16, 96, (T + 1) * B * 2
+    monkeypatch.setattr(moe, "_WALK_TILE", tile)
+    net = tiny_net(held=(2, 4), moe_buffer_rows=buffer_rows)
+    params, batch = tiny_inputs(net, 5)
+    step = jax.make_jaxpr(jax.grad(
+        lambda p: impala_loss(p, learn_apply(net), batch,
+                              ImpalaConfig(**LOSS))[0]
+    ))(params)
+    moves = _row_moves(step.jaxpr)
+    assert not [m for m in moves if m[0] in (buffer_rows, worst)]
+    # a layer and branch: gather and combine forward, the gather rebuilt,
+    # and a transpose each; two layers, two branches
+    assert moves.count((tile, True)) == 2 * 2 * 5
+    assert (tile, False) not in moves
 
 
 def test_the_experiment_reaches_the_model_by_its_model_switch():

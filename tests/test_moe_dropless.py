@@ -2,7 +2,10 @@
 over experts under ``jit``, forward and gradients; at an imbalance that
 sends every token to one expert; with a buffer smaller than the
 routing asks; and with the Pallas grouped matmul (in the interpreter) in
-the place of ``ragged_dot``."""
+the place of ``ragged_dot``. And its two row movers, ``take_rows`` and
+``add_rows``, against the whole-buffer gather and scatter-add they stand
+for, at every count of rows in use around a tile's edge, with their
+gradients in the four places ``models/lm.py`` puts the layer."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +15,7 @@ import pytest
 from moolib_tpu.learner import ImpalaConfig, impala_loss
 from moolib_tpu.parallel import moe
 from moolib_tpu.parallel.moe import (
-    linear_scores, moe_dropless, resolve_grouped,
+    add_rows, linear_scores, moe_dropless, resolve_grouped, take_rows,
 )
 
 T, D, F, E = 96, 16, 12, 8
@@ -178,6 +181,147 @@ def test_the_pallas_grouped_matmul_equals_ragged_dot(held, buffer_rows,
     for a, b in zip(jax.tree_util.tree_leaves(g_g),
                     jax.tree_util.tree_leaves(g_r)):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+
+
+# A buffer of five tiles over 300 tokens, so tokens repeat; the width is
+# small, the tile the one the chip walks.
+TILE = moe._WALK_TILE
+WALK_ROWS = 5 * TILE
+WALK_T, WALK_D = 300, 8
+
+
+def walk_inputs(seed=21):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    token = jax.random.randint(ks[0], (WALK_ROWS,), 0, WALK_T)
+    x = jax.random.normal(ks[1], (WALK_T, WALK_D))
+    v = jax.random.normal(ks[2], (WALK_ROWS, WALK_D))
+    w = jax.random.normal(ks[3], (WALK_ROWS, WALK_D))
+    return token, x, v, w
+
+
+@pytest.mark.parametrize("rows,tokens,width,dtype,loops", [
+    (20480, 8192, 2304, jnp.bfloat16, True),   # mellum2_share8: 2.5 a token
+    (10240, 8192, 2048, jnp.bfloat16, True),   # glm47_flash_share8: 1.25
+    (5120, 4096, 3584, jnp.bfloat16, True),    # xing4_share8: 1.25
+    (4608, 8192, 2048, jnp.bfloat16, False),   # zaya1_share8: 0.56
+    (2048, 4096, 4096, jnp.bfloat16, False),   # solar_open2_share8: 0.5
+    (20480, 8192, 8192, jnp.bfloat16, False),  # a tile of 8 MB: not read
+    (20480, 8192, 2304, jnp.float32, False),   # the same in bytes
+    (20000, 8192, 2304, jnp.bfloat16, False),  # no multiple of the tile
+])
+def test_the_walk_is_a_loop_where_the_chip_read_a_gain(rows, tokens, width,
+                                                       dtype, loops):
+    tile = moe._walk_tile(rows, tokens, width, dtype)
+    assert tile == (TILE if loops else rows)
+
+
+def plain_take(x, token, n):
+    return jnp.where((jnp.arange(token.shape[0]) < n)[:, None], x[token], 0)
+
+
+def plain_add(v, token, n, T):
+    used = (jnp.arange(token.shape[0]) < n)[:, None]
+    return jnp.zeros((T, v.shape[1]), v.dtype).at[token].add(
+        jnp.where(used, v, 0)
+    )
+
+
+@pytest.mark.parametrize("n", [
+    0, 1, moe._WALK_TILE - 1, moe._WALK_TILE, moe._WALK_TILE + 1, WALK_ROWS,
+])
+def test_the_row_movers_equal_the_whole_buffer_gather_and_scatter_add(n):
+    assert moe._walk_tile(WALK_ROWS, WALK_T, WALK_D, jnp.float32) == TILE
+    token, x, v, _ = walk_inputs()
+    n = jnp.asarray(n, jnp.int32)
+    np.testing.assert_array_equal(
+        jax.jit(take_rows, static_argnums=3)(x, token, n, TILE),
+        plain_take(x, token, n)
+    )
+    np.testing.assert_allclose(
+        jax.jit(add_rows, static_argnums=(3, 4))(v, token, n, WALK_T, TILE),
+        plain_add(v, token, n, WALK_T), rtol=1e-5, atol=1e-5,
+    )
+    # bfloat16 rows, as the cells move them: the gather is still exact
+    np.testing.assert_array_equal(
+        take_rows(x.astype(jnp.bfloat16), token, n, TILE).astype(jnp.float32),
+        plain_take(x.astype(jnp.bfloat16), token, n).astype(jnp.float32),
+    )
+
+
+def _in_cond(f):
+    return lambda a, token, n: jax.lax.cond(
+        n > 7, f, lambda a, token, n: 2 * f(a, token, n), a, token, n
+    )
+
+
+def _in_scan(f):
+    def scanned(a, token, n):
+        def body(scale, _):
+            return scale * 0.5, scale * f(a, token, n)
+        return jnp.sum(jax.lax.scan(body, 1.0, None, length=2)[1], axis=0)
+    return scanned
+
+
+@pytest.mark.parametrize("place", [
+    jax.jit, jax.checkpoint, _in_cond, _in_scan,
+])
+@pytest.mark.parametrize("mover", ["take_rows", "add_rows"])
+def test_the_row_movers_gradients_where_the_model_puts_the_layer(mover,
+                                                                 place):
+    """Each mover's backward rule is the other one, with a trip count read
+    on the device: its gradient equals the plain composition's under
+    ``jit``, rebuilt by ``checkpoint``, behind a ``cond`` and in a ``scan``'s
+    body."""
+    token, x, v, w = walk_inputs()
+    n = jnp.asarray(2 * moe._WALK_TILE + 5, jnp.int32)
+    if mover == "take_rows":
+        ours = lambda x, token, n: take_rows(x, token, n, TILE)  # noqa
+        plain, a, weight = plain_take, x, w
+    else:
+        ours = lambda v, token, n: add_rows(v, token, n, WALK_T, TILE)  # noqa
+        plain = lambda v, token, n: plain_add(v, token, n, WALK_T)  # noqa
+        a, weight = v, x
+
+    def grad_of(f):
+        return jax.jit(jax.grad(
+            lambda a: jnp.sum(weight * place(f)(a, token, n))
+        ))(a)
+
+    np.testing.assert_allclose(
+        grad_of(ours), grad_of(plain), rtol=1e-5, atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("buffer_rows", [None, 8 * moe._WALK_TILE])
+def test_rows_moved_counts_the_tiles_that_hold_an_assignment(buffer_rows,
+                                                             monkeypatch):
+    """A drawn routing over 4,096 tokens, top-2, two experts of eight held
+    here: the gather walks the tiles that hold an assignment, whole, and
+    never more than the buffer; the layer's answer is one whole walk's."""
+    tile, tokens = moe._WALK_TILE, 4096
+    ks = jax.random.split(jax.random.PRNGKey(17), 5)
+    params = {
+        "w_gate": jax.random.normal(ks[1], (2, D, F)) / 4,
+        "w_up": jax.random.normal(ks[2], (2, D, F)) / 4,
+        "w_down": jax.random.normal(ks[3], (2, F, D)) / 3,
+    }
+    x = jax.random.normal(ks[4], (tokens, D))
+    scores = linear_scores(x, jax.random.normal(ks[0], (D, E)) / 4)
+
+    def layer(p, x, s):
+        return moe_dropless(
+            p, x, s, top_k=2, held=(3, 2), buffer_rows=buffer_rows
+        )
+
+    y, aux = jax.jit(layer)(params, x, scores)
+    held = int(aux["moe_assignments_held"])
+    rows = buffer_rows or 2 * tokens
+    assert 0 < held < rows - tile and float(aux["moe_spills"]) == 0
+    assert float(aux["moe_rows_moved"]) == -(-held // tile) * tile <= rows
+    monkeypatch.setattr(moe, "_walk_tile", lambda rows, *_: rows)
+    whole, aux = jax.jit(lambda *a: layer(*a))(params, x, scores)  # anew
+    assert float(aux["moe_rows_moved"]) == rows
+    np.testing.assert_allclose(y, whole, rtol=1e-5, atol=1e-6)
 
 
 def test_auto_is_ragged_dot_off_the_chip_and_the_router_load_is_whole():
