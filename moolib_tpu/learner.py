@@ -83,6 +83,44 @@ def _entropy(logits):
     return -jnp.mean(jnp.sum(p * logp, axis=-1))
 
 
+def _check_axes(batch, logits_shape, baseline_shape, grouped: bool):
+    """The learn-batch contract's lengths, held where the loss is traced:
+    leaves that disagree would otherwise broadcast, or slice to nothing."""
+    steps = batch["done"].shape[0] - 1
+    actions = batch["actions"].shape[0]
+    lengths = (
+        f"done {batch['done'].shape}, rewards {batch['rewards'].shape}, "
+        f"actions {batch['actions'].shape}, behavior_logits "
+        f"{batch['behavior_logits'].shape}; the model gave logits "
+        f"{logits_shape} and a baseline {baseline_shape}"
+    )
+    wrong = (
+        batch["rewards"].shape != batch["done"].shape
+        or batch["behavior_logits"].shape[:2] != batch["actions"].shape
+    )
+    if grouped:
+        lengths += f"; action_step {batch['action_step'].shape}"
+        wrong = (
+            wrong
+            or batch["action_step"].shape != batch["actions"].shape
+            or logits_shape[0] != actions
+            or baseline_shape[0] <= actions
+        )
+    else:
+        wrong = (
+            wrong or actions != steps
+            or logits_shape[0] != steps + 1 or baseline_shape[0] != steps + 1
+        )
+    if wrong:
+        raise ValueError(
+            "the learn batch's leaves disagree on their axes (a batch "
+            + ("with action_step: N token-actions, T steps, logits [N], a "
+               "baseline [N + K], done and rewards [T + 1]" if grouped
+               else "of T steps: T + 1 frames, T actions")
+            + "): " + lengths
+        )
+
+
 def impala_loss(
     params,
     apply_fn: Callable,
@@ -106,6 +144,30 @@ def impala_loss(
     The model is unrolled over all T+1 frames; frame T provides the
     bootstrap value.
 
+    **An action that is a set of tokens** (optional; a denoising step of a
+    block-diffusion language model). With the leaf ``action_step`` the
+    batch has two time axes, ``N`` token-actions and ``T`` steps:
+
+    - ``action_step``: [N, B] int32  the step, in ``[0, T)``, that
+      token-action ``i`` belongs to; a step is the set of its tokens
+    - ``actions``: [N, B] and ``behavior_logits``: [N, B, A] lie on the
+      token axis, ``done`` and ``rewards`` [T+1, B] on the step axis
+    - ``obs`` is whatever the model reads (for
+      :class:`moolib_tpu.models.lm.DecoderLM` with ``diffusion`` a dict,
+      ``{"tokens", "reveal_step"}``, on a token axis of its own)
+
+    and the model returns logits ``[N, B, A]``, one row a token-action,
+    and a baseline ``[N + K, B]``: a value a token-action and ``K >= 1``
+    rows of the bootstrap frame, whose mean is the bootstrap value. A
+    step's log-ratio and log-probability are its tokens' sums, its entropy
+    their entropies' sum, its value their values' mean, and V-trace runs
+    over the ``T`` steps (:func:`moolib_tpu.ops.vtrace.from_grouped_logits`).
+    A batch without the leaf never reaches that code.
+
+    Leaves whose axes disagree (``actions`` against the logits,
+    ``rewards`` against ``done``, the steps against the model's frames)
+    raise a ``ValueError`` that names the lengths, where they are traced.
+
     ``apply_fn`` may return an optional THIRD element, a dict of model aux.
     The one loss term it can carry is ``mtp_loss`` (a multi-token-prediction
     module's or the further prediction heads' cross-entropy), folded into
@@ -122,8 +184,15 @@ def impala_loss(
         (logits, baseline), _, model_aux = out
     else:
         (logits, baseline), _ = out
-    logits, bootstrap_value = logits[:-1], baseline[-1]
-    baseline = baseline[:-1]
+    grouped = "action_step" in batch
+    _check_axes(batch, logits.shape, baseline.shape, grouped)
+    if grouped:
+        tokens = batch["actions"].shape[0]
+        bootstrap_value = jnp.mean(baseline[tokens:], axis=0)
+        token_values = baseline[:tokens]
+    else:
+        logits, bootstrap_value = logits[:-1], baseline[-1]
+        baseline = baseline[:-1]
 
     with jax.named_scope("moolib.loss"):
         rewards = batch["rewards"][1:]
@@ -135,22 +204,39 @@ def impala_loss(
             ~batch["done"][1:]
         ).astype(jnp.float32) * config.discounting
 
-        vt = vtrace.from_logits(
-            behavior_policy_logits=batch["behavior_logits"],
-            target_policy_logits=logits,
-            actions=batch["actions"],
-            discounts=discounts,
-            rewards=rewards,
-            values=baseline,
-            bootstrap_value=bootstrap_value,
+        clips = dict(
             clip_rho_threshold=config.clip_rho_threshold,
             clip_pg_rho_threshold=config.clip_pg_rho_threshold,
             lambda_=config.lambda_,
         )
+        if grouped:
+            vt = vtrace.from_grouped_logits(
+                behavior_policy_logits=batch["behavior_logits"],
+                target_policy_logits=logits,
+                actions=batch["actions"],
+                action_step=batch["action_step"],
+                token_values=token_values,
+                discounts=discounts,
+                rewards=rewards,
+                bootstrap_value=bootstrap_value,
+                **clips,
+            )
+            baseline = vt.values  # a step's: its tokens' mean
+        else:
+            vt = vtrace.from_logits(
+                behavior_policy_logits=batch["behavior_logits"],
+                target_policy_logits=logits,
+                actions=batch["actions"],
+                discounts=discounts,
+                rewards=rewards,
+                values=baseline,
+                bootstrap_value=bootstrap_value,
+                **clips,
+            )
 
         pg_loss = -jnp.mean(vt.target_action_log_probs * vt.pg_advantages)
         baseline_loss = 0.5 * jnp.mean((vt.vs - baseline) ** 2)
-        entropy = _entropy(logits)
+        entropy = jnp.mean(vt.entropies) if grouped else _entropy(logits)
 
         total = (
             pg_loss

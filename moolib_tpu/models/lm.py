@@ -127,6 +127,56 @@ nothing is carried from call to call. ``cca_taps_cut``
 (:func:`cca_taps_cut`, every block's) counts the taps and shifted values
 that read zero, in the step's metrics.
 
+With ``qk_norm`` a softmax kind with dense projections normalises every
+query and key head before the rotary, ``q <- rms_head(q) * g_q``, ``k <-
+rms_head(k) * g_k``, one gain ``[D]`` each a layer (Qwen3's attention).
+
+**Block diffusion** (``diffusion``, :class:`Diffusion`; the model's, not a
+kind's: BD3-LMs, arXiv:2503.09573, as SDAR trains it, arXiv:2510.06303).
+The policy's action is a *denoising step*, which reveals a set of tokens
+of one block at once. ``obs`` is then a dict, ``{"tokens", "reveal_step"}``
+``[L, B]``: a sequence of ``L = D (N + 1)`` tokens in blocks of ``D``, and
+for each token of blocks ``0..N-1`` the step ``r`` in ``[0, S)`` of its
+block at which the sampler revealed it; ``done`` lies on the **step axis**,
+``[S N + 1, B]``, frame ``u = S b + tau`` the state before step ``tau`` of
+block ``b`` and the last frame block ``N``, all masked, which gives the
+bootstrap value alone; an episode may begin at a block's first step only
+(``done[S b]``). The stack runs over ``1 + S`` copies of the sequence,
+``(1 + S) L`` rows, copy-major:
+
+- row ``(clean, i)`` reads ``E[x_i]``; row ``(tau, i)`` ``E[x_i]`` where
+  ``r_i < tau`` and ``E[mask_id]`` otherwise (block ``N``: the mask in every
+  copy); a row's position is ``i`` in every copy;
+- row ``(c, i)`` sees key row ``(c', j)`` iff both are of one episode and
+  either ``c'`` is the clean copy and ``j``'s block lies before ``i``'s, or
+  ``c' = c`` and the two blocks are one: in both directions, under one
+  softmax. That is what a sampler computes at step ``tau`` of block ``b``:
+  the finished blocks as they were computed clean, and its own
+  half-revealed block;
+- norms, projections, router and experts act on every row alike;
+- token ``i < D N`` is scored in row ``(r_i, i)``: the call returns
+  ``logits`` ``[D N, B, A]``, a row a token, and ``baseline`` ``[L, B]``,
+  a value a token and block ``N``'s ``D`` rows of copy 0 last, whose mean
+  the loss takes for the bootstrap value
+  (:func:`moolib_tpu.learner.impala_loss` with ``action_step``).
+
+No kernel is the mask's own: the copies ride as further grouped query
+heads of the clean copy's key/value heads, one flash call with the ids
+``episode << bits | block`` on both axes as group and rank (a key is seen
+where its block is *strictly* earlier, ``rank_bits``; the kernels skip
+every tile above the block diagonal) under ``moolib.lm.attn_core`` with its
+row statistics, then the ``D x D`` scores of a row's own block against its
+own copy's keys and :func:`~moolib_tpu.ops.attention.merge_attention`
+under ``moolib.lm.blockdiff_local`` (:func:`blockdiff_attention`). The
+copies' inputs and the choice of the scored rows run under
+``moolib.lm.blockdiff_rows``. The step's metrics carry ``blockdiff_rows``
+(rows through the stack), ``blockdiff_masked_inputs``,
+``blockdiff_scored_tokens``, ``blockdiff_steps`` ((block, step) pairs that
+reveal a token) and ``blockdiff_pairs`` (visible (query row, key row)
+pairs a query head, every block's: :func:`blockdiff_counts`). Built for
+one stream, softmax kinds with dense projections and no window, one
+prediction head.
+
 Without ``rope`` (null) a softmax kind has no position encoding at all;
 with ``output_gate`` its heads' output is multiplied by ``sigmoid(x
 W_g)``, elementwise over ``heads x head_dim``, before ``W_o``.
@@ -264,6 +314,7 @@ __all__ = [
     "Cca",
     "DecoderLM",
     "Delta",
+    "Diffusion",
     "Eva",
     "Latent",
     "Residual",
@@ -348,6 +399,17 @@ class Cca:
 
 
 @dataclasses.dataclass(frozen=True)
+class Diffusion:
+    """Block diffusion (module docstring): the tokens of a block, the
+    denoising steps a block is revealed in, and the row of the embedding
+    that stands for a token not yet revealed."""
+
+    block: int
+    steps: int
+    mask_id: int
+
+
+@dataclasses.dataclass(frozen=True)
 class AttentionKind:
     window: Optional[int]  # None: full causal attention
     rope: Optional[Rope]  # None: no position encoding at all
@@ -357,6 +419,9 @@ class AttentionKind:
     output_gate: bool = False
     delta: Optional[Delta] = None  # no softmax: the delta rule's state
     cca: Optional[Cca] = None  # queries and keys mixed by two convolutions
+    # dense projections' query and key heads through an RMS norm of their
+    # own, one gain [head_dim] each, before the rotary
+    qk_norm: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -489,6 +554,10 @@ class _Attention(nn.Module):
     backend: str
     block: int
     dtype: jnp.dtype
+    norm_eps: float = 1e-6  # of the query/key norm
+    norm_unit_offset: bool = False
+    # block diffusion: the rows are the copies, the ids group and rank
+    diffusion: Optional[Diffusion] = None
 
     @nn.compact
     def __call__(self, x, seg_bt, positions):
@@ -496,9 +565,13 @@ class _Attention(nn.Module):
         H, Hkv, D = self.num_heads, self.num_kv_heads, self.head_dim
 
         def proj(name, heads):
-            return _dense(name, heads * D, self.dtype)(x).reshape(
-                T, B, heads, D
-            )
+            y = _dense(name, heads * D, self.dtype)(x).reshape(T, B, heads, D)
+            if self.kind.qk_norm and name != "v":
+                y = RMSNorm(
+                    self.norm_eps, self.dtype, self.norm_unit_offset,
+                    name=name + "_norm",
+                )(y)
+            return y
 
         with jax.named_scope("moolib.lm.attn_proj"):
             turn = lambda t: t  # noqa: E731  (no position encoding)
@@ -506,19 +579,128 @@ class _Attention(nn.Module):
                 cos, sin = _rotary_tables(self.kind.rope, positions, D)
                 turn = lambda t: _rotary(t, cos, sin)  # noqa: E731
             q, k, v = turn(proj("q", H)), turn(proj("k", Hkv)), proj("v", Hkv)
-            # [T, B, heads, D] -> [B, heads, T, D]
-            q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
-        with jax.named_scope("moolib.lm.attn_core"):
-            o = attend(
-                q, k, v, seg_bt, backend=self.backend,
-                window=self.kind.window, block_q=self.block,
-                block_k=self.block,
+        if self.diffusion is not None:
+            o = blockdiff_attention(
+                q, k, v, seg_bt, self.diffusion, backend=self.backend,
+                block=self.block,
             )
+        else:
+            with jax.named_scope("moolib.lm.attn_proj"):
+                # [T, B, heads, D] -> [B, heads, T, D]
+                q, k, v = (t.transpose(1, 2, 0, 3) for t in (q, k, v))
+            with jax.named_scope("moolib.lm.attn_core"):
+                o = attend(
+                    q, k, v, seg_bt, backend=self.backend,
+                    window=self.kind.window, block_q=self.block,
+                    block_k=self.block,
+                )
+            with jax.named_scope("moolib.lm.attn_proj"):
+                o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
         with jax.named_scope("moolib.lm.attn_proj"):
-            o = o.transpose(2, 0, 1, 3).reshape(T, B, H * D)
             if self.kind.output_gate:
                 o = o * jax.nn.sigmoid(_dense("gate", H * D, self.dtype)(x))
             return _dense("o", x.shape[-1], self.dtype)(o)
+
+
+def blockdiff_bits(L: int, spec: Diffusion) -> int:
+    """The low bits of a block-diffusion id that hold the block's index."""
+    return max(1, (L // spec.block).bit_length())
+
+
+def blockdiff_ids(done, L: int, spec: Diffusion):
+    """``[B, L]`` int32, ``episode << bits | block`` of every token, from
+    ``done`` on the step axis ``[S N + 1, B]``: an episode begins at a
+    block's first step (``done[S b]``; frame ``S N`` is block ``N``'s), and
+    a token is of its block's episode. Neither part ever decreases along
+    the sequence, as the flash kernels' skipping by ``rank_bits`` needs."""
+    episode = segment_ids_from_done(done[::spec.steps])  # [B, N + 1]
+    block = jnp.arange(L) // spec.block
+    return (episode[:, block] << blockdiff_bits(L, spec)) | block
+
+
+def blockdiff_counts(ids, reveal_lb, spec: Diffusion) -> dict:
+    """What one call under block diffusion runs, counted from the ids
+    ``[B, L]`` (:func:`blockdiff_ids`) and the reveal steps ``[L, B]``,
+    int32: ``blockdiff_rows`` rows through the stack;
+    ``blockdiff_masked_inputs`` of them that read the mask's embedding;
+    ``blockdiff_scored_tokens``; ``blockdiff_steps`` (block, step) pairs
+    that reveal a token; ``blockdiff_pairs`` the (query row, key row)
+    pairs one query head of one block sees: for a row of block ``b`` the
+    clean rows of its episode's blocks before ``b`` and its own copy's
+    ``D`` rows of ``b``, by cumulative counts and no score matrix."""
+    B, L = ids.shape
+    D, S = spec.block, spec.steps
+    acted = L - D
+    bits = blockdiff_bits(L, spec)
+    group = (ids >> bits) << bits
+    first = jax.vmap(lambda a, x: jnp.searchsorted(a, x, side="left"))
+    # clean rows of the episode's earlier blocks, and the block's own D
+    seen = first(ids, ids) - first(ids, group) + D
+    reveal = reveal_lb[:acted].T.reshape(B, -1, D)
+    steps = sum(
+        jnp.sum(jnp.any(reveal == tau, axis=-1)) for tau in range(S)
+    )
+    # copy tau masks what its step and the later ones reveal, and block N
+    masked = sum(jnp.sum(reveal >= tau) for tau in range(S)) + S * D * B
+    return {
+        "blockdiff_rows": jnp.asarray((1 + S) * L * B, jnp.int32),
+        "blockdiff_masked_inputs": masked.astype(jnp.int32),
+        "blockdiff_scored_tokens": jnp.asarray(acted * B, jnp.int32),
+        "blockdiff_steps": steps.astype(jnp.int32),
+        "blockdiff_pairs": ((1 + S) * jnp.sum(seen)).astype(jnp.int32),
+    }
+
+
+def blockdiff_attention(q, k, v, ids, spec: Diffusion, *, backend: str,
+                        block: int):
+    """The attention core under block diffusion (module docstring). ``q``
+    ``[C L, B, H, D]``, ``k`` and ``v`` ``[C L, B, Hkv, D]``, the ``C = 1 +
+    S`` copies one after the other, the clean one first; ``ids`` ``[B, L]``
+    (:func:`blockdiff_ids`). Returns ``[C L, B, H D]``.
+
+    The copies ride as further grouped query heads, ``[B, Hkv (C G), L,
+    D]``, on the clean copy's ``[B, Hkv, L, D]``: one call of the
+    attention call site reads every row's earlier blocks (group = episode,
+    rank = block, strictly lower), and the rows' own blocks are ``L / D``
+    products of ``D x D`` against the row's own copy, merged with the
+    call's result by the two row statistics."""
+    C, Db = spec.steps + 1, spec.block
+    rows, B, H, D = q.shape
+    Hkv, L = k.shape[2], rows // C
+    G, n = H // Hkv, L // Db
+    with jax.named_scope("moolib.lm.attn_proj"):
+        # [C L, B, heads, D] -> [B, Hkv, C, (G,) L, D]
+        q = q.reshape(C, L, B, Hkv, G, D).transpose(2, 3, 0, 4, 1, 5)
+        k, v = (
+            t.reshape(C, L, B, Hkv, D).transpose(2, 3, 0, 1, 4)
+            for t in (k, v)
+        )
+    with jax.named_scope("moolib.lm.attn_core"):
+        earlier = attend(
+            q.reshape(B, H * C, L, D), k[:, :, 0], v[:, :, 0], ids,
+            kv_seg_bt=ids, causal=False,
+            rank_bits=blockdiff_bits(L, spec), return_lse=True,
+            backend=backend, block_q=block, block_k=block,
+        )
+    with jax.named_scope("moolib.lm.blockdiff_local"):
+        scores = jnp.einsum(
+            "bhcgnqd,bhcnkd->bhcgnqk", q.reshape(B, Hkv, C, G, n, Db, D),
+            k.reshape(B, Hkv, C, n, Db, D),
+            preferred_element_type=jnp.float32,
+        ) * D ** -0.5
+        lse = jax.nn.logsumexp(scores, axis=-1)
+        own = jnp.einsum(
+            "bhcgnqk,bhcnkd->bhcgnqd", jnp.exp(scores - lse[..., None]),
+            v.reshape(B, Hkv, C, n, Db, D).astype(jnp.float32),
+        )
+        o = attn_ops.merge_attention(
+            *earlier, own.reshape(B, H * C, L, D).astype(v.dtype),
+            lse.reshape(B, H * C, L),
+        )
+    with jax.named_scope("moolib.lm.attn_proj"):
+        return o.reshape(B, Hkv, C, G, L, D).transpose(
+            2, 4, 0, 1, 3, 5
+        ).reshape(rows, B, H * D)
 
 
 def eva_ids(seg_bt, T: int, window: int, chunk: int):
@@ -1083,6 +1265,7 @@ class _Sizes:
     intermediate_size: Optional[int]
     residual: Union[None, Residual, str] = None
     norm_unit_offset: bool = False
+    diffusion: Optional[Diffusion] = None
 
     @property
     def depth_state(self) -> bool:
@@ -1168,6 +1351,21 @@ class _Block(nn.Module):
     def __call__(self, x, seg_bt, positions, state=()):
         net = self.net
         norm = net.norm
+        if net.diffusion is not None and (
+            self.kind.latent, self.kind.eva, self.kind.delta, self.kind.cca,
+            self.kind.window,
+        ) != (None,) * 5:
+            raise ValueError(
+                "block diffusion is built for softmax kinds with dense "
+                "projections and no window"
+            )
+        if self.kind.qk_norm and (
+            self.kind.latent, self.kind.eva, self.kind.delta, self.kind.cca,
+        ) != (None,) * 4:
+            raise ValueError(
+                "qk_norm is the dense projections' kind's: the others "
+                "normalise their queries and keys themselves, or not at all"
+            )
         if self.kind.delta is not None:
             if (self.kind.latent, self.kind.eva, self.kind.window,
                     self.kind.rope) != (None,) * 4 or self.kind.output_gate:
@@ -1205,7 +1403,8 @@ class _Block(nn.Module):
             attention = _Attention(
                 self.kind, net.num_heads, net.num_kv_heads, net.head_dim,
                 net.attention_backend, net.attention_block,
-                net.compute_dtype, name="attn",
+                net.compute_dtype, net.rms_norm_eps, net.norm_unit_offset,
+                net.diffusion, name="attn",
             )
         else:
             attention = _LatentAttention(
@@ -1457,6 +1656,10 @@ class DecoderLM(nn.Module):
     # The head is the embedding, transposed: one matrix over the rows
     # held, which takes both gradients; no ``head`` leaf.
     tie_embeddings: bool = False
+    # Block diffusion: ``obs`` is ``{"tokens", "reveal_step"}``, ``done``
+    # lies on the step axis, and the stack runs over the clean sequence and
+    # its masked copies (module docstring).
+    diffusion: Optional[Diffusion] = None
 
     def _sizes(self) -> _Sizes:
         return _Sizes(
@@ -1466,18 +1669,70 @@ class DecoderLM(nn.Module):
             self.rms_norm_eps, jnp.dtype(self.compute_dtype),
             self.attention_backend, self.attention_block, self.router,
             self.shared_expert_size, self.intermediate_size, self.residual,
-            self.norm_unit_offset,
+            self.norm_unit_offset, self.diffusion,
         )
+
+    def _copies(self, obs, done):
+        """The rows a call under block diffusion runs over: the ids the
+        ``1 + S`` copies read ``[(1 + S) L, B]``, each token's reveal step
+        with block ``N``'s as 0 ``[L, B]``, the attention's ids ``[B, L]``
+        and the rows' positions."""
+        spec = self.diffusion
+        tokens = obs["tokens"].astype(jnp.int32)
+        reveal = obs["reveal_step"].astype(jnp.int32)
+        L = tokens.shape[0]
+        blocks = L // spec.block - 1
+        if L % spec.block or blocks < 1 or reveal.shape != tokens.shape or (
+            done.shape[0] != spec.steps * blocks + 1
+        ):
+            raise ValueError(
+                f"block diffusion reads tokens and reveal_step [L, B] with "
+                f"L = {spec.block} (N + 1) and done [{spec.steps} N + 1, B]:"
+                f" tokens {tokens.shape}, reveal_step {reveal.shape}, done "
+                f"{done.shape}"
+            )
+        acted = (jnp.arange(L) < L - spec.block)[:, None]
+        rows = jnp.concatenate([tokens] + [
+            jnp.where(
+                jnp.logical_and(reveal < tau, acted), tokens, spec.mask_id
+            )
+            for tau in range(spec.steps)
+        ])
+        return (rows, jnp.where(acted, reveal, 0),
+                blockdiff_ids(done, L, spec),
+                jnp.tile(jnp.arange(L), 1 + spec.steps))
 
     @nn.compact
     def __call__(self, obs, done, core_state):
-        T = obs.shape[0]
-        obs = obs.astype(jnp.int32)
         embed = nn.Embed(
             self.vocab_size, self.hidden_size, dtype=self.compute_dtype,
             name="embed",
         )
-        x = embedded = embed(obs)
+        if self.diffusion is None:
+            T = obs.shape[0]
+            obs = obs.astype(jnp.int32)
+            x = embedded = embed(obs)
+            seg_bt = segment_ids_from_done(done)
+            positions = jnp.arange(T)
+        else:
+            if (self.mtp is not None or self.num_pred_heads > 1
+                    or self.residual is not None or core_state):
+                raise ValueError(
+                    "block diffusion is built for one stream with a plain "
+                    "sum, one prediction head and no carried state"
+                )
+            with jax.named_scope("moolib.lm.blockdiff_rows"):
+                rows, scored_in, seg_bt, positions = self._copies(obs, done)
+                x = embed(rows)
+            T = scored_in.shape[0]  # the tokens: the head's rows
+            counters = blockdiff_counts(
+                seg_bt, obs["reveal_step"].astype(jnp.int32), self.diffusion
+            )
+            # every block reads the same pairs
+            counters["blockdiff_pairs"] *= sum(
+                (repeat or [1])[0] for _, _, *repeat in self.layers
+            )
+            self.sow("intermediates", "blockdiff_counters", counters)
         if self.residual not in (None, "scaled") and not isinstance(
             self.residual, Residual
         ):
@@ -1502,8 +1757,6 @@ class DecoderLM(nn.Module):
                 )
             # every stream starts as the token's embedding
             x = jnp.broadcast_to(x, (self.residual.streams,) + x.shape)
-        seg_bt = segment_ids_from_done(done)
-        positions = jnp.arange(T)
         if sizes.depth_state:
             # the stack's first router reads no state of a layer before it
             x = (x, jnp.zeros(
@@ -1542,10 +1795,23 @@ class DecoderLM(nn.Module):
         head = embed.attend if self.tie_embeddings else _dense(
             "head", heads * self.vocab_size, self.compute_dtype
         )
+        if self.diffusion is not None:
+            with jax.named_scope("moolib.lm.blockdiff_rows"):
+                # a token's row is that of the copy it was revealed from
+                copies = x.reshape((-1, T) + x.shape[1:])
+                x = copies[1]
+                for tau in range(1, self.diffusion.steps):
+                    x = jnp.where(
+                        (scored_in == tau)[..., None], copies[1 + tau], x
+                    )
         hidden = x
         with jax.named_scope("moolib.lm.head"):
             x = sizes.norm("final_norm")(x)
-            logits = head(x).astype(jnp.float32)
+            # under block diffusion the last block is the bootstrap
+            # frame's: it has values and no logits
+            scored = x if self.diffusion is None else (
+                x[:T - self.diffusion.block])
+            logits = head(scored).astype(jnp.float32)
             baseline = nn.Dense(1, name="baseline")(
                 x.astype(jnp.float32)
             ).squeeze(-1)
@@ -1623,14 +1889,15 @@ class DecoderLM(nn.Module):
 
 
 def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
-               mtp=None, residual=None, **kwargs) -> DecoderLM:
+               mtp=None, residual=None, diffusion=None,
+               **kwargs) -> DecoderLM:
     """A :class:`DecoderLM` from JSON-shaped arguments: ``layers`` a list
     of ``{"attention": kind, "mlp": "sparse" | "dense"}``, an entry with
     ``"repeat": n`` standing for ``n`` identical blocks run as a scan;
     ``attention_kinds`` a mapping ``kind -> {"window": int or null,
     "rope": {...} or null, "latent": {...} or absent, "eva": {...} or
     absent, "output_gate": bool or absent, "delta": {...} or absent,
-    "cca": {...} or absent}``
+    "cca": {...} or absent, "qk_norm": bool or absent}``
     whose ``rope`` holds the fields of :class:`Rope` (null: no position
     encoding; ``partial_rotary_factor`` the share of a head it turns),
     whose ``latent`` those of :class:`Latent`, whose ``eva``
@@ -1644,7 +1911,8 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
     ``layers``; ``residual`` the fields of :class:`Residual`, or
     ``"scaled"`` (absent: the skeleton with one stream and a plain sum);
     ``tie_embeddings`` (a keyword like the other sizes) makes the head
-    the embedding."""
+    the embedding; ``diffusion`` the fields of :class:`Diffusion` (absent:
+    a causal decoder whose action is the next token)."""
     kinds = tuple(
         (name, AttentionKind(
             spec.get("window"),
@@ -1654,6 +1922,7 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
             spec.get("output_gate", False),
             Delta(**spec["delta"]) if spec.get("delta") else None,
             Cca(**spec["cca"]) if spec.get("cca") else None,
+            spec.get("qk_norm", False),
         ))
         for name, spec in sorted(attention_kinds.items())
     )
@@ -1671,6 +1940,7 @@ def decoder_lm(*, layers, attention_kinds, experts_held=None, router=None,
         residual=residual if not isinstance(residual, dict) else Residual(
             **dict(residual, res_clamp=tuple(residual["res_clamp"]))
         ),
+        diffusion=None if diffusion is None else Diffusion(**diffusion),
         **kwargs,
     )
 
@@ -1691,7 +1961,7 @@ def _sum_counters(intermediates) -> dict:
         if name in total:
             total[name] = total[name] / layers
     for name in ("mtp_loss", "eva_local_pairs", "kda_state_resets",
-                 "cca_taps_cut", "router_state_rms"):
+                 "cca_taps_cut", "router_state_rms", "blockdiff_rows"):
         for sown in sown_dicts(intermediates, name):
             total.update(sown)
     # the delta rule's gauges, an element a block: the worst, and the mean
@@ -1740,8 +2010,10 @@ def router_loads(net: DecoderLM) -> Callable:
     benchmark's seeding reads it)."""
 
     def loads(params, obs, done):
+        # obs may be a dict of [.., B] leaves (block diffusion)
+        columns = jax.tree_util.tree_leaves(obs)[0].shape[1]
         _, inter = net.apply(
-            params, obs, done, net.initial_state(obs.shape[1]),
+            params, obs, done, net.initial_state(columns),
             mutable=["intermediates"],
         )
         blocks = inter["intermediates"]

@@ -44,6 +44,16 @@ other shape, dtype and backend is ``"plain"``: ``[20, 256, 6]``, ``[80, 128,
 for every reader already. The target logits come from the head in a layout
 XLA reads well and need a gradient: they stay :func:`action_log_probs`.
 
+**An action that is a set of tokens** (:func:`from_grouped_logits`). A
+denoising step of a block-diffusion language model reveals several tokens at
+once: the step is the action, its probability the product of its tokens'
+probabilities under both policies, its entropy their entropies' sum, and the
+recursion above runs over *steps*, fewer than tokens and of uneven size. The
+logits and the chosen entries then lie on a token axis, rewards, discounts and
+values on a step axis, and ``action_step`` ``[tokens, B]`` says which step a
+token belongs to (:func:`group_sum`: one segment sum a column, whatever the
+order of the tokens).
+
 Definitions (paper eq. 1):
     delta_t = rho_t (r_t + gamma_t V(x_{t+1}) - V(x_t))
     v_t     = V(x_t) + delta_t + gamma_t c_t (v_{t+1} - V(x_{t+1}))
@@ -65,8 +75,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..telemetry import global_telemetry
 
-__all__ = ["VTraceReturns", "VTraceFromLogitsReturns", "from_importance_weights",
-           "from_logits", "action_log_probs", "action_logprob_path",
+__all__ = ["VTraceReturns", "VTraceFromLogitsReturns",
+           "VTraceFromGroupedLogitsReturns", "from_importance_weights",
+           "from_logits", "from_grouped_logits", "group_sum",
+           "action_log_probs", "action_logprob_path",
            "streamed_action_log_probs"]
 
 LANES, SUBLANES = 128, 8
@@ -83,6 +95,15 @@ class VTraceFromLogitsReturns(NamedTuple):
     log_rhos: jax.Array
     behavior_action_log_probs: jax.Array
     target_action_log_probs: jax.Array
+
+
+class VTraceFromGroupedLogitsReturns(NamedTuple):
+    vs: jax.Array  # [steps, B]
+    pg_advantages: jax.Array  # [steps, B]
+    log_rhos: jax.Array  # [steps, B]: a step's tokens' log-ratios, summed
+    target_action_log_probs: jax.Array  # [steps, B]: log pi of the set
+    entropies: jax.Array  # [steps, B]: the tokens' entropies, summed
+    values: jax.Array  # [steps, B]: the tokens' values, averaged
 
 
 def action_log_probs(policy_logits: jax.Array, actions: jax.Array) -> jax.Array:
@@ -278,6 +299,26 @@ def from_importance_weights(
     return VTraceReturns(vs=vs, pg_advantages=pg_advantages)
 
 
+def _behavior_pass(behavior_policy_logits):
+    """The function that computes ``log mu(a|x)`` of these logits, which
+    need no gradient: :func:`action_logprob_path`'s choice, on record."""
+    # Inside a shard_map no cell's logits are large, the pass has not run
+    # there on a chip and Pallas' interpreter cannot (its grid loop drops
+    # the varying axes, jax 0.9.0): plain, whatever the shape.
+    path = "plain" if jax.typeof(behavior_policy_logits).vma else (
+        action_logprob_path(
+            behavior_policy_logits.shape, behavior_policy_logits.dtype
+        )
+    )
+    # once a trace: once a compile under jit
+    global_telemetry().registry.counter(
+        "vtrace_logprob_calls_traced_total", path=path
+    ).inc()
+    return (
+        streamed_action_log_probs if path == "streamed" else action_log_probs
+    )
+
+
 def from_logits(
     behavior_policy_logits: jax.Array,
     target_policy_logits: jax.Array,
@@ -291,21 +332,7 @@ def from_logits(
     lambda_: float = 1.0,
 ) -> VTraceFromLogitsReturns:
     """V-trace for softmax policies: [T, B, A] logits, [T, B] actions."""
-    # Inside a shard_map no cell's logits are large, the pass has not run
-    # there on a chip and Pallas' interpreter cannot (its grid loop drops
-    # the varying axes, jax 0.9.0): plain, whatever the shape.
-    path = "plain" if jax.typeof(behavior_policy_logits).vma else (
-        action_logprob_path(
-            behavior_policy_logits.shape, behavior_policy_logits.dtype
-        )
-    )
-    # once a trace: once a compile under jit
-    global_telemetry().registry.counter(
-        "vtrace_logprob_calls_traced_total", path=path
-    ).inc()
-    behavior = (
-        streamed_action_log_probs if path == "streamed" else action_log_probs
-    )
+    behavior = _behavior_pass(behavior_policy_logits)
     with jax.named_scope("moolib.vtrace"):
         behavior_log_probs = behavior(behavior_policy_logits, actions)
         target_log_probs = action_log_probs(target_policy_logits, actions)
@@ -326,4 +353,87 @@ def from_logits(
             log_rhos=log_rhos,
             behavior_action_log_probs=behavior_log_probs,
             target_action_log_probs=target_log_probs,
+        )
+
+
+def group_sum(x: jax.Array, action_step: jax.Array, steps: int) -> jax.Array:
+    """``x`` ``[tokens, B, ...]`` summed over the tokens of each step:
+    ``out[u, b] = sum of x[i, b] over the i with action_step[i, b] == u``,
+    ``[steps, B, ...]``. The tokens of a step may lie anywhere on the token
+    axis; a step that no token names is zero; a token whose step is not in
+    ``[0, steps)`` is dropped."""
+    B = x.shape[1]
+    flat = (action_step.astype(jnp.int32) * B + jnp.arange(B)).reshape(-1)
+    flat = jnp.where(
+        jnp.logical_and(action_step >= 0, action_step < steps).reshape(-1),
+        flat, steps * B,
+    )
+    out = jax.ops.segment_sum(
+        x.reshape((-1,) + x.shape[2:]), flat, num_segments=steps * B
+    )
+    return out.reshape((steps, B) + x.shape[2:])
+
+
+def from_grouped_logits(
+    behavior_policy_logits: jax.Array,
+    target_policy_logits: jax.Array,
+    actions: jax.Array,
+    action_step: jax.Array,
+    token_values: jax.Array,
+    discounts: jax.Array,
+    rewards: jax.Array,
+    bootstrap_value: jax.Array,
+    clip_rho_threshold: float | None = 1.0,
+    clip_pg_rho_threshold: float | None = 1.0,
+    lambda_: float = 1.0,
+) -> VTraceFromGroupedLogitsReturns:
+    """V-trace where an action is a *set* of categorical choices (the
+    module docstring): logits ``[tokens, B, A]`` and ``actions``,
+    ``action_step`` and ``token_values`` ``[tokens, B]`` on the token axis;
+    ``discounts`` and ``rewards`` ``[steps, B]`` and ``bootstrap_value``
+    ``[B]`` on the step axis. With ``G(u)`` the tokens of step ``u``:
+
+        log rho_u = sum over G(u) of (log pi(a_i|x_i) - log mu(a_i|x_i))
+        log pi_u  = sum over G(u) of log pi(a_i|x_i)
+        H_u       = sum over G(u) of H(pi(.|x_i))
+        V_u       = mean over G(u) of token_values_i
+
+    and :func:`from_importance_weights` over the steps: the ratios are
+    clipped a step, not a token. The behaviour side is
+    :func:`action_logprob_path`'s pass, as in :func:`from_logits`. A step
+    without a token has ``log rho`` 0, ``H`` 0 and the value 0."""
+    steps = discounts.shape[0]
+    behavior = _behavior_pass(behavior_policy_logits)
+    with jax.named_scope("moolib.vtrace"):
+        behavior_log_probs = behavior(behavior_policy_logits, actions)
+        logp = jax.nn.log_softmax(target_policy_logits, axis=-1)
+        target_log_probs = jnp.take_along_axis(
+            logp, actions[..., None], axis=-1
+        ).squeeze(-1)
+        entropies = -jnp.sum(jnp.exp(logp) * logp, axis=-1)
+        # one segment sum of the five token quantities, a row a token
+        log_rhos, log_pi, entropy, value_sum, count = jnp.moveaxis(group_sum(
+            jnp.stack([
+                target_log_probs - behavior_log_probs, target_log_probs,
+                entropies, token_values, jnp.ones_like(token_values),
+            ], axis=-1), action_step, steps,
+        ), -1, 0)
+        values = value_sum / jnp.maximum(count, 1.0)
+        vt = from_importance_weights(
+            log_rhos=log_rhos,
+            discounts=discounts,
+            rewards=rewards,
+            values=values,
+            bootstrap_value=bootstrap_value,
+            clip_rho_threshold=clip_rho_threshold,
+            clip_pg_rho_threshold=clip_pg_rho_threshold,
+            lambda_=lambda_,
+        )
+        return VTraceFromGroupedLogitsReturns(
+            vs=vt.vs,
+            pg_advantages=vt.pg_advantages,
+            log_rhos=log_rhos,
+            target_action_log_probs=log_pi,
+            entropies=entropy,
+            values=values,
         )

@@ -114,6 +114,42 @@ def test_flash_kernels_compile_at_the_cells_shape(one_chip, no_compile_cache,
     assert f"bf16[{HKV},{tokens},{DV}]" in text.replace(" ", "")
 
 
+def test_block_diffusions_flash_call_compiles_at_the_cells_shape(
+        one_chip, no_compile_cache):
+    """``sdar_share8``'s one flash call a block: the three copies as 96
+    grouped query heads on the clean copy's 4 key/value heads of 128 over
+    8,192 tokens, ids as group and rank (``rank_bits`` 12, not causal), the
+    row statistics an output with a cotangent. A key/value head's 24 query
+    heads' dq (192 MiB) does not fit the backward's fast memory at once:
+    three rows of its grid hold 8 heads each, and it stays one kernel."""
+    from moolib_tpu.ops.attention import _dq_passes
+
+    tokens, H, HKV, D = T, 96, 4, 128
+    assert _dq_passes(H // HKV, tokens, D, 2) == 3
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, heads, tokens, D), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    seg = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=one_chip)
+
+    def step(q, k, v, seg):
+        def loss(q, k, v):
+            out, lse = flash_attention(
+                q, k, v, causal=False, segment_ids=seg, kv_segment_ids=seg,
+                rank_bits=12, block_q=BLOCK, block_k=BLOCK, return_lse=True,
+            )
+            return out.astype(jnp.float32).sum() + lse.sum()
+
+        return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(step).lower(
+        shape(H), shape(HKV), shape(HKV), seg
+    ).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert f"bf16[{H},{tokens},{D}]" in text.replace(" ", "")
+
+
 # mellum2_share8's expert layer (softmax top-8) by both products, and
 # glm47_flash_share8's (sigmoid top-4 with a selection bias, scaled gates)
 @pytest.mark.parametrize("grouped,d,f,rows,top_k,router", [

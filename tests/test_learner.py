@@ -300,3 +300,170 @@ def test_examples_thread_state_through_donating_apply(monkeypatch):
         "remote_actors must NOT donate: infer() reads params outside "
         "the lock concurrently with the train step"
     )
+
+
+# --------------------------------------- an action that is a set of tokens
+
+def _grouped(batch, action_step, steps):
+    """``batch`` [T] as a batch whose token axis is its own: the steps'
+    leaves cut to ``steps`` frames, the grouping beside the actions."""
+    return dict(
+        batch, action_step=jnp.asarray(action_step, jnp.int32),
+        done=batch["done"][:steps + 1], rewards=batch["rewards"][:steps + 1],
+    )
+
+
+def _token_apply(net, tokens: int):
+    """``apply_fn`` in the grouped contract from the A2C net (no state, so
+    ``done`` is not read): logits of the first ``tokens`` rows, the
+    token-actions', and a baseline of every row."""
+    def apply(p, obs, done, core_state):
+        (logits, baseline), state = net.apply(
+            p, obs, jnp.zeros(obs.shape[:2], bool), core_state)
+        return (logits[:tokens], baseline), state
+
+    return apply
+
+
+def test_one_token_a_step_is_the_loss_there_was(net_and_params, rng):
+    """Every step holding exactly one token, in order, with one bootstrap
+    row: the grouped loss is today's loss on the same numbers, to float32
+    rounding (1e-6: the entropy is summed token by token and then averaged
+    where the other form averages once, and a step's value is a sum over
+    one token divided by a count of one)."""
+    net, params = net_and_params
+    batch = dict(make_batch(rng), done=jnp.zeros((T + 1, B), bool))
+    plain, m_plain = impala_loss(params, net.apply, batch, ImpalaConfig())
+    one_each = _grouped(batch, np.tile(np.arange(T)[:, None], (1, B)), T)
+    grouped, m_grouped = impala_loss(
+        params, _token_apply(net, T), one_each, ImpalaConfig())
+    np.testing.assert_allclose(grouped, plain, rtol=1e-6, atol=1e-6)
+    for name in ("pg_loss", "baseline_loss", "entropy", "mean_baseline"):
+        np.testing.assert_allclose(
+            m_grouped[name], m_plain[name], rtol=1e-6, atol=1e-6)
+
+
+def test_uneven_groups_against_the_equations_in_numpy(net_and_params, rng):
+    """Steps of one to three tokens that lie in any order on the token
+    axis, two bootstrap rows: the loss against a NumPy transcription of
+    its definition (sums of log-probabilities and entropies and means of
+    values over a step's tokens, ratios clipped a step, the recursion as a
+    backward loop)."""
+    net, params = net_and_params
+    steps, tokens, cfg = 4, T - 1, ImpalaConfig()  # T + 1 rows: two last
+    batch = make_batch(rng)
+    # the tokens of steps 0..3, shuffled a column: sizes 1, 3, 2, 1
+    step = np.stack([
+        rng.permutation(np.repeat(np.arange(steps), [1, 3, 2, 1]))
+        for _ in range(B)
+    ], axis=1)
+    grouped = dict(
+        _grouped(batch, step, steps), actions=batch["actions"][:tokens],
+        behavior_logits=jax.random.normal(
+            jax.random.PRNGKey(5), (tokens, B, A)),
+    )
+
+    apply = _token_apply(net, tokens)
+    total, metrics = impala_loss(params, apply, grouped, cfg)
+    (logits, values), _ = apply(params, grouped["obs"], None, ())
+    logits, values = np.asarray(logits, np.float64), np.asarray(
+        values, np.float64)
+    logp = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
+    mu = np.asarray(grouped["behavior_logits"], np.float64)
+    logmu = mu - np.log(np.exp(mu).sum(-1, keepdims=True))
+    a = np.asarray(grouped["actions"])
+    take = lambda x: np.take_along_axis(x, a[..., None], -1)[..., 0]  # noqa
+    lp, lm = take(logp), take(logmu)
+    H = -(np.exp(logp) * logp).sum(-1)
+    log_rho, log_pi, H_u, V = (np.zeros((steps, B)) for _ in range(4))
+    for b in range(B):
+        for u in range(steps):
+            G = step[:, b] == u
+            log_rho[u, b] = (lp - lm)[G, b].sum()
+            log_pi[u, b], H_u[u, b] = lp[G, b].sum(), H[G, b].sum()
+            V[u, b] = values[:tokens][G, b].mean()
+    V_n = values[tokens:].mean(0)
+    r = np.clip(np.asarray(grouped["rewards"], np.float64)[1:], -1, 1)
+    g = (~np.asarray(grouped["done"])[1:]) * cfg.discounting
+    rho = np.exp(log_rho)
+    V_next = np.concatenate([V[1:], V_n[None]])
+    delta = np.minimum(rho, 1) * (r + g * V_next - V)
+    acc, vs = np.zeros(B), np.zeros((steps, B))
+    for u in reversed(range(steps)):
+        acc = delta[u] + g[u] * np.minimum(rho[u], 1) * acc
+        vs[u] = V[u] + acc
+    vs_next = np.concatenate([vs[1:], V_n[None]])
+    adv = np.minimum(rho, 1) * (r + g * vs_next - V)
+    want = (-(log_pi * adv).mean() + cfg.baseline_cost * 0.5 * (
+        (vs - V) ** 2).mean() - cfg.entropy_cost * H_u.mean())
+    np.testing.assert_allclose(total, want, rtol=2e-5)
+    np.testing.assert_allclose(metrics["entropy"], H_u.mean(), rtol=2e-5)
+    # and the gradient flows to every parameter through the group sums
+    grads = jax.grad(lambda p: impala_loss(p, apply, grouped, cfg)[0])(params)
+    assert all(float(jnp.max(jnp.abs(x))) > 0
+               for x in jax.tree_util.tree_leaves(grads))
+
+
+def test_a_batch_without_the_grouping_never_reaches_its_code(
+        net_and_params, rng, monkeypatch):
+    """The nine learner cells' batches carry no ``action_step``: with the
+    grouping's functions replaced by ones that raise, the IMPALA net's
+    loss and a decoder's (``mellum2_tiny``'s shape: next-token actions,
+    causal kinds) trace and run as before."""
+    from moolib_tpu.models.lm import decoder_lm, learn_apply
+    from moolib_tpu.ops import vtrace
+
+    def never(*args, **kwargs):
+        raise AssertionError("the grouping's code was reached")
+
+    monkeypatch.setattr(vtrace, "from_grouped_logits", never)
+    monkeypatch.setattr(vtrace, "group_sum", never)
+    net, params = net_and_params
+    loss, _ = impala_loss(params, net.apply, make_batch(rng), ImpalaConfig())
+    assert np.isfinite(float(loss))
+    lm_net = decoder_lm(
+        vocab_size=64, hidden_size=32,
+        layers=[{"attention": "sliding", "mlp": "sparse"},
+                {"attention": "full", "mlp": "sparse"}],
+        attention_kinds={
+            "sliding": {"window": 8, "rope": {"theta": 500000.0}},
+            "full": {"window": None, "rope": {"theta": 500000.0}},
+        },
+        num_heads=4, num_kv_heads=1, head_dim=16, num_experts=8, top_k=2,
+        moe_intermediate_size=24, experts_held=[2, 4],
+    )
+    obs = jax.random.randint(jax.random.PRNGKey(1), (T + 1, 2), 0, 64)
+    batch = {
+        "obs": obs, "done": jnp.zeros((T + 1, 2), bool).at[3].set(True),
+        "rewards": jnp.ones((T + 1, 2)), "actions": obs[1:],
+        "behavior_logits": jnp.zeros((T, 2, 64)), "core_state": (),
+    }
+    lm_params = lm_net.init(jax.random.PRNGKey(0), obs, batch["done"], ())
+    loss, metrics = jax.jit(
+        lambda p, b: impala_loss(p, learn_apply(lm_net), b, ImpalaConfig())
+    )(lm_params, batch)
+    assert np.isfinite(float(loss)) and "moe_assignments_held" in metrics
+
+
+@pytest.mark.parametrize("leaf,shape,says", [
+    ("actions", (T - 1, B), r"actions \(7, 16\)"),
+    ("rewards", (T, B), r"rewards \(8, 16\)"),
+    ("behavior_logits", (T + 1, B, A), r"behavior_logits \(9, 16, 3\)"),
+    ("done", (T + 2, B), r"done \(10, 16\)"),
+    ("action_step", (T, B), r"action_step \(8, 16\)"),
+])
+def test_leaves_that_disagree_on_their_axes_say_so(net_and_params, rng, leaf,
+                                                   shape, says):
+    """A batch whose leaves disagree on their axes' lengths fails where
+    the loss is traced, with the lengths in the message, and not as a
+    broadcast."""
+    net, params = net_and_params
+    batch = make_batch(rng)
+    apply = net.apply
+    if leaf == "action_step":  # T token-actions need a baseline of T + K
+        batch = dict(batch, obs=batch["obs"][:T])
+    batch[leaf] = jnp.zeros(shape, batch.get(leaf, jnp.zeros((), jnp.int32)
+                                             ).dtype)
+    with pytest.raises(ValueError, match=says):
+        jax.jit(lambda p, b: impala_loss(p, apply, b, ImpalaConfig()))(
+            params, batch)

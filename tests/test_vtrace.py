@@ -382,3 +382,62 @@ def test_the_rule_sends_each_cells_logits_its_way(shape, path, monkeypatch,
     assert vtrace.action_logprob_path(shape, jnp.float32) == path
     assert vtrace.action_logprob_path(shape, jnp.bfloat16) == "plain"
     assert traces() == {path: 1}
+
+
+# --------------------------------------- an action that is a set of tokens
+
+def test_group_sum_is_a_segment_sum_a_column():
+    """Tokens of a step anywhere on the token axis, a step no token names,
+    a token whose step lies outside: sums a column, zeros, dropped."""
+    x = jnp.arange(12, dtype=jnp.float32).reshape(6, 2)
+    step = jnp.asarray([[2, 0], [0, 0], [2, 3], [0, 9], [3, -1], [2, 3]])
+    got = vtrace.group_sum(x, step, 4)
+    want = np.zeros((4, 2), np.float32)
+    for i in range(6):
+        for b in range(2):
+            if 0 <= int(step[i, b]) < 4:
+                want[int(step[i, b]), b] += float(x[i, b])
+    np.testing.assert_array_equal(got, want)
+    wide = vtrace.group_sum(jnp.stack([x, 2 * x], -1), step, 4)
+    np.testing.assert_array_equal(wide[..., 1], 2 * want)
+
+
+def test_grouped_logits_on_policy_and_clipped_a_step():
+    """On policy every step's log-ratio is 0 whatever its size; off policy
+    the ratio that is clipped is the step's product, not the tokens': two
+    tokens at ratios 2 and 1/4 make a step at 1/2, which a clip a token
+    (1 and 1/4) would read as 1/4."""
+    N, B, A, steps = 6, 1, 4, 3
+    rng = np.random.default_rng(0)
+    logits = jnp.asarray(rng.normal(size=(N, B, A)), jnp.float32)
+    actions = jnp.asarray(rng.integers(0, A, (N, B)))
+    step = jnp.asarray([[0], [1], [1], [2], [2], [2]])
+    common = dict(
+        actions=actions, action_step=step,
+        token_values=jnp.zeros((N, B)), discounts=jnp.full((steps, B), 0.9),
+        rewards=jnp.ones((steps, B)), bootstrap_value=jnp.zeros((B,)),
+    )
+    on = vtrace.from_grouped_logits(logits, logits, **common)
+    np.testing.assert_allclose(on.log_rhos, 0.0, atol=1e-6)
+    logp = jax.nn.log_softmax(logits, -1)
+    want = vtrace.group_sum(
+        jnp.take_along_axis(logp, actions[..., None], -1)[..., 0], step,
+        steps)
+    np.testing.assert_allclose(on.target_action_log_probs, want, atol=1e-6)
+    # off policy: the ratio that is clipped is the step's product
+    behaviour = jnp.asarray(rng.normal(size=(N, B, A)), jnp.float32)
+    off = vtrace.from_grouped_logits(behaviour, logits, **common)
+    take = lambda x: jnp.take_along_axis(  # noqa: E731
+        jax.nn.log_softmax(x, -1), actions[..., None], -1)[..., 0]
+    token_log_rhos = np.asarray(take(logits) - take(behaviour))
+    np.testing.assert_allclose(
+        off.log_rhos[:, 0],
+        [token_log_rhos[0, 0], token_log_rhos[1:3, 0].sum(),
+         token_log_rhos[3:, 0].sum()], atol=1e-5)
+    a_step = np.minimum(1.0, np.exp(np.asarray(off.log_rhos)))
+    a_token = np.asarray(vtrace.group_sum(
+        jnp.minimum(0.0, token_log_rhos), step, steps))
+    assert np.abs(a_step - np.exp(a_token)).max() > 1e-2  # the two differ
+    vs_next = np.concatenate([np.asarray(off.vs)[1:], np.zeros((1, B))])
+    np.testing.assert_allclose(
+        off.pg_advantages, a_step * (1.0 + 0.9 * vs_next), rtol=1e-5)
